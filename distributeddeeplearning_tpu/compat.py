@@ -1,111 +1,25 @@
-"""Version portability shims for the narrow band of JAX APIs this codebase
-uses that moved (or did not exist yet) across the JAX releases the repo runs
-under — the container pins one JAX, real chip sessions may pin another.
+"""``shard_map`` as this codebase uses it: replication checking OFF.
 
-Two groups:
-
-- **Renames/moves** (``shard_map``, ``use_mesh``, ``get_abstract_mesh``,
-  ``typeof``, Pallas ``CompilerParams``): resolve the newest-API name first,
-  fall back to the older spelling, never change behavior.
-- **Replication-check semantics** (``shard_map``'s ``check_vma`` /
-  ``check_rep``): the explicit-DP train path (train/steps.py) performs every
-  cross-shard reduction EXPLICITLY through parallel/collectives.py — the
-  whole point of the bucketed all-reduce is owning the grad-sync schedule —
-  so the automatic psum that replication-checked autodiff inserts for
-  replicated inputs must be OFF. ``shard_map`` here therefore always
-  disables the check: per-shard values stay local until code psums them.
-  With the check off ``pvary`` is semantically a no-op, so its shim is
-  identity on versions that lack it.
+The explicit-DP train path (train/steps.py) performs every cross-shard
+reduction EXPLICITLY through parallel/collectives.py — the whole point of
+the bucketed all-reduce is owning the grad-sync schedule — so the automatic
+psum that replication-checked autodiff inserts for replicated inputs must be
+off: per-shard values stay local until code psums them. Written for the one
+installation the repo runs on (jax 0.9: ``jax.shard_map(check_vma=...)``).
 """
 
 from __future__ import annotations
-
-from typing import Any
 
 import jax
 
 
 def shard_map(f, *, mesh=None, in_specs, out_specs):
-    """``jax.shard_map`` across API generations, replication checking OFF.
+    """``jax.shard_map`` with ``check_vma=False``; ``mesh=None`` uses the
+    ambient mesh (parallel/mesh.use_mesh).
 
     Callers own their collectives: gradients/metrics/statistics that must
     agree across shards are explicitly ``psum``/``pmean``-ed (train/steps.py,
     parallel/collectives.py), so no output relies on inferred replication.
-
-    ``mesh=None`` uses the ambient mesh (``use_mesh``) — on older JAX, where
-    shard_map has no ambient-mesh resolution, it is looked up explicitly.
     """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None and mesh is None:
-        mesh = get_abstract_mesh()
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:  # a jax.shard_map generation before check_vma
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def pvary(x, axis_names):
-    """``jax.lax.pvary`` where it exists; identity elsewhere.
-
-    Only meaningful under varying-manual-axes checking, which
-    :func:`shard_map` above disables — the call is kept so the code reads
-    identically to the checked form and survives a future re-enable.
-    """
-    fn = getattr(jax.lax, "pvary", None)
-    return x if fn is None else fn(x, axis_names)
-
-
-def axis_size(axis_names):
-    """``jax.lax.axis_size`` where it exists; the classic ``psum(1, axis)``
-    idiom elsewhere (constant-folds to the mesh axis size inside manual
-    collectives)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_names)
-    return jax.lax.psum(1, axis_names)
-
-
-def typeof(x) -> Any:
-    """``jax.typeof`` (new) / ``jax.core.get_aval`` (old).
-
-    Consumers only getattr optional attributes (e.g. ``.vma``) off the
-    result, so the old aval — which simply lacks them — is a valid stand-in.
-    """
-    fn = getattr(jax, "typeof", None)
-    if fn is not None:
-        return fn(x)
-    return jax.core.get_aval(x)
-
-
-def get_abstract_mesh():
-    """The ambient mesh set by ``use_mesh`` (parallel/mesh.py), across the
-    ``jax.sharding.get_abstract_mesh`` rename. Falls back to the legacy
-    thread-resources physical mesh (what ``with mesh:`` sets); callers
-    treat an empty mesh (no axes) as "no ambient mesh"."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as mesh_lib
-
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
-def tpu_compiler_params(*, dimension_semantics: tuple[str, ...]):
-    """Pallas-TPU compiler params across the ``TPUCompilerParams`` →
-    ``CompilerParams`` rename; None (pallas_call's default) when neither
-    exists so interpret-mode-only environments still run."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:  # pragma: no cover - pallas always ships with jax
-        return None
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:  # pragma: no cover
-        return None
-    return cls(dimension_semantics=dimension_semantics)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -247,9 +247,11 @@ class TrainConfig:
     """Top-level run description — one per acceptance config."""
 
     model: str = "resnet50"
-    backend: str = "tpu"          # tpu | cpu (BASELINE.json:5); "cpu" forces
-                                  # the mesh onto host CPU devices even when
-                                  # an accelerator platform is active
+    backend: Optional[str] = None  # "tpu": TPUs or an error, never a
+                                  # quiet CPU run (the CLIs' default);
+                                  # "cpu": the host's CPU devices; None:
+                                  # JAX's devices as the process was
+                                  # started (parallel/mesh.backend_devices)
     global_batch_size: int = 32   # config 1 default (BASELINE.json:7)
     num_epochs: float = 90.0
     steps_per_epoch: Optional[int] = None  # derived from dataset if None
@@ -344,9 +346,6 @@ class TrainConfig:
     fused_bn: bool = False        # Pallas fused BN+ReLU kernels (CNNs)
     fused_block: bool = False     # conv-epilogue fusion: bottleneck 1x1
                                   # convs as Pallas matmul+BN (resnet50+)
-    fused_conv3: bool = False     # fused_block v2: stride-1 3x3 convs as
-                                  # Pallas conv+BN (ops/fused_conv_bn.py);
-                                  # requires fused_block
     sync_bn: bool = False         # cross-replica BN statistics (psum over
                                   # the data axis; torch SyncBatchNorm)
     optimizer_sharding: str = "none"  # none | zero1 | zero2 | zero3
@@ -368,19 +367,13 @@ class TrainConfig:
                                   # of one serialized pass after backward.
                                   # Off = A/B baseline; update math is
                                   # unchanged either way
-    opt_state_offload: bool = False  # place the sharded optimizer-state
-                                  # chunks in host RAM (pinned_host memory
-                                  # kind) instead of HBM. Needs runtime
-                                  # support (TPU); silently-loud no-op
-                                  # fallback elsewhere (docs/
-                                  # zero_sharding.md caveats)
-    compile_cache_dir: Optional[str] = None  # persistent compile cache + AOT
-                                  # step executables (perf/compile_cache.py):
-                                  # None = $DDL_COMPILE_CACHE, else the
-                                  # repo-local .cache/jax_compile default;
-                                  # "off" disables. Volatile w.r.t. the
-                                  # config fingerprint — it never changes
-                                  # the compiled program
+    compile_cache: bool = True    # persistent compile cache + AOT step
+                                  # executables; WHERE is decided from
+                                  # outside (perf/compile_cache.py:
+                                  # $JAX_COMPILATION_CACHE_DIR, else
+                                  # <repo>/.cache/jax_compile). Volatile
+                                  # w.r.t. the config fingerprint — it
+                                  # never changes the compiled program
     # GPipe microbatch count for *_pp models (None = model default). The
     # bubble wastes (P-1)/(M+P-1) of every stage-tick; M >= 4(P-1) keeps it
     # under ~20% (tools/bench_parallel_overhead.py measures this).

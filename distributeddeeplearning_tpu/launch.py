@@ -109,22 +109,8 @@ def maybe_initialize_distributed() -> Optional[int]:
             process_id=int(os.environ[ENV_PROCESS_ID]),
             num_processes=int(os.environ[ENV_NUM_PROCESSES]),
             coordinator=os.environ[ENV_COORDINATOR])
-        if spec.num_processes > 1:
-            # Multi-process on the CPU backend (virtual hosts: tests, the
-            # elastic soak, chaos bench) needs a real cross-process
-            # collectives transport. jaxlib's CPU client defaults to
-            # 'none' and then rejects ANY computation spanning processes
-            # ("Multiprocess computations aren't implemented on the CPU
-            # backend"); the option is config-only — jax never reads it
-            # from the environment — so exporting a var in the launcher
-            # cannot fix it. Gloo-over-TCP ships in jaxlib; turn it on
-            # before the first backend use. No-op on TPU (the option only
-            # affects CPU clients) and on jax builds without it.
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except (AttributeError, ValueError):
-                pass
+        # (Multi-process runs on the CPU backend — virtual hosts in tests,
+        # the elastic soak — ride jaxlib's gloo collectives, its default.)
         jax.distributed.initialize(
             coordinator_address=spec.coordinator,
             num_processes=spec.num_processes,
@@ -880,11 +866,18 @@ def _spawn_replica(replica: int, num_replicas: int, workdir: str, *,
     ranks of one mesh. ``trace_dir`` arms per-request tracing in the
     child (``DDL_TRACE_DIR``) — set per spawn, never on the supervisor's
     own environ, so a traced serve run cannot leak tracing into later
-    untraced children."""
+    untraced children.
+
+    One process per chip: replica ``i`` is given chip ``i`` of the host and
+    no other, through libtpu's own visibility settings, before the child
+    imports jax — without them every replica would open every chip and all
+    but the first would fail. The supervisor itself never touches jax. On a
+    CPU run (tests) the settings are inert."""
     env = dict(os.environ)
     env[ENV_PROCESS_ID] = str(replica)
     env[ENV_NUM_PROCESSES] = str(num_replicas)
     env.pop(ENV_COORDINATOR, None)
+    env.update(replica_chip_env(replica))
     if trace_dir is not None:
         env[telemetry.ENV_TRACE_DIR] = trace_dir
     else:
@@ -912,6 +905,15 @@ def _spawn_replica(replica: int, num_replicas: int, workdir: str, *,
                "distributeddeeplearning_tpu.serve.replica",
                "--workdir", workdir, "--replica", str(replica)]
     return subprocess.Popen(command, env=env)
+
+
+def replica_chip_env(replica: int) -> dict[str, str]:
+    """libtpu settings that show a process exactly one chip of its host:
+    the chip by index, and a 1x1x1 process topology so the runtime does not
+    wait for the host's other chips (nor take the whole-host lock)."""
+    return {"TPU_VISIBLE_CHIPS": str(replica),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
 
 
 def _dispatch_request(workdir: str, replica: int, attempt: int,
@@ -1095,7 +1097,8 @@ def run_serve(num_replicas: int, requests: Sequence[dict],
         reps.append({"proc": proc, "alive": True, "attempt": 0,
                      "restarts": 0, "ever_beat": False, "hung": False,
                      "last_step": 0, "offset": 0, "rc": None,
-                     "drained": False, "draining": False})
+                     "drained": False, "draining": False,
+                     "boots": []})
         flight.record("spawn", child=i, pid=proc.pid, scope="serve")
 
     redispatched = 0
@@ -1156,6 +1159,11 @@ def run_serve(num_replicas: int, requests: Sequence[dict],
                     st["failed"] = e.get("reason", "unknown")
             elif kind == "drained":
                 rep["drained"] = True
+            elif kind == "ready":
+                # One per boot: which device the replica holds and whether
+                # it warm-booted from the AOT cache (restarts append).
+                rep["boots"].append({k: e.get(k) for k in
+                                     ("attempt", "device", "aot")})
 
     def on_replica_death(rid: int, rc: int) -> None:
         nonlocal redispatched, total_restarts
@@ -1279,7 +1287,8 @@ def run_serve(num_replicas: int, requests: Sequence[dict],
                                  "attempt": 0, "restarts": 0,
                                  "ever_beat": False, "hung": False,
                                  "last_step": 0, "offset": 0, "rc": None,
-                                 "drained": False, "draining": False})
+                                 "drained": False, "draining": False,
+                     "boots": []})
                     scale_ups += 1
                     flight.record("spawn", child=rid, pid=proc.pid,
                                   scope="serve")
@@ -1346,6 +1355,16 @@ def run_serve(num_replicas: int, requests: Sequence[dict],
                             f.write("drain\n")
                 if not any(r["alive"] for r in reps):
                     break
+            elif not any(r["alive"] for r in reps):
+                # Every replica is gone and its restart budget with it:
+                # nothing can serve the open requests, so fail now instead
+                # of polling an empty fleet until the timeout.
+                raise RuntimeError(
+                    f"serve supervision: no replica left alive (exit codes "
+                    f"{[r['rc'] for r in reps]}, restart budget "
+                    f"{max_restarts} spent) with "
+                    f"{sum(1 for s in reqs.values() if not closed(s))} "
+                    f"request(s) open")
             if now - t0 > timeout_s:
                 raise RuntimeError(
                     f"serve supervision timed out after {timeout_s:.0f}s: "
@@ -1383,7 +1402,8 @@ def run_serve(num_replicas: int, requests: Sequence[dict],
     out = {"results": results, "redispatched": redispatched,
            "restarts": total_restarts, "window_s": window_s,
            "leak_check_ok": leak_check_ok,
-           "replica_rcs": {i: r["rc"] for i, r in enumerate(reps)}}
+           "replica_rcs": {i: r["rc"] for i, r in enumerate(reps)},
+           "replica_boots": {i: r["boots"] for i, r in enumerate(reps)}}
     if trace_dir is not None:
         if sup_tele is not None:
             sup_tele.export()
@@ -1543,11 +1563,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         "attribution/restart events — the crash-surviving "
                         "record tools/postmortem.py reads. Default: the "
                         "training command's own --flight-dir, else off")
-    p.add_argument("--compile-cache-dir", default=None,
-                   help="persistent compile cache shared by every child and "
-                        "every restart attempt (docs/compile_cache.md); "
-                        "default $DDL_COMPILE_CACHE or the repo-local "
-                        ".cache/jax_compile; 'off' disables")
     p.add_argument("--serve", default=None, metavar="REQUESTS.json",
                    help="serve mode: supervise --num-processes engine "
                         "replicas over this request trace (list of "
@@ -1597,22 +1612,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if not command:
         p.error("no training command given (pass it after `--`)")
 
-    # One compile cache for the whole job: resolve launcher flag > training
-    # command's own --compile-cache-dir > env > default, then export it so
-    # EVERY child of EVERY restart attempt lands on the same cache — a
-    # restarted attempt then loads the previous attempt's executables
-    # instead of recompiling (perf/compile_cache.py; jax-free here).
-    from distributeddeeplearning_tpu.perf import compile_cache
-    cache_flag = (args.compile_cache_dir
-                  if args.compile_cache_dir is not None
-                  else _flag_from_command(command, "--compile-cache-dir"))
-    cache_dir = compile_cache.resolve_dir(cache_flag)
-    if cache_dir is not None:
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-        except OSError:
-            cache_dir = None
-    compile_cache.export_env(cache_dir)
+    # One compile cache for the whole job needs no plumbing here: every
+    # child of every restart attempt inherits $JAX_COMPILATION_CACHE_DIR or
+    # computes the same repo default (perf/compile_cache.py), so a restarted
+    # attempt loads the previous attempt's executables.
 
     if args.hostfile:
         if args.process_id is None:

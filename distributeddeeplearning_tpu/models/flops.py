@@ -66,15 +66,23 @@ _HBM_BW_BY_KIND = (
 
 
 def _by_kind(table, device_kind: str) -> float | None:
+    """The table's value for a TPU ``device_kind``; None for a device that
+    is not a TPU (roofline fields are then absent, never computed from an
+    assumed peak). A TPU the table does not know is an error, not a
+    silently missing field: add its row, with its source."""
     kind = device_kind.lower()
     for sub, value in table:
         if sub in kind:
             return value
+    if "tpu" in kind:
+        raise ValueError(
+            f"no published peak for TPU device_kind {device_kind!r} in "
+            f"models/flops.py — add it to the tables with its source")
     return None
 
 
 def bf16_peak_flops(device_kind: str) -> float | None:
-    """Per-chip bf16 peak for a jax device_kind, or None if unknown."""
+    """Per-chip bf16 peak for a jax device_kind; None off TPU."""
     return _by_kind(_BF16_PEAK_BY_KIND, device_kind)
 
 
@@ -87,7 +95,8 @@ _F32_PEAK_DIVISOR = 6.0
 
 
 def peak_flops(device_kind: str, dtype: str = "bfloat16") -> float | None:
-    """Per-chip matmul peak for a compute dtype, or None if unknown.
+    """Per-chip matmul peak for a compute dtype; None off TPU, an error
+    for a TPU kind the table does not list.
 
     The dtype-aware roofline denominator (docs/perf_measurement.md): an
     fp32 arm is scored against the fp32 roof and a mixed/bf16 arm against
@@ -107,7 +116,7 @@ def peak_flops(device_kind: str, dtype: str = "bfloat16") -> float | None:
 
 
 def hbm_bw_bytes(device_kind: str) -> float | None:
-    """Per-chip HBM bandwidth (bytes/s), or None if unknown."""
+    """Per-chip HBM bandwidth (bytes/s); None off TPU."""
     return _by_kind(_HBM_BW_BY_KIND, device_kind)
 
 

@@ -78,13 +78,6 @@ class FusedBottleneckBlock(nn.Module):
     # path only). The epilogue sums are per-shard; syncing is two (C,)
     # pmeans per BN — negligible next to the gradient allreduce.
     axis_name: Any = None
-    # v2 coverage (VERDICT r4 Next #2): run the 3x3 conv itself as a
-    # Pallas kernel with bn1-apply in its prologue and bn2-statistics in
-    # its epilogue (ops/fused_conv_bn.py) — the a1 materialization and the
-    # bn2 stats pass disappear. Stride-2 blocks (3/16 in ResNet-50) keep
-    # the XLA conv path; the kernel is stride-1/pad-1 only.
-    conv3_fused: bool = False
-
     def _stats(self, s, ss, m: int):
         mean, ex2 = s / m, ss / m
         if self.axis_name is not None:
@@ -137,45 +130,30 @@ class FusedBottleneckBlock(nn.Module):
         self._update_running(rm1, rv1, mean1, var1)
         inv1 = jax.lax.rsqrt(var1 + eps)
 
-        if self.conv3_fused and self.strides == 1:
-            # v2: the 3x3 consumes RAW y1 — bn1's apply happens in the
-            # conv kernel's prologue and bn2's Σ/Σ² in its epilogue
-            # (ops/fused_conv_bn.py); neither a1 nor a stats pass touches
-            # HBM.
-            from distributeddeeplearning_tpu.ops.fused_conv_bn import (
-                bn_conv3x3_stats)
-            y2, s2, ss2 = bn_conv3x3_stats(
-                y1.reshape(b, h, w_sp, f), mean1, inv1, g1, b1,
-                w2k.astype(self.dtype), True, True)
-            h_out, w_out = y2.shape[1], y2.shape[2]
-            y2d = y2.reshape(-1, f)
-            m2 = y2d.shape[0]
-            mean2, var2 = self._stats(s2, ss2, m2)
-        else:
-            # bn1 apply must materialize (it feeds the XLA 3x3) — one
-            # elementwise pass, XLA-fused.
-            a1 = jnp.maximum(
-                (y1.astype(jnp.float32) - mean1) * (inv1 * g1) + b1, 0.0
-            ).astype(self.dtype).reshape(b, h, w_sp, f)
+        # bn1 apply must materialize (it feeds the XLA 3x3) — one
+        # elementwise pass, XLA-fused.
+        a1 = jnp.maximum(
+            (y1.astype(jnp.float32) - mean1) * (inv1 * g1) + b1, 0.0
+        ).astype(self.dtype).reshape(b, h, w_sp, f)
 
-            # conv2: XLA 3x3 (stride lives here, v1.5), raw output y2.
-            y2 = jax.lax.conv_general_dilated(
-                a1, w2k.astype(self.dtype),
-                window_strides=(self.strides, self.strides),
-                padding=[(1, 1), (1, 1)],
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                preferred_element_type=self.dtype)
-            # Output spatial dims come from the strided conv itself — with
-            # odd inputs ceil(h/2) != h//2, and the ::stride shortcut slice
-            # agrees with the conv, not with floor division.
-            h_out, w_out = y2.shape[1], y2.shape[2]
-            y2d = y2.reshape(-1, f)
-            m2 = y2d.shape[0]
-            # bn2 statistics: one XLA multi-output reduce over y2 (its
-            # apply pass is what conv3's prologue absorbs).
-            y2f = y2d.astype(jnp.float32)
-            mean2, var2 = self._stats(y2f.sum(axis=0),
-                                      (y2f * y2f).sum(axis=0), m2)
+        # conv2: XLA 3x3 (stride lives here, v1.5), raw output y2.
+        y2 = jax.lax.conv_general_dilated(
+            a1, w2k.astype(self.dtype),
+            window_strides=(self.strides, self.strides),
+            padding=[(1, 1), (1, 1)],
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            preferred_element_type=self.dtype)
+        # Output spatial dims come from the strided conv itself — with
+        # odd inputs ceil(h/2) != h//2, and the ::stride shortcut slice
+        # agrees with the conv, not with floor division.
+        h_out, w_out = y2.shape[1], y2.shape[2]
+        y2d = y2.reshape(-1, f)
+        m2 = y2d.shape[0]
+        # bn2 statistics: one XLA multi-output reduce over y2 (its
+        # apply pass is what conv3's prologue absorbs).
+        y2f = y2d.astype(jnp.float32)
+        mean2, var2 = self._stats(y2f.sum(axis=0),
+                                  (y2f * y2f).sum(axis=0), m2)
         self._update_running(rm2, rv2, mean2, var2)
         inv2 = jax.lax.rsqrt(var2 + eps)
 

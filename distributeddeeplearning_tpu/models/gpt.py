@@ -22,7 +22,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from distributeddeeplearning_tpu import compat
 from distributeddeeplearning_tpu.ops.embedding import embedding_lookup
 
 Dtype = Any
@@ -220,9 +219,8 @@ class GptLM(nn.Module):
         if cfg.attention_impl == "zigzag" and not decode:
             from distributeddeeplearning_tpu.parallel.ring_attention import (
                 zigzag_indices)
-            ambient = compat.get_abstract_mesh()
-            n_seq = (ambient.shape.get("seq", 1)
-                     if ambient is not None and not ambient.empty else 1)
+            ambient = jax.sharding.get_abstract_mesh()
+            n_seq = ambient.shape.get("seq", 1)
             if n_seq > 1:
                 if s % (2 * n_seq):
                     raise ValueError(

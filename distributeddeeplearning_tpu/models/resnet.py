@@ -101,10 +101,6 @@ class ResNet(nn.Module):
     # bn2's apply in conv3's prologue (models/fused_block.py). Bottleneck
     # nets only; variable-compatible with the unfused path.
     fused_block: bool = False
-    # fused_block v2 (ops/fused_conv_bn.py): additionally run stride-1 3x3
-    # convs as Pallas kernels with bn1-apply prologue + bn2-stats epilogue;
-    # requires fused_block. Stride-2 blocks keep the XLA conv.
-    fused_conv3: bool = False
     # Cross-replica BatchNorm (torch SyncBatchNorm semantics): mesh axis
     # name(s) to pmean the batch statistics over. Only meaningful inside
     # the shard_map DP train step, where those axes are bound; None keeps
@@ -157,10 +153,6 @@ class ResNet(nn.Module):
             raise ValueError("fused_block requires bottleneck blocks "
                              "(resnet50/101/152); basic blocks have no 1x1 "
                              "convolutions to fuse")
-        if self.fused_conv3 and not use_fused_block:
-            raise ValueError("fused_conv3 extends fused_block (the 3x3 "
-                             "kernel shares its statistics plumbing); pass "
-                             "fused_block=True on a bottleneck net")
         for i, num_blocks in enumerate(self.stage_sizes):
             for j in range(num_blocks):
                 strides = 2 if i > 0 and j == 0 else 1
@@ -171,7 +163,6 @@ class ResNet(nn.Module):
                     x = FusedBottleneckBlock(
                         filters=self.width * 2 ** i, strides=strides,
                         dtype=self.dtype, axis_name=self.bn_axis_name,
-                        conv3_fused=self.fused_conv3,
                         name=name)(x, train=train)
                 else:
                     x = self.block(filters=self.width * 2 ** i,
@@ -188,68 +179,61 @@ class ResNet(nn.Module):
 
 def resnet18(num_classes: int = 1000, dtype: Any = jnp.bfloat16,
             fused_bn: bool = False, fused_block: bool = False,
-            fused_conv3: bool = False,
             bn_axis_name: Any = None) -> ResNet:
     return ResNet([2, 2, 2, 2], BasicBlock, num_classes, dtype=dtype,
                   fused_bn=fused_bn, fused_block=fused_block,
-                  fused_conv3=fused_conv3, bn_axis_name=bn_axis_name)
+                  bn_axis_name=bn_axis_name)
 
 
 def resnet18_thin(num_classes: int = 1000, dtype: Any = jnp.bfloat16,
                   fused_bn: bool = False, fused_block: bool = False,
-            fused_conv3: bool = False,
             bn_axis_name: Any = None) -> ResNet:
     """Width-16 ResNet-18 (1/16th the conv FLOPs): the CPU-tractable stand-in
     for convergence-recipe demonstrations (tools/convergence_lars.py) and
     fast tests — same depth, blocks, and BN structure as the real thing."""
     return ResNet([2, 2, 2, 2], BasicBlock, num_classes, width=16,
                   dtype=dtype, fused_bn=fused_bn, fused_block=fused_block,
-                  fused_conv3=fused_conv3, bn_axis_name=bn_axis_name)
+                  bn_axis_name=bn_axis_name)
 
 
 def resnet26_thin(num_classes: int = 1000, dtype: Any = jnp.bfloat16,
                   fused_bn: bool = False, fused_block: bool = False,
-            fused_conv3: bool = False,
             bn_axis_name: Any = None) -> ResNet:
     """Width-16 bottleneck ResNet-26 ([2,2,2,2] Bottleneck): the
     CPU-tractable stand-in with the SAME block structure as resnet50 —
     what fused_block tests and bottleneck recipe demos run on."""
     return ResNet([2, 2, 2, 2], BottleneckBlock, num_classes, width=16,
                   dtype=dtype, fused_bn=fused_bn, fused_block=fused_block,
-                  fused_conv3=fused_conv3, bn_axis_name=bn_axis_name)
+                  bn_axis_name=bn_axis_name)
 
 
 def resnet34(num_classes: int = 1000, dtype: Any = jnp.bfloat16,
             fused_bn: bool = False, fused_block: bool = False,
-            fused_conv3: bool = False,
             bn_axis_name: Any = None) -> ResNet:
     return ResNet([3, 4, 6, 3], BasicBlock, num_classes, dtype=dtype,
                   fused_bn=fused_bn, fused_block=fused_block,
-                  fused_conv3=fused_conv3, bn_axis_name=bn_axis_name)
+                  bn_axis_name=bn_axis_name)
 
 
 def resnet50(num_classes: int = 1000, dtype: Any = jnp.bfloat16,
             fused_bn: bool = False, fused_block: bool = False,
-            fused_conv3: bool = False,
             bn_axis_name: Any = None) -> ResNet:
     return ResNet([3, 4, 6, 3], BottleneckBlock, num_classes, dtype=dtype,
                   fused_bn=fused_bn, fused_block=fused_block,
-                  fused_conv3=fused_conv3, bn_axis_name=bn_axis_name)
+                  bn_axis_name=bn_axis_name)
 
 
 def resnet101(num_classes: int = 1000, dtype: Any = jnp.bfloat16,
             fused_bn: bool = False, fused_block: bool = False,
-            fused_conv3: bool = False,
             bn_axis_name: Any = None) -> ResNet:
     return ResNet([3, 4, 23, 3], BottleneckBlock, num_classes, dtype=dtype,
                   fused_bn=fused_bn, fused_block=fused_block,
-                  fused_conv3=fused_conv3, bn_axis_name=bn_axis_name)
+                  bn_axis_name=bn_axis_name)
 
 
 def resnet152(num_classes: int = 1000, dtype: Any = jnp.bfloat16,
             fused_bn: bool = False, fused_block: bool = False,
-            fused_conv3: bool = False,
             bn_axis_name: Any = None) -> ResNet:
     return ResNet([3, 8, 36, 3], BottleneckBlock, num_classes, dtype=dtype,
                   fused_bn=fused_bn, fused_block=fused_block,
-                  fused_conv3=fused_conv3, bn_axis_name=bn_axis_name)
+                  bn_axis_name=bn_axis_name)

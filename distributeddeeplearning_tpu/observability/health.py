@@ -1,8 +1,8 @@
 """Heartbeat health: child-side writer, launcher-side staleness check.
 
 The launcher's fail-whole monitor (launch.py) only sees *exits* — a child
-that hangs (deadlocked collective, wedged data pipeline, remote-device
-tunnel gone quiet) keeps the whole job alive forever. Heartbeats close that
+that hangs (deadlocked collective, wedged data pipeline, a device that
+stopped answering) keeps the whole job alive forever. Heartbeats close that
 gap: every training process touches a per-rank file on its log cadence, and
 the launcher treats a heartbeat that stops aging as a hung child, kills it,
 and lets the existing attribution + restart machinery (PR 3) take over.

@@ -18,7 +18,7 @@ Design constraints, in order:
    *disabled* path is a true no-op: ``span()`` returns a shared do-nothing
    context manager (no allocation) and every record method returns after
    one attribute check, so an uninstrumented run pays a few nanoseconds
-   per call site (bounded by a tier-1 test and the gated chip_window A/B).
+   per call site (bounded by a tier-1 test).
 2. **Importable everywhere.** Pure stdlib: the launcher (which must never
    import jax — children own the accelerator) and robustness/faults.py
    record through the same API as the train loop.

@@ -34,19 +34,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from distributeddeeplearning_tpu import compat
 
 
 def _replicate_if_meshed(x):
     """with_sharding_constraint(x, P()) under an ambient mesh, identity
     otherwise (plain single-device unit tests run without a mesh)."""
-    try:
-        mesh = compat.get_abstract_mesh()
-        if mesh is None or not mesh.shape_tuple:
-            return x
-        return jax.lax.with_sharding_constraint(x, P())
-    except Exception:
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, P())
 
 
 @functools.lru_cache(maxsize=None)

@@ -17,14 +17,14 @@ logsumexp (the flash recurrence) in two kernels: dq (accumulated over the
 K-tile grid axis) and dk/dv (accumulated over the Q-tile grid axis); the
 revisited output blocks stay resident in VMEM across the accumulation axis.
 
-Kernels run compiled on TPU and in Pallas interpret mode elsewhere, so the
-CPU test mesh exercises the same code path (SURVEY.md §4).
+Kernels run compiled on TPU devices and in Pallas interpret mode elsewhere
+(ops/pallas.py decides, at lowering time), so the CPU test mesh exercises
+the same code path (SURVEY.md §4).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,12 +33,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from distributeddeeplearning_tpu import compat
 from distributeddeeplearning_tpu.ops.masks import block_causal_mask
+from distributeddeeplearning_tpu.ops.pallas import pallas_call
 
 _NEG = -1e30
-
-
-def _should_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 _PAD_GRANULE = 128  # TPU lane width; also the floor _block can return after
@@ -161,7 +158,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, o_ref, lse_ref,
             l[:, 0] > 0, m_scr[:][:, 0] + jnp.log(safe_l[:, 0]), 0.0)
 
 
-def _fwd(q, k, v, mask, seed, *, scale, block_q, block_k, interpret, causal,
+def _fwd(q, k, v, mask, seed, *, scale, block_q, block_k, causal,
          dropout_rate):
     # Rank-1-per-tile operands (mask, lse) ride as (BH, 1, S) so every block
     # shape is rank >= 2 with a compiled-lowering-legal tail: Mosaic requires
@@ -170,7 +167,7 @@ def _fwd(q, k, v, mask, seed, *, scale, block_q, block_k, interpret, causal,
     # first real-TPU run).
     bh, s, d = q.shape
     bq, bk = _block(s, block_q), _block(s, block_k)
-    out, lse = pl.pallas_call(
+    out, lse = pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate),
         grid=(bh, s // bq, s // bk),
@@ -194,9 +191,8 @@ def _fwd(q, k, v, mask, seed, *, scale, block_q, block_k, interpret, causal,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
     )(q, k, v, mask[:, None, :], seed)
     return out, lse.reshape(bh, s)
 
@@ -319,8 +315,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(scale, block_q, block_k, interpret, causal, dropout_rate,
-         residuals, g):
+def _bwd(scale, block_q, block_k, causal, dropout_rate, residuals, g):
     q, k, v, mask, seed, out, lse = residuals
     bh, s, d = q.shape
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
@@ -334,7 +329,7 @@ def _bwd(scale, block_q, block_k, interpret, causal, dropout_rate,
     maskk = pl.BlockSpec((1, 1, bk), lambda b, i, j: (b, 0, j))
     vec_q = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
 
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate),
         grid=(bh, s // bq, s // bk),
@@ -343,9 +338,8 @@ def _bwd(scale, block_q, block_k, interpret, causal, dropout_rate,
         out_specs=[q_tile],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
     )(q, k, v, mask3, g, lse3, delta3, seed)[0]
 
     # dk/dv: K tiles are the revisited outputs, Q is the accumulation axis
@@ -354,7 +348,7 @@ def _bwd(scale, block_q, block_k, interpret, causal, dropout_rate,
     k_out = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
     maskk2 = pl.BlockSpec((1, 1, bk), lambda b, j, i: (b, 0, j))
     vec_q2 = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i))
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate),
         grid=(bh, s // bk, s // bq),
@@ -365,26 +359,25 @@ def _bwd(scale, block_q, block_k, interpret, causal, dropout_rate,
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
     )(q, k, v, mask3, g, lse3, delta3, seed)
     return dq, dk, dv, None, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, mask, seed, scale, block_q, block_k, interpret, causal,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, mask, seed, scale, block_q, block_k, causal,
            dropout_rate):
     out, _ = _fwd(q, k, v, mask, seed, scale=scale, block_q=block_q,
-                  block_k=block_k, interpret=interpret, causal=causal,
+                  block_k=block_k, causal=causal,
                   dropout_rate=dropout_rate)
     return out
 
 
-def _flash_fwd(q, k, v, mask, seed, scale, block_q, block_k, interpret,
-               causal, dropout_rate):
+def _flash_fwd(q, k, v, mask, seed, scale, block_q, block_k, causal,
+               dropout_rate):
     out, lse = _fwd(q, k, v, mask, seed, scale=scale, block_q=block_q,
-                    block_k=block_k, interpret=interpret, causal=causal,
+                    block_k=block_k, causal=causal,
                     dropout_rate=dropout_rate)
     return out, (q, k, v, mask, seed, out, lse)
 
@@ -394,7 +387,6 @@ _flash.defvjp(_flash_fwd, _bwd)
 
 def flash_attention(q, k, v, kv_mask=None, *, block_q: int = 512,
                     block_k: int = 1024, causal: bool = False,
-                    interpret: Optional[bool] = None,
                     dropout_rate: float = 0.0, dropout_seed=None,
                     bh_offsets=None):
     """Fused attention with a key-padding mask; ``causal=True`` adds the
@@ -413,8 +405,6 @@ def flash_attention(q, k, v, kv_mask=None, *, block_q: int = 512,
     defaults to the unsharded identity.
     """
     b, s, h, d = q.shape
-    if interpret is None:
-        interpret = _should_interpret()
     if kv_mask is None:
         kv_mask = jnp.ones((b, s), jnp.int32)
     if dropout_rate > 0.0 and dropout_seed is None:
@@ -447,7 +437,7 @@ def flash_attention(q, k, v, kv_mask=None, *, block_q: int = 512,
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
     out = _flash(to_bh(q), to_bh(k), to_bh(v), kv_mask, seed,
-                 d ** -0.5, block_q, block_k, interpret, causal,
+                 d ** -0.5, block_q, block_k, causal,
                  float(dropout_rate))
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)[:, :s_orig]
 
@@ -470,8 +460,8 @@ def flash_attention_sharded(q, k, v, kv_mask=None, *,
     """
     from jax.sharding import PartitionSpec as P
 
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return flash_attention(q, k, v, kv_mask,
                                dropout_rate=dropout_rate,
                                dropout_seed=dropout_seed, **kw)

@@ -19,8 +19,8 @@ is far fewer:
 Every kernel reads bf16 activations and accumulates float32 in VMEM
 scratch, so numerics match the unfused float32-statistics BatchNorm to
 rounding (tests/test_fused_batchnorm.py asserts fwd+grads vs the flax
-composition). Kernels run compiled on TPU and in Pallas interpret mode
-elsewhere, same policy as ops/flash_attention.py.
+composition). Kernels run compiled on TPU devices and in Pallas interpret
+mode elsewhere (ops/pallas.py decides).
 
 The module :class:`FusedBatchNormAct` is variable-compatible with
 ``flax.linen.BatchNorm`` (params ``scale``/``bias``, batch_stats
@@ -35,7 +35,7 @@ mutable batch_stats, they are state updates, not differentiable outputs.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -43,39 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distributeddeeplearning_tpu import compat
-
-
-def _should_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _struct(shape, dtype, like):
-    """ShapeDtypeStruct carrying ``like``'s varying-mesh-axes (vma) type:
-    under shard_map with check_vma (the explicit-collective DP train step),
-    pallas_call outputs must declare how they vary across mesh axes — they
-    vary exactly as the activations they are computed from."""
-    vma = getattr(compat.typeof(like), "vma", None)
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _match_vma(ct, primal):
-    """Give a cotangent the primal's varying-mesh-axes type.
-
-    Under shard_map (the DP train step), activations vary over the data
-    axes while params are unvarying (replicated); the cotangent of an
-    unvarying input must itself be unvarying, which means summing the
-    per-shard contributions — exactly the psum that shard_map's AD inserts
-    when transposing the implicit broadcast in the unfused composition.
-    Outside shard_map both vma sets are empty and this is the identity."""
-    ct_vma = getattr(compat.typeof(ct), "vma", None) or frozenset()
-    primal_vma = getattr(compat.typeof(primal), "vma", None) or frozenset()
-    extra = tuple(sorted(ct_vma - primal_vma))
-    if extra:
-        ct = jax.lax.psum(ct, extra)
-    return ct
+from distributeddeeplearning_tpu.ops.pallas import pallas_call
 
 
 def _tile(size: int, target: int) -> int:
@@ -128,18 +96,6 @@ def _fold_sum(v, f: int):
     return v if f == 1 else v.reshape(f, -1).sum(axis=0)
 
 
-def _jnp_twin(x) -> bool:
-    """Use the jnp equivalent instead of a Pallas kernel: interpret mode
-    inside shard_map. Interpreted kernels inline into the traced program,
-    where their unvarying scratch-buffer inits collide with varying
-    operands under check_vma; the jnp twins are mathematically identical.
-    Compiled TPU kernels are opaque to vma tracking (only the declared
-    boundary types matter — see :func:`_struct`), so on hardware the
-    kernels always run."""
-    return (_should_interpret()
-            and bool(getattr(compat.typeof(x), "vma", None)))
-
-
 # ---------------------------------------------------------------------------
 # Forward: per-channel sum/sumsq in one pass over (M, C)
 # ---------------------------------------------------------------------------
@@ -162,29 +118,23 @@ def _stats_kernel(x_ref, sum_ref, sumsq_ref, s_scr, ss_scr):
         sumsq_ref[...] = ss_scr[...]
 
 
-def bn_stats(x2d: jax.Array, *, interpret: Optional[bool] = None):
+def bn_stats(x2d: jax.Array):
     """(M, C) -> (mean, var) per channel, float32, biased variance."""
     m_true, c_true = x2d.shape
-    if _jnp_twin(x2d):
-        xf = x2d.astype(jnp.float32)
-        mean = xf.mean(axis=0)
-        return mean, jnp.maximum((xf * xf).mean(axis=0) - mean * mean, 0.0)
     f = _fold_factor(m_true, c_true)
     x2d = _fold(x2d, f)
     m, c = x2d.shape
     tm, tc = _tile(m, 1024), _tile(c, 512)
-    interp = _should_interpret() if interpret is None else interpret
-    s, ss = pl.pallas_call(
+    s, ss = pallas_call(
         _stats_kernel,
         grid=(c // tc, m // tm),
         in_specs=[pl.BlockSpec((tm, tc), lambda ci, mi: (mi, ci))],
         out_specs=[pl.BlockSpec((1, tc), lambda ci, mi: (0, ci)),
                    pl.BlockSpec((1, tc), lambda ci, mi: (0, ci))],
-        out_shape=[_struct((1, c), jnp.float32, x2d),
-                   _struct((1, c), jnp.float32, x2d)],
+        out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
+                   jax.ShapeDtypeStruct((1, c), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((1, tc), jnp.float32),
                         pltpu.VMEM((1, tc), jnp.float32)],
-        interpret=interp,
     )(x2d)
     mean = _fold_sum(s[0], f) / m_true
     var = _fold_sum(ss[0], f) / m_true - mean * mean
@@ -206,16 +156,8 @@ def _apply_kernel(x_ref, mean_ref, inv_ref, gamma_ref, beta_ref, o_ref, *,
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def bn_apply(x2d, mean, inv, gamma, beta, residual2d=None, *, relu: bool,
-             interpret: Optional[bool] = None):
+def bn_apply(x2d, mean, inv, gamma, beta, residual2d=None, *, relu: bool):
     m_true, c_true = x2d.shape
-    if _jnp_twin(x2d):
-        y = (x2d.astype(jnp.float32) - mean) * (inv * gamma) + beta
-        if residual2d is not None:
-            y = y + residual2d.astype(jnp.float32)
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        return y.astype(x2d.dtype)
     f = _fold_factor(m_true, c_true)
     x2d = _fold(x2d, f)
     if residual2d is not None:
@@ -224,7 +166,6 @@ def bn_apply(x2d, mean, inv, gamma, beta, residual2d=None, *, relu: bool,
     gamma, beta = _tile_vec(gamma, f), _tile_vec(beta, f)
     m, c = x2d.shape
     tm, tc = _tile(m, 1024), _tile(c, 512)
-    interp = _should_interpret() if interpret is None else interpret
     vec = pl.BlockSpec((1, tc), lambda mi, ci: (0, ci))
     tile = pl.BlockSpec((tm, tc), lambda mi, ci: (mi, ci))
     operands = [x2d, mean[None], inv[None], gamma[None], beta[None]]
@@ -238,13 +179,12 @@ def bn_apply(x2d, mean, inv, gamma, beta, residual2d=None, *, relu: bool,
     else:
         def kernel(x, mn, iv, g, b, o):
             _apply_kernel(x, mn, iv, g, b, o, relu=relu)
-    return _unfold(pl.pallas_call(
+    return _unfold(pallas_call(
         kernel,
         grid=(m // tm, c // tc),
         in_specs=in_specs,
         out_specs=tile,
-        out_shape=_struct((m, c), x2d.dtype, x2d),
-        interpret=interp,
+        out_shape=jax.ShapeDtypeStruct((m, c), x2d.dtype),
     )(*operands), f)
 
 
@@ -279,15 +219,8 @@ def _bwd_reduce_kernel(dy_ref, x_ref, mean_ref, inv_ref,
         dgamma_ref[...] = dg_scr[...]
 
 
-def bn_bwd_reduce(dy2d, y2d, x2d, mean, inv, *, relu: bool,
-                  interpret: Optional[bool] = None):
+def bn_bwd_reduce(dy2d, y2d, x2d, mean, inv, *, relu: bool):
     m_true, c_true = x2d.shape
-    if _jnp_twin(x2d):
-        dz = dy2d.astype(jnp.float32)
-        if relu:
-            dz = jnp.where(y2d.astype(jnp.float32) > 0, dz, 0.0)
-        xh = (x2d.astype(jnp.float32) - mean) * inv
-        return dz.sum(axis=0), (dz * xh).sum(axis=0)
     f = _fold_factor(m_true, c_true)
     dy2d, x2d = _fold(dy2d, f), _fold(x2d, f)
     if relu:
@@ -295,7 +228,6 @@ def bn_bwd_reduce(dy2d, y2d, x2d, mean, inv, *, relu: bool,
     mean, inv = _tile_vec(mean, f), _tile_vec(inv, f)
     m, c = x2d.shape
     tm, tc = _tile(m, 1024), _tile(c, 512)
-    interp = _should_interpret() if interpret is None else interpret
     vec = pl.BlockSpec((1, tc), lambda ci, mi: (0, ci))
     tile = pl.BlockSpec((tm, tc), lambda ci, mi: (mi, ci))
     operands = [dy2d, x2d, mean[None], inv[None]]
@@ -310,16 +242,15 @@ def bn_bwd_reduce(dy2d, y2d, x2d, mean, inv, *, relu: bool,
     else:
         def kernel(dy, x, mn, iv, db_o, dg_o, db_s, dg_s):
             _bwd_reduce_kernel(dy, x, mn, iv, db_o, dg_o, db_s, dg_s)
-    db, dg = pl.pallas_call(
+    db, dg = pallas_call(
         kernel,
         grid=(c // tc, m // tm),
         in_specs=in_specs,
         out_specs=[vec, vec],
-        out_shape=[_struct((1, c), jnp.float32, x2d),
-                   _struct((1, c), jnp.float32, x2d)],
+        out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
+                   jax.ShapeDtypeStruct((1, c), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((1, tc), jnp.float32),
                         pltpu.VMEM((1, tc), jnp.float32)],
-        interpret=interp,
     )(*operands)
     return _fold_sum(db[0], f), _fold_sum(dg[0], f)
 
@@ -343,17 +274,8 @@ def _bwd_dx_kernel(dy_ref, x_ref, mean_ref, inv_ref, c1_ref, c2_ref,
 
 
 def bn_bwd_dx(dy2d, y2d, x2d, mean, inv, gamma, dbeta, dgamma, *,
-              relu: bool, want_dres: bool,
-              interpret: Optional[bool] = None):
+              relu: bool, want_dres: bool):
     m_true, c_true = x2d.shape
-    if _jnp_twin(x2d):
-        dz = dy2d.astype(jnp.float32)
-        if relu:
-            dz = jnp.where(y2d.astype(jnp.float32) > 0, dz, 0.0)
-        xh = (x2d.astype(jnp.float32) - mean) * inv
-        dx = (gamma * inv) * (dz - dbeta / m_true - xh * (dgamma / m_true))
-        return (dx.astype(x2d.dtype),
-                dz.astype(x2d.dtype) if want_dres else None)
     f = _fold_factor(m_true, c_true)
     dy2d, x2d = _fold(dy2d, f), _fold(x2d, f)
     if relu:
@@ -364,7 +286,6 @@ def bn_bwd_dx(dy2d, y2d, x2d, mean, inv, gamma, dbeta, dgamma, *,
     mean, inv = _tile_vec(mean, f), _tile_vec(inv, f)
     m, c = x2d.shape
     tm, tc = _tile(m, 1024), _tile(c, 512)
-    interp = _should_interpret() if interpret is None else interpret
     vec = pl.BlockSpec((1, tc), lambda mi, ci: (0, ci))
     tile = pl.BlockSpec((tm, tc), lambda mi, ci: (mi, ci))
     operands = [dy2d, x2d, mean[None], inv[None], c1[None], c2[None],
@@ -373,10 +294,10 @@ def bn_bwd_dx(dy2d, y2d, x2d, mean, inv, gamma, dbeta, dgamma, *,
     if relu:
         operands.append(y2d)
         in_specs.append(tile)
-    out_shape = [_struct((m, c), x2d.dtype, x2d)]
+    out_shape = [jax.ShapeDtypeStruct((m, c), x2d.dtype)]
     out_specs = [tile]
     if want_dres:
-        out_shape.append(_struct((m, c), x2d.dtype, x2d))
+        out_shape.append(jax.ShapeDtypeStruct((m, c), x2d.dtype))
         out_specs.append(tile)
     n_in = len(operands)
 
@@ -387,13 +308,12 @@ def bn_bwd_dx(dy2d, y2d, x2d, mean, inv, gamma, dbeta, dgamma, *,
         _bwd_dx_kernel(dy, x, mn, iv, a, b, d, outs[0], y_ref=y,
                        dres_ref=outs[1] if want_dres else None)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(m // tm, c // tc),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interp,
     )(*operands)
     return ((_unfold(out[0], f), _unfold(out[1], f)) if want_dres
             else (_unfold(out[0], f), None))
@@ -434,8 +354,8 @@ def _bn_act_bwd(relu, eps, saved, cots):
     dbeta, dgamma = bn_bwd_reduce(dy, y, x2d, mean, inv, relu=relu)
     dx, _ = bn_bwd_dx(dy, y, x2d, mean, inv, gamma.astype(jnp.float32),
                       dbeta, dgamma, relu=relu, want_dres=False)
-    return (dx, _match_vma(dgamma.astype(gamma.dtype), gamma),
-            _match_vma(dbeta.astype(gamma.dtype), gamma))
+    return (dx, dgamma.astype(gamma.dtype),
+            dbeta.astype(gamma.dtype))
 
 
 bn_act_train.defvjp(_bn_act_fwd, _bn_act_bwd)
@@ -460,8 +380,8 @@ def _bn_act_res_bwd(relu, eps, saved, cots):
     dbeta, dgamma = bn_bwd_reduce(dy, y, x2d, mean, inv, relu=relu)
     dx, dres = bn_bwd_dx(dy, y, x2d, mean, inv, gamma.astype(jnp.float32),
                          dbeta, dgamma, relu=relu, want_dres=True)
-    return (dx, _match_vma(dgamma.astype(gamma.dtype), gamma),
-            _match_vma(dbeta.astype(gamma.dtype), gamma), dres)
+    return (dx, dgamma.astype(gamma.dtype),
+            dbeta.astype(gamma.dtype), dres)
 
 
 bn_act_res_train.defvjp(_bn_act_res_fwd, _bn_act_res_bwd)

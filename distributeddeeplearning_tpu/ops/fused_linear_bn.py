@@ -36,22 +36,20 @@ Statistics are taken over y as stored (bf16) so they match exactly what
 the next layer's prologue will normalize.
 
 All kernels read bf16, accumulate float32 (MXU preferred_element_type and
-VMEM scratch), and run in interpret mode off-TPU with jnp twins under
-shard_map's check_vma — same policy as ops/fused_batchnorm.py.
+VMEM scratch), and run compiled on TPU devices, in interpret mode elsewhere
+(ops/pallas.py decides).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distributeddeeplearning_tpu.ops.fused_batchnorm import (
-    _jnp_twin, _match_vma, _should_interpret, _struct, _tile)
+from distributeddeeplearning_tpu.ops.fused_batchnorm import _tile
+from distributeddeeplearning_tpu.ops.pallas import pallas_call
 
 
 def _tiles(m: int, k: int, n: int):
@@ -101,30 +99,27 @@ def _fwd_kernel(x_ref, w_ref, mu_ref, inv_ref, g_ref, b_ref,
         ss_ref[...] = ss_scr[...]
 
 
-def _fwd(x, mu, inv, gamma, beta, w, relu, bn,
-         interpret: Optional[bool] = None):
+def _fwd(x, mu, inv, gamma, beta, w, relu, bn):
     m, k = x.shape
     n = w.shape[1]
     tm, tk, tn = _tiles(m, k, n)
     nk = k // tk
-    interp = _should_interpret() if interpret is None else interpret
     xs = pl.BlockSpec((tm, tk), lambda ni, mi, ki: (mi, ki))
     ws = pl.BlockSpec((tk, tn), lambda ni, mi, ki: (ki, ni))
     vk = pl.BlockSpec((1, tk), lambda ni, mi, ki: (0, ki))
     ys = pl.BlockSpec((tm, tn), lambda ni, mi, ki: (mi, ni))
     vn = pl.BlockSpec((1, tn), lambda ni, mi, ki: (0, ni))
-    y, s, ss = pl.pallas_call(
+    y, s, ss = pallas_call(
         functools.partial(_fwd_kernel, relu=relu, bn=bn, nk=nk),
         grid=(n // tn, m // tm, nk),
         in_specs=[xs, ws, vk, vk, vk, vk],
         out_specs=[ys, vn, vn],
-        out_shape=[_struct((m, n), x.dtype, x),
-                   _struct((1, n), jnp.float32, x),
-                   _struct((1, n), jnp.float32, x)],
+        out_shape=[jax.ShapeDtypeStruct((m, n), x.dtype),
+                   jax.ShapeDtypeStruct((1, n), jnp.float32),
+                   jax.ShapeDtypeStruct((1, n), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32),
                         pltpu.VMEM((1, tn), jnp.float32),
                         pltpu.VMEM((1, tn), jnp.float32)],
-        interpret=interp,
     )(x, w, mu[None], inv[None], gamma[None], beta[None])
     return y, s[0], ss[0]
 
@@ -180,30 +175,27 @@ def _bwd_dx_kernel(dy_ref, y_ref, ds_ref, dss_ref, w_ref, x_ref,
         dg_ref[...] = dg_scr[...]
 
 
-def _bwd_dx(dy, y, ds, dss, w, x, mu, inv, gamma, beta, relu, bn,
-            interpret: Optional[bool] = None):
+def _bwd_dx(dy, y, ds, dss, w, x, mu, inv, gamma, beta, relu, bn):
     m, k = x.shape
     n = w.shape[1]
     tm, tk, tn = _tiles(m, k, n)
     nn = n // tn
-    interp = _should_interpret() if interpret is None else interpret
     dys = pl.BlockSpec((tm, tn), lambda ki, mi, ni: (mi, ni))
     ws = pl.BlockSpec((tk, tn), lambda ki, mi, ni: (ki, ni))
     xs = pl.BlockSpec((tm, tk), lambda ki, mi, ni: (mi, ki))
     vn = pl.BlockSpec((1, tn), lambda ki, mi, ni: (0, ni))
     vk = pl.BlockSpec((1, tk), lambda ki, mi, ni: (0, ki))
-    dx, db, dg = pl.pallas_call(
+    dx, db, dg = pallas_call(
         functools.partial(_bwd_dx_kernel, relu=relu, bn=bn, nn=nn),
         grid=(k // tk, m // tm, nn),
         in_specs=[dys, dys, vn, vn, ws, xs, vk, vk, vk, vk],
         out_specs=[xs, vk, vk],
-        out_shape=[_struct((m, k), x.dtype, x),
-                   _struct((1, k), jnp.float32, x),
-                   _struct((1, k), jnp.float32, x)],
+        out_shape=[jax.ShapeDtypeStruct((m, k), x.dtype),
+                   jax.ShapeDtypeStruct((1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((1, k), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((tm, tk), jnp.float32),
                         pltpu.VMEM((1, tk), jnp.float32),
                         pltpu.VMEM((1, tk), jnp.float32)],
-        interpret=interp,
     )(dy, y, ds[None], dss[None], w, x, mu[None], inv[None],
       gamma[None], beta[None])
     return dx, db[0], dg[0]
@@ -243,45 +235,30 @@ def _bwd_dw_kernel(x_ref, mu_ref, inv_ref, g_ref, b_ref,
         dw_ref[...] = acc[...].astype(dw_ref.dtype)
 
 
-def _bwd_dw(x, mu, inv, gamma, beta, dy, y, ds, dss, relu, bn,
-            interpret: Optional[bool] = None):
+def _bwd_dw(x, mu, inv, gamma, beta, dy, y, ds, dss, relu, bn):
     m, k = x.shape
     n = dy.shape[1]
     tm, tk, tn = _tiles(m, k, n)
     nm = m // tm
-    interp = _should_interpret() if interpret is None else interpret
     xs = pl.BlockSpec((tm, tk), lambda ki, ni, mi: (mi, ki))
     dys = pl.BlockSpec((tm, tn), lambda ki, ni, mi: (mi, ni))
     vk = pl.BlockSpec((1, tk), lambda ki, ni, mi: (0, ki))
     vn = pl.BlockSpec((1, tn), lambda ki, ni, mi: (0, ni))
     ws = pl.BlockSpec((tk, tn), lambda ki, ni, mi: (ki, ni))
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_bwd_dw_kernel, relu=relu, bn=bn, nm=nm),
         grid=(k // tk, n // tn, nm),
         in_specs=[xs, vk, vk, vk, vk, dys, dys, vn, vn],
         out_specs=ws,
-        out_shape=_struct((k, n), dy.dtype, x),
+        out_shape=jax.ShapeDtypeStruct((k, n), dy.dtype),
         scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
-        interpret=interp,
     )(x, mu[None], inv[None], gamma[None], beta[None], dy, y,
       ds[None], dss[None])
 
 
 # ---------------------------------------------------------------------------
-# jnp twin (interpret-under-shard_map contexts) and the public custom-VJP op
+# The public custom-VJP op
 # ---------------------------------------------------------------------------
-
-def _twin_fwd(x, mu, inv, gamma, beta, w, relu, bn):
-    a = x
-    if bn:
-        af = (x.astype(jnp.float32) - mu) * (inv * gamma) + beta
-        if relu:
-            af = jnp.maximum(af, 0.0)
-        a = af.astype(x.dtype)
-    y = jnp.dot(a, w, preferred_element_type=jnp.float32).astype(x.dtype)
-    yf = y.astype(jnp.float32)
-    return y, yf.sum(axis=0), (yf * yf).sum(axis=0)
-
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def bn_linear_stats(x, mu, inv, gamma, beta, w, relu: bool = True,
@@ -293,69 +270,27 @@ def bn_linear_stats(x, mu, inv, gamma, beta, w, relu: bool = True,
     shape used for matmuls whose input is already materialized, keeping
     only the statistics epilogue. Returns ``(y, s, ss)``.
     """
-    y, s, ss = _fwd_any(x, mu, inv, gamma, beta, w, relu, bn)
-    return y, s, ss
-
-
-def _fwd_any(x, mu, inv, gamma, beta, w, relu, bn):
-    if _jnp_twin(x):
-        return _twin_fwd(x, mu, inv, gamma, beta, w, relu, bn)
     return _fwd(x, mu, inv, gamma, beta, w, relu, bn)
 
 
 def _vjp_fwd(x, mu, inv, gamma, beta, w, relu, bn):
-    y, s, ss = _fwd_any(x, mu, inv, gamma, beta, w, relu, bn)
+    y, s, ss = _fwd(x, mu, inv, gamma, beta, w, relu, bn)
     return (y, s, ss), (x, mu, inv, gamma, beta, w, y)
 
 
 def _vjp_bwd(relu, bn, saved, cots):
     x, mu, inv, gamma, beta, w, y = saved
     dy, ds, dss = cots
-    if _jnp_twin(x):
-        dx, db, dg, dw = _twin_bwd(dy, ds, dss, x, mu, inv, gamma, beta,
-                                   w, y, relu, bn)
-    else:
-        dx, db, dg = _bwd_dx(dy, y, ds, dss, w, x, mu, inv, gamma, beta,
-                             relu, bn)
-        dw = _bwd_dw(x, mu, inv, gamma, beta, dy, y, ds, dss, relu, bn)
-    dw = _match_vma(dw, w)  # w is replicated under DP; psum its cotangent
+    dx, db, dg = _bwd_dx(dy, y, ds, dss, w, x, mu, inv, gamma, beta,
+                         relu, bn)
+    dw = _bwd_dw(x, mu, inv, gamma, beta, dy, y, ds, dss, relu, bn)
     if not bn:
         zero = jnp.zeros_like(mu)
         return (dx, zero, zero, zero, zero, dw)
     dmu = -gamma * inv * db
     dinv = gamma * dg / inv
-    return (dx,
-            _match_vma(dmu, mu), _match_vma(dinv, inv),
-            _match_vma(dg.astype(gamma.dtype), gamma),
-            _match_vma(db.astype(beta.dtype), beta),
+    return (dx, dmu, dinv, dg.astype(gamma.dtype), db.astype(beta.dtype),
             dw)
-
-
-def _twin_bwd(dy, ds, dss, x, mu, inv, gamma, beta, w, y, relu, bn):
-    yf = y.astype(jnp.float32)
-    dyf = dy.astype(jnp.float32) + ds + 2.0 * yf * dss
-    da = jnp.dot(dyf.astype(dy.dtype), w.T,
-                 preferred_element_type=jnp.float32)
-    if bn:
-        xh = (x.astype(jnp.float32) - mu) * inv
-        dzl = da
-        if relu:
-            z = xh * gamma + beta
-            dzl = jnp.where(z > 0, da, 0.0)
-        dx = (dzl * (gamma * inv)).astype(x.dtype)
-        db = dzl.sum(axis=0)
-        dg = (dzl * xh).sum(axis=0)
-        af = xh * gamma + beta
-        if relu:
-            af = jnp.maximum(af, 0.0)
-        a = af.astype(x.dtype)
-    else:
-        dx = da.astype(x.dtype)
-        db = dg = jnp.zeros_like(mu)
-        a = x
-    dw = jnp.dot(a.T, dyf.astype(dy.dtype),
-                 preferred_element_type=jnp.float32).astype(dy.dtype)
-    return dx, db, dg, dw
 
 
 bn_linear_stats.defvjp(_vjp_fwd, _vjp_bwd)

@@ -30,8 +30,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from distributeddeeplearning_tpu import compat
-
 # Odd 32-bit constants for the coordinate combine (golden-ratio family) and
 # the murmur3 finalizer multipliers.
 _C_ROW = 0x9E3779B9
@@ -61,9 +59,11 @@ def keep_mask(seed, bh, rows, cols, rate: float):
          ^ cols.astype(jnp.uint32) * jnp.uint32(_C_COL)
          ^ bh.astype(jnp.uint32) * jnp.uint32(_C_BH))
     h = _mix32(h ^ lax.convert_element_type(seed, jnp.uint32))
-    # uniform in [0, 1): keep iff u >= rate  =>  P(keep) = 1 - rate.
-    u = h.astype(jnp.float32) * jnp.float32(2.0 ** -32)
-    return u >= jnp.float32(rate)
+    # Integer threshold on the hash: keep iff h >= rate·2^32, so
+    # P(keep) = 1 - rate. All-integer on purpose — Mosaic has no
+    # uint32 -> float32 cast, and an integer compare realizes the same mask
+    # compiled and interpreted, in every kernel and the dense reference.
+    return h >= jnp.uint32(min(int(rate * 2.0 ** 32), 2 ** 32 - 1))
 
 
 def dense_keep_mask(seed, b: int, h: int, s_q: int, s_k: int, rate: float):
@@ -86,9 +86,9 @@ def shard_bh_offsets(batch_axes, head_axis: str, b_local: int,
 
     b_idx = jnp.int32(0)
     for ax in batch_axes:
-        b_idx = b_idx * compat.axis_size(ax) + lax.axis_index(ax)
+        b_idx = b_idx * lax.axis_size(ax) + lax.axis_index(ax)
     return (b_idx * b_local, lax.axis_index(head_axis) * h_local,
-            h_local * compat.axis_size(head_axis))
+            h_local * lax.axis_size(head_axis))
 
 
 def seed_from_key(key):

@@ -60,13 +60,6 @@ def _numel(shape: Sequence[int]) -> int:
     return n
 
 
-def _path_str(path) -> str:
-    keystr = getattr(jax.tree_util, "keystr", None)
-    if keystr is not None:
-        return keystr(path)
-    return "/".join(str(k) for k in path)  # pragma: no cover - old jax
-
-
 @dataclasses.dataclass(frozen=True)
 class BucketPlan:
     """A deterministic leaf -> fusion-bucket assignment for ONE tree shape.
@@ -115,7 +108,7 @@ def plan_buckets(tree, bucket_bytes: Optional[int] = None) -> BucketPlan:
     if bucket_bytes is None:
         bucket_bytes = int(DEFAULT_BUCKET_MB * _MB)
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    paths = tuple(_path_str(p) for p, _ in flat)
+    paths = tuple(jax.tree_util.keystr(p) for p, _ in flat)
     if len(set(paths)) != len(paths):  # pragma: no cover - pytrees keys are
         raise ValueError("duplicate leaf paths in gradient tree")  # unique
     shapes = tuple(tuple(leaf.shape) for _, leaf in flat)

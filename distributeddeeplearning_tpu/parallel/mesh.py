@@ -29,24 +29,41 @@ MESH_AXES: tuple[str, ...] = (
     "pipeline", "data", "fsdp", "expert", "seq", "model")
 
 
+def backend_devices(backend: Optional[str] = None) -> list[jax.Device]:
+    """The devices a run on ``backend`` is placed on.
+
+    ``"tpu"`` means TPUs: it raises when JAX's devices are anything else,
+    instead of quietly training on the CPU and exiting 0. ``"cpu"`` forces
+    the host's CPU devices (tests, ``--backend cpu``). ``None`` takes JAX's
+    default devices as they are (library callers that placed the process
+    themselves, e.g. with ``JAX_PLATFORMS``).
+    """
+    if backend not in (None, "tpu", "cpu"):
+        raise ValueError(f"unknown backend {backend!r} (tpu | cpu)")
+    if backend == "cpu":
+        return jax.devices("cpu")
+    devices = jax.devices()
+    if backend == "tpu" and devices[0].platform != "tpu":
+        raise RuntimeError(
+            f"backend 'tpu' was asked for but JAX found only "
+            f"{devices[0].platform} devices; run on a machine with a TPU, "
+            f"or say --backend cpu (JAX_PLATFORMS=cpu) to mean the CPU")
+    return devices
+
+
 def make_mesh(parallel: ParallelConfig,
               devices: Optional[Sequence[jax.Device]] = None,
               backend: Optional[str] = None) -> Mesh:
-    """Build a Mesh matching ``parallel``'s axis sizes.
-
-    ``backend="cpu"`` forces the mesh onto the host's CPU devices even when
-    an accelerator platform is active — the library-level counterpart of
-    ``train.py --backend=cpu`` (BASELINE.json:5), so
-    ``TrainConfig(backend="cpu")`` works from Python too. ``backend="tpu"``
-    (the default) uses the ambient platform's devices, matching the CLI's
-    env-var dispatch.
+    """Build a Mesh matching ``parallel``'s axis sizes on ``devices``, or on
+    :func:`backend_devices` of ``backend`` (``TrainConfig.backend``, the
+    library-level counterpart of ``train.py --backend``).
 
     Uses ``mesh_utils.create_device_mesh`` on real TPU platforms so the mesh
     axes align with the physical ICI torus; falls back to a reshape for CPU
     test devices (where topology is fake anyway).
     """
     if devices is None:
-        devices = jax.devices("cpu") if backend == "cpu" else jax.devices()
+        devices = backend_devices(backend)
     sizes = parallel.axis_sizes()
     shape = tuple(sizes[a] for a in MESH_AXES)
     n = int(np.prod(shape))
@@ -133,16 +150,9 @@ def data_parallel_degree(parallel: ParallelConfig) -> int:
 
 
 def use_mesh(mesh: Mesh):
-    """Ambient-mesh context manager, across jax API renames.
-
-    Needed so ``with_sharding_constraint``/flax logical constraints can
-    resolve bare PartitionSpecs during tracing. Newest name first; on JAX
-    generations predating both ``use_mesh`` and ``set_mesh`` the Mesh object
-    itself is the context manager that installs the thread-resources env.
-    """
-    setter = (getattr(jax.sharding, "use_mesh", None)
-              or getattr(jax.sharding, "set_mesh", None))
-    return setter(mesh) if setter is not None else mesh
+    """Ambient-mesh context manager, so ``with_sharding_constraint``/flax
+    logical constraints can resolve bare PartitionSpecs during tracing."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def local_mesh_description(mesh: Mesh) -> str:

@@ -107,7 +107,7 @@ def ring_attention(q, k, v, kv_mask, *, axis_name: str = "seq",
     """
     b, sq, h, d = q.shape
     scale = d ** -0.5
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     m = jnp.full((b, h, sq), _NEG, jnp.float32)
     l = jnp.zeros((b, h, sq), jnp.float32)
     acc = jnp.zeros((b, h, sq, d), jnp.float32)
@@ -191,8 +191,8 @@ def ring_attention_sharded(q, k, v, kv_mask, *,
         raise ValueError("ring_attention_sharded: dropout_rate > 0 needs "
                          "a dropout_seed")
     if mesh is None:
-        ambient = compat.get_abstract_mesh()
-        if ambient is None or ambient.empty:
+        ambient = jax.sharding.get_abstract_mesh()
+        if ambient.empty:
             # No mesh context (single-device apply / notebook use): one local
             # block is the whole ring. Zigzag over one shard with identity
             # permutation is plain causal attention.
@@ -299,7 +299,7 @@ def zigzag_ring_attention(q, k, v, kv_mask, *, axis_name: str = "seq",
     b, sl, h, d = q.shape
     c = sl // 2
     scale = d ** -0.5
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     kv_mask = kv_mask.astype(jnp.bool_)
 

@@ -570,23 +570,16 @@ class ZeroStateConverter:
     """
 
     def __init__(self, tx, params_struct, layout: Zero1Layout, mesh,
-                 axis_names: AxisNames, stage: int = 1,
-                 opt_memory_kind: Optional[str] = None):
+                 axis_names: AxisNames, stage: int = 1):
         if stage not in (1, 2, 3):
             raise ValueError(f"stage must be 1, 2 or 3 (got {stage})")
         self.layout = layout
         self.stage = stage
-        self.opt_memory_kind = opt_memory_kind
         self._params_struct = _struct_tree(params_struct)
         self._flat_canon, self._flat_chunk, self._treedef, self._mask = (
             _opt_templates(tx, params_struct, layout))
         self._rep = NamedSharding(mesh, P())
         self._chunk_shd = NamedSharding(mesh, P(axis_names))
-        # Host-RAM offload (--opt-state-offload): the chunked opt-state
-        # leaves carry a host memory kind; params/ema chunk placements
-        # (stage 3) stay in device memory — they're touched every fwd/bwd.
-        self._opt_chunk_shd = (self._chunk_shd.with_memory_kind(
-            opt_memory_kind) if opt_memory_kind else self._chunk_shd)
         self._full_params_jit = None
 
     def _flat(self, opt_state):
@@ -615,7 +608,7 @@ class ZeroStateConverter:
     def opt_shardings(self):
         return jax.tree_util.tree_unflatten(
             self._treedef,
-            [self._opt_chunk_shd if m else self._rep for m in self._mask])
+            [self._chunk_shd if m else self._rep for m in self._mask])
 
     def param_shardings(self, tree):
         """Chunk shardings for a params-shaped tree (stage-3 live layout)."""

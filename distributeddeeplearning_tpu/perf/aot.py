@@ -57,7 +57,7 @@ VOLATILE_FIELDS = frozenset({
     "trace_dir", "trace_steps", "trace_max_events",
     "straggler_threshold", "bad_step_limit",
     "fault_plan", "fail_at_step",
-    "compile_cache_dir",
+    "compile_cache",
 })
 
 # Same for DataConfig: host-pipeline knobs that leave batch shapes alone.
@@ -112,14 +112,15 @@ def config_fingerprint(config, *, total_steps: Optional[int] = None,
     return hashlib.sha256(blob.encode()).hexdigest()[:32]
 
 
-def runtime_tag() -> str:
-    """Device-topology component of executable keys: an executable compiled
-    for one platform/chip/mesh size never deserializes onto another."""
+def runtime_tag(devices) -> str:
+    """Device component of executable keys: an executable compiled for one
+    platform/chip/device set never deserializes onto another (a replica on
+    device 2 must not be handed the entry built for device 0). ``devices``
+    are the program's, in assignment order."""
     import jax
-    devices = jax.devices()
     dev = devices[0]
     return (f"{dev.platform}:{getattr(dev, 'device_kind', '?')}:"
-            f"{len(devices)}x{jax.process_count()}")
+            f"{','.join(str(d.id) for d in devices)}x{jax.process_count()}")
 
 
 def _aval_signature(args) -> list:
@@ -158,12 +159,20 @@ class StepExecutableCache:
     """Fingerprint-keyed store of serialized step executables.
 
     One instance per run (train/loop.build creates it); disabled entirely
-    when the compile cache is off (``cache_dir=None``). All methods are
-    best-effort: a broken entry is a miss, a failed save is a warning.
+    when the compile cache is off (``cache_dir=None``). ``devices`` are the
+    ones the run's programs are compiled for, in assignment order (the
+    mesh's, or the serve replica's one device): they are part of every key,
+    and a saved executable is loaded back onto exactly these —
+    ``deserialize_and_load`` would otherwise spread a one-device program
+    over every local device and fail at its first dispatch. A broken entry
+    is a miss and a failed save is a warning; an entry that loads is
+    trusted, so a dispatch failure after it propagates.
     """
 
-    def __init__(self, cache_dir: Optional[str], fingerprint: str):
+    def __init__(self, cache_dir: Optional[str], fingerprint: str,
+                 devices):
         self.cache_dir = cache_dir
+        self.devices = list(devices)
         self.dir = (os.path.join(cache_dir, compile_cache.AOT_SUBDIR)
                     if cache_dir else None)
         self.fingerprint = fingerprint
@@ -174,13 +183,11 @@ class StepExecutableCache:
         self.sources: dict[str, str] = {}  # step name -> aot_hit | compiled
 
     @classmethod
-    def for_config(cls, config, *, total_steps: Optional[int] = None,
-                   cache_dir: Optional[str] = None) -> "StepExecutableCache":
-        explicit = (cache_dir if cache_dir is not None
-                    else getattr(config, "compile_cache_dir", None))
-        resolved = compile_cache.resolve_dir(explicit)
-        return cls(resolved, config_fingerprint(config,
-                                                total_steps=total_steps))
+    def for_config(cls, config, devices, *,
+                   total_steps: Optional[int] = None) -> "StepExecutableCache":
+        return cls(compile_cache.cache_dir(config.compile_cache),
+                   config_fingerprint(config, total_steps=total_steps),
+                   devices)
 
     @property
     def enabled(self) -> bool:
@@ -188,7 +195,8 @@ class StepExecutableCache:
 
     def key(self, name: str, args) -> str:
         blob = json.dumps(
-            [self.fingerprint, name, runtime_tag(), _aval_signature(args)],
+            [self.fingerprint, name, runtime_tag(self.devices),
+             _aval_signature(args)],
             sort_keys=True, default=repr)
         return hashlib.sha256(blob.encode()).hexdigest()[:32]
 
@@ -196,9 +204,10 @@ class StepExecutableCache:
         return os.path.join(self.dir, f"{key}.aotx")
 
     def load(self, name: str, key: str):
-        """Deserialize the cached executable for ``key``; None on miss or
-        on ANY mismatch (format, jax version, corrupt payload) — the caller
-        cold-compiles and overwrites the entry."""
+        """Deserialize the cached executable for ``key`` onto this run's
+        devices; None on miss or on ANY mismatch (format, jax version,
+        corrupt payload) — the caller cold-compiles and overwrites the
+        entry."""
         if self.dir is None:
             return None
         path = self._path(key)
@@ -218,7 +227,8 @@ class StepExecutableCache:
             from jax.experimental import serialize_executable
             fn = serialize_executable.deserialize_and_load(
                 payload["executable"], payload["in_tree"],
-                payload["out_tree"])
+                payload["out_tree"],
+                execution_devices=self.devices)
             # Donation backstop (the PR 5 bug class, cheap runtime form of
             # analysis/donation.py): the deserialized executable must
             # donate exactly the inputs it donated when saved. A drifted
@@ -260,7 +270,7 @@ class StepExecutableCache:
             blob = pickle.dumps({
                 "format": FORMAT_VERSION,
                 "versions": _versions(),
-                "runtime": runtime_tag(),
+                "runtime": runtime_tag(self.devices),
                 "name": name,
                 "fingerprint": self.fingerprint,
                 "executable": executable,

@@ -1,19 +1,22 @@
 """Shared persistent compile-cache policy for every entry point.
 
-One resolution order, everywhere::
+The cache is placed from outside, by one rule kept in this module and nowhere
+else:
 
-    explicit flag/config value  >  $DDL_COMPILE_CACHE  >  <repo>/.cache/jax_compile
+- ``JAX_COMPILATION_CACHE_DIR`` set  -> JAX's persistent compilation cache
+  and the AOT executables (``<dir>/aot/``, perf/aot.py) live there, and the
+  code sets no other directory and never rewrites the variable;
+- not set -> ``<repo>/.cache/jax_compile``.
 
-``"off"`` (or ``"none"``/``"0"``/``"disabled"``/empty) at any level disables
-caching outright. ``activate()`` points JAX's persistent compilation cache at
-the resolved directory and re-exports it through the environment
-(``DDL_COMPILE_CACHE`` + ``JAX_COMPILATION_CACHE_DIR``) so launcher children
-and every ``DDL_RESTART_ATTEMPT`` inherit the same cache without replumbing.
+Either way the path is fixed for a given checkout and environment — never a
+temporary name, pid or time, since the path is part of the cache key. Child
+processes (launcher spawns, restart attempts, serve replicas) inherit the
+variable or compute the same default, so nothing is exported. A run may turn
+the cache off (``TrainConfig.compile_cache=False`` / ``--no-compile-cache``);
+nothing else about it is configurable.
 
-The cache is an optimization, never a dependency: every failure path here
-degrades to "no cache" with a warning instead of raising. This module stays
-importable without jax (launch.py runs on hosts before jax is initialized);
-jax is imported lazily inside ``activate()`` only.
+This module stays importable without jax (launch.py runs on hosts before jax
+is initialized); jax is imported inside ``activate()`` only.
 
 Hit/miss counters for the AOT executable layer (perf/aot.py) are persisted
 to ``<cache_dir>/ddl_cache_stats.json`` so ``tools/doctor.py`` can report
@@ -24,79 +27,54 @@ from __future__ import annotations
 
 import json
 import os
-import sys
 import time
 from typing import Any, Optional
 
-ENV_CACHE = "DDL_COMPILE_CACHE"
+ENV_CACHE = "JAX_COMPILATION_CACHE_DIR"
 STATS_FILE = "ddl_cache_stats.json"
 AOT_SUBDIR = "aot"
-_OFF_VALUES = frozenset({"off", "none", "0", "disabled", ""})
 
 
 def default_dir() -> str:
-    """Repo-local default: ``<repo>/.cache/jax_compile`` (the directory
-    bench.py historically used privately, now shared by all entry points)."""
+    """``<repo>/.cache/jax_compile`` — where the cache lives when the
+    environment does not place it."""
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     return os.path.join(repo, ".cache", "jax_compile")
 
 
-def resolve_dir(explicit: Optional[str] = None) -> Optional[str]:
-    """Resolve the cache directory (flag > env > default); None = disabled."""
-    value = explicit if explicit is not None else os.environ.get(ENV_CACHE)
-    if value is None:
-        return default_dir()
-    if value.strip().lower() in _OFF_VALUES:
+def cache_dir(enabled: bool = True) -> Optional[str]:
+    """The cache directory: ``$JAX_COMPILATION_CACHE_DIR`` if set, else the
+    repo default; None when this run turned the cache off."""
+    if not enabled:
         return None
-    return os.path.abspath(os.path.expanduser(value))
+    return os.environ.get(ENV_CACHE) or default_dir()
 
 
-def export_env(path: Optional[str]) -> None:
-    """Export the resolved cache dir so child processes (launcher spawns,
-    restart attempts) land on the same cache. jax-free, launcher-safe."""
+def activate(enabled: bool = True) -> Optional[str]:
+    """Point JAX's persistent compilation cache at :func:`cache_dir` (or
+    switch it off for this process) before the first compile. Returns the
+    active directory, or None when disabled."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    path = cache_dir(enabled)
+    if (jax.config.jax_enable_compilation_cache != (path is not None)
+            or (path and jax.config.jax_compilation_cache_dir != path)):
+        # JAX latches "is the cache used, and where" at its first compile;
+        # a process that changes either (tests, a bench arm) must unlatch.
+        cc.reset_cache()
+    jax.config.update("jax_enable_compilation_cache", path is not None)
     if path is None:
-        os.environ[ENV_CACHE] = "off"
-        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-    else:
-        os.environ[ENV_CACHE] = path
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = path
-
-
-def activate(explicit: Optional[str] = None, *,
-             export: bool = True) -> Optional[str]:
-    """Enable JAX's persistent compilation cache at the resolved directory.
-
-    Returns the active cache dir, or None when disabled / unavailable.
-    Never raises: the cache is an optimization, not a dependency.
-    """
-    path = resolve_dir(explicit)
-    if path is None:
-        if export:
-            export_env(None)
         return None
-    try:
-        os.makedirs(path, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        # jax gates the persistent cache behind a minimum compile time /
-        # entry size meant for interactive GPU use; a training step is
-        # always worth caching, and the CPU test path must exercise the
-        # same machinery the TPU path uses. Knobs vary across jax
-        # versions, so each is best-effort.
-        for knob, value in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(knob, value)
-            except Exception:
-                pass
-    except Exception as exc:  # noqa: BLE001 - degrade, never fail the run
-        print(f"[compile_cache] disabled ({type(exc).__name__}: {exc})",
-              file=sys.stderr)
-        return None
-    if export:
-        export_env(path)
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    # jax gates the persistent cache behind a minimum compile time / entry
+    # size meant for interactive use; a training step is always worth
+    # caching, and the CPU test path must exercise the same machinery the
+    # TPU path uses.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
 
 
@@ -106,7 +84,7 @@ def activate(explicit: Optional[str] = None, *,
 
 def summarize(path: Optional[str] = None) -> dict[str, Any]:
     """Entry count / total size for a cache directory (0s when absent)."""
-    path = resolve_dir(path) if path is None else path
+    path = cache_dir() if path is None else path
     out: dict[str, Any] = {"dir": path, "entries": 0, "aot_entries": 0,
                            "total_bytes": 0}
     if not path or not os.path.isdir(path):
@@ -127,50 +105,48 @@ def summarize(path: Optional[str] = None) -> dict[str, Any]:
     return out
 
 
-def _stats_path(cache_dir: str) -> str:
-    return os.path.join(cache_dir, STATS_FILE)
+def _stats_path(path: str) -> str:
+    return os.path.join(path, STATS_FILE)
 
 
-def write_stats(cache_dir: Optional[str], stats: dict[str, Any]) -> None:
+def write_stats(path: Optional[str], stats: dict[str, Any]) -> None:
     """Persist last-run counters (best-effort; last writer wins)."""
-    if not cache_dir:
+    if not path:
         return
     try:
         payload = dict(stats)
         payload["updated_at"] = time.time()
         payload["pid"] = os.getpid()
-        tmp = _stats_path(cache_dir) + f".tmp.{os.getpid()}"
+        tmp = _stats_path(path) + f".tmp.{os.getpid()}"
         with open(tmp, "w") as fh:
             json.dump(payload, fh, indent=2, sort_keys=True)
-        os.replace(tmp, _stats_path(cache_dir))
+        os.replace(tmp, _stats_path(path))
     except Exception:  # noqa: BLE001
         pass
 
 
-def read_stats(cache_dir: Optional[str] = None) -> Optional[dict[str, Any]]:
-    cache_dir = resolve_dir(None) if cache_dir is None else cache_dir
-    if not cache_dir:
-        return None
+def read_stats(path: Optional[str] = None) -> Optional[dict[str, Any]]:
+    path = cache_dir() if path is None else path
     try:
-        with open(_stats_path(cache_dir)) as fh:
+        with open(_stats_path(path)) as fh:
             return json.load(fh)
     except Exception:  # noqa: BLE001
         return None
 
 
-def prune(cache_dir: Optional[str] = None, *,
+def prune(path: Optional[str] = None, *,
           max_age_days: float = 30.0) -> tuple[int, int]:
     """Delete cache entries older than ``max_age_days`` (by mtime).
 
     Returns ``(removed, kept)``. Safe on a live cache: jax re-creates
     entries on miss, and the AOT layer treats a vanished file as a miss.
     """
-    cache_dir = resolve_dir(None) if cache_dir is None else cache_dir
+    path = cache_dir() if path is None else path
     removed = kept = 0
-    if not cache_dir or not os.path.isdir(cache_dir):
+    if not os.path.isdir(path):
         return removed, kept
     cutoff = time.time() - max_age_days * 86400.0
-    for root, _dirs, files in os.walk(cache_dir):
+    for root, _dirs, files in os.walk(path):
         for name in files:
             if name == STATS_FILE:
                 continue

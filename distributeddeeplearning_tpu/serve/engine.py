@@ -73,7 +73,7 @@ from distributeddeeplearning_tpu.serve.scheduler import (BrownoutController,
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
     """Everything that shapes the compiled serve programs, plus the one
-    volatile knob (``compile_cache_dir``) excluded from the fingerprint."""
+    volatile knob (``compile_cache``) excluded from the fingerprint."""
 
     model: str = "gpt_tiny"
     vocab_size: int = 1024
@@ -95,7 +95,7 @@ class ServeConfig:
     # Both must be set together.
     spec_draft_model: Optional[str] = None
     spec_k: int = 0
-    compile_cache_dir: Optional[str] = None
+    compile_cache: bool = True
 
     @property
     def slot_capacity(self) -> int:
@@ -116,7 +116,7 @@ def serve_fingerprint(config: ServeConfig) -> str:
     import jaxlib
 
     d = dataclasses.asdict(config)
-    d.pop("compile_cache_dir", None)  # volatile: where, not what
+    d.pop("compile_cache", None)  # volatile: never shapes a program
     d["_versions"] = {"jax": jax.__version__, "jaxlib": jaxlib.__version__}
     blob = json.dumps(d, sort_keys=True, default=repr)
     return hashlib.sha256(blob.encode()).hexdigest()[:32]
@@ -217,6 +217,13 @@ class Engine:
             raise ValueError("prefill_buckets must name at least one "
                              "padded prompt length")
         self.config = cfg
+        # One replica, one device: everything below (params, KV pools, the
+        # compiled programs) lives on the process's first device. A serve
+        # replica process is given exactly one chip (launch._spawn_replica),
+        # and a replica is told apart by which chip that is, not by a
+        # device index in here.
+        self.device = jax.devices()[0]
+        compile_cache.activate(cfg.compile_cache)
         self.scheduler = scheduler or SloScheduler()
         self._clock = clock or time.monotonic
         # Resolved ONCE: telemetry must be configured before the engine
@@ -328,8 +335,8 @@ class Engine:
         self._stall = stall or time.sleep
 
         self._aot = aotlib.StepExecutableCache(
-            compile_cache.resolve_dir(cfg.compile_cache_dir),
-            serve_fingerprint(cfg))
+            compile_cache.cache_dir(cfg.compile_cache),
+            serve_fingerprint(cfg), [self.device])
         self._prefill_exec: dict = {}
         self._block_prefill_exec: dict = {}
         self._decode_exec = None

@@ -15,7 +15,8 @@ dying at ANY instruction with no cleanup:
   token stream the supervisor already received for a re-dispatched victim;
   the replica folds it into the prompt (``Engine`` prefix-folding), so the
   continuation is token-identical to the uninterrupted run.
-- ``<workdir>/events/r<I>.jsonl``  — append-only stream back: ``accepted``
+- ``<workdir>/events/r<I>.jsonl``  — append-only stream back: ``ready``
+  (the device this replica holds + its AOT warm-boot stats) / ``accepted``
   / ``token`` / ``finished`` / ``failed`` / ``drained``. Flushed per step:
   an OS-buffered line survives SIGKILL of the writer, so the supervisor's
   view after a replica loss is exactly "everything up to the last completed
@@ -83,11 +84,24 @@ def main(argv=None) -> int:
     flight.get().record("serve_replica_start", replica=rid, attempt=attempt)
     hb = health.HeartbeatWriter.from_env()
 
+    import jax
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices),
+              "id": devices[0].id,
+              "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS")}
+    if device["platform"] == "tpu" and len(devices) != 1:
+        # One process per chip (launch._spawn_replica): a replica that sees
+        # the whole host would take chips its siblings need.
+        raise RuntimeError(
+            f"serve replica {rid} sees {len(devices)} TPU devices; it must "
+            f"be given exactly one (TPU_VISIBLE_CHIPS="
+            f"{device['visible_chips']!r})")
+
     eng = enginelib.Engine(cfg)
     aot = eng.warmup()
     if hb is not None:
         hb.beat(0)
-
     # Per-attempt inbox: a restarted replica must NOT replay its
     # predecessor's inbox — the supervisor already re-dispatched those
     # victims (possibly to this very replica, into the new inbox).
@@ -98,7 +112,7 @@ def main(argv=None) -> int:
     os.makedirs(os.path.dirname(events_path), exist_ok=True)
     ev = open(events_path, "a", encoding="utf-8")
     _emit(ev, {"ev": "ready", "replica": rid, "attempt": attempt,
-               "aot": aot})
+               "aot": aot, "device": device})
 
     seen: set = set()
     reqs: dict = {}    # supervisor uid -> engine Request

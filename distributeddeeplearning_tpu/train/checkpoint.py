@@ -211,13 +211,8 @@ class Checkpointer:
 
     def _reload(self) -> None:
         """Refresh the manager's view of the directory after a quarantine
-        rename (step caches vary by orbax version; recreate if needed)."""
-        reload_fn = getattr(self._mgr, "reload", None)
-        if callable(reload_fn):
-            reload_fn()
-            return
-        self._mgr.close()
-        self._mgr = self._make_manager()
+        rename."""
+        self._mgr.reload()
 
     def restore_latest(self, state_like: Any) -> Optional[Any]:
         """Restore the newest checkpoint into ``state_like``'s layout, or
@@ -279,9 +274,8 @@ class Checkpointer:
         """Whether checkpoint ``step`` carries real EMA arrays, from the
         StandardSave ``_METADATA`` manifest on disk. (A fresh
         CheckpointManager's ``item_metadata`` cannot reconstruct the item
-        without a handler registry in this orbax version — it returns a
-        tree of None with an absl warning — so the file is the reliable
-        source.) None = manifest unreadable; caller falls back to the
+        without a handler registry — it returns a tree of None with an
+        absl warning — so the file is the reliable source.) None = manifest unreadable; caller falls back to the
         strict structure-matched restore."""
         path = os.path.join(str(self._mgr.directory), str(step), "default",
                             "_METADATA")
@@ -289,9 +283,8 @@ class Checkpointer:
             with open(path) as f:
                 tree_meta = json.load(f)["tree_metadata"]
         except (OSError, ValueError, KeyError, TypeError) as e:
-            # Visible degradation (ADVICE r4): an orbax upgrade that moves
-            # or reshapes this private manifest must not SILENTLY demote
-            # the friendly EMA-flip handling to the strict
+            # Visible degradation: an unreadable manifest must not
+            # SILENTLY demote the friendly EMA-flip handling to the strict
             # structure-mismatch error path.
             import warnings
 
@@ -299,8 +292,7 @@ class Checkpointer:
                 f"checkpoint manifest {path} unreadable "
                 f"({type(e).__name__}: {e}); EMA-flip detection disabled "
                 f"for this restore — falling back to strict "
-                f"structure-matched restore (did an orbax upgrade change "
-                f"the _METADATA layout?)")
+                f"structure-matched restore")
             return None
         for key, entry in tree_meta.items():
             if key.startswith("('ema_params'"):
@@ -360,16 +352,16 @@ class Checkpointer:
         For consumers that don't train (generate.py): the optimizer state's
         structure depends on the training run's optimizer choice, which a
         sampler neither knows nor needs. Uses a raw (target-less) restore —
-        this orbax version has no partial StandardRestore — so the whole
-        tree loads to host once; sampler-scale only."""
+        orbax has no partial StandardRestore — so the whole tree loads to
+        host once; sampler-scale only."""
         return self._with_fallback(
             lambda step: self._restore_subtree(
                 self._restore_raw(step)["params"], params_like, "params"))
 
     def _restore_raw(self, step: int) -> Any:
         """Target-less restore of the raw checkpoint tree (host arrays).
-        This orbax version's ``restore(step)`` with no args needs a handler
-        registry to reconstruct the item; the explicit empty
+        Orbax's ``restore(step)`` with no args needs a handler registry to
+        reconstruct the item; the explicit empty
         ``StandardRestore`` asks for the tree as saved instead."""
         return self._mgr_restore(step, ocp.args.StandardRestore())
 

@@ -2,8 +2,9 @@
 
 Three layers under test:
 
-- policy (perf/compile_cache.py): flag > env > default resolution, off
-  switch, stats sidecar, age-based prune;
+- policy (perf/compile_cache.py): the cache is placed from outside
+  ($JAX_COMPILATION_CACHE_DIR, else the repo default) and the variable is
+  never rewritten; off switch, stats sidecar, age-based prune;
 - fingerprint (perf/aot.py): equal configs -> equal keys, volatile host
   knobs never perturb the key, program-shaping fields and jax upgrades
   always do, and attempt-scoped faults expire out of the hash;
@@ -49,33 +50,58 @@ def _cfg(**kw):
 # Cache-dir policy
 # ---------------------------------------------------------------------------
 
-@pytest.mark.core
-def test_resolve_dir_precedence(monkeypatch, tmp_path):
-    monkeypatch.delenv(compile_cache.ENV_CACHE, raising=False)
-    assert compile_cache.resolve_dir() == compile_cache.default_dir()
-    monkeypatch.setenv(compile_cache.ENV_CACHE, str(tmp_path / "env"))
-    assert compile_cache.resolve_dir() == str(tmp_path / "env")
-    # explicit flag beats env
-    assert compile_cache.resolve_dir(str(tmp_path / "flag")) == \
-        str(tmp_path / "flag")
-    # any off-spelling disables, at either level
-    for off in ("off", "none", "0", "disabled", "OFF"):
-        assert compile_cache.resolve_dir(off) is None
-    monkeypatch.setenv(compile_cache.ENV_CACHE, "off")
-    assert compile_cache.resolve_dir() is None
+@pytest.fixture
+def restore_jax_cache():
+    """activate() re-points the process-global jax cache; put it back."""
+    yield
+    compile_cache.activate()
 
 
 @pytest.mark.core
-def test_export_env_roundtrip(monkeypatch, tmp_path):
-    monkeypatch.delenv(compile_cache.ENV_CACHE, raising=False)
+def test_cache_dir_is_placed_from_outside(monkeypatch, tmp_path):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    compile_cache.export_env(str(tmp_path))
-    assert os.environ[compile_cache.ENV_CACHE] == str(tmp_path)
+    assert compile_cache.cache_dir() == compile_cache.default_dir()
+    assert compile_cache.default_dir().endswith(
+        os.path.join(".cache", "jax_compile"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert compile_cache.cache_dir() == str(tmp_path / "env")
+    # the one switch a run has: off
+    assert compile_cache.cache_dir(enabled=False) is None
+
+
+@pytest.mark.core
+@pytest.mark.parametrize("placed", [True, False])
+def test_activate_never_touches_the_variable(monkeypatch, tmp_path, placed,
+                                             restore_jax_cache):
+    """Set => jax's cache and aot/ land there and the variable is left as
+    it was; unset => the repo default, and the variable stays unset."""
+    want = str(tmp_path / "placed")
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = compile_cache.default_dir()
+    before = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.activate() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert jax.config.jax_enable_compilation_cache
+    handle = aot.StepExecutableCache.for_config(_cfg(), jax.devices(),
+                                                total_steps=4)
+    assert handle.dir == os.path.join(want, compile_cache.AOT_SUBDIR)
+    assert os.environ.get("JAX_COMPILATION_CACHE_DIR") == before
+    assert "DDL_COMPILE_CACHE" not in os.environ
+
+
+@pytest.mark.core
+def test_activate_off_disables_both_layers(monkeypatch, tmp_path,
+                                           restore_jax_cache):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.activate(False) is None
+    assert not jax.config.jax_enable_compilation_cache
+    handle = aot.StepExecutableCache.for_config(
+        _cfg(compile_cache=False), jax.devices(), total_steps=4)
+    assert not handle.enabled
     assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
-    compile_cache.export_env(None)  # disable propagates to children too
-    assert os.environ[compile_cache.ENV_CACHE] == "off"
-    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
-    assert compile_cache.resolve_dir() is None
 
 
 @pytest.mark.core
@@ -125,7 +151,7 @@ def test_volatile_fields_do_not_change_key(monkeypatch, tmp_path):
                     checkpoint_every_steps=2),
                dict(log_every=1),
                dict(straggler_threshold=9.9),
-               dict(compile_cache_dir=str(tmp_path / "cc")),
+               dict(compile_cache=False),
                # host-side process faults (crash/sigterm) never reach the
                # compiled program — only nan_grads does (tested below)
                dict(fault_plan="crash@3,sigterm@5")):
@@ -201,6 +227,7 @@ def test_restart_attempt_hits_aot_cache_zero_retraces(tmp_path, monkeypatch):
     monkeypatch.delenv(faults.ENV_ATTEMPT, raising=False)
     cfg = _cfg()
     cache = str(tmp_path / "cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
     batch = {
         "image": jax.random.normal(jax.random.key(2), (16, 8, 8, 3)),
         "label": jax.random.randint(jax.random.key(3), (16,), 0, 10),
@@ -211,9 +238,9 @@ def test_restart_attempt_hits_aot_cache_zero_retraces(tmp_path, monkeypatch):
     def run_once():
         # Fresh build per attempt, exactly like a relaunched process: new
         # jit function, new cache handle — only the disk entry is shared.
-        cache_handle = aot.StepExecutableCache.for_config(
-            cfg, total_steps=4, cache_dir=cache)
         mesh = meshlib.make_mesh(cfg.parallel)
+        cache_handle = aot.StepExecutableCache.for_config(
+            cfg, mesh.devices.flat, total_steps=4)
         model = _TinyNet()
         tx, _ = optim.make_optimizer(cfg.optimizer, cfg.global_batch_size,
                                      4, None)
@@ -252,13 +279,10 @@ def test_loop_warm_start_summary_and_zero_retrace(tmp_path, monkeypatch):
     from distributeddeeplearning_tpu.utils.logging import MetricLogger
 
     cache = str(tmp_path / "cache")
-    # Set through monkeypatch so loop.run's export_env mutations of these
-    # keys are rolled back at teardown.
-    monkeypatch.setenv(compile_cache.ENV_CACHE, cache)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
     monkeypatch.delenv(faults.ENV_PLAN, raising=False)
     monkeypatch.delenv(faults.ENV_ATTEMPT, raising=False)
-    cfg = _cfg(log_every=1, compile_cache_dir=cache)
+    cfg = _cfg(log_every=1)
     try:
         stream = io.StringIO()
         s1 = loop.run(cfg, total_steps=2, eval_batches=1,
@@ -284,8 +308,8 @@ def test_loop_warm_start_summary_and_zero_retrace(tmp_path, monkeypatch):
     finally:
         # loop.run pointed the process-global jax persistent cache at the
         # tmp dir; re-point it at the repo default for the rest of the suite.
-        jax.config.update("jax_compilation_cache_dir",
-                          compile_cache.default_dir())
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        compile_cache.activate()
 
 
 @pytest.mark.usefixtures("devices8")
@@ -304,11 +328,10 @@ def test_warm_resume_with_checkpointing_is_donation_safe(tmp_path, monkeypatch):
     from distributeddeeplearning_tpu.utils.logging import MetricLogger
 
     cache = str(tmp_path / "cache")
-    monkeypatch.setenv(compile_cache.ENV_CACHE, cache)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
     monkeypatch.delenv(faults.ENV_PLAN, raising=False)
     monkeypatch.delenv(faults.ENV_ATTEMPT, raising=False)
-    kw = dict(compile_cache_dir=cache, checkpoint_every_steps=1)
+    kw = dict(checkpoint_every_steps=1)
     try:
         # Uninterrupted reference: cold-compiles and populates the cache.
         ref = loop.run(_cfg(checkpoint_dir=str(tmp_path / "ck_ref"), **kw),
@@ -337,8 +360,38 @@ def test_warm_resume_with_checkpointing_is_donation_safe(tmp_path, monkeypatch):
         # Recovery is bitwise: kill + restore + warm executable fully erased.
         assert s["final_metrics"]["loss"] == ref["final_metrics"]["loss"]
     finally:
-        jax.config.update("jax_compilation_cache_dir",
-                          compile_cache.default_dir())
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        compile_cache.activate()
+
+
+# ---------------------------------------------------------------------------
+# Executables load onto the devices they were compiled for
+# ---------------------------------------------------------------------------
+
+@pytest.mark.usefixtures("devices8")
+def test_aot_entry_is_keyed_by_and_loaded_onto_its_devices(tmp_path,
+                                                           monkeypatch):
+    """On a host with several devices a one-device program must come back
+    as a one-device program ON ITS device: the key separates device 0's
+    entry from device 2's, and the warm executable dispatches (it used to
+    load onto all 8 and die at the first call)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    devs = jax.devices()
+    fn = jax.jit(lambda x: x * 2.0)
+    keys = {}
+    for d in (devs[0], devs[2]):
+        x = jax.device_put(jnp.arange(4.0), d)
+        cold = aot.StepExecutableCache.for_config(_cfg(), [d], total_steps=4)
+        keys[d.id] = key = cold.key("double", (x,))
+        assert cold.load("double", key) is None
+        assert cold.save("double", key, fn.lower(x).compile())
+        warm = aot.StepExecutableCache.for_config(_cfg(), [d], total_steps=4)
+        loaded = warm.load("double", key)
+        assert loaded is not None and warm.hits == 1
+        out = loaded(x)  # dispatches — not merely deserializes
+        assert out.devices() == {d}
+        assert out.tolist() == [0.0, 2.0, 4.0, 6.0]
+    assert keys[devs[0].id] != keys[devs[2].id]
 
 
 # ---------------------------------------------------------------------------
@@ -375,8 +428,9 @@ def test_aot_load_rejects_drifted_donation_set(tmp_path, monkeypatch):
     no alias header, so the signature probe is patched to simulate the
     TPU donation sets."""
     cache = str(tmp_path / "cache")
-    handle = aot.StepExecutableCache.for_config(_cfg(), total_steps=4,
-                                                cache_dir=cache)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+    one = jax.devices()[:1]
+    handle = aot.StepExecutableCache.for_config(_cfg(), one, total_steps=4)
     args = (jnp.ones((4,)), jnp.ones((4,)))
     compiled = jax.jit(lambda x, y: x + y).lower(*args).compile()
     key = handle.key("step", args)
@@ -385,15 +439,13 @@ def test_aot_load_rejects_drifted_donation_set(tmp_path, monkeypatch):
     assert handle.save("step", key, compiled)
 
     # Unchanged donation set: a hit.
-    warm = aot.StepExecutableCache.for_config(_cfg(), total_steps=4,
-                                              cache_dir=cache)
+    warm = aot.StepExecutableCache.for_config(_cfg(), one, total_steps=4)
     assert warm.load("step", key) is not None
     assert warm.hits == 1 and warm.failures == 0
 
     # Drifted donation set: deleted + cold fallback.
     monkeypatch.setattr(aot, "donation_signature", lambda _: "{{1}:(0,{})}")
-    drifted = aot.StepExecutableCache.for_config(_cfg(), total_steps=4,
-                                                 cache_dir=cache)
+    drifted = aot.StepExecutableCache.for_config(_cfg(), one, total_steps=4)
     assert drifted.load("step", key) is None
     assert drifted.failures == 1 and drifted.hits == 0
     assert not os.path.exists(os.path.join(
@@ -405,7 +457,6 @@ def test_aot_load_rejects_drifted_donation_set(tmp_path, monkeypatch):
     monkeypatch.setattr(aot, "donation_signature", lambda _: None)
     assert handle.save("step", key, compiled)
     monkeypatch.setattr(aot, "donation_signature", lambda _: "{{0}:(0,{})}")
-    legacy = aot.StepExecutableCache.for_config(_cfg(), total_steps=4,
-                                                cache_dir=cache)
+    legacy = aot.StepExecutableCache.for_config(_cfg(), one, total_steps=4)
     assert legacy.load("step", key) is not None
     assert legacy.failures == 0

@@ -53,8 +53,8 @@ def _run_cli(*args, timeout=420):
 
 def test_clean_repo_gate(tmp_path):
     """The acceptance gate: all three passes over the shipping repo come
-    back empty. Runs the real CLI (fresh interpreter, same entry CI and
-    chip_window.sh use); the fingerprint registry is pointed at a tmp
+    back empty. Runs the real CLI (fresh interpreter, the entry CI uses);
+    the fingerprint registry is pointed at a tmp
     file so ambient .cache state can neither mask nor seed a failure."""
     reg = str(tmp_path / "registry.json")
     proc = _run_cli("--json", "--no-record", "--fingerprint-registry", reg)

@@ -99,14 +99,15 @@ def test_train_cli_smoke():
 
 def test_bench_error_path_emits_parseable_json(tmp_path):
     """A child that cannot even build its model must still produce exactly
-    one parseable JSON line with an error record (the driver contract)."""
+    one parseable JSON line with an error record — and a non-zero exit: no
+    fresh measurement landed (the driver contract)."""
     proc = subprocess.run(
         [sys.executable, "bench.py", "--platform", "cpu",
          "--model", "no_such_model", "--attempts", "1",
          "--attempt-timeout", "120", "--budget", "180"],
         capture_output=True, text=True, timeout=300,
         cwd=str(Path(__file__).resolve().parent.parent))
-    assert proc.returncode == 0, proc.stderr
+    assert proc.returncode == 1, proc.stderr
     lines = [l for l in proc.stdout.splitlines() if l.strip()]
     assert len(lines) == 1, proc.stdout
     rec = json.loads(lines[0])

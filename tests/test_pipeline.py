@@ -449,11 +449,10 @@ def test_aot_warm_boot_zero_retrace(tmp_path, monkeypatch, schedule, v):
     from distributeddeeplearning_tpu.train import loop
 
     cache = str(tmp_path / "cache")
-    monkeypatch.setenv(compile_cache.ENV_CACHE, cache)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
     monkeypatch.delenv(faults.ENV_PLAN, raising=False)
     monkeypatch.delenv(faults.ENV_ATTEMPT, raising=False)
-    cfg = _loop_cfg(tmp_path, schedule, v, compile_cache_dir=cache)
+    cfg = _loop_cfg(tmp_path, schedule, v)
     try:
         s1 = loop.run(cfg, total_steps=2)
         assert s1["compile_cache"]["sources"]["gspmd_train_step"] == \
@@ -467,8 +466,8 @@ def test_aot_warm_boot_zero_retrace(tmp_path, monkeypatch, schedule, v):
         assert s2["pipeline"]["schedule"] == schedule
         assert s2["pipeline"]["bubble_fraction"] is None  # no trace, no lie
     finally:
-        jax.config.update("jax_compilation_cache_dir",
-                          compile_cache.default_dir())
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        compile_cache.activate()
 
 
 @pytest.mark.pipeline

@@ -40,7 +40,7 @@ def _engine(model="gpt_tiny", **kw):
     kw.setdefault("num_pages", 32)
     kw.setdefault("max_pages_per_slot", 8)
     kw.setdefault("prefill_buckets", (8, 16))
-    kw.setdefault("compile_cache_dir", "off")
+    kw.setdefault("compile_cache", False)
     t = [0.0]
 
     def clock():
@@ -266,12 +266,13 @@ def test_engine_preemption_resumes_token_identical():
     assert eng.allocator.free_pages == eng.config.num_pages
 
 
-def test_engine_aot_warm_boot_zero_retrace(tmp_path):
+def test_engine_aot_warm_boot_zero_retrace(tmp_path, monkeypatch):
     """Second engine with the same fingerprint deserializes every program
     (prefill per bucket + decode) instead of retracing — and still
     decodes token-identically."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     kw = dict(max_slots=2, page_size=4, num_pages=16, max_pages_per_slot=4,
-              prefill_buckets=(8,), compile_cache_dir=str(tmp_path))
+              prefill_buckets=(8,), compile_cache=True)
     cold = _engine("gpt_tiny", **kw)
     stats = cold.warmup()
     assert stats["aot_misses"] == 2 and stats["aot_saves"] == 2
@@ -287,9 +288,9 @@ def test_engine_aot_warm_boot_zero_retrace(tmp_path):
     assert warm_req.tokens == cold_req.tokens
 
 
-def test_serve_fingerprint_tracks_program_shape_not_cache_dir():
-    a = ServeConfig(compile_cache_dir=None)
-    b = ServeConfig(compile_cache_dir="/somewhere/else")
+def test_serve_fingerprint_tracks_program_shape_not_cache_switch():
+    a = ServeConfig(compile_cache=True)
+    b = ServeConfig(compile_cache=False)
     c = ServeConfig(page_size=a.page_size * 2)
     assert serve_fingerprint(a) == serve_fingerprint(b)
     assert serve_fingerprint(a) != serve_fingerprint(c)
@@ -319,7 +320,7 @@ def test_engine_rejects_capacity_exceeding_config():
         # gpt_tiny's max_position is 128; 64-token pages x 4 = 256 > 128.
         Engine(ServeConfig(model="gpt_tiny", vocab_size=VOCAB, max_slots=1,
                            page_size=64, num_pages=8, max_pages_per_slot=4,
-                           prefill_buckets=(16,), compile_cache_dir="off"))
+                           prefill_buckets=(16,), compile_cache=False))
 
 
 # --- bench record smoke -----------------------------------------------------
@@ -335,11 +336,12 @@ def test_bench_serve_emits_valid_provenance_record(tmp_path, monkeypatch,
                         lambda name, payload: written.update(
                             {name: payload}) or str(tmp_path / "s.json"))
     rc = bench_serve.main([
+        "--platform", "cpu",
         "--model", "gpt_tiny", "--vocab-size", str(VOCAB),
         "--requests", "3", "--rate", "1000", "--max-new", "3",
         "--prompt-lens", "4,6", "--max-slots", "2", "--page-size", "4",
         "--num-pages", "16", "--max-pages-per-slot", "4",
-        "--prefill-buckets", "8", "--compile-cache-dir", "off"])
+        "--prefill-buckets", "8", "--no-compile-cache"])
     assert rc == 0
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert perf_report.validate(rec) == []
@@ -358,10 +360,13 @@ def chaos_aot(tmp_path_factory):
     """One AOT executable cache shared by every chaos-arm engine in this
     module: identical ServeConfig -> identical fingerprint -> the first
     test pays the compile, the rest warm-boot (tier-1 stays cheap)."""
-    return str(tmp_path_factory.mktemp("serve-chaos-aot"))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("JAX_COMPILATION_CACHE_DIR",
+                  str(tmp_path_factory.mktemp("serve-chaos-aot")))
+        yield
 
 
-def _chaos_engine(cache_dir, **engine_kw):
+def _chaos_engine(_placed_cache, **engine_kw):
     t = [0.0]
 
     def clock():
@@ -370,7 +375,7 @@ def _chaos_engine(cache_dir, **engine_kw):
 
     cfg = ServeConfig(model="gpt_tiny", vocab_size=VOCAB, max_slots=2,
                       page_size=4, num_pages=32, max_pages_per_slot=8,
-                      prefill_buckets=(8, 16), compile_cache_dir=cache_dir)
+                      prefill_buckets=(8, 16))
     return Engine(cfg, clock=clock, **engine_kw)
 
 
@@ -561,7 +566,8 @@ def test_anomaly_update_serve_kinds():
 # --- the serve chaos soak: SIGKILL a replica mid-stream ---------------------
 
 @pytest.mark.chaos
-def test_serve_chaos_soak_sigkill_replica_token_identical(tmp_path):
+def test_serve_chaos_soak_sigkill_replica_token_identical(tmp_path,
+                                                          monkeypatch):
     """SIGKILL replica 0 at engine step 3 through the supervised launch
     path: its in-flight requests are re-dispatched with their received
     prefix folded, every completion is token-identical to an uninterrupted
@@ -579,8 +585,8 @@ def test_serve_chaos_soak_sigkill_replica_token_identical(tmp_path):
 
     cfg = ServeConfig(model="gpt_tiny", vocab_size=VOCAB, max_slots=2,
                       page_size=4, num_pages=32, max_pages_per_slot=8,
-                      prefill_buckets=(16,),
-                      compile_cache_dir=str(tmp_path / "aot"))
+                      prefill_buckets=(16,))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "aot"))
     prompts = [[(7 * i + j) % (VOCAB - 1) + 1 for j in range(4 + i % 3)]
                for i in range(4)]
 
@@ -669,13 +675,13 @@ def test_bench_serve_chaos_arm_record(tmp_path, monkeypatch, capsys):
 
     monkeypatch.setattr(sidecars, "write",
                         lambda name, payload: str(tmp_path / "s.json"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "aot"))
     rc = bench_serve.main([
-        "--chaos", "--model", "gpt_tiny", "--vocab-size", str(VOCAB),
+        "--chaos", "--platform", "cpu", "--model", "gpt_tiny", "--vocab-size", str(VOCAB),
         "--requests", "4", "--rate", "1000", "--max-new", "6",
         "--prompt-lens", "4,6", "--max-slots", "2", "--page-size", "4",
         "--num-pages", "32", "--max-pages-per-slot", "8",
-        "--prefill-buckets", "16",
-        "--compile-cache-dir", str(tmp_path / "aot")])
+        "--prefill-buckets", "16"])
     assert rc == 0
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert perf_report.validate(rec) == []

@@ -39,7 +39,7 @@ def _engine(model="gpt_tiny", **kw):
     kw.setdefault("num_pages", 32)
     kw.setdefault("max_pages_per_slot", 8)
     kw.setdefault("prefill_buckets", (16,))
-    kw.setdefault("compile_cache_dir", "off")
+    kw.setdefault("compile_cache", False)
     t = [0.0]
 
     def clock():
@@ -295,14 +295,14 @@ def test_fast_path_preemption_resumes_token_identical(model, draft):
 
 # --- AOT warm boot of the fast-path programs --------------------------------
 
-def test_fast_path_aot_warm_boot_zero_retrace(tmp_path):
+def test_fast_path_aot_warm_boot_zero_retrace(tmp_path, monkeypatch):
     """Both features on: the block-prefill, page-clone, draft, and verify
     programs all ride the serve fingerprint — a second engine must
     deserialize every one (zero retraces) and decode identically."""
     kw = dict(max_slots=2, page_size=4, num_pages=16, max_pages_per_slot=4,
               prefill_buckets=(8,), prefix_cache=True,
-              spec_draft_model="gpt_nano", spec_k=3,
-              compile_cache_dir=str(tmp_path))
+              spec_draft_model="gpt_nano", spec_k=3, compile_cache=True)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     cold = _engine("gpt_tiny", **kw)
     stats = cold.warmup()
     assert stats["aot_misses"] == stats["aot_saves"] > 2  # > base engine
@@ -351,7 +351,8 @@ def test_anomaly_spec_acceptance_collapse_fires_and_stays_quiet():
 
 @pytest.mark.chaos
 @pytest.mark.slow
-def test_fast_path_chaos_soak_sigkill_token_identical(tmp_path):
+def test_fast_path_chaos_soak_sigkill_token_identical(tmp_path,
+                                                      monkeypatch):
     """SIGKILL a replica mid-stream with prefix cache + spec decoding on:
     re-dispatched victims must replay token-identically (the survivor's
     radix tree and drafter state are its own — correctness can't depend
@@ -369,8 +370,8 @@ def test_fast_path_chaos_soak_sigkill_token_identical(tmp_path):
     cfg = ServeConfig(model="gpt_tiny", vocab_size=VOCAB, max_slots=2,
                       page_size=4, num_pages=32, max_pages_per_slot=8,
                       prefill_buckets=(16,), prefix_cache=True,
-                      spec_draft_model="gpt_nano", spec_k=3,
-                      compile_cache_dir=str(tmp_path / "aot"))
+                      spec_draft_model="gpt_nano", spec_k=3)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "aot"))
     head = [(3 * j) % (VOCAB - 1) + 1 for j in range(6)]
     prompts = [head + [(7 * i + j) % (VOCAB - 1) + 1
                        for j in range(2 + i % 3)] for i in range(4)]

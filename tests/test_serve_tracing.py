@@ -56,7 +56,7 @@ def _engine(model="gpt_tiny", **kw):
     kw.setdefault("num_pages", 32)
     kw.setdefault("max_pages_per_slot", 8)
     kw.setdefault("prefill_buckets", (8,))
-    kw.setdefault("compile_cache_dir", "off")
+    kw.setdefault("compile_cache", False)
     t = [0.0]
 
     def clock():

@@ -1,0 +1,128 @@
+"""The main path's Pallas kernels, compiled by the real TPU compiler.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses: a
+cast it does not lower, a slice off the tiling, too much VMEM. The TPU's
+compiler is installed here and compiles for a chip that is described, not
+attached — so these cases lower each kernel at the widths the train commands
+in README.md use, for one device of a described ``v5e:2x2``, and assert the
+``tpu_custom_call`` is in the compiled text. Nothing runs; correctness on the
+chip is chip_smoke.py's job.
+
+Which way a kernel runs is decided at lowering time from the platform of the
+devices the program is placed on (ops/pallas.py), so handing a described TPU
+device is all the steering these tests need. The topology is described inside
+a fixture — never at import, in a skipif or in parametrize arguments — because
+only one process may load libtpu and every xdist worker imports this file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_for(one_chip):
+    """``compile_for(fn, *shapes)`` -> compiled HLO text of ``fn`` lowered
+    for the described chip. The persistent compile cache is off around it:
+    an entry written for a described device cannot be read back without
+    one, and every later compile would warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def run(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield run
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+def _flash_loss(q, k, v, mask, *, causal, dropout_rate):
+    from distributeddeeplearning_tpu.ops import flash_attention
+    out = flash_attention(q, k, v, mask, causal=causal,
+                          dropout_rate=dropout_rate,
+                          dropout_seed=jnp.int32(7) if dropout_rate else None)
+    return out.astype(F32).sum()
+
+
+# (B, S, H, D), causal, dropout: the gpt2_small train shape (README's
+# ``--attn flash`` command, dropout 0.1 being the model default), masked
+# BERT-base, and the long-context D=128 shape.
+FLASH_CASES = [
+    pytest.param((16, 1024, 12, 64), True, 0.0, id="gpt2-causal"),
+    pytest.param((16, 1024, 12, 64), True, 0.1, id="gpt2-causal-dropout"),
+    pytest.param((8, 512, 12, 64), False, 0.0, id="bert-masked"),
+    pytest.param((8, 512, 12, 64), False, 0.1, id="bert-masked-dropout"),
+    pytest.param((2, 2048, 8, 128), True, 0.0, id="d128-s2048"),
+]
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("shape,causal,rate", FLASH_CASES)
+def test_flash_attention_compiles_for_v5e(compile_for, shape, causal, rate,
+                                          grad):
+    fn = functools.partial(_flash_loss, causal=causal, dropout_rate=rate)
+    if grad:
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    text = compile_for(fn, (shape, BF16), (shape, BF16), (shape, BF16),
+                       (shape[:2], I32))
+    # forward alone is one kernel; backward adds dq and dk/dv
+    assert text.count("tpu_custom_call") >= (3 if grad else 1)
+
+
+def test_fused_batchnorm_compiles_for_v5e(compile_for):
+    """bn_act_train fwd+bwd at resnet50's stem activation, batch 256:
+    (256*56*56, 64) — the lane-folded narrow-channel case."""
+    from distributeddeeplearning_tpu.ops.fused_batchnorm import bn_act_train
+
+    def loss(x, gamma, beta):
+        y, _, _ = bn_act_train(x, gamma, beta, True, 1e-5)
+        return y.astype(F32).sum()
+
+    text = compile_for(jax.grad(loss, argnums=(0, 1, 2)),
+                       ((256 * 56 * 56, 64), BF16), ((64,), F32),
+                       ((64,), F32))
+    assert text.count("tpu_custom_call") >= 4  # stats, apply, reduce, dx
+
+
+# (M, K, N): resnet50 batch-256 bottleneck 1x1 convs — stage-1 conv3,
+# stage-3 conv1, stage-4 conv3.
+@pytest.mark.parametrize("m,k,n", [
+    (802816, 64, 256), (50176, 1024, 256), (12544, 512, 2048)])
+def test_fused_linear_bn_compiles_for_v5e(compile_for, m, k, n):
+    from distributeddeeplearning_tpu.ops.fused_linear_bn import (
+        bn_linear_stats)
+
+    def loss(x, mu, inv, gamma, beta, w):
+        y, s, ss = bn_linear_stats(x, mu, inv, gamma, beta, w, True, True)
+        return y.astype(F32).sum() + s.sum() + ss.sum()
+
+    vec = ((k,), F32)
+    text = compile_for(jax.grad(loss, argnums=(0, 3, 4, 5)),
+                       ((m, k), BF16), vec, vec, vec, vec, ((k, n), BF16))
+    assert text.count("tpu_custom_call") >= 3  # fwd, dx, dw

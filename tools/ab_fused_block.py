@@ -5,8 +5,8 @@
         [--model resnet50] [--platform cpu]
 
 One JSON line per batch size: unfused and fused img/s/chip and the
-speedup. Run on a live chip (tools/chip_window.sh step 3 calls this);
---platform cpu exists for smoke-testing the harness itself.
+speedup. Needs a TPU; --platform cpu exists for smoke-testing the harness
+itself.
 """
 
 from __future__ import annotations
@@ -21,7 +21,8 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def step_rate(model: str, batch: int, steps: int, **flags) -> float:
+def step_rate(model: str, batch: int, steps: int, backend: str,
+              **flags) -> float:
     import jax
 
     from distributeddeeplearning_tpu import data as datalib
@@ -31,7 +32,7 @@ def step_rate(model: str, batch: int, steps: int, **flags) -> float:
 
     cfg = TrainConfig(model=model, global_batch_size=batch,
                       dtype="bfloat16", log_every=10**9,
-                      parallel=ParallelConfig(data=1),
+                      parallel=ParallelConfig(data=1), backend=backend,
                       data=DataConfig(synthetic=True), **flags)
     mesh, m, shd, state, train_step, _, rng = loop.build(cfg, 64)
     src = datalib.make_source(cfg, "image", shd)
@@ -39,12 +40,12 @@ def step_rate(model: str, batch: int, steps: int, **flags) -> float:
     for _ in range(5):
         state, metrics = train_step(state, src.batch(i), rng)
         i += 1
-    jax.device_get(metrics)
+    jax.block_until_ready(metrics)
     t0 = time.perf_counter()
     for _ in range(steps):
         state, metrics = train_step(state, src.batch(i), rng)
         i += 1
-    jax.device_get(metrics)
+    jax.block_until_ready(metrics)
     return batch * steps / (time.perf_counter() - t0)
 
 
@@ -53,27 +54,19 @@ def main(argv=None) -> int:
     p.add_argument("--model", default="resnet50")
     p.add_argument("--batches", default="256,512")
     p.add_argument("--steps", type=int, default=20)
-    p.add_argument("--conv3", action="store_true",
-                   help="also measure the v2 variant (stride-1 3x3 convs "
-                        "as Pallas conv+BN, --fused-conv3)")
-    p.add_argument("--platform", default=None)
+    p.add_argument("--platform", default="tpu", choices=["tpu", "cpu"])
     args = p.parse_args(argv)
-    if args.platform:
-        os.environ["JAX_PLATFORMS"] = args.platform
+    if args.platform == "cpu":
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
     variants = [("unfused", {}), ("fused", {"fused_block": True})]
-    if args.conv3:
-        # v2: 3x3 convs fused too (ops/fused_conv_bn.py). A separate
-        # variant, not a replacement — if Mosaic rejects the new kernel
-        # on-chip, the v1 verdict still lands.
-        variants.append(("fused_conv3", {"fused_block": True,
-                                         "fused_conv3": True}))
     for batch in (int(b) for b in args.batches.split(",")):
         rates = {}
         for name, flags in variants:
             try:
                 rates[name] = round(
-                    step_rate(args.model, batch, args.steps, **flags), 1)
+                    step_rate(args.model, batch, args.steps, args.platform,
+                              **flags), 1)
             except Exception as e:  # one failure must not sink the rest
                 rates[name] = None
                 rates[f"{name}_error"] = f"{type(e).__name__}: {e}"[:300]

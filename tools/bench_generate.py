@@ -29,7 +29,8 @@ def main(argv=None) -> int:
     p.add_argument("--prompt-len", type=int, default=128)
     p.add_argument("--new-tokens", type=int, default=128)
     p.add_argument("--vocab-size", type=int, default=None)
-    p.add_argument("--platform", default=None)
+    p.add_argument("--platform", default="tpu", choices=["tpu", "cpu"],
+                   help="tpu (default) needs a TPU and fails without one")
     p.add_argument("--skip-refeed", action="store_true",
                    help="cache-only (the refeed arm is O(S^2) and slow at "
                         "long prompts)")
@@ -38,12 +39,15 @@ def main(argv=None) -> int:
                         "all-accepted upper bound on spec-decode speedup")
     p.add_argument("--draft-len", type=int, default=4)
     args = p.parse_args(argv)
-    if args.platform:
-        os.environ["JAX_PLATFORMS"] = args.platform
+    if args.platform == "cpu":
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
     import jax
     import jax.numpy as jnp
     import numpy as np
+
+    from distributeddeeplearning_tpu.parallel import mesh as meshlib
+    meshlib.backend_devices(args.platform)  # no TPU, no measurement
 
     from distributeddeeplearning_tpu.models import flops as flopslib
     from distributeddeeplearning_tpu.models import model_spec

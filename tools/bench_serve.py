@@ -295,8 +295,10 @@ def main(argv=None) -> int:
     p.add_argument("--num-pages", type=int, default=128)
     p.add_argument("--max-pages-per-slot", type=int, default=4)
     p.add_argument("--prefill-buckets", default="16,32")
-    p.add_argument("--platform", default=None)
-    p.add_argument("--compile-cache-dir", default=None)
+    p.add_argument("--platform", default="tpu", choices=["tpu", "cpu"],
+                   help="tpu (default) needs a TPU and fails without one; "
+                        "cpu is the explicit way to smoke-test")
+    p.add_argument("--no-compile-cache", action="store_true")
     p.add_argument("--prefix-cache", action="store_true",
                    help="radix prefix cache on (serve fast path)")
     p.add_argument("--spec-draft-model", default=None,
@@ -334,8 +336,14 @@ def main(argv=None) -> int:
                         "window, asserting recovery is token-identical "
                         "and the page-leak check holds")
     args = p.parse_args(argv)
-    if args.platform:
-        os.environ["JAX_PLATFORMS"] = args.platform
+    if args.chaos and args.platform != "cpu":
+        # One process per chip: the in-process arms below hold the chip the
+        # supervised replica children would need.
+        p.error("--chaos is a CPU harness (its in-process arm and its "
+                "replica children cannot share a chip); pass "
+                "--platform cpu")
+    if args.platform == "cpu":
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
     import numpy as np
 
@@ -345,7 +353,10 @@ def main(argv=None) -> int:
     from distributeddeeplearning_tpu.observability import perf_report
     from distributeddeeplearning_tpu.observability import sidecars
     from distributeddeeplearning_tpu.observability import telemetry
+    from distributeddeeplearning_tpu.parallel import mesh as meshlib
     from distributeddeeplearning_tpu.serve.engine import Engine, ServeConfig
+
+    meshlib.backend_devices(args.platform)  # no TPU, no measurement
 
     if args.trace_dir:
         # Must precede Engine construction: the engine resolves its
@@ -364,7 +375,7 @@ def main(argv=None) -> int:
                               args.prefill_buckets.split(",") if x),
         seed=args.seed, prefix_cache=args.prefix_cache,
         spec_draft_model=args.spec_draft_model, spec_k=args.spec_k,
-        compile_cache_dir=args.compile_cache_dir)
+        compile_cache=not args.no_compile_cache)
 
     # Per-tenant shared system prompts, fixed across every arm and every
     # sweep rate: real multi-tenant traffic repeats the instruction head,

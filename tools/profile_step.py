@@ -51,7 +51,6 @@ def run_and_trace(args, log_dir: str) -> None:
         model=args.model, global_batch_size=args.batch_size * n_dev,
         dtype="bfloat16", log_every=10**9, fused_bn=args.fused_bn,
         fused_block=args.fused_block,
-        fused_conv3=getattr(args, "fused_conv3", False),
         attention_impl=args.attention_impl, remat=args.remat,
         parallel=ParallelConfig(data=n_dev), data=data)
     mesh, model, batch_shd, state, train_step, sched, rng = loop.build(
@@ -167,7 +166,6 @@ def main(argv=None) -> int:
     p.add_argument("--remat", action="store_true")
     p.add_argument("--fused-bn", action="store_true")
     p.add_argument("--fused-block", action="store_true")
-    p.add_argument("--fused-conv3", action="store_true")
     p.add_argument("--warmup", type=int, default=4)
     p.add_argument("--steps", type=int, default=6)
     p.add_argument("--top", type=int, default=25)
@@ -183,7 +181,6 @@ def main(argv=None) -> int:
     out["batch_per_chip"] = args.batch_size
     out["fused_bn"] = args.fused_bn
     out["fused_block"] = args.fused_block
-    out["fused_conv3"] = args.fused_conv3
     # Analytic-MFU cross-check against DEVICE-BUSY time (not wall):
     # by_family_ms should roughly partition this much useful work.
     try:

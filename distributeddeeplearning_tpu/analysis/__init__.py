@@ -24,6 +24,13 @@ that catches those bug classes before a chip ever runs them:
   provenance stamps on perf-record writes, and axis-name consistency
   between ``parallel/mesh.py`` and collective call sites.
 
+Beside the passes, :mod:`.anatomy` reads a compiled step's HLO text the
+other way round: not to check it, but to say which part of the program
+(flash kernel, head, loss, optimizer, ...) each instruction belongs to, from
+the scope and kernel names the program put there. It feeds the benchmark's
+``device_ms.*`` metrics and ``tools/profile_step.py``; it is no pass and
+reports no findings.
+
 All passes share one finding shape (:func:`finding`) and run through the
 ``tools/ddl_lint.py`` CLI, which gates tier-1 via ``@pytest.mark.lint``
 tests. Everything here is *analysis*: passes report, they never mutate,

@@ -1,0 +1,181 @@
+"""Step anatomy: which part of the program a compiled instruction belongs to.
+
+A device trace names an operation by its HLO instruction (``%fusion.3884``),
+which says nothing about what it computes. The compiled step's own HLO text
+does: every instruction carries ``metadata={op_name="..."}``, the jax name
+stack it was traced under — ``jvp(...)`` / ``transpose(...)`` for the pass,
+the flax module path, every ``jax.named_scope`` (train/steps.py's
+``STEP_SCOPES`` and ``LOSS_SCOPE``, models/gpt.py's ``embed`` / ``mlp`` /
+``head``) and every Pallas kernel's ``name`` (ops/pallas.py). This module
+reads those names back:
+
+- :func:`table` maps each instruction of an HLO module text to its
+  ``op_name``;
+- :func:`part_of` maps an ``op_name`` to ``(phase, part)``;
+- :func:`by_part` sums a trace's time per operation by the two.
+
+The vocabulary of parts and the rule that assigns them live here and nowhere
+else. Pure stdlib, like ``analysis/collectives.py``: importing it never
+imports jax.
+"""
+from __future__ import annotations
+
+import re
+
+PHASES = ("forward", "backward", "update")
+
+# Every part a step's device time is booked under. ``unattributed`` is what
+# no rule placed (instructions the compiler made without metadata, scopes the
+# rule does not know — a CNN's convolutions, today).
+PARTS = ("flash_fwd", "flash_dq", "flash_dkv", "attention_other", "mlp",
+         "layernorm", "embed", "head", "loss", "loss_scale", "optimizer",
+         "ema_guard", "grad_reduce", "unattributed")
+
+_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+# train/steps.py STEP_SCOPES outside ``grads`` -> part; all are phase update.
+_UPDATE_SCOPES = {"grad_reduce": "grad_reduce", "loss_scale": "loss_scale",
+                  "optimizer": "optimizer", "ema": "ema_guard",
+                  "guard": "ema_guard"}
+_LAYERNORM = re.compile(r"^(ln_?\w*|\w*layer_?norm\w*)$", re.IGNORECASE)
+_BLOCK = re.compile(r"^layers?_?\d+$")
+
+# "  ROOT %name = <shape> opcode(" — the shape is one token or a
+# parenthesised tuple, and may hold layout braces and memory-space
+# annotations; the opcode is the first bare word followed by "(" after it.
+_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+) = ")
+_OPCODE = re.compile(r"\s*([a-z][\w\-]*)\(")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_LEADING_NAME = re.compile(r"%?([\w.\-]*)")
+
+
+def _balanced(text: str) -> int:
+    """Index just past the parenthesis that closes ``text[0] == "("``."""
+    depth = 0
+    for i, ch in enumerate(text):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return i + 1
+    return len(text)
+
+
+def _opcode_and_operands(rest: str) -> tuple[str, list[str]]:
+    """Opcode and operand names of an instruction, from what follows
+    ``" = "``: a shape (one token, or a parenthesised tuple), the opcode, the
+    parenthesised operands."""
+    body = (rest[_balanced(rest):] if rest.startswith("(")
+            else rest.partition(" ")[2])
+    m = _OPCODE.match(body)
+    if m is None:
+        return "", []
+    operands = body[m.end() - 1:]
+    return m.group(1), _OPERAND.findall(operands[:_balanced(operands)])
+
+
+def table(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: op_name}`` over every computation of the module.
+
+    An instruction that carries no ``op_name`` of its own takes the one that
+    explains it: a fusion (anything with ``calls=``) its called
+    computation's root's; any other (the copies, bitcasts and tuples the
+    compiler inserts) its first operand's that has one, or, where it only
+    reads parameters, its first user's. HLO text lists callees before
+    callers and operands before users, so one pass resolves all three.
+    Parameters keep no name: an argument's path names no part of the
+    program. Instructions nothing explains are left out. Tolerant of torn
+    text: a line that does not parse is skipped.
+    """
+    names: dict[str, str] = {}
+    roots: dict[str, str] = {}     # computation -> its root's op_name
+    unexplained: set[str] = set()  # waiting for a user
+    computation = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                computation = c.group(1)
+            continue
+        is_root, name = bool(m.group(1)), m.group(2)
+        opcode, operands = _opcode_and_operands(line[m.end():])
+        if opcode == "parameter":
+            continue
+        meta = _OP_NAME.search(line)
+        if meta is not None:
+            op_name = meta.group(1).replace('\\"', '"').replace("\\'", "'")
+        else:
+            called = _CALLS.search(line)
+            op_name = roots.get(called.group(1)) if called else None
+            if op_name is None:
+                op_name = next((names[o] for o in operands if o in names),
+                               None)
+        if op_name is None:
+            unexplained.add(name)
+            continue
+        names[name] = op_name
+        for operand in unexplained.intersection(operands):
+            names[operand] = op_name
+            unexplained.discard(operand)
+        if is_root and computation is not None:
+            roots[computation] = op_name
+    return names
+
+
+def part_of(op_name: str) -> tuple[str, str]:
+    """``(phase, part)`` of one ``op_name``; ``part`` is one of :data:`PARTS`,
+    ``phase`` one of :data:`PHASES`.
+
+    The name stack is split into its scopes (``jit(step_fn)/grads/
+    transpose(jvp(GptLM))/layer3/ln1/mul`` -> jit, step_fn, grads, transpose,
+    jvp, GptLM, layer3, ln1, mul). Phase: an update scope of the step makes
+    it ``update``; else ``transpose`` (the linear transpose of the forward
+    pass, and a custom-vjp backward rule, which jax names
+    ``transpose(...)/jvp(...)``) makes it ``backward``; else ``jvp`` or the
+    ``grads`` scope (the forward's random keys are not differentiated) makes
+    it ``forward``; what is outside every scope of the step (its key
+    fold-in, the step counter) is ``update``. Part, first match: a flash
+    kernel's name; an update scope; ``loss`` / ``head`` / ``embed``; a
+    LayerNorm module; the ``mlp`` scope or an ``mlp*`` module; anything else
+    inside an attention module or bare in a decoder block (the attention
+    half's dropout and residual) is ``attention_other``.
+    """
+    scopes = [s for s in re.split(r"[/()]", op_name) if s]
+    have = set(scopes)
+    update = next((s for s in scopes if s in _UPDATE_SCOPES), None)
+    if update is not None:
+        return "update", _UPDATE_SCOPES[update]
+    if "transpose(" in op_name:  # the transform, not the array primitive
+        phase = "backward"
+    elif "jvp(" in op_name or "grads" in have:
+        phase = "forward"
+    else:
+        phase = "update"
+    for kernel in _KERNELS:
+        if kernel in have:
+            return phase, kernel
+    for part in ("loss", "head", "embed"):
+        if part in have:
+            return phase, part
+    if any(_LAYERNORM.match(s) for s in scopes):
+        return phase, "layernorm"
+    if any(s.startswith("mlp") for s in scopes):
+        return phase, "mlp"
+    if any("attention" in s.lower() or _BLOCK.match(s) for s in scopes):
+        return phase, "attention_other"
+    return phase, "unattributed"
+
+
+def by_part(durations: dict[str, float], table: dict[str, str]
+            ) -> dict[tuple[str, str], float]:
+    """``{(phase, part): time}`` of a trace's ``{operation: time}``. A trace
+    names an operation by its instruction or by its whole HLO line, which
+    starts with it (``%fusion.7 = ...``). An instruction that ``table`` does
+    not hold has no phase (``"-"``) and the part ``unattributed``."""
+    out: dict[tuple[str, str], float] = {}
+    for line, time in durations.items():
+        op_name = table.get(_LEADING_NAME.match(line).group(1))
+        key = part_of(op_name) if op_name else ("-", "unattributed")
+        out[key] = out.get(key, 0.0) + time
+    return out

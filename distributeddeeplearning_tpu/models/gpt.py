@@ -162,13 +162,17 @@ class DecoderBlock(nn.Module):
         x = x + nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
         h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype,
                          param_dtype=jnp.float32, name="ln2")(x)
-        h = _dense(cfg.intermediate_size, ("embed", "mlp"), "mlp_in",
-                   self.dtype)(h)
-        h = nn.gelu(h, approximate=True)  # GPT-2 uses the tanh approximation
-        h = _dense(cfg.hidden_size, ("mlp", "embed"), "mlp_out",
-                   self.dtype)(h)
-        return x + nn.Dropout(cfg.dropout_rate)(
-            h, deterministic=deterministic)
+        # Scope names here and in GptLM are what analysis/anatomy.py reads a
+        # compiled step's parts from; what is left bare in a block is the
+        # attention half's dropout and residual.
+        with jax.named_scope("mlp"):
+            h = _dense(cfg.intermediate_size, ("embed", "mlp"), "mlp_in",
+                       self.dtype)(h)
+            h = nn.gelu(h, approximate=True)  # GPT-2: the tanh approximation
+            h = _dense(cfg.hidden_size, ("mlp", "embed"), "mlp_out",
+                       self.dtype)(h)
+            return x + nn.Dropout(cfg.dropout_rate)(
+                h, deterministic=deterministic)
 
 
 class GptLM(nn.Module):
@@ -264,11 +268,12 @@ class GptLM(nn.Module):
         # (ops/embedding.py; VERDICT r4 Missing #5). Shared 1D positions
         # broadcast over the batch; paged per-row (B, 1) positions already
         # carry the batch dim.
-        pos_emb = embedding_lookup(wpe, pos_index)
-        x = (embedding_lookup(wte, input_ids)
-             + (pos_emb if pos_emb.ndim == 3 else pos_emb[None])
-             ).astype(self.dtype)
-        x = nn.Dropout(cfg.dropout_rate)(x, deterministic=deterministic)
+        with jax.named_scope("embed"):
+            pos_emb = embedding_lookup(wpe, pos_index)
+            x = (embedding_lookup(wte, input_ids)
+                 + (pos_emb if pos_emb.ndim == 3 else pos_emb[None])
+                 ).astype(self.dtype)
+            x = nn.Dropout(cfg.dropout_rate)(x, deterministic=deterministic)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
         if cfg.pipeline_stages > 1:
@@ -307,8 +312,9 @@ class GptLM(nn.Module):
             x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype,
                          param_dtype=jnp.float32, name="ln_f")(x)
-        logits = jnp.einsum("bsh,vh->bsv", x, wte.astype(self.dtype))
-        return logits.astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bsh,vh->bsv", x, wte.astype(self.dtype))
+            return logits.astype(jnp.float32)
 
 
 def _fit_positions(cfg: GptConfig, seq_len: Optional[int]) -> GptConfig:

@@ -170,6 +170,7 @@ def _fwd(q, k, v, mask, seed, *, scale, block_q, block_k, causal,
     out, lse = pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate),
+        name="flash_fwd",
         grid=(bh, s // bq, s // bk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -332,6 +333,7 @@ def _bwd(scale, block_q, block_k, causal, dropout_rate, residuals, g):
     dq = pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate),
+        name="flash_dq",
         grid=(bh, s // bq, s // bk),
         in_specs=[q_tile, k_tile, k_tile, maskk, q_tile, vec_q, vec_q,
                   smem],
@@ -351,6 +353,7 @@ def _bwd(scale, block_q, block_k, causal, dropout_rate, residuals, g):
     dk, dv = pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate),
+        name="flash_dkv",
         grid=(bh, s // bk, s // bq),
         in_specs=[q_acc, k_out, k_out, maskk2, q_acc, vec_q2, vec_q2,
                   smem],

@@ -127,6 +127,7 @@ def bn_stats(x2d: jax.Array):
     tm, tc = _tile(m, 1024), _tile(c, 512)
     s, ss = pallas_call(
         _stats_kernel,
+        name="bn_stats",
         grid=(c // tc, m // tm),
         in_specs=[pl.BlockSpec((tm, tc), lambda ci, mi: (mi, ci))],
         out_specs=[pl.BlockSpec((1, tc), lambda ci, mi: (0, ci)),
@@ -181,6 +182,7 @@ def bn_apply(x2d, mean, inv, gamma, beta, residual2d=None, *, relu: bool):
             _apply_kernel(x, mn, iv, g, b, o, relu=relu)
     return _unfold(pallas_call(
         kernel,
+        name="bn_apply",
         grid=(m // tm, c // tc),
         in_specs=in_specs,
         out_specs=tile,
@@ -244,6 +246,7 @@ def bn_bwd_reduce(dy2d, y2d, x2d, mean, inv, *, relu: bool):
             _bwd_reduce_kernel(dy, x, mn, iv, db_o, dg_o, db_s, dg_s)
     db, dg = pallas_call(
         kernel,
+        name="bn_bwd_reduce",
         grid=(c // tc, m // tm),
         in_specs=in_specs,
         out_specs=[vec, vec],
@@ -310,6 +313,7 @@ def bn_bwd_dx(dy2d, y2d, x2d, mean, inv, gamma, dbeta, dgamma, *,
 
     out = pallas_call(
         kernel,
+        name="bn_bwd_dx",
         grid=(m // tm, c // tc),
         in_specs=in_specs,
         out_specs=out_specs,

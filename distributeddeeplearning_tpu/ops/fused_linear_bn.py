@@ -111,6 +111,7 @@ def _fwd(x, mu, inv, gamma, beta, w, relu, bn):
     vn = pl.BlockSpec((1, tn), lambda ni, mi, ki: (0, ni))
     y, s, ss = pallas_call(
         functools.partial(_fwd_kernel, relu=relu, bn=bn, nk=nk),
+        name="linear_bn_fwd",
         grid=(n // tn, m // tm, nk),
         in_specs=[xs, ws, vk, vk, vk, vk],
         out_specs=[ys, vn, vn],
@@ -187,6 +188,7 @@ def _bwd_dx(dy, y, ds, dss, w, x, mu, inv, gamma, beta, relu, bn):
     vk = pl.BlockSpec((1, tk), lambda ki, mi, ni: (0, ki))
     dx, db, dg = pallas_call(
         functools.partial(_bwd_dx_kernel, relu=relu, bn=bn, nn=nn),
+        name="linear_bn_dx",
         grid=(k // tk, m // tm, nn),
         in_specs=[dys, dys, vn, vn, ws, xs, vk, vk, vk, vk],
         out_specs=[xs, vk, vk],
@@ -247,6 +249,7 @@ def _bwd_dw(x, mu, inv, gamma, beta, dy, y, ds, dss, relu, bn):
     ws = pl.BlockSpec((tk, tn), lambda ki, ni, mi: (ki, ni))
     return pallas_call(
         functools.partial(_bwd_dw_kernel, relu=relu, bn=bn, nm=nm),
+        name="linear_bn_dw",
         grid=(k // tk, n // tn, nm),
         in_specs=[xs, vk, vk, vk, vk, dys, dys, vn, vn],
         out_specs=ws,

@@ -15,14 +15,18 @@ import jax
 from jax.experimental import pallas as pl
 
 
-def pallas_call(kernel, **kwargs):
-    """``pl.pallas_call(kernel, **kwargs)``: Mosaic on TPU, interpret mode
-    elsewhere. Only the branch for the lowering platform is lowered."""
-    compiled = pl.pallas_call(kernel, **kwargs)
-    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+def pallas_call(kernel, *, name: str, **kwargs):
+    """``pl.pallas_call(kernel, name=name, **kwargs)``: Mosaic on TPU,
+    interpret mode elsewhere. Only the branch for the lowering platform is
+    lowered. ``name`` says what the kernel computes: it is the kernel's name
+    in the compiled program and the ``jax.named_scope`` round the call, so a
+    device trace and ``analysis/anatomy.py`` find the kernel by it."""
+    compiled = pl.pallas_call(kernel, name=name, **kwargs)
+    interpreted = pl.pallas_call(kernel, name=name, interpret=True, **kwargs)
 
     def call(*args):
-        return jax.lax.platform_dependent(
-            *args, tpu=compiled, default=interpreted)
+        with jax.named_scope(name):
+            return jax.lax.platform_dependent(
+                *args, tpu=compiled, default=interpreted)
 
     return call

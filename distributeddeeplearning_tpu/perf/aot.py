@@ -6,7 +6,8 @@ the train step. This module removes that cost end to end:
 
 - ``config_fingerprint`` hashes exactly the parts of a ``TrainConfig`` that
   reach the compiled program (model, topology, parallel axes, dtypes,
-  optimizer/schedule inputs, jax/jaxlib versions) and *excludes* volatile
+  optimizer/schedule inputs, jax/jaxlib versions, a digest of this
+  package's source) and *excludes* volatile
   host-side knobs (trace dirs, checkpoint paths, log cadence, fault plans).
   The one program-affecting piece of fault injection — compiled-in NaN-grad
   injection and the bad-step guard — re-enters the hash via the *resolved*
@@ -23,6 +24,13 @@ A cache hit loads byte-identical XLA output for the same program, so
 numerics are unchanged (the zero1<->replicated and chaos-soak bitwise pins
 hold with the cache hot or cold).
 
+Beside each entry ``save`` writes ``<key>.anatomy.json``, the executable's
+``{instruction name: op_name}`` table (analysis/anatomy.py), and the module
+remembers which entry each step name last resolved to, so that
+:func:`anatomy` can read the table after the step itself has been freed (the
+benchmark's metric readers run then). Written on the cold path only; a warm
+load touches nothing of it.
+
 The serve engine rides the same ``StepExecutableCache`` under its own
 ``serve/engine.py serve_fingerprint`` (a full-``ServeConfig`` hash, so
 fast-path fields — ``prefix_cache``, ``spec_draft_model``, ``spec_k`` —
@@ -34,6 +42,7 @@ from it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -42,9 +51,14 @@ import sys
 import time
 from typing import Any, Optional
 
+from distributeddeeplearning_tpu.analysis import anatomy as anatomy_lib
 from distributeddeeplearning_tpu.perf import compile_cache
 
 FORMAT_VERSION = 1
+
+# step name -> path of the entry it last resolved to (hit or save) in this
+# process; what :func:`anatomy` reads from.
+_RESOLVED: dict[str, str] = {}
 
 # TrainConfig fields that never reach the compiled step program: paths,
 # cadences, watchdog thresholds, and host-side fault orchestration. The
@@ -67,7 +81,28 @@ VOLATILE_DATA_FIELDS = frozenset({
 })
 
 
-def _versions() -> dict[str, str]:
+@functools.cache
+def source_digest() -> str:
+    """Digest of the package's ``.py`` files (relative path and bytes),
+    read once per process. The config says what program was asked for; the
+    source says what program that is. Without it a checkout whose step code
+    changed would load the executable its parent saved under the same
+    config, wherever the cache outlives a checkout."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    digest = hashlib.sha256()
+    for folder, subdirs, files in os.walk(root):
+        subdirs.sort()
+        for name in sorted(f for f in files if f.endswith(".py")):
+            path = os.path.join(folder, name)
+            digest.update(os.path.relpath(path, root).encode() + b"\0")
+            with open(path, "rb") as fh:
+                digest.update(fh.read() + b"\0")
+    return digest.hexdigest()[:16]
+
+
+def versions() -> dict[str, str]:
+    """What, besides a config, decides which program gets compiled. Part
+    of every fingerprint (train and serve) and checked again at load."""
     import jax
     import jaxlib
     # The RNG lowering is part of the compiled program: an executable built
@@ -75,7 +110,8 @@ def _versions() -> dict[str, str]:
     # (set in the package __init__) must miss the cache, not poison it.
     return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
             "threefry_partitionable":
-                str(bool(jax.config.jax_threefry_partitionable))}
+                str(bool(jax.config.jax_threefry_partitionable)),
+            "source": source_digest()}
 
 
 def config_fingerprint(config, *, total_steps: Optional[int] = None,
@@ -83,7 +119,7 @@ def config_fingerprint(config, *, total_steps: Optional[int] = None,
     """Stable hash of everything about ``config`` that shapes the compiled
     step program. Equal configs -> equal keys; volatile fields (trace dirs,
     checkpoint paths, host-side fault plans, cadences) never perturb it;
-    a jax/jaxlib upgrade always does.
+    a jax/jaxlib upgrade or an edit of this package's source always does.
 
     ``total_steps`` must be passed when known: the LR schedule bakes it
     into the update computation (train/optim.py), so two runs differing
@@ -105,7 +141,7 @@ def config_fingerprint(config, *, total_steps: Optional[int] = None,
                                                  False)),
     }
     d["_total_steps"] = total_steps
-    d["_versions"] = _versions()
+    d["_versions"] = versions()
     if extra is not None:
         d["_extra"] = extra
     blob = json.dumps(d, sort_keys=True, default=repr)
@@ -152,6 +188,49 @@ def donation_signature(compiled_exec) -> Optional[str]:
                     return "".join(text[brace:i + 1].split())
         return None
     except Exception:  # noqa: BLE001 — absence of evidence, not mismatch
+        return None
+
+
+def compile_lowered(lowered):
+    """``lowered.compile()`` for a program that becomes an entry here, with
+    JAX's persistent cache keyed on metadata for this one compile.
+
+    By default JAX leaves ``op_name`` and source lines out of its key, so a
+    tree that changed nothing but scope names gets back the executable an
+    older tree compiled, old names inside (seen on the CPU: ``lossA`` ->
+    ``lossB`` came back as ``lossA``) — and ``save`` would write that as
+    this step's anatomy. Only an entry's own compile pays for the stricter
+    key (metadata holds file paths and the caller's frames, so another
+    checkout or entry script misses); every other program keeps JAX's
+    default and is shared. The flag is process-wide for the moment of the
+    compile: a compile on another thread meanwhile only keys more strictly.
+    """
+    import jax
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update(flag, was)
+
+
+def _anatomy_path(entry_path: str) -> str:
+    return entry_path[:-len(".aotx")] + ".anatomy.json"
+
+
+def anatomy(step_name: str) -> Optional[dict[str, str]]:
+    """``{instruction name: op_name}`` of the executable that ``step_name``
+    (``"gspmd_train_step"``, ``"dp_train_step"``, ...) resolved to in this
+    process, read from the file ``save`` wrote beside the entry. None where
+    no such step was resolved through a cache, or the file is gone."""
+    path = _RESOLVED.get(step_name)
+    if path is None:
+        return None
+    try:
+        with open(_anatomy_path(path)) as fh:
+            return json.load(fh)
+    except (OSError, ValueError):
         return None
 
 
@@ -220,10 +299,10 @@ class StepExecutableCache:
                 payload = pickle.load(fh)
             if payload.get("format") != FORMAT_VERSION:
                 raise ValueError(f"format {payload.get('format')!r}")
-            if payload.get("versions") != _versions():
+            if payload.get("versions") != versions():
                 raise ValueError(
                     f"built under jax {payload.get('versions')}, "
-                    f"running {_versions()}")
+                    f"running {versions()}")
             from jax.experimental import serialize_executable
             fn = serialize_executable.deserialize_and_load(
                 payload["executable"], payload["in_tree"],
@@ -256,6 +335,7 @@ class StepExecutableCache:
             return None
         self.hits += 1
         self.sources[name] = "aot_hit"
+        _RESOLVED[name] = path
         return fn
 
     def save(self, name: str, key: str, compiled_exec) -> bool:
@@ -269,7 +349,7 @@ class StepExecutableCache:
                 compiled_exec)
             blob = pickle.dumps({
                 "format": FORMAT_VERSION,
-                "versions": _versions(),
+                "versions": versions(),
                 "runtime": runtime_tag(self.devices),
                 "name": name,
                 "fingerprint": self.fingerprint,
@@ -279,12 +359,17 @@ class StepExecutableCache:
                 "donation": donation_signature(compiled_exec),
                 "saved_at": time.time(),
             })
+            table = anatomy_lib.table(compiled_exec.as_text())
             os.makedirs(self.dir, exist_ok=True)
             path = self._path(key)
-            tmp = f"{path}.tmp.{os.getpid()}"
-            with open(tmp, "wb") as fh:
-                fh.write(blob)
-            os.replace(tmp, path)
+            for target, mode, data in (
+                    (_anatomy_path(path), "w", json.dumps(table)),
+                    (path, "wb", blob)):
+                tmp = f"{target}.tmp.{os.getpid()}"
+                with open(tmp, mode) as fh:
+                    fh.write(data)
+                os.replace(tmp, target)
+            _RESOLVED[name] = path
         except Exception as exc:  # noqa: BLE001 - saving is optional
             print(f"[aot] could not serialize {name} "
                   f"({type(exc).__name__}: {exc}); run continues uncached",

@@ -98,10 +98,10 @@ def summarize(path: Optional[str] = None) -> dict[str, Any]:
                 out["total_bytes"] += os.path.getsize(full)
             except OSError:
                 continue
-            if os.path.basename(root) == AOT_SUBDIR:
-                out["aot_entries"] += 1
-            else:
+            if os.path.basename(root) != AOT_SUBDIR:
                 out["entries"] += 1
+            elif name.endswith(".aotx"):  # not its .anatomy.json beside it
+                out["aot_entries"] += 1
     return out
 
 

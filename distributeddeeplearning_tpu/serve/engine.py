@@ -108,16 +108,16 @@ class ServeConfig:
 
 
 def serve_fingerprint(config: ServeConfig) -> str:
-    """Stable hash of the program-shaping serve config (+ jax versions) —
-    the serving analogue of ``perf/aot.config_fingerprint``, which cannot
-    be reused directly because it resolves TrainConfig-only fields (fault
-    plans) that a ServeConfig does not have."""
-    import jax
-    import jaxlib
+    """Stable hash of the program-shaping serve config (+ jax versions and
+    the package's source digest, ``perf/aot.versions``) — the serving
+    analogue of ``perf/aot.config_fingerprint``, which cannot be reused
+    directly because it resolves TrainConfig-only fields (fault plans) that
+    a ServeConfig does not have."""
+    from distributeddeeplearning_tpu.perf import aot as aotlib
 
     d = dataclasses.asdict(config)
     d.pop("compile_cache", None)  # volatile: never shapes a program
-    d["_versions"] = {"jax": jax.__version__, "jaxlib": jaxlib.__version__}
+    d["_versions"] = aotlib.versions()
     blob = json.dumps(d, sort_keys=True, default=repr)
     return hashlib.sha256(blob.encode()).hexdigest()[:32]
 
@@ -594,12 +594,14 @@ class Engine:
         deserialize instead of retracing."""
         import jax
 
+        from distributeddeeplearning_tpu.perf import aot as aotlib
+
         key = self._aot.key(name, example_args)
         cached = self._aot.load(name, key)
         if cached is not None:
             return cached
-        compiled = jax.jit(fn, donate_argnums=donate_argnums).lower(
-            *example_args).compile()
+        compiled = aotlib.compile_lowered(
+            jax.jit(fn, donate_argnums=donate_argnums).lower(*example_args))
         self._aot.save(name, key, compiled)
         return compiled
 

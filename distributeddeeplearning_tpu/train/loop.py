@@ -1565,7 +1565,7 @@ class _EvaluatorBase:
                 if fn is None:
                     lower = getattr(self.eval_step, "lower_for",
                                     None) or self.eval_step.lower
-                    fn = lower(state_struct, batch).compile()
+                    fn = aotlib.compile_lowered(lower(state_struct, batch))
                     if key is not None:
                         aot.save("eval_step", key, fn)
                 self._warm_exec = fn

@@ -37,17 +37,28 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributeddeeplearning_tpu import compat
+from distributeddeeplearning_tpu.analysis import anatomy
 from distributeddeeplearning_tpu.config import TrainConfig, resolve_precision
 from distributeddeeplearning_tpu.parallel import collectives
 from distributeddeeplearning_tpu.parallel import sharding as shardlib
 from distributeddeeplearning_tpu.parallel import zero
 from distributeddeeplearning_tpu.parallel.mesh import use_mesh
 from distributeddeeplearning_tpu.observability import telemetry
+from distributeddeeplearning_tpu.perf import aot as aotlib
 from distributeddeeplearning_tpu.robustness import faults
 from distributeddeeplearning_tpu.train import losses
 from distributeddeeplearning_tpu.train.state import TrainState
 
 DATA_AXES = ("data", "fsdp")
+
+# Scope names inside the compiled step, shared by both step builders below
+# and read back out of the executable's HLO by analysis/anatomy.py (its
+# ``part_of`` holds the same strings). Names are compile-time metadata: they
+# change no schedule and cost nothing at run time.
+STEP_SCOPES = ("grads", "grad_reduce", "loss_scale", "optimizer", "ema",
+               "guard")
+GRADS, GRAD_REDUCE, LOSS_SCALE, OPTIMIZER, EMA, GUARD = STEP_SCOPES
+LOSS_SCOPE = "loss"  # round each loss closure's loss, inside ``grads``
 
 # Trace-time counters, keyed by step name. A step function's Python body
 # runs only while jax is TRACING it, so each counter increments exactly once
@@ -74,10 +85,22 @@ def _aot_acquire(aot, name: str, jitted, args):
         tele.record_span("aot_load", t0, time.perf_counter())
         return fn
     t0 = time.perf_counter()
-    compiled_exec = jitted.lower(*args).compile()
+    compiled_exec = aotlib.compile_lowered(jitted.lower(*args))
     tele.record_span("compile", t0, time.perf_counter())
     aot.save(name, key, compiled_exec)
     return compiled_exec
+
+
+def _anatomy_of(executable) -> dict[str, str]:
+    """``{instruction name: op_name}`` of the executable a step runs
+    (analysis/anatomy.py) — ``train_step.anatomy()`` of both builders. A
+    step holds its executable from its first call on, and only when it
+    resolved one through the AOT cache; a plain ``jit`` keeps its own."""
+    if not hasattr(executable, "as_text"):
+        raise RuntimeError(
+            "this train step holds no executable to read: call it once "
+            "first, with the compile cache on (TrainConfig.compile_cache)")
+    return anatomy.table(executable.as_text())
 
 
 def _inject_nan_grads(grads, step, nan_steps):
@@ -187,9 +210,10 @@ def _image_loss_fn(model, config: TrainConfig):
         out, mutated = model.apply(
             variables, batch["image"], train=True, mutable=["batch_stats"],
             rngs={"dropout": rng})
-        loss = losses.smoothed_softmax_ce(out, batch["label"], smoothing)
-        metrics = {"loss": loss,
-                   "accuracy": losses.top1_accuracy(out, batch["label"])}
+        with jax.named_scope(LOSS_SCOPE):
+            loss = losses.smoothed_softmax_ce(out, batch["label"], smoothing)
+            metrics = {"loss": loss,
+                       "accuracy": losses.top1_accuracy(out, batch["label"])}
         return loss, (mutated.get("batch_stats"), metrics)
 
     return loss_fn
@@ -211,8 +235,9 @@ def _token_loss_fn(model, config: TrainConfig):
             {"params": params}, batch["input_ids"],
             attention_mask=batch.get("attention_mask"),
             train=True, rngs={"dropout": rng}, mutable=["moe_losses"], **kw)
-        loss = losses.mlm_loss(
-            logits, batch.get("masked_labels", batch.get("labels")))
+        with jax.named_scope(LOSS_SCOPE):
+            loss = losses.mlm_loss(
+                logits, batch.get("masked_labels", batch.get("labels")))
         metrics = {"loss": loss}
         aux_leaves = jax.tree_util.tree_leaves(mutated.get("moe_losses", {}))
         if aux_leaves:
@@ -233,8 +258,9 @@ def _causal_loss_fn(model, config: TrainConfig):
             {"params": params}, batch["input_ids"],
             attention_mask=batch.get("attention_mask"),
             train=True, rngs={"dropout": rng})
-        loss = losses.causal_lm_loss(
-            logits, batch["input_ids"], batch.get("attention_mask"))
+        with jax.named_scope(LOSS_SCOPE):
+            loss = losses.causal_lm_loss(
+                logits, batch["input_ids"], batch.get("attention_mask"))
         return loss, (None, {"loss": loss})
 
     return loss_fn
@@ -428,42 +454,43 @@ def make_dp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         # scatters, so the cross-shard sum order differs from zero1's single
         # post-accumulation scatter — same math, not bitwise; accum=1 is.)
         gchunks = pchunks = None
-        if stage == "zero3":
-            # Inside shard_map the P(DATA_AXES) in_spec on the chunked
-            # global form means state.params leaves ARE this shard's local
-            # (chunk,) slices — no dynamic_slice needed.
-            pchunks = state.params
-            if overlap:
+        with jax.named_scope(GRADS):
+            if stage == "zero3":
+                # Inside shard_map the P(DATA_AXES) in_spec on the chunked
+                # global form means state.params leaves ARE this shard's
+                # local (chunk,) slices — no dynamic_slice needed.
+                pchunks = state.params
+                if overlap:
+                    def chunk_loss(pc, bn, b, r):
+                        full = zero.gather_params_overlapped(
+                            pc, layout, DATA_AXES, payload_dtype=payload,
+                            out_dtype=gather_dtype)
+                        return lfn(full, bn, b, r)
+                    gchunks, new_bn, metrics = accumulated_grads(
+                        chunk_loss, pchunks, state.batch_stats, batch, rng,
+                        accum)
+                else:
+                    full = zero.all_gather_chunks(pchunks, layout, DATA_AXES,
+                                                  out_dtype=gather_dtype)
+                    grads, new_bn, metrics = accumulated_grads(
+                        lfn, full, state.batch_stats, batch, rng, accum)
+            elif stage == "zero2" and overlap:
+                pchunks = zero.local_chunks(state.params, layout, DATA_AXES)
+
                 def chunk_loss(pc, bn, b, r):
-                    full = zero.gather_params_overlapped(
-                        pc, layout, DATA_AXES, payload_dtype=payload,
-                        out_dtype=gather_dtype)
+                    # state.params enters as a closure CONSTANT (the
+                    # identity forward), so only the chunk cotangents
+                    # survive — the full gradient tree is never a live value.
+                    full = zero.assemble_params_overlapped(
+                        state.params, pc, layout, DATA_AXES,
+                        payload_dtype=payload)
                     return lfn(full, bn, b, r)
+
                 gchunks, new_bn, metrics = accumulated_grads(
-                    chunk_loss, pchunks, state.batch_stats, batch, rng,
-                    accum)
+                    chunk_loss, pchunks, state.batch_stats, batch, rng, accum)
             else:
-                full = zero.all_gather_chunks(pchunks, layout, DATA_AXES,
-                                              out_dtype=gather_dtype)
                 grads, new_bn, metrics = accumulated_grads(
-                    lfn, full, state.batch_stats, batch, rng, accum)
-        elif stage == "zero2" and overlap:
-            pchunks = zero.local_chunks(state.params, layout, DATA_AXES)
-
-            def chunk_loss(pc, bn, b, r):
-                # state.params enters as a closure CONSTANT (the identity
-                # forward), so only the chunk cotangents survive — the full
-                # gradient tree is never a live value.
-                full = zero.assemble_params_overlapped(
-                    state.params, pc, layout, DATA_AXES,
-                    payload_dtype=payload)
-                return lfn(full, bn, b, r)
-
-            gchunks, new_bn, metrics = accumulated_grads(
-                chunk_loss, pchunks, state.batch_stats, batch, rng, accum)
-        else:
-            grads, new_bn, metrics = accumulated_grads(
-                lfn, state.params, state.batch_stats, batch, rng, accum)
+                    lfn, state.params, state.batch_stats, batch, rng, accum)
 
         if nan_steps:
             if gchunks is not None:
@@ -471,11 +498,12 @@ def make_dp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             else:
                 grads = _inject_nan_grads(grads, state.step, nan_steps)
 
-        metrics = jax.lax.pmean(metrics, DATA_AXES)
-        if new_bn is not None:
-            # Sync running statistics (cheap; normalization itself stayed
-            # local per shard, matching per-GPU BN under Horovod).
-            new_bn = jax.lax.pmean(new_bn, DATA_AXES)
+        with jax.named_scope(GRAD_REDUCE):
+            metrics = jax.lax.pmean(metrics, DATA_AXES)
+            if new_bn is not None:
+                # Sync running statistics (cheap; normalization itself stayed
+                # local per shard, matching per-GPU BN under Horovod).
+                new_bn = jax.lax.pmean(new_bn, DATA_AXES)
 
         if sharded:
             # Shard-local optimizer update on this shard's 1/N chunk of
@@ -483,30 +511,36 @@ def make_dp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # (train/optim.py), so any cross-leaf norms (global clip,
             # LARS/LAMB trust ratios) psum their squared sums and the
             # chunked update matches the replicated one per element.
-            if gchunks is None:
-                # zero1 / overlap-off schedules: full gradient tree was
-                # materialized; run the ring's first half now.
-                gchunks = zero.reduce_scatter(grads, layout, DATA_AXES,
-                                              payload_dtype=payload)
-            gchunks = jax.tree_util.tree_map(lambda g: g / dp_size, gchunks)
+            with jax.named_scope(GRAD_REDUCE):
+                if gchunks is None:
+                    # zero1 / overlap-off schedules: full gradient tree was
+                    # materialized; run the ring's first half now.
+                    gchunks = zero.reduce_scatter(grads, layout, DATA_AXES,
+                                                  payload_dtype=payload)
+                gchunks = jax.tree_util.tree_map(
+                    lambda g: g / dp_size, gchunks)
             if scaling:
                 # Overflow check on the still-scaled chunks, then unscale.
                 # Each shard holds 1/N of every leaf, so the squared norm
                 # needs one psum to make the verdict shard-consistent.
-                overflow = ~jnp.isfinite(
-                    jax.lax.psum(_tree_sq_norm(gchunks), DATA_AXES))
-                gchunks = jax.tree_util.tree_map(
-                    lambda g: g / ls_scale, gchunks)
-            if pchunks is None:
-                pchunks = zero.local_chunks(state.params, layout, DATA_AXES)
-            updates, new_opt = tx.update(gchunks, state.opt_state, pchunks)
-            new_pchunks = optax.apply_updates(pchunks, updates)
-            if stage == "zero3":
-                # Chunks ARE the persistent parameter layout — no gather.
-                new_params = new_pchunks
-            else:
-                new_params = zero.all_gather_chunks(new_pchunks, layout,
-                                                    DATA_AXES)
+                with jax.named_scope(LOSS_SCALE):
+                    overflow = ~jnp.isfinite(
+                        jax.lax.psum(_tree_sq_norm(gchunks), DATA_AXES))
+                    gchunks = jax.tree_util.tree_map(
+                        lambda g: g / ls_scale, gchunks)
+            with jax.named_scope(OPTIMIZER):
+                if pchunks is None:
+                    pchunks = zero.local_chunks(state.params, layout,
+                                                DATA_AXES)
+                updates, new_opt = tx.update(gchunks, state.opt_state,
+                                             pchunks)
+                new_pchunks = optax.apply_updates(pchunks, updates)
+                if stage == "zero3":
+                    # Chunks ARE the persistent parameter layout — no gather.
+                    new_params = new_pchunks
+                else:
+                    new_params = zero.all_gather_chunks(new_pchunks, layout,
+                                                        DATA_AXES)
         else:
             # The allreduce. compat.shard_map runs with replication checking
             # OFF, so autodiff does NOT auto-psum gradients for the
@@ -517,20 +551,26 @@ def make_dp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # XLA can overlap with remaining backward compute. Dividing the
             # sum by the shard count turns the ring-allreduce-sum into the
             # gradient *average* hvd applies.
-            grads = collectives.all_reduce_gradients(
-                grads, DATA_AXES, axis_size=dp_size,
-                options=ar_options)
-            grads = jax.tree_util.tree_map(lambda g: g / dp_size, grads)
+            with jax.named_scope(GRAD_REDUCE):
+                grads = collectives.all_reduce_gradients(
+                    grads, DATA_AXES, axis_size=dp_size,
+                    options=ar_options)
+                grads = jax.tree_util.tree_map(lambda g: g / dp_size, grads)
             if scaling:
                 # Post-all-reduce gradients are shard-identical, so the
                 # overflow verdict is shard-consistent without a collective.
-                overflow = ~jnp.isfinite(_tree_sq_norm(grads))
-                grads = jax.tree_util.tree_map(lambda g: g / ls_scale, grads)
-            updates, new_opt = tx.update(grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+                with jax.named_scope(LOSS_SCALE):
+                    overflow = ~jnp.isfinite(_tree_sq_norm(grads))
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g / ls_scale, grads)
+            with jax.named_scope(OPTIMIZER):
+                updates, new_opt = tx.update(grads, state.opt_state,
+                                             state.params)
+                new_params = optax.apply_updates(state.params, updates)
 
-        new_ema = _ema_update(state.ema_params, new_params,
-                              config.optimizer.ema_decay)
+        with jax.named_scope(EMA):
+            new_ema = _ema_update(state.ema_params, new_params,
+                                  config.optimizer.ema_decay)
         new_ls = state.loss_scale
         if scaling:
             # Loss-scale skip-on-overflow: same select machinery as the
@@ -539,12 +579,14 @@ def make_dp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # normal mixed-precision operation, not a run anomaly, and the
             # guard below must see the already-restored (finite) state so a
             # backoff can never double-count.
-            new_params = _skip_if_bad(overflow, new_params, state.params)
-            new_opt = _skip_if_bad(overflow, new_opt, state.opt_state)
-            new_bn = _skip_if_bad(overflow, new_bn, state.batch_stats)
-            new_ema = _skip_if_bad(overflow, new_ema, state.ema_params)
-            new_ls, ls_metrics = _next_loss_scale(
-                policy, ls_scale, state.loss_scale["good_steps"], overflow)
+            with jax.named_scope(LOSS_SCALE):
+                new_params = _skip_if_bad(overflow, new_params, state.params)
+                new_opt = _skip_if_bad(overflow, new_opt, state.opt_state)
+                new_bn = _skip_if_bad(overflow, new_bn, state.batch_stats)
+                new_ema = _skip_if_bad(overflow, new_ema, state.ema_params)
+                new_ls, ls_metrics = _next_loss_scale(
+                    policy, ls_scale, state.loss_scale["good_steps"],
+                    overflow)
             metrics.update(ls_metrics)
         if guard:
             # Bad-step guard (docs/fault_tolerance.md). The decision must be
@@ -556,24 +598,25 @@ def make_dp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # catches them — one local (collective-free) reduction per step,
             # except under zero3 where new_params is this shard's chunks
             # only and the norm needs a psum to stay shard-consistent.
-            sq = _tree_sq_norm(new_params)
-            if stage == "zero3":
-                sq = jax.lax.psum(sq, DATA_AXES)
-            bad = jnp.logical_or(~jnp.isfinite(metrics["loss"]),
-                                 ~jnp.isfinite(sq))
-            if scaling:
-                # An overflow step already skipped above; even if its loss
-                # was non-finite, the scaler owns it — not the anomaly
-                # budget.
-                bad = jnp.logical_and(bad, jnp.logical_not(overflow))
-            # Skip-on-bad: the step index still advances (the batch is
-            # consumed; a skip is a skip, not a retry), but params/opt/BN/
-            # EMA keep their pre-update values so one poisoned batch can't
-            # wreck the run.
-            new_params = _skip_if_bad(bad, new_params, state.params)
-            new_opt = _skip_if_bad(bad, new_opt, state.opt_state)
-            new_bn = _skip_if_bad(bad, new_bn, state.batch_stats)
-            new_ema = _skip_if_bad(bad, new_ema, state.ema_params)
+            with jax.named_scope(GUARD):
+                sq = _tree_sq_norm(new_params)
+                if stage == "zero3":
+                    sq = jax.lax.psum(sq, DATA_AXES)
+                bad = jnp.logical_or(~jnp.isfinite(metrics["loss"]),
+                                     ~jnp.isfinite(sq))
+                if scaling:
+                    # An overflow step already skipped above; even if its loss
+                    # was non-finite, the scaler owns it — not the anomaly
+                    # budget.
+                    bad = jnp.logical_and(bad, jnp.logical_not(overflow))
+                # Skip-on-bad: the step index still advances (the batch is
+                # consumed; a skip is a skip, not a retry), but params/opt/BN/
+                # EMA keep their pre-update values so one poisoned batch can't
+                # wreck the run.
+                new_params = _skip_if_bad(bad, new_params, state.params)
+                new_opt = _skip_if_bad(bad, new_opt, state.opt_state)
+                new_bn = _skip_if_bad(bad, new_bn, state.batch_stats)
+                new_ema = _skip_if_bad(bad, new_ema, state.ema_params)
             metrics["bad_step"] = bad.astype(jnp.float32)
         new_state = TrainState(step=state.step + 1, params=new_params,
                                opt_state=new_opt, batch_stats=new_bn,
@@ -648,6 +691,7 @@ def make_dp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     # The step program as jax lowers it for these arguments — for reading
     # what was compiled (kernels, collectives, memory), not for running.
     compiled.lower = jitted.lower
+    compiled.anatomy = lambda: _anatomy_of(aot_exec["fn"])
     # Raw traceable step for the fused multi-step loop
     # (make_fused_train_loop): shard_map composes under an outer jit+scan.
     compiled.raw_step = mapped
@@ -866,7 +910,7 @@ def make_gspmd_train_step(model, tx, mesh: Mesh, config: TrainConfig,
                 return loss * ls_scale, aux
         else:
             lfn = loss_fn
-        with _unreplicated_rules_ctx(config):
+        with _unreplicated_rules_ctx(config), jax.named_scope(GRADS):
             # Microbatching under GSPMD: the (B,) -> (A, B/A) reshape crosses
             # the dp sharding, so XLA may insert a small resharding collective
             # on the *batch* (token batches are tiny; image configs use the
@@ -886,34 +930,41 @@ def make_gspmd_train_step(model, tx, mesh: Mesh, config: TrainConfig,
             # One logical program: XLA inserts whatever cross-shard
             # reduction the norm needs, so the verdict is globally
             # consistent without an explicit psum.
-            overflow = ~jnp.isfinite(_tree_sq_norm(grads))
-            grads = jax.tree_util.tree_map(lambda g: g / ls_scale, grads)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_ema = _ema_update(state.ema_params, new_params,
-                              config.optimizer.ema_decay)
+            with jax.named_scope(LOSS_SCALE):
+                overflow = ~jnp.isfinite(_tree_sq_norm(grads))
+                grads = jax.tree_util.tree_map(lambda g: g / ls_scale, grads)
+        with jax.named_scope(OPTIMIZER):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(EMA):
+            new_ema = _ema_update(state.ema_params, new_params,
+                                  config.optimizer.ema_decay)
         new_ls = state.loss_scale
         if scaling:
-            new_params = _skip_if_bad(overflow, new_params, state.params)
-            new_opt = _skip_if_bad(overflow, new_opt, state.opt_state)
-            new_bn = _skip_if_bad(overflow, new_bn, state.batch_stats)
-            new_ema = _skip_if_bad(overflow, new_ema, state.ema_params)
-            new_ls, ls_metrics = _next_loss_scale(
-                policy, ls_scale, state.loss_scale["good_steps"], overflow)
+            with jax.named_scope(LOSS_SCALE):
+                new_params = _skip_if_bad(overflow, new_params, state.params)
+                new_opt = _skip_if_bad(overflow, new_opt, state.opt_state)
+                new_bn = _skip_if_bad(overflow, new_bn, state.batch_stats)
+                new_ema = _skip_if_bad(overflow, new_ema, state.ema_params)
+                new_ls, ls_metrics = _next_loss_scale(
+                    policy, ls_scale, state.loss_scale["good_steps"],
+                    overflow)
             metrics.update(ls_metrics)
         if bad_guard:
             # Bad-step guard on the post-update params (same placement as
             # the DP path). One logical program: XLA inserts any cross-shard
             # reduction the norm needs, so the scalar is globally
             # consistent without an explicit psum.
-            bad = jnp.logical_or(~jnp.isfinite(metrics["loss"]),
-                                 ~jnp.isfinite(_tree_sq_norm(new_params)))
-            if scaling:
-                bad = jnp.logical_and(bad, jnp.logical_not(overflow))
-            new_params = _skip_if_bad(bad, new_params, state.params)
-            new_opt = _skip_if_bad(bad, new_opt, state.opt_state)
-            new_bn = _skip_if_bad(bad, new_bn, state.batch_stats)
-            new_ema = _skip_if_bad(bad, new_ema, state.ema_params)
+            with jax.named_scope(GUARD):
+                bad = jnp.logical_or(
+                    ~jnp.isfinite(metrics["loss"]),
+                    ~jnp.isfinite(_tree_sq_norm(new_params)))
+                if scaling:
+                    bad = jnp.logical_and(bad, jnp.logical_not(overflow))
+                new_params = _skip_if_bad(bad, new_params, state.params)
+                new_opt = _skip_if_bad(bad, new_opt, state.opt_state)
+                new_bn = _skip_if_bad(bad, new_bn, state.batch_stats)
+                new_ema = _skip_if_bad(bad, new_ema, state.ema_params)
             metrics["bad_step"] = bad.astype(jnp.float32)
         new_state = TrainState(step=state.step + 1, params=new_params,
                                opt_state=new_opt, batch_stats=new_bn,
@@ -982,6 +1033,8 @@ def make_gspmd_train_step(model, tx, mesh: Mesh, config: TrainConfig,
 
     compiled.warm = warm
     compiled.lower = lower
+    compiled.anatomy = lambda: _anatomy_of(
+        next(iter(jit_cache.values()), None))
     compiled.raw_step = step_fn
     compiled.state_shardings = state_shardings
     return compiled

@@ -1,0 +1,263 @@
+"""Step anatomy (analysis/anatomy.py): the names the program puts into its
+compiled train step, read back per instruction and mapped to a phase and a
+part. The tiny GPT step is compiled once here on the CPU; what the parts
+cost on the chip is the benchmark's to say (`device_ms.*`).
+"""
+
+import collections
+import re
+
+import jax
+import pytest
+
+from distributeddeeplearning_tpu import data as datalib
+from distributeddeeplearning_tpu.analysis import anatomy
+from distributeddeeplearning_tpu.config import (
+    DataConfig, OptimizerConfig, ParallelConfig, PrecisionPolicy, TrainConfig)
+from distributeddeeplearning_tpu.models import model_spec
+from distributeddeeplearning_tpu.parallel.mesh import use_mesh
+from distributeddeeplearning_tpu.perf import aot, compile_cache
+from distributeddeeplearning_tpu.train import loop
+
+LAYERS = 2  # gpt_tiny
+
+
+def _config(**kw):
+    policy = PrecisionPolicy.mixed()
+    return TrainConfig(
+        model="gpt_tiny", global_batch_size=4, seed=0,
+        dtype=policy.compute_dtype, precision=policy, log_every=10 ** 9,
+        attention_impl="flash", parallel=ParallelConfig(data=1),
+        data=DataConfig(synthetic=True, dataset="mlm", seq_len=64,
+                        vocab_size=1024),
+        optimizer=OptimizerConfig(
+            name="adamw", learning_rate=6e-4, reference_batch=4,
+            weight_decay=0.1, schedule="constant", warmup_epochs=0.0,
+            beta1=0.9, beta2=0.95), **kw)
+
+
+def _built(config):
+    mesh, _model, batch_shd, state, step, _sched, rng = loop.build(
+        config, 1000)
+    spec = model_spec(config.model)
+    batch = datalib.make_source(config, spec.input_kind, batch_shd,
+                                objective=spec.objective).batch(0)
+    return mesh, state, batch, rng, step
+
+
+@pytest.fixture(scope="module")
+def tiny_step():
+    """The tiny GPT step as train.py builds it (mixed precision, flash
+    attention with dropout), without the compile cache."""
+    return _built(_config(compile_cache=False))
+
+
+@pytest.fixture(scope="module")
+def compiled_text(tiny_step):
+    _mesh, state, batch, rng, step = tiny_step
+    return step.lower(state, batch, rng).compile().as_text()
+
+
+# What moves no data of its own: the compiler's bookkeeping round the work.
+PLUMBING = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+            "copy"}
+
+
+def _entry_instructions(text):
+    """(name, opcode) of the entry computation's instructions."""
+    lines = text.splitlines()
+    start = next(i for i, ln in enumerate(lines) if ln.startswith("ENTRY"))
+    out = []
+    for ln in lines[start + 1:]:
+        if ln.startswith("}"):
+            break
+        name = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = ", ln).group(1)
+        opcode = re.search(r"[\s)]([a-z][\w\-]*)\(", ln.split(" = ", 1)[1])
+        out.append((name, opcode.group(1)))
+    return out
+
+
+def test_every_entry_instruction_of_the_tiny_step_gets_a_part(compiled_text):
+    table = anatomy.table(compiled_text)
+    working = [(name, op) for name, op in _entry_instructions(compiled_text)
+               if op not in PLUMBING]
+    assert len(working) > 300
+    seen = collections.Counter()
+    for name, _ in working:
+        phase, part = (anatomy.part_of(table[name]) if name in table
+                       else ("update", "unattributed"))
+        assert phase in anatomy.PHASES and part in anatomy.PARTS, name
+        seen[phase, part] += 1
+    unattributed = sum(n for (_, part), n in seen.items()
+                       if part == "unattributed")
+    assert unattributed < 0.05 * len(working), seen
+    # each part of the step is there, in the phase it belongs to
+    for key in [("forward", "flash_fwd"), ("backward", "flash_dq"),
+                ("backward", "flash_dkv"), ("forward", "head"),
+                ("backward", "head"), ("forward", "loss"),
+                ("backward", "loss"), ("forward", "embed"),
+                ("forward", "mlp"), ("backward", "mlp"),
+                ("forward", "layernorm"), ("backward", "layernorm"),
+                ("forward", "attention_other"),
+                ("backward", "attention_other"), ("update", "loss_scale"),
+                ("update", "optimizer")]:
+        assert seen[key] > 0, (key, seen)
+
+
+STEP = "jit(step_fn)/"
+GPT = STEP + "grads/jvp(GptLM)/"
+GPT_T = STEP + "grads/transpose(jvp(GptLM))/"
+CASES = [
+    (GPT + "layer3/attention/flash_fwd/cond/branch_0_fun/flash_fwd/"
+     "pallas_call", "forward", "flash_fwd"),
+    (STEP + "grads/transpose(grads)/jvp(GptLM)/layer3/attention/flash_dq/"
+     "cond/branch_0_fun/flash_dq/pallas_call", "backward", "flash_dq"),
+    (STEP + "grads/transpose(grads)/jvp(GptLM)/layer0/attention/flash_dkv/"
+     "cond/branch_0_fun/flash_dkv/pallas_call", "backward", "flash_dkv"),
+    (GPT + "layer0/attention/query/dot_general", "forward",
+     "attention_other"),
+    # the array primitive `transpose` is no backward pass
+    (GPT + "layer0/attention/transpose", "forward", "attention_other"),
+    (GPT + "layer11/Dropout_0/jit(_bernoulli)/jit(_uniform)/add", "forward",
+     "attention_other"),
+    (GPT_T + "layer0/attention/key/dot_general", "backward",
+     "attention_other"),
+    (GPT + "layer0/mlp/mlp_in/dot_general", "forward", "mlp"),
+    (GPT + "layer0/mlp/tanh", "forward", "mlp"),
+    (GPT_T + "layer0/mlp/mlp_out/reduce_sum", "backward", "mlp"),
+    (GPT + "layer0/ln1/rsqrt", "forward", "layernorm"),
+    (GPT + "ln_f/reduce_sum", "forward", "layernorm"),
+    (GPT_T + "layer5/ln2/mul", "backward", "layernorm"),
+    (GPT + "embed/gather", "forward", "embed"),
+    (GPT_T + "embed/scatter-add", "backward", "embed"),
+    (GPT + "head/bsh,vh->bsv/dot_general", "forward", "head"),
+    (GPT_T + "head/bsh,vh->bsv/dot_general", "backward", "head"),
+    (STEP + "grads/jvp(loss)/reduce_max", "forward", "loss"),
+    (STEP + "grads/transpose(jvp(loss))/jit(take_along_axis)/scatter-add",
+     "backward", "loss"),
+    (STEP + "loss_scale/reduce_sum", "update", "loss_scale"),
+    (STEP + "loss_scale/jit(_where)/select_n", "update", "loss_scale"),
+    (STEP + "optimizer/sqrt", "update", "optimizer"),
+    (STEP + "ema/mul", "update", "ema_guard"),
+    (STEP + "guard/reduce_sum", "update", "ema_guard"),
+    (STEP + "grad_reduce/psum", "update", "grad_reduce"),
+    (STEP + "jit(_threefry_fold_in)/slice", "update", "unattributed"),
+    (STEP + "grads/jit(_threefry_fold_in)/GptLM.__call__/xor", "forward",
+     "unattributed"),
+    (STEP + "grads/transpose(jvp(ResNet))/conv_init/conv_general_dilated",
+     "backward", "unattributed"),
+]
+
+
+@pytest.mark.parametrize("op_name,phase,part", CASES,
+                         ids=[f"{c[1]}-{c[2]}-{i}"
+                              for i, c in enumerate(CASES)])
+def test_part_of(op_name, phase, part):
+    assert anatomy.part_of(op_name) == (phase, part)
+
+
+def test_the_cases_cover_the_vocabulary():
+    assert {c[2] for c in CASES} == set(anatomy.PARTS)
+    assert {c[1] for c in CASES} == set(anatomy.PHASES)
+
+
+def test_table_resolves_fusions_and_the_compilers_copies():
+    text = """HloModule jit_f, entry_computation_layout={(f32[8]{0})->f32[]}
+
+%fused_computation.1 (param_0.1: f32[8]) -> f32[] {
+  %param_0.1 = f32[8]{0} parameter(0)
+  %mul.3 = f32[8]{0} multiply(%param_0.1, %param_0.1), metadata={op_name="jit(f)/optimizer/mul"}
+  ROOT %reduce.2 = f32[] reduce(%mul.3), to_apply=%region_0, metadata={op_name="jit(f)/optimizer/reduce_sum" source_file="a.py" source_line=3}
+}
+
+ENTRY %main.9 (x.1: f32[8]) -> f32[] {
+  %x.1 = f32[8]{0:T(128)} parameter(0), metadata={op_name="x"}
+  %copy.4 = f32[8]{0:T(128)S(1)} copy(%x.1)
+  %fusion.7 = f32[] fusion(%copy.4), kind=kInput, calls=%fused_computation.1
+  %named.1 = (f32[], f32[8]{0:T(8,128)(2,1)}) custom-call(%fusion.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/grads/jvp(M)/flash_fwd/pallas_call"}
+  %bitcast.5 = f32[] bitcast(%named.1)
+  ROOT %orphan.1 = f32[] constant(0)
+}
+"""
+    assert anatomy.table(text) == {
+        "mul.3": "jit(f)/optimizer/mul",
+        "reduce.2": "jit(f)/optimizer/reduce_sum",
+        # a fusion is what its root is; a copy of a parameter belongs to the
+        # first instruction that reads it; a bitcast to what it reads
+        "fusion.7": "jit(f)/optimizer/reduce_sum",
+        "copy.4": "jit(f)/optimizer/reduce_sum",
+        "named.1": "jit(f)/grads/jvp(M)/flash_fwd/pallas_call",
+        "bitcast.5": "jit(f)/grads/jvp(M)/flash_fwd/pallas_call",
+    }
+    assert anatomy.table("torn { text\n  %a = f32[] add(") == {}
+
+
+def test_by_part_joins_a_traces_operations_to_the_table():
+    table = {"fusion.7": GPT_T + "head/bsh,vh->bsv/dot_general",
+             "flash_fwd.3": GPT + "layer0/attention/flash_fwd/pallas_call"}
+    durations = {"%fusion.7 = f32[8]{0} fusion(%a), kind=kLoop": 2.0,
+                 "flash_fwd.3": 3.0,            # named by the instruction
+                 "%flash_fwd.3 = bf16[4] custom-call(%q)": 0.5,
+                 "%fusion.70 = f32[] fusion()": 0.25}  # not fusion.7
+    assert anatomy.by_part(durations, table) == {
+        ("backward", "head"): 2.0, ("forward", "flash_fwd"): 3.5,
+        ("-", "unattributed"): 0.25}
+
+
+def test_step_lowered_for_tpu_names_each_flash_kernel_once_a_layer(tiny_step):
+    """Lowered for the TPU platform from the CPU (nothing compiles, no libtpu
+    is loaded), the step holds one Mosaic call of each name per layer."""
+    mesh, state, batch, rng, step = tiny_step
+    with use_mesh(mesh):
+        text = jax.jit(step.raw_step).trace(state, batch, rng).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 3 * LAYERS
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert text.count(f'kernel_name = "{name}"') == LAYERS, name
+
+
+def test_pallas_call_without_a_name_raises():
+    from distributeddeeplearning_tpu.ops.pallas import pallas_call
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    shape = jax.ShapeDtypeStruct((8, 128), jax.numpy.float32)
+    with pytest.raises(TypeError, match="name"):
+        pallas_call(kernel, out_shape=shape)
+    out = pallas_call(kernel, name="copy_tile", out_shape=shape)(
+        jax.numpy.ones((8, 128)))
+    assert out.shape == (8, 128)
+
+
+def test_step_scopes_are_the_rules_update_scopes():
+    """train/steps.py names the scopes, analysis/anatomy.py reads them: the
+    two lists must agree."""
+    from distributeddeeplearning_tpu.train import steps
+    assert set(steps.STEP_SCOPES) - {steps.GRADS} == \
+        set(anatomy._UPDATE_SCOPES)
+    assert steps.LOSS_SCOPE in anatomy.PARTS
+
+
+@pytest.fixture
+def cache_here(tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    yield str(tmp_path / "cache")
+    monkeypatch.undo()
+    compile_cache.activate()
+
+
+def test_a_live_step_and_the_saved_file_give_the_same_table(cache_here):
+    """`train_step.anatomy()` reads the executable the step runs;
+    `aot.anatomy(name)` reads what `save` wrote beside the entry, and still
+    does once the step is gone."""
+    _mesh, state, batch, rng, step = _built(_config())
+    with pytest.raises(RuntimeError, match="no executable"):
+        step.anatomy()
+    state, _ = step(state, batch, rng)
+    live = step.anatomy()
+    assert {"flash_fwd", "optimizer"} <= {
+        anatomy.part_of(v)[1] for v in live.values()}
+    del step, state
+    assert aot.anatomy("gspmd_train_step") == live
+    assert aot.anatomy("no_such_step") is None

@@ -201,6 +201,111 @@ def test_jax_version_changes_key(monkeypatch):
     assert aot.config_fingerprint(_cfg(), total_steps=10) != base
 
 
+@pytest.mark.core
+def test_source_digest_is_part_of_the_key(monkeypatch):
+    """The config says which program was asked for, the package's source
+    which program that is: the same tree gives the same key twice, another
+    tree's digest another key (train and serve), and its payload is refused
+    at load even under an equal key."""
+    from distributeddeeplearning_tpu.serve.engine import (ServeConfig,
+                                                          serve_fingerprint)
+    monkeypatch.delenv(faults.ENV_PLAN, raising=False)
+    monkeypatch.delenv(faults.ENV_ATTEMPT, raising=False)
+    base = aot.config_fingerprint(_cfg(), total_steps=10)
+    serve = serve_fingerprint(ServeConfig(model="gpt_tiny"))
+    assert aot.config_fingerprint(_cfg(), total_steps=10) == base
+    assert aot.source_digest() == aot.source_digest()
+    assert aot.versions()["source"] == aot.source_digest()
+    monkeypatch.setattr(aot, "source_digest", lambda: "another-tree")
+    assert aot.config_fingerprint(_cfg(), total_steps=10) != base
+    assert serve_fingerprint(ServeConfig(model="gpt_tiny")) != serve
+
+
+def test_source_digest_reads_the_package_files(tmp_path, monkeypatch):
+    """The digest covers every .py file under the package by path and
+    content, and nothing else."""
+    pkg = tmp_path / "pkg"
+    (pkg / "perf").mkdir(parents=True)
+    (pkg / "perf" / "aot.py").write_text("a = 1\n")
+    (pkg / "steps.py").write_text("b = 2\n")
+    (pkg / "notes.txt").write_text("not source\n")
+    monkeypatch.setattr(aot, "__file__", str(pkg / "perf" / "aot.py"))
+
+    def digest():
+        aot.source_digest.cache_clear()
+        return aot.source_digest()
+
+    try:
+        first = digest()
+        assert digest() == first
+        (pkg / "notes.txt").write_text("changed\n")
+        assert digest() == first
+        (pkg / "steps.py").write_text("b = 3\n")
+        changed = digest()
+        assert changed != first
+        (pkg / "steps.py").rename(pkg / "steps2.py")
+        assert digest() not in (first, changed)
+    finally:
+        monkeypatch.undo()
+        aot.source_digest.cache_clear()
+
+
+@pytest.mark.usefixtures("devices8")
+def test_entry_saved_by_another_tree_is_not_loaded(tmp_path, monkeypatch):
+    """Even handed the same key, a payload whose `versions` name another
+    source digest is a miss, deleted and recompiled cold; and `save` leaves
+    the step's anatomy table beside the entry."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    one = jax.devices()[:1]
+    x = jnp.arange(4.0)
+
+    def scaled(x):
+        with jax.named_scope("optimizer"):
+            return x * 2.0
+
+    compiled = jax.jit(scaled).lower(x).compile()
+    cache = aot.StepExecutableCache.for_config(_cfg(), one, total_steps=4)
+    key = cache.key("double", (x,))
+    assert cache.save("double", key, compiled)
+    table = aot.anatomy("double")
+    assert table and all("optimizer" in v for v in table.values())
+    with open(os.path.join(str(tmp_path), compile_cache.AOT_SUBDIR,
+                           f"{key}.anatomy.json")) as fh:
+        assert json.load(fh) == table
+    again = aot.StepExecutableCache.for_config(_cfg(), one, total_steps=4)
+    assert again.key("double", (x,)) == key
+    assert again.load("double", key) is not None
+    monkeypatch.setattr(aot, "source_digest", lambda: "another-tree")
+    other = aot.StepExecutableCache.for_config(_cfg(), one, total_steps=4)
+    assert other.key("double", (x,)) != key
+    assert other.load("double", key) is None and other.failures == 1
+
+
+def test_an_entrys_compile_never_gets_another_trees_names(
+        tmp_path, monkeypatch, restore_jax_cache):
+    """Two programs that differ only in a scope name are one program to
+    JAX's persistent cache (metadata is not in its key), so the second
+    would come back with the first's names in it. `aot.compile_lowered`
+    keys that one compile on metadata, and puts the flag back."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    compile_cache.activate()
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    assert getattr(jax.config, flag) is False
+
+    def program(scope):
+        def fn(x):
+            with jax.named_scope(scope):
+                return jnp.tanh(x @ x).sum()
+        return jax.jit(fn).lower(jnp.ones((64, 64)))
+
+    assert "/lossA/" in program("lossA").compile().as_text()
+    stale = program("lossB").compile().as_text()
+    assert "/lossA/" in stale and "/lossB/" not in stale  # the hazard
+    assert "/lossB/" in aot.compile_lowered(program("lossB")).as_text()
+    assert "/lossC/" in aot.compile_lowered(program("lossC")).as_text()
+    assert getattr(jax.config, flag) is False
+
+
 # ---------------------------------------------------------------------------
 # Warm restart: zero retraces through run_with_restarts
 # ---------------------------------------------------------------------------

@@ -1,7 +1,8 @@
 """tools/profile_step.py's trace aggregation, against a synthetic perfetto
 trace — the tool backs BASELINE.md's where-the-step-goes claims, so its
 track selection (XLA Ops only, no double-counting of module/step slices)
-and family classification are pinned here."""
+and its grouping by the step's anatomy (phase and part of each instruction,
+from the table the compiled step gives) are pinned here."""
 
 import gzip
 import json
@@ -52,19 +53,27 @@ def test_summarize_uses_only_the_ops_track(tmp_path):
         # Host-side slice — wrong pid, must not be counted.
         {"ph": "X", "pid": 2, "tid": 1, "name": "fusion.1", "dur": 77777},
     ]
-    out = profile_step.summarize(_trace(tmp_path, events), steps=2, top=10)
+    table = {
+        "fusion.1": "jit(step_fn)/grads/jvp(ResNet)/head/dot_general",
+        "convert_reduce_fusion.2": "jit(step_fn)/loss_scale/reduce_sum",
+        "copy.5": "jit(step_fn)/optimizer/mul",
+    }
+    out = profile_step.summarize(_trace(tmp_path, events), steps=2, top=10,
+                                 table=table)
     # 6.5 ms of ops over 2 steps = 3.25 ms/step; the 9 ms module slice and
     # the 77 ms host slice are excluded.
     assert out["device_ms_per_step"] == pytest.approx(3.25)
-    fam = out["by_family_ms"]
-    assert fam["elementwise"] == pytest.approx(1.5)   # fusion.1
-    assert fam["bn_reduce"] == pytest.approx(1.0)     # convert_reduce
-    assert fam["copy_reshape"] == pytest.approx(0.5)  # copy.5
-    assert fam["other"] == pytest.approx(0.25)        # bn_stem (pallas name)
+    parts = out["by_part_ms"]
+    assert parts["forward/head"] == pytest.approx(1.5)       # fusion.1
+    assert parts["update/loss_scale"] == pytest.approx(1.0)  # convert_reduce
+    assert parts["update/optimizer"] == pytest.approx(0.5)   # copy.5
+    assert parts["-/unattributed"] == pytest.approx(0.25)    # not in table
+    assert list(parts) == ["forward/head", "update/loss_scale",
+                           "update/optimizer", "-/unattributed"]
     assert out["top_ops_ms"]["fusion.1"] == pytest.approx(1.5)
     assert "jit_step_fn" not in out["top_ops_ms"]
 
 
 def test_summarize_missing_trace_raises(tmp_path):
     with pytest.raises(FileNotFoundError):
-        profile_step.summarize(str(tmp_path), steps=1, top=5)
+        profile_step.summarize(str(tmp_path), steps=1, top=5, table={})

@@ -2,15 +2,16 @@
 """Op-level device-time profile of a train step (the BASELINE.md method).
 
 Runs a few steps of any config under ``jax.profiler.trace`` with a perfetto
-JSON trace, then aggregates on-device slice durations by a coarse op family
-(conv/matmul fusions, BN-ish reduce fusions, elementwise passes, Pallas
-custom calls, copies, infeed). This is how "where the step goes" tables in
-BASELINE.md are produced; it needs a live chip to say anything about TPU.
+JSON trace, then aggregates on-device slice durations by the part of the
+program each operation belongs to: the step's own anatomy
+(``train_step.anatomy()``, analysis/anatomy.py — phase and part read from
+the scope and kernel names the program compiled in), joined to the trace on
+the instruction name. It needs a live chip to say anything about TPU.
 
     python tools/profile_step.py --model resnet50 --batch-size 256 \
         [--fused-bn] [--steps 6] [--top 25]
 
-Prints one JSON line: total device ms/step and a per-family + per-op-top-N
+Prints one JSON line: total device ms/step and a per-part + per-op-top-N
 breakdown (ms/step, averaged over the traced steps).
 """
 
@@ -31,7 +32,9 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def run_and_trace(args, log_dir: str) -> None:
+def run_and_trace(args, log_dir: str) -> dict:
+    """Trace ``args.steps`` steps into ``log_dir``; returns the step's
+    anatomy table (``{instruction name: op_name}``)."""
     import jax
 
     from distributeddeeplearning_tpu import data as datalib
@@ -68,40 +71,15 @@ def run_and_trace(args, log_dir: str) -> None:
             state, metrics = train_step(state, source.batch(i), rng)
             i += 1
         jax.device_get(metrics)
+    return train_step.anatomy()
 
 
-FAMILIES = (
-    # (family, compiled regex over slice name + HLO metadata) — first
-    # match wins. conv_matmul outranks the reduce/elementwise families
-    # because an XLA *fusion* slice whose metadata mentions a convolution
-    # or dot is MXU work with fused epilogues, not an elementwise pass —
-    # classifying those by the bare "fusion"/"convert_reduce" slice name
-    # is exactly how the round-2 profile undercounted conv time
-    # (BASELINE.md's MFU-correction note).
-    ("pallas", re.compile(r"custom-call|pallas|tpu_custom_call")),
-    ("conv_matmul", re.compile(
-        r"convolution|conv_general|dot_general|dot\b|matmul|cudnn|mxu")),
-    ("bn_reduce", re.compile(r"convert_reduce|reduce")),
-    ("elementwise", re.compile(
-        r"fusion|add|multiply|maximum|select|convert|divide|subtract|rsqrt")),
-    ("copy_reshape", re.compile(r"copy|bitcast|reshape|transpose|pad|slice")),
-    ("infeed_outfeed", re.compile(r"infeed|outfeed|transfer")),
-)
+def summarize(log_dir: str, steps: int, top: int, table: dict):
+    """Device ms/step of the newest perfetto trace under ``log_dir``: total,
+    by ``phase/part`` of the step's anatomy ``table``, and the top ops. An
+    operation the table does not hold is booked under ``-/unattributed``."""
+    from distributeddeeplearning_tpu.analysis import anatomy
 
-
-def classify(name: str, meta: str = "") -> str:
-    """Family for a trace slice. ``meta`` is the stringified event args —
-    jax's perfetto traces carry the HLO long name / source expression
-    there, which reveals what a generically-named fusion actually
-    computes."""
-    low = f"{name} {meta}".lower()
-    for fam, pat in FAMILIES:
-        if pat.search(low):
-            return fam
-    return "other"
-
-
-def summarize(log_dir: str, steps: int, top: int):
     paths = glob.glob(os.path.join(
         log_dir, "**", "*perfetto_trace.json.gz"), recursive=True)
     if not paths:
@@ -131,26 +109,22 @@ def summarize(log_dir: str, steps: int, top: int):
     op_keys = {key for key, name in tid_names.items()
                if key[0] in device_pids and "op" in name.lower()}
     per_op = collections.Counter()
-    op_meta: dict = {}
     for ev in events:
         if ev.get("ph") != "X" or (ev.get("pid"), ev.get("tid")) not in op_keys:
             continue
-        name = ev.get("name", "?")
-        per_op[name] += ev.get("dur", 0)  # microseconds
-        if name not in op_meta and ev.get("args"):
-            op_meta[name] = " ".join(str(v) for v in ev["args"].values())
+        per_op[ev.get("name", "?")] += ev.get("dur", 0)  # microseconds
     if not per_op:  # fall back: no recognized op track
         for ev in events:
             if ev.get("ph") == "X":
                 per_op[ev.get("name", "?")] += ev.get("dur", 0)
-    fam = collections.Counter()
-    for name, us in per_op.items():
-        fam[classify(name, op_meta.get(name, ""))] += us
+    parts = collections.Counter({
+        "/".join(key): us
+        for key, us in anatomy.by_part(per_op, table).items()})
     total_ms = sum(per_op.values()) / 1000 / steps
     return {
         "device_ms_per_step": round(total_ms, 2),
-        "by_family_ms": {k: round(v / 1000 / steps, 2)
-                         for k, v in fam.most_common()},
+        "by_part_ms": {k: round(v / 1000 / steps, 2)
+                       for k, v in parts.most_common()},
         "top_ops_ms": {name: round(us / 1000 / steps, 2)
                        for name, us in per_op.most_common(top)},
         "device_tracks": sorted(pid_names[p] for p in device_pids),
@@ -175,14 +149,14 @@ def main(argv=None) -> int:
 
     log_dir = args.keep_trace or tempfile.mkdtemp(prefix="ddl_profile_")
     t0 = time.time()
-    run_and_trace(args, log_dir)
-    out = summarize(log_dir, args.steps, args.top)
+    table = run_and_trace(args, log_dir)
+    out = summarize(log_dir, args.steps, args.top, table)
     out["model"] = args.model
     out["batch_per_chip"] = args.batch_size
     out["fused_bn"] = args.fused_bn
     out["fused_block"] = args.fused_block
     # Analytic-MFU cross-check against DEVICE-BUSY time (not wall):
-    # by_family_ms should roughly partition this much useful work.
+    # by_part_ms should roughly partition this much useful work.
     try:
         from distributeddeeplearning_tpu.config import (
             resolve_mlm_max_predictions)

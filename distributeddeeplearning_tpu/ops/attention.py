@@ -6,8 +6,9 @@ key-padding mask, optionally causal.
 
 - ``dense``: materialized (S, S) scores, f32 softmax, XLA-fused — right for
   short sequences.
-- ``flash``: Pallas TPU kernel (ops/flash_attention.py), O(S·D) HBM traffic,
-  causal variant skips above-diagonal blocks.
+- ``flash``: Pallas TPU kernel (ops/flash_attention.py), O(S·D) HBM traffic;
+  the causal variant's grid holds no tile above the diagonal (from S = 1024
+  on, at the derived 512 x 512 tiles).
 - ``ring``: exact blockwise ring over the ``seq`` mesh axis
   (parallel/ring_attention.py) — the sharded-sequence long-context path.
 - ``zigzag``: load-balanced causal ring (caller supplies zigzag layout).

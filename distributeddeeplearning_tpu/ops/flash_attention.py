@@ -3,19 +3,26 @@
 Why a kernel at all: dense attention materializes the (S, S) probability
 matrix in HBM — at BERT-base shapes that is B*H*S*S*4 bytes of write+read
 traffic per layer, and HBM bandwidth is the TPU's usual bottleneck. These
-kernels iterate a (batch*heads, Q-tiles, K-tiles) grid where each step holds
-only (BLOCK, D) tiles of Q/K/V in VMEM — Pallas streams the tiles per grid
-step — with the online-softmax running state (m, l, acc) carried across the
-K dimension in f32 VMEM scratch. HBM traffic is O(S·D) per Q-tile row and
-VMEM residency is O(BLOCK·D), so sequence length is bounded by HBM, not VMEM.
+kernels walk a (batch*heads, visited tiles) grid where each step holds only
+(BLOCK, D) tiles of Q/K/V in VMEM — Pallas streams the tiles per grid step —
+with the online-softmax running state (m, l, acc) carried across a Q tile's
+K tiles in f32 VMEM scratch. HBM traffic is O(S·D) per Q-tile row and VMEM
+residency is O(BLOCK·D), so sequence length is bounded by HBM, not VMEM.
 
 Key-padding mask, non-causal (BERT, models/bert.py) or causal
-(``causal=True`` — GPT, models/gpt.py; above-diagonal blocks are skipped
-entirely, halving FLOPs at large S). The backward pass recomputes block
-scores from the saved
-logsumexp (the flash recurrence) in two kernels: dq (accumulated over the
-K-tile grid axis) and dk/dv (accumulated over the Q-tile grid axis); the
-revisited output blocks stay resident in VMEM across the accumulation axis.
+(``causal=True`` — GPT, models/gpt.py). Which (Q tile, K tile) pairs exist
+is decided once, statically, by :func:`tile_plan`: the grids are built from
+its list of visited tiles (two scalar-prefetch tables say which tile a grid
+step works on), so a causal call holds no grid step for a tile above the
+diagonal. With the derived 512 x 512 tiles the skip engages from S = 1024
+on (3 of 4 tiles visited there, 10 of 16 at S = 2048, 136 of 256 at S =
+8192); at S <= 512 one tile holds the triangle and is faster than three
+smaller ones (PERF.md, PR 26).
+The backward pass recomputes block scores from the saved logsumexp (the
+flash recurrence) in two kernels: dq (accumulated over a Q tile's K tiles)
+and dk/dv (accumulated over a K tile's Q tiles, on transposed (BK, BQ)
+scores so that nothing has to be transposed); the revisited output blocks
+stay resident in VMEM across the accumulation.
 
 Kernels run compiled on TPU devices and in Pallas interpret mode elsewhere
 (ops/pallas.py decides, at lowering time), so the CPU test mesh exercises
@@ -25,9 +32,11 @@ the same code path (SURVEY.md §4).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -40,6 +49,12 @@ _NEG = -1e30
 
 _PAD_GRANULE = 128  # TPU lane width; also the floor _block can return after
 #                     flash_attention pads S to a multiple of it.
+
+
+def _padded_len(s: int) -> int:
+    """The sequence length the kernels run for a call of length ``s``: up to
+    the next multiple of the lane width once ``s`` is past one granule."""
+    return s + (-s % _PAD_GRANULE if s > _PAD_GRANULE else 0)
 
 
 def _block(size: int, target: int) -> int:
@@ -70,10 +85,98 @@ def _block(size: int, target: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Forward: grid (B*H, nQ, nK); m/l/acc scratch carries across the K axis.
+# The plan: which (Q tile, K tile) pairs the three kernels visit, and in
+# which order. Everything here is static (numpy at trace time).
 # ---------------------------------------------------------------------------
 
-def _block_keep(seed_ref, pid, i, j, bq: int, bk: int, rate: float):
+# Tile sizes when the caller names none, by what the call can see. Causal:
+# square tiles, so that some lie wholly above the diagonal and are never
+# visited; as large as measured fastest, because a tile pays for its rows
+# (the forward's cross-lane row maximum, a grid step) whatever its width: at
+# S = 1024, 512 x 512 visits 75 % of the scores and beat 256 x 256 (62.5 %)
+# by a third. Not causal: there is no triangle, and a whole-row K tile makes
+# the fewest grid steps. Measured on the v5e at the shapes the models run
+# (PERF.md, PR 26).
+_CAUSAL_TILES = (512, 512)
+_FULL_TILES = (512, 1024)
+
+
+class TilePlan(NamedTuple):
+    """What the kernels do at one shape: the tile sizes, the tiles of the
+    (S, S) score rectangle, how many of them the grids hold (the grids hold
+    nothing else: ``visited`` is grid steps per batch·head), and how many of
+    those the causal diagonal crosses (part of their scores is masked: the
+    work this tiling still does beyond the triangle)."""
+    bq: int
+    bk: int
+    total: int
+    visited: int
+    diagonal: int
+
+
+def _needed(i, j, bq: int, bk: int):
+    """Tile (i, j) holds a pair with key <= query: its first key column is
+    not past its last query row."""
+    return j * bk <= (i + 1) * bq - 1
+
+
+def _crosses(i, j, bq: int, bk: int):
+    """Tile (i, j) holds a pair with key > query: the diagonal crosses a
+    visited tile unless it lies strictly under it, ``(j+1)*bk - 1 <=
+    i*bq``."""
+    return (j + 1) * bk - 1 > i * bq
+
+
+def _tiles(s: int, bq: int, bk: int, causal: bool):
+    """(qi, kj): tile indices of every tile the kernels visit, Q-major (K
+    innermost). The grids and the plan's counts are both made from this."""
+    qi, kj = np.meshgrid(np.arange(s // bq, dtype=np.int32),
+                         np.arange(s // bk, dtype=np.int32), indexing="ij")
+    keep = (_needed(qi, kj, bq, bk) if causal
+            else np.ones_like(qi, dtype=bool))
+    return qi[keep], kj[keep]
+
+
+def tile_plan(s: int, causal: bool, block_q: Optional[int] = None,
+              block_k: Optional[int] = None) -> TilePlan:
+    """The plan for a call of sequence length ``s`` (padded as the call pads
+    it). ``block_q`` / ``block_k`` override the derived tile sizes (the
+    largest divisor of the padded ``s`` not above them is taken, as before).
+    Head size does not enter: 64 is the one measured on the chip."""
+    s = _padded_len(s)
+    tq, tk = _CAUSAL_TILES if causal else _FULL_TILES
+    bq, bk = _block(s, block_q or tq), _block(s, block_k or tk)
+    qi, kj = _tiles(s, bq, bk, causal)
+    return TilePlan(bq, bk, (s // bq) * (s // bk), int(qi.size),
+                    int(_crosses(qi, kj, bq, bk).sum()) if causal else 0)
+
+
+def _schedule(s: int, plan: TilePlan, causal: bool, k_major: bool = False):
+    """The two scalar-prefetch tables of a kernel: grid step t works on tile
+    (qi[t], kj[t]). Q-major for forward and dQ (a Q tile's K tiles follow
+    each other, so its accumulators stay in scratch); K-major for dK/dV."""
+    qi, kj = _tiles(s, plan.bq, plan.bk, causal)
+    if k_major:
+        order = np.lexsort((qi, kj))
+        qi, kj = qi[order], kj[order]
+    return jnp.asarray(qi), jnp.asarray(kj)
+
+
+def _run_edges(row_ref, t):
+    """(first, last): does grid step ``t`` open / close its run of equal
+    entries of ``row_ref``, the table of the tile index that stays put while
+    the kernel accumulates (qi for forward and dQ, kj for dK/dV)? Read from
+    the table itself, so the kernels hold no second copy of the schedule."""
+    n = pl.num_programs(1)
+    cur = row_ref[t]
+    first = jnp.logical_or(t == 0, row_ref[jnp.maximum(t - 1, 0)] != cur)
+    last = jnp.logical_or(t == n - 1,
+                          row_ref[jnp.minimum(t + 1, n - 1)] != cur)
+    return first, last
+
+
+def _block_keep(seed_ref, pid, i, j, bq: int, bk: int, rate: float,
+                transposed: bool = False):
     """The (BQ, BK) keep-mask for block (i, j) of grid row ``pid``
     (= pl.program_id(0), hoisted to the kernel top level — program_id may
     not be bound under a pl.when body), in GLOBAL coordinates — the same
@@ -81,75 +184,120 @@ def _block_keep(seed_ref, pid, i, j, bq: int, bk: int, rate: float):
     asks for it. seed_ref (SMEM): [seed, b_start, h_start, h_local,
     h_total] — the last four place this shard's (batch, head) range in the
     global index space so the realized mask is sharding-invariant
-    (dense == flash at any dp x tp)."""
+    (dense == flash at any dp x tp).
+
+    The coordinates go in as one column of rows and one row of columns:
+    keep_mask's coordinate multiplies then run once a row and once a column,
+    and only the xor that joins them, and the finalizer, run per element.
+    ``transposed`` gives the same mask as (BK, BQ), keys down the rows, for
+    the dK/dV kernel: the query coordinates are then the row vector."""
     from distributeddeeplearning_tpu.ops.hash_dropout import keep_mask
 
     h_n = seed_ref[3]
     bh = ((seed_ref[1] + pid // h_n) * seed_ref[4]
           + seed_ref[2] + pid % h_n)
-    rows = (jax.lax.broadcasted_iota(jnp.uint32, (bq, bk), 0)
+    q_shape, k_shape = ((1, bq), (bk, 1)) if transposed else ((bq, 1), (1, bk))
+    rows = (jax.lax.broadcasted_iota(jnp.uint32, q_shape, int(transposed))
             + (i * bq).astype(jnp.uint32))
-    cols = (jax.lax.broadcasted_iota(jnp.uint32, (bq, bk), 1)
+    cols = (jax.lax.broadcasted_iota(jnp.uint32, k_shape, 1 - transposed)
             + (j * bk).astype(jnp.uint32))
     return keep_mask(seed_ref[0], jnp.uint32(0) + bh.astype(jnp.uint32),
                      rows, cols, rate)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale: float, causal: bool,
-                dropout_rate: float):
-    i, j = pl.program_id(1), pl.program_id(2)
-    pid0 = pl.program_id(0)
+def _causal_t(i, j, bq: int, bk: int):
+    """ops/masks.py::block_causal_mask of tile (i, j), transposed: (BK, BQ)
+    bool, key position (down the rows) <= query position (along the
+    lanes). A test holds the two equal."""
+    kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+    return kpos <= qpos
+
+
+def _valid(mask_ref, causal: bool, i, j, bq: int, bk: int,
+           transposed: bool = False):
+    """Which scores of tile (i, j) count: the key-padding mask, one vector
+    over the keys that broadcasts, and the causal triangle. (A second tile
+    body without the triangle for tiles wholly under the diagonal measured
+    no faster at S = 1024 and 1.3 % faster at S = 8192: PERF.md, PR 26.)
+    ``transposed``: as (BK, BQ), keys down the rows."""
+    if transposed:
+        # the relayout to a column wants the int32s, not the compared bools
+        valid = mask_ref[0, 0][:, None] != 0
+        return valid & _causal_t(i, j, bq, bk) if causal else valid
+    valid = mask_ref[0] != 0
+    return valid & block_causal_mask(i, j, bq, bk) if causal else valid
+
+
+# ---------------------------------------------------------------------------
+# Forward: grid (B*H, visited tiles); m/l/acc scratch carries across a Q
+# tile's K tiles.
+# ---------------------------------------------------------------------------
+
+def _l_lanes(bk: int) -> int:
+    """Width of the forward's running denominator: a K tile of whole lane
+    groups keeps one partial sum a lane (see _lane_sums), any other one sum
+    a row."""
+    return _PAD_GRANULE if bk % _PAD_GRANULE == 0 else 1
+
+
+def _lane_sums(p, lanes: int):
+    """Row sums of ``p`` (BQ, BK) left as ``lanes`` partial sums a row: the
+    BK/128 lane groups added elementwise, and the sum across the lanes left
+    to the kernel's close, once a Q tile; a cross-lane reduction a row on
+    every tile was most of what a tile cost beyond its scores."""
+    if lanes == 1:
+        return p.sum(axis=-1, keepdims=True)
+    return sum(p[:, c:c + lanes] for c in range(0, p.shape[1], lanes))
+
+
+def _fwd_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
+                o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale: float,
+                causal: bool, dropout_rate: float):
+    pid0, t = pl.program_id(0), pl.program_id(1)
+    i, j = qi_ref[t], kj_ref[t]
+    first, last = _run_edges(qi_ref, t)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _():
         m_scr[:] = jnp.full_like(m_scr, _NEG)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def work():
-        # Matmul operands stay in their storage dtype (bf16 on the training
-        # path): the MXU takes bf16 inputs at full rate with f32 accumulation
-        # via preferred_element_type — upcasting first would halve MXU
-        # throughput and double VMEM traffic for zero precision gain.
-        q = q_ref[0]                                      # (BQ, D)
-        k = k_ref[0]                                      # (BK, D)
-        v = v_ref[0]
-        valid = jnp.broadcast_to((mask_ref[0, 0] != 0)[None, :], (bq, bk))
-        if causal:
-            valid = valid & block_causal_mask(i, j, bq, bk)
+    # Matmul operands stay in their storage dtype (bf16 on the training
+    # path): the MXU takes bf16 inputs at full rate with f32 accumulation via
+    # preferred_element_type — upcasting first would halve MXU throughput and
+    # double VMEM traffic for zero precision gain.
+    q = q_ref[0]                                          # (BQ, D)
+    k = k_ref[0]                                          # (BK, D)
+    v = v_ref[0]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale       # (BQ, BK) f32
+    s = jnp.where(_valid(mask_ref, causal, i, j, bq, bk), s, _NEG)
+    m_prev = m_scr[:]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    # A masked score is _NEG, and exp(_NEG - m) is exactly 0 under any real
+    # maximum m. Only a row that has seen no valid key yet (m_new still _NEG)
+    # would read exp(0) = 1 there: take 0 for its maximum, one select a row
+    # and none over the tile.
+    p = jnp.exp(s - jnp.where(m_new > _NEG, m_new, 0.0))
+    corr = jnp.exp(m_prev - m_new)
+    m_scr[:] = m_new
+    # l accumulates UNdropped p: dense semantics normalize first (softmax),
+    # then drop — o = (softmax ∘ keep/(1-r)) v.
+    l_scr[:] = l_scr[:] * corr + _lane_sums(p, l_scr.shape[1])
+    if dropout_rate > 0.0:
+        keep = _block_keep(seed_ref, pid0, i, j, bq, bk, dropout_rate)
+        p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
+    acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (BQ, BK) f32
-        s = jnp.where(valid, s, _NEG)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        # l accumulates UNdropped p: dense semantics normalize first
-        # (softmax), then drop — o = (softmax ∘ keep/(1-r)) v.
-        l_scr[:] = l_scr[:] * corr + p.sum(axis=-1, keepdims=True)
-        if dropout_rate > 0.0:
-            keep = _block_keep(seed_ref, pid0, i, j, bq, bk, dropout_rate)
-            p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        # Blocks strictly above the diagonal contribute nothing — skip the
-        # matmuls entirely (halves causal FLOPs at large S).
-        pl.when(j * bk < (i + 1) * bq)(work)
-    else:
-        work()
-
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _():
-        l = l_scr[:]
+        l = l_scr[:].sum(axis=-1, keepdims=True)
         safe_l = jnp.maximum(l, 1e-30)
         o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
         # Fully-masked rows: zero output, lse pinned to 0 so backward's
@@ -158,242 +306,227 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, seed_ref, o_ref, lse_ref,
             l[:, 0] > 0, m_scr[:][:, 0] + jnp.log(safe_l[:, 0]), 0.0)
 
 
-def _fwd(q, k, v, mask, seed, *, scale, block_q, block_k, causal,
-         dropout_rate):
+def _grid_spec(bh: int, tables, in_specs, out_specs, scratch_shapes):
+    """Grid (B*H, visited tiles) with the schedule tables and the dropout
+    seed as scalar-prefetch operands: index maps and kernels read the tile
+    of grid step t from them."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(bh, int(tables[0].shape[0])),
+        in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=scratch_shapes)
+
+
+_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def _tile_specs(bq: int, bk: int, d: int):
+    """Block specs by what a block follows: a Q tile and its per-row
+    vectors follow qi[t], a K tile and the key mask follow kj[t]."""
     # Rank-1-per-tile operands (mask, lse) ride as (BH, 1, S) so every block
     # shape is rank >= 2 with a compiled-lowering-legal tail: Mosaic requires
     # the last two block dims be (multiples of, or equal to) the array dims —
     # a (1, BK) block over a (BH, S) array is not (VERDICT r1 #6, found on
     # first real-TPU run).
+    q_tile = pl.BlockSpec((1, bq, d), lambda b, t, qi, kj, _: (b, qi[t], 0))
+    k_tile = pl.BlockSpec((1, bk, d), lambda b, t, qi, kj, _: (b, kj[t], 0))
+    vec_q = pl.BlockSpec((1, 1, bq), lambda b, t, qi, kj, _: (b, 0, qi[t]))
+    vec_k = pl.BlockSpec((1, 1, bk), lambda b, t, qi, kj, _: (b, 0, kj[t]))
+    return q_tile, k_tile, vec_q, vec_k
+
+
+def _fwd(q, k, v, mask, seed, *, scale, plan, causal, dropout_rate):
     bh, s, d = q.shape
-    bq, bk = _block(s, block_q), _block(s, block_k)
+    bq, bk = plan.bq, plan.bk
+    tables = _schedule(s, plan, causal)
+    q_tile, k_tile, vec_q, vec_k = _tile_specs(bq, bk, d)
     out, lse = pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate),
         name="flash_fwd",
-        grid=(bh, s // bq, s // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
-        ],
+        grid_spec=_grid_spec(
+            bh, tables, [q_tile, k_tile, k_tile, vec_k], [q_tile, vec_q],
+            [pltpu.VMEM((bq, 1), jnp.float32),
+             pltpu.VMEM((bq, _l_lanes(bk)), jnp.float32),
+             pltpu.VMEM((bq, d), jnp.float32)]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(q, k, v, mask[:, None, :], seed)
+        compiler_params=_PARAMS,
+    )(*tables, seed, q, k, v, mask[:, None, :])
     return out, lse.reshape(bh, s)
 
 
 # ---------------------------------------------------------------------------
-# Backward: dq accumulates over the K grid axis; dk/dv over the Q grid axis.
-# Scores are recomputed from the saved lse (flash recurrence).
+# Backward: dq accumulates over a Q tile's K tiles; dk/dv over a K tile's Q
+# tiles. Scores are recomputed from the saved lse (flash recurrence).
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-               seed_ref, dq_ref, dq_scr, *, scale: float, causal: bool,
-               dropout_rate: float):
-    i, j = pl.program_id(1), pl.program_id(2)
-    pid0 = pl.program_id(0)
+def _dq_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
+               do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *, scale: float,
+               causal: bool, dropout_rate: float):
+    pid0, t = pl.program_id(0), pl.program_id(1)
+    i, j = qi_ref[t], kj_ref[t]
+    first, last = _run_edges(qi_ref, t)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def work():
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        k = k_ref[0]
-        v = v_ref[0]
-        valid = jnp.broadcast_to((mask_ref[0, 0] != 0)[None, :], (bq, bk))
-        if causal:
-            valid = valid & block_causal_mask(i, j, bq, bk)
+    q = q_ref[0]
+    do = do_ref[0]
+    lse = lse_ref[0, 0][:, None]
+    delta = delta_ref[0, 0][:, None]
+    k = k_ref[0]
+    v = v_ref[0]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    s = jnp.where(_valid(mask_ref, causal, i, j, bq, bk), s, _NEG)
+    p = jnp.exp(s - lse)                              # (BQ, BK)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if dropout_rate > 0.0:
+        # Regenerate the forward's exact mask. delta = sum(do*o) already IS
+        # sum_k p*m*dp (o carries the dropped probs), so the flash delta
+        # trick needs no dropout correction — only dp does:
+        # ds = p * (m*dp - delta).
+        keep = _block_keep(seed_ref, pid0, i, j, bq, bk, dropout_rate)
+        dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
+    ds = (p * (dp - delta) * scale).astype(k.dtype)
+    dq_scr[:] += jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid, s, _NEG)
-        p = jnp.exp(s - lse)                              # (BQ, BK)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            # Regenerate the forward's exact mask. delta = sum(do*o)
-            # already IS sum_k p*m*dp (o carries the dropped probs), so the
-            # flash delta trick needs no dropout correction — only dp does:
-            # ds = p * (m*dp - delta).
-            keep = _block_keep(seed_ref, pid0, i, j, bq, bk, dropout_rate)
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        pl.when(j * bk < (i + 1) * bq)(work)
-    else:
-        work()
-
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                seed_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
-                causal: bool, dropout_rate: float):
-    j, i = pl.program_id(1), pl.program_id(2)  # j: K tile; i: Q (accum) tile
-    pid0 = pl.program_id(0)
+def _dkv_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
+                do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                *, scale: float, causal: bool, dropout_rate: float):
+    pid0, t = pl.program_id(0), pl.program_id(1)
+    i, j = qi_ref[t], kj_ref[t]          # K-major: j stays, i accumulates
+    first, last = _run_edges(kj_ref, t)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def work():
-        k = k_ref[0]                                      # (BK, D)
-        v = v_ref[0]
-        q = q_ref[0]                                      # (BQ, D)
-        do = do_ref[0]
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        valid = jnp.broadcast_to((mask_ref[0, 0] != 0)[None, :], (bq, bk))
-        if causal:
-            valid = valid & block_causal_mask(i, j, bq, bk)
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid, s, _NEG)
-        p = jnp.exp(s - lse)                              # (BQ, BK)
-        if dropout_rate > 0.0:
-            # (i, j) here are the same logical (Q-tile, K-tile) indices the
-            # forward used (the grid swaps their nesting, not their
-            # meaning), so this regenerates the forward's exact mask.
-            keep = _block_keep(seed_ref, pid0, i, j, bq, bk, dropout_rate)
-            inv_keep = 1.0 / (1.0 - dropout_rate)
-            p_drop = jnp.where(keep, p * inv_keep, 0.0)
-        else:
-            keep, p_drop = None, p
-        dv_scr[:] += jax.lax.dot_general(
-            p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if keep is not None:
-            dp = jnp.where(keep, dp * inv_keep, 0.0)
-        ds = (p * (dp - delta) * scale).astype(q.dtype)   # (BQ, BK)
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        pl.when(j * bk < (i + 1) * bq)(work)
+    # Everything here is (BK, BQ): keys down the rows, queries along the
+    # lanes. K and V are then left operands of plain products, dV and dK
+    # contract over the lanes of p and ds, and lse / delta are the rows they
+    # are stored as: nothing is transposed, neither operands nor results nor
+    # the per-query vectors.
+    k = k_ref[0]                                      # (BK, D)
+    v = v_ref[0]
+    q = q_ref[0]                                      # (BQ, D)
+    do = do_ref[0]
+    s = jax.lax.dot_general(
+        k, q, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    s = jnp.where(_valid(mask_ref, causal, i, j, bq, bk,
+                         transposed=True), s, _NEG)
+    p = jnp.exp(s - lse_ref[0])                       # (BK, BQ)
+    if dropout_rate > 0.0:
+        # (i, j) are the same logical (Q-tile, K-tile) indices the forward
+        # used (the schedule swaps their nesting, not their meaning), so
+        # this regenerates the forward's exact mask.
+        keep = _block_keep(seed_ref, pid0, i, j, bq, bk, dropout_rate,
+                           transposed=True)
+        inv_keep = 1.0 / (1.0 - dropout_rate)
+        p_drop = jnp.where(keep, p * inv_keep, 0.0)
     else:
-        work()
+        keep, p_drop = None, p
+    dv_scr[:] += jax.lax.dot_general(
+        p_drop.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(
+        v, do, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if keep is not None:
+        dp = jnp.where(keep, dp * inv_keep, 0.0)
+    ds = (p * (dp - delta_ref[0]) * scale).astype(q.dtype)
+    dk_scr[:] += jax.lax.dot_general(
+        ds, q, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(scale, block_q, block_k, causal, dropout_rate, residuals, g):
+def _bwd(scale, plan, causal, dropout_rate, residuals, g):
     q, k, v, mask, seed, out, lse = residuals
     bh, s, d = q.shape
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    # (BH, 1, S) lift for the rank-1-per-tile operands — see _fwd.
+    # (BH, 1, S) lift for the rank-1-per-tile operands — see _tile_specs.
     mask3, lse3, delta3 = (x[:, None, :] for x in (mask, lse, delta))
 
-    bq, bk = _block(s, block_q), _block(s, block_k)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    q_tile = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
-    k_tile = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0))
-    maskk = pl.BlockSpec((1, 1, bk), lambda b, i, j: (b, 0, j))
-    vec_q = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+    bq, bk = plan.bq, plan.bk
+    q_tile, k_tile, vec_q, vec_k = _tile_specs(bq, bk, d)
+    in_specs = [q_tile, k_tile, k_tile, vec_k, q_tile, vec_q, vec_q]
+    operands = (seed, q, k, v, mask3, g, lse3, delta3)
 
+    tables = _schedule(s, plan, causal)
     dq = pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate),
         name="flash_dq",
-        grid=(bh, s // bq, s // bk),
-        in_specs=[q_tile, k_tile, k_tile, maskk, q_tile, vec_q, vec_q,
-                  smem],
-        out_specs=[q_tile],
+        grid_spec=_grid_spec(bh, tables, in_specs, [q_tile],
+                             [pltpu.VMEM((bq, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(q, k, v, mask3, g, lse3, delta3, seed)[0]
+        compiler_params=_PARAMS,
+    )(*tables, *operands)[0]
 
-    # dk/dv: K tiles are the revisited outputs, Q is the accumulation axis
-    # (innermost grid dim), so swap the roles of the last two grid indices.
-    q_acc = pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0))
-    k_out = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
-    maskk2 = pl.BlockSpec((1, 1, bk), lambda b, j, i: (b, 0, j))
-    vec_q2 = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i))
+    # dk/dv: K tiles are the revisited outputs and Q the accumulation axis,
+    # so the same tiles are walked K-major.
+    tables = _schedule(s, plan, causal, k_major=True)
     dk, dv = pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate),
         name="flash_dkv",
-        grid=(bh, s // bk, s // bq),
-        in_specs=[q_acc, k_out, k_out, maskk2, q_acc, vec_q2, vec_q2,
-                  smem],
-        out_specs=[k_out, k_out],
+        grid_spec=_grid_spec(bh, tables, in_specs, [k_tile, k_tile],
+                             [pltpu.VMEM((bk, d), jnp.float32),
+                              pltpu.VMEM((bk, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(q, k, v, mask3, g, lse3, delta3, seed)
+        compiler_params=_PARAMS,
+    )(*tables, *operands)
     return dq, dk, dv, None, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, mask, seed, scale, block_q, block_k, causal,
-           dropout_rate):
-    out, _ = _fwd(q, k, v, mask, seed, scale=scale, block_q=block_q,
-                  block_k=block_k, causal=causal,
-                  dropout_rate=dropout_rate)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, mask, seed, scale, plan, causal, dropout_rate):
+    out, _ = _fwd(q, k, v, mask, seed, scale=scale, plan=plan,
+                  causal=causal, dropout_rate=dropout_rate)
     return out
 
 
-def _flash_fwd(q, k, v, mask, seed, scale, block_q, block_k, causal,
-               dropout_rate):
-    out, lse = _fwd(q, k, v, mask, seed, scale=scale, block_q=block_q,
-                    block_k=block_k, causal=causal,
-                    dropout_rate=dropout_rate)
+def _flash_fwd(q, k, v, mask, seed, scale, plan, causal, dropout_rate):
+    out, lse = _fwd(q, k, v, mask, seed, scale=scale, plan=plan,
+                    causal=causal, dropout_rate=dropout_rate)
     return out, (q, k, v, mask, seed, out, lse)
 
 
 _flash.defvjp(_flash_fwd, _bwd)
 
 
-def flash_attention(q, k, v, kv_mask=None, *, block_q: int = 512,
-                    block_k: int = 1024, causal: bool = False,
+def flash_attention(q, k, v, kv_mask=None, *,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None, causal: bool = False,
                     dropout_rate: float = 0.0, dropout_seed=None,
                     bh_offsets=None):
     """Fused attention with a key-padding mask; ``causal=True`` adds the
-    autoregressive lower-triangular mask (and skips above-diagonal blocks).
+    autoregressive lower-triangular mask (tiles above the diagonal are not
+    in the grid). ``block_q`` / ``block_k`` override the tile sizes that
+    :func:`tile_plan` derives from (S, causal); leave them out.
 
     q/k/v: (B, S, H, D) — the models' layout; kv_mask: (B, S) (True/nonzero
     = attend), or None for all-valid. Returns (B, S, H, D) in q.dtype.
@@ -427,8 +560,8 @@ def flash_attention(q, k, v, kv_mask=None, *, block_q: int = 512,
     # sliced off below; grad flows through pad/slice transparently since
     # both sit outside the custom-VJP boundary.
     s_orig = s
-    if s > _PAD_GRANULE and s % _PAD_GRANULE:
-        pad = _PAD_GRANULE - s % _PAD_GRANULE
+    if _padded_len(s) != s:
+        pad = _padded_len(s) - s
         q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
                    for x in (q, k, v))
         kv_mask = jnp.pad(kv_mask.astype(jnp.int32), ((0, 0), (0, pad)))
@@ -439,8 +572,8 @@ def flash_attention(q, k, v, kv_mask=None, *, block_q: int = 512,
     def to_bh(x):  # (B, S, H, D) -> (B*H, S, D)
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
-    out = _flash(to_bh(q), to_bh(k), to_bh(v), kv_mask, seed,
-                 d ** -0.5, block_q, block_k, causal,
+    out = _flash(to_bh(q), to_bh(k), to_bh(v), kv_mask, seed, d ** -0.5,
+                 tile_plan(s, causal, block_q, block_k), causal,
                  float(dropout_rate))
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)[:, :s_orig]
 

@@ -18,8 +18,13 @@ pure hash of the global coordinates and a per-call seed, so
 
 The mixer is the murmur3 finalizer (full avalanche) over a linear combine of
 the coordinates — measured uniform on this backend (mean .4985, std .2896 vs
-ideal .2887 for 2^20 draws). Dropout needs decorrelation, not cryptography;
-the finalizer is 5 VPU ops per element and works identically in compiled
+ideal .2887 for 2^20 draws). Dropout needs decorrelation, not cryptography.
+Per element the mask costs the xors that join the coordinate terms, the
+finalizer (three shifts, three xors, two 32-bit multiplies) and the
+threshold compare; the three coordinate multiplies run on whatever shapes
+the caller broadcasts from, so the flash kernels pass one column of rows
+and one row of columns and pay them once a row and once a column of a tile
+(ops/flash_attention.py::_block_keep). It works identically in compiled
 Mosaic and Pallas interpret mode (the TPU PRNG primitive does not lower on
 CPU interpret — measured NotImplementedError — which rules it out here: the
 CPU test mesh must execute the same code path).

@@ -94,6 +94,71 @@ def test_flash_dropout_block_size_invariant():
                                    rtol=1e-5, atol=1e-5)
 
 
+# (S, block_q, block_k): square tiles, and both ways of bq != bk.
+KEEP_GEOMETRIES = [(256, 128, 128), (512, 128, 256), (512, 256, 128)]
+
+
+@pytest.mark.parametrize("offsets", [None, (2, 1, 4)],
+                         ids=["unsharded", "bh_offsets"])
+@pytest.mark.parametrize("s,bq,bk", KEEP_GEOMETRIES)
+def test_block_keep_bit_identical_to_dense_mask(s, bq, bk, offsets):
+    """The kernels' keep-mask, built from one column of row coordinates and
+    one row of column coordinates, is bit for bit ``dense_keep_mask`` on
+    EVERY tile of a (bh, S, S) problem: interior, diagonal and last tiles
+    alike, with and without this shard's (batch, head) offsets."""
+    from distributeddeeplearning_tpu.ops.flash_attention import _block_keep
+
+    b_local, h_local = 2, 2
+    b_start, h_start, h_total = offsets or (0, 0, h_local)
+    dense = np.asarray(dense_keep_mask(
+        SEED, b_start + b_local, h_total, s, s, RATE))
+    # what the kernels read from SMEM: [seed, b_start, h_start, h, h_total]
+    seed = jnp.asarray([SEED, b_start, h_start, h_local, h_total], jnp.int32)
+    tile = jax.jit(lambda pid, i, j: _block_keep(seed, pid, i, j, bq, bk,
+                                                 RATE))
+    for pid in range(b_local * h_local):
+        b, h = b_start + pid // h_local, h_start + pid % h_local
+        for i in range(s // bq):
+            for j in range(s // bk):
+                got = np.asarray(tile(jnp.int32(pid), jnp.int32(i),
+                                      jnp.int32(j)))
+                assert got.shape == (bq, bk) and got.dtype == np.bool_
+                np.testing.assert_array_equal(
+                    got, dense[b, h, i * bq:(i + 1) * bq,
+                               j * bk:(j + 1) * bk],
+                    err_msg=f"pid {pid} tile ({i}, {j})")
+
+
+@pytest.mark.parametrize("s,bq,bk", [(128, 32, 32), (128, 16, 64),
+                                     (128, 64, 32), (300, 128, 128)])
+def test_flash_causal_dropout_skipping_geometries(s, bq, bk):
+    """Causal + dropout + key-padding mask where the plan skips tiles and
+    runs others without the causal mask: forward and q/k/v gradients equal
+    the dense softmax with the same materialized mask."""
+    q, k, v = random_qkv(jax.random.key(8), s=s, h=2, d=16)
+    mask = jnp.asarray(np.arange(s)[None, :]
+                       < np.asarray([s, s - s // 3])[:, None])
+
+    def f_flash(q, k, v):
+        return flash_attention(q, k, v, mask, block_q=bq, block_k=bk,
+                               causal=True, dropout_rate=RATE,
+                               dropout_seed=SEED)
+
+    def f_ref(q, k, v):
+        return dropped_dense_reference(q, k, v, mask, causal=True)
+
+    np.testing.assert_allclose(np.asarray(f_flash(q, k, v)),
+                               np.asarray(f_ref(q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+    gf = jax.grad(lambda *a: (f_flash(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    gr = jax.grad(lambda *a: (f_ref(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, name in zip(gf, gr, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4, err_msg=name)
+
+
 @pytest.mark.core
 def test_ring_dropout_matches_reference(devices8):
     """Ring over 4 seq shards with dropout == dense-with-same-mask, fwd and

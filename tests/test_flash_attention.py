@@ -60,6 +60,132 @@ def test_grads_match_dense():
                                    rtol=1e-4, atol=1e-4)
 
 
+def _ragged_mask(b, s, lens):
+    return jnp.asarray(np.arange(s)[None, :] < np.asarray(lens)[:, None])
+
+
+# (S, block_q, block_k, valid keys per batch row or None). Tile sizes small
+# enough that the causal plan drops tiles above the diagonal and visits
+# tiles wholly under it beside those it crosses; bq != bk both ways; a
+# key-padding mask whose edge falls in an interior tile (row 0) and in a
+# diagonal one (row 1); S off the lane width (padded to 384 inside, the pad
+# masked).
+CAUSAL_CASES = [
+    pytest.param(128, 32, 32, None, id="s128-32x32"),
+    pytest.param(128, 16, 64, None, id="s128-16x64"),
+    pytest.param(128, 64, 16, None, id="s128-64x16"),
+    pytest.param(128, 32, 32, (128, 40), id="s128-32x32-masked"),
+    pytest.param(128, 64, 32, (50, 100), id="s128-64x32-masked"),
+    pytest.param(300, 128, 128, None, id="s300-padded"),
+    pytest.param(300, 128, 128, (300, 140), id="s300-padded-masked"),
+    pytest.param(1024, None, None, None, id="s1024-derived"),
+]
+
+
+@pytest.mark.parametrize("s,block_q,block_k,lens", CAUSAL_CASES)
+def test_causal_forward_and_grads_match_dense(s, block_q, block_k, lens):
+    from distributeddeeplearning_tpu.ops.flash_attention import tile_plan
+
+    q, k, v = random_qkv(jax.random.key(5), s=s)
+    mask = None if lens is None else _ragged_mask(q.shape[0], s, lens)
+    plan = tile_plan(s, True, block_q, block_k)
+    # the case does exercise the skip, on interior and diagonal tiles
+    assert plan.visited < plan.total
+    assert plan.diagonal < plan.visited
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask, causal=True,
+                               block_q=block_q, block_k=block_k)
+
+    def dense(q, k, v):
+        return dense_reference(q, k, v, mask, causal=True)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+    gf = jax.grad(lambda *a: (flash(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    gd = jax.grad(lambda *a: (dense(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, name in zip(gf, gd, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("i,j,bq,bk", [(0, 0, 32, 32), (3, 1, 16, 64),
+                                       (1, 2, 64, 32), (2, 2, 128, 128)])
+def test_transposed_causal_rule_is_the_shared_one(i, j, bq, bk):
+    """The dK/dV kernel works on (BK, BQ) tiles and builds its triangle in
+    that orientation; it is ops/masks.py::block_causal_mask, the rule the
+    other two kernels and ring attention use, transposed."""
+    from distributeddeeplearning_tpu.ops.flash_attention import _causal_t
+    from distributeddeeplearning_tpu.ops.masks import block_causal_mask
+
+    np.testing.assert_array_equal(
+        np.asarray(_causal_t(i, j, bq, bk)),
+        np.asarray(block_causal_mask(i, j, bq, bk)).T)
+
+
+def _plan_by_hand(s, bq, bk, causal):
+    """(total, visited, diagonal) counted pair by pair from the definition:
+    a tile is visited if it holds a pair with key <= query, and is diagonal
+    if it also holds one with key > query."""
+    total = visited = diagonal = 0
+    for i in range(s // bq):
+        for j in range(s // bk):
+            total += 1
+            if not causal:
+                visited += 1
+                continue
+            below = j * bk <= i * bq + bq - 1   # min key <= max query
+            above = j * bk + bk - 1 > i * bq    # max key > min query
+            visited += below
+            diagonal += below and above
+    return total, visited, diagonal
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("s", [128, 512, 1024, 2048, 8192])
+def test_tile_plan_counts(s, causal):
+    """The plan the kernels' grids are built from: counts at the derived
+    tile sizes equal a count by hand, the schedule tables hold exactly the
+    visited tiles (each once, in both orders), and from S = 1024 on a causal
+    call never visits the whole rectangle (at S = 512 one 512 x 512 tile
+    measured faster on the v5e than three of 256 x 256: PERF.md, PR 26)."""
+    from distributeddeeplearning_tpu.ops.flash_attention import (
+        _schedule, tile_plan)
+
+    plan = tile_plan(s, causal)
+    assert s % plan.bq == 0 and s % plan.bk == 0
+    assert min(plan.bq, plan.bk) >= 128  # Mosaic's (1, 1, b) blocks
+    assert (plan.total, plan.visited, plan.diagonal) == _plan_by_hand(
+        s, plan.bq, plan.bk, causal)
+    for k_major in (False, True):
+        qi, kj = (np.asarray(t) for t in _schedule(s, plan, causal, k_major))
+        assert len(qi) == plan.visited
+        assert len(set(zip(qi.tolist(), kj.tolist()))) == plan.visited
+        run = kj if k_major else qi   # the tile that stays while one sums
+        assert (np.diff(run) >= 0).all()
+    if causal and s >= 1024:
+        assert plan.visited / plan.total <= 0.75
+    if causal and s >= 8192:
+        assert plan.visited / plan.total <= 0.54
+    if not causal:
+        assert plan.visited == plan.total and plan.diagonal == 0
+
+
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (256, 128),
+                                             (128, 512), (512, 512)])
+def test_tile_plan_overrides(block_q, block_k):
+    """block_q / block_k stay explicit overrides; the counts follow them."""
+    from distributeddeeplearning_tpu.ops.flash_attention import tile_plan
+
+    plan = tile_plan(1024, True, block_q, block_k)
+    assert (plan.bq, plan.bk) == (block_q, block_k)
+    assert (plan.total, plan.visited, plan.diagonal) == _plan_by_hand(
+        1024, block_q, block_k, True)
+
+
 def test_non_power_of_two_seq_padded_not_degenerate():
     """S=197 (ViT-with-CLS shape; prime) used to resolve _block to 1 — a
     degenerate 197-step grid. flash_attention now pads S to a lane multiple

@@ -3,10 +3,17 @@
 COMPILED forward+backward on the TPU and compare against a dense float32
 reference — plain, causal, and with the in-kernel hash dropout against the
 materialized ``dense_keep_mask`` reference — then (unless --skip-timing)
-time flash against dense.
+time flash against dense, and read each kernel's device time from a trace.
 
     python tools/validate_flash_tpu.py [--shape 8,512,12,64] [--causal]
-        [--skip-timing]
+        [--skip-timing] [--tiles 512x512,256x256]
+
+With no ``--shape`` it does all of that at the two shapes the models run:
+the benchmark cell's (16,1024,12,64, causal) and BERT's (8,512,12,64, not
+causal); both with a key-padding mask and dropout 0.1 in the kernel times.
+``--tiles`` times further tile sizes (block_q x block_k overrides) beside
+the derived ones, which is how a default in ``ops/flash_attention.py`` is
+chosen.
 
 Prints one JSON line per check; exits nonzero off-TPU and on any mismatch.
 ``check_correctness`` is what chip_smoke.py runs at the train phase's shape.
@@ -15,10 +22,14 @@ Prints one JSON line per check; exits nonzero off-TPU and on any mismatch.
 from __future__ import annotations
 
 import argparse
+import collections
 import functools
+import glob
 import json
 import os
+import re
 import sys
+import tempfile
 import time
 
 sys.path.insert(
@@ -151,21 +162,89 @@ def time_kernels(shape, causal) -> None:
     }), flush=True)
 
 
+KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+
+
+def kernel_times(shape, causal, block_q=None, block_k=None,
+                 iters=10) -> None:
+    """The plan at ``shape`` and the device time of each of the three
+    kernels, from a profiler trace of ``iters`` forward+backward calls with
+    the key-padding mask and dropout in the kernels: ms a call, and us a
+    visited tile. The kernels are found by the names they were given
+    (ops/flash_attention.py), as the benchmark's ``device_ms.flash_*`` do."""
+    from distributeddeeplearning_tpu.ops.flash_attention import (
+        flash_attention, tile_plan)
+
+    b, s, h, _ = shape
+    q, k, v, mask = _inputs(shape)
+    step = jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(
+            q, k, v, mask, causal=causal, block_q=block_q, block_k=block_k,
+            dropout_rate=RATE, dropout_seed=jnp.int32(SEED),
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+    jax.block_until_ready(step(q, k, v))  # compile + warm
+    with tempfile.TemporaryDirectory() as log_dir:
+        with jax.profiler.trace(log_dir):
+            for _ in range(iters):
+                out = step(q, k, v)
+            jax.block_until_ready(out)
+        path = sorted(glob.glob(os.path.join(
+            log_dir, "**", "*.xplane.pb"), recursive=True))[-1]
+        data = jax.profiler.ProfileData.from_file(path)
+    ns = collections.Counter()
+    for plane in data.planes:
+        if not re.match(r"^/device:TPU:\d+$", plane.name):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for ev in line.events:
+                found = re.search(r"flash_(fwd|dq|dkv)\b", ev.name)
+                ns[found.group(0) if found else "other"] += ev.duration_ns
+    plan = tile_plan(s, causal, block_q, block_k)
+    ms = {name: ns[name] / iters / 1e6 for name in KERNELS}
+    print(json.dumps({
+        "check": "kernel_times", "shape": list(shape), "causal": causal,
+        "plan": plan._asdict(),
+        "visited_share": round(plan.visited / plan.total, 4),
+        "ms_a_call": {n: round(t, 4) for n, t in ms.items()},
+        "us_a_tile": {n: round(t * 1e3 / (b * h * plan.visited), 3)
+                      for n, t in ms.items()},
+        "sum_ms": round(sum(ms.values()), 4),
+        "other_ms": round(ns["other"] / iters / 1e6, 4),
+    }), flush=True)
+
+
+# What the models run: the benchmark cell's attention (gpt2_small, 16 x
+# 1024, causal) and BERT-base's (8 x 512, key-padding mask only).
+MODEL_SHAPES = (((16, 1024, 12, 64), True), ((8, 512, 12, 64), False))
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser()
-    p.add_argument("--shape", default="8,512,12,64",
-                   help="B,S,H,D (default: BERT-base attention)")
+    p.add_argument("--shape", default=None,
+                   help="B,S,H,D (default: the two shapes the models run)")
     p.add_argument("--causal", action="store_true")
     p.add_argument("--skip-timing", action="store_true")
+    p.add_argument("--tiles", default="",
+                   help="further block_q x block_k to time, e.g. "
+                        "512x512,256x256")
     args = p.parse_args(argv)
     platform = jax.devices()[0].platform
     if platform != "tpu":
         print(json.dumps({"error": f"need TPU, got {platform}"}))
         return 1
-    shape = tuple(int(x) for x in args.shape.split(","))
-    ok = check_correctness(shape, args.causal)
-    if not args.skip_timing:
-        time_kernels(shape, args.causal)
+    cases = MODEL_SHAPES if args.shape is None else (
+        (tuple(int(x) for x in args.shape.split(",")), args.causal),)
+    tiles = [(None, None)] + [tuple(int(x) for x in t.split("x"))
+                              for t in args.tiles.split(",") if t]
+    ok = True
+    for shape, causal in cases:
+        ok &= check_correctness(shape, causal)
+        if not args.skip_timing:
+            time_kernels(shape, causal)
+            for block_q, block_k in tiles:
+                kernel_times(shape, causal, block_q, block_k)
     return 0 if ok else 1
 
 

@@ -6,8 +6,10 @@ does: every instruction carries ``metadata={op_name="..."}``, the jax name
 stack it was traced under — ``jvp(...)`` / ``transpose(...)`` for the pass,
 the flax module path, every ``jax.named_scope`` (train/steps.py's
 ``STEP_SCOPES`` and ``LOSS_SCOPE``, models/gpt.py's ``embed`` / ``mlp`` /
-``head``) and every Pallas kernel's ``name`` (ops/pallas.py). This module
-reads those names back:
+``head``, models/afmoe.py's ``attn_window`` / ``attn_full``, models/moe.py's
+``moe_router`` / ``moe_dispatch`` / ``moe_experts`` / ``moe_combine``) and
+every Pallas kernel's ``name`` (ops/pallas.py). This module reads those names
+back:
 
 - :func:`table` maps each instruction of an HLO module text to its
   ``op_name``;
@@ -27,17 +29,38 @@ PHASES = ("forward", "backward", "update")
 # Every part a step's device time is booked under. ``unattributed`` is what
 # no rule placed (instructions the compiler made without metadata, scopes the
 # rule does not know — a CNN's convolutions, today).
-PARTS = ("flash_fwd", "flash_dq", "flash_dkv", "attention_other", "mlp",
-         "layernorm", "embed", "head", "loss", "loss_scale", "optimizer",
-         "ema_guard", "grad_reduce", "unattributed")
+PARTS = ("flash_fwd", "flash_dq", "flash_dkv", "attention_window",
+         "attention_full", "moe_routing", "moe_experts", "attention_other",
+         "mlp", "layernorm", "embed", "head", "loss", "remat", "loss_scale",
+         "optimizer", "ema_guard", "grad_reduce", "unattributed")
 
 _KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+# Scopes a model puts round its own parts -> part. A model whose layers
+# differ in attention kind scopes the kernel calls by kind, and the three
+# kernels then book under the kind; one with routed experts has the router,
+# the sort, the gathers and the weighted sum as ``moe_routing`` and the
+# grouped products as ``moe_experts``.
+_MODEL_SCOPES = {"attn_window": "attention_window",
+                 "attn_full": "attention_full",
+                 "moe_router": "moe_routing", "moe_dispatch": "moe_routing",
+                 "moe_combine": "moe_routing", "moe_experts": "moe_experts"}
+# The TPU compiler turns ``jax.lax.ragged_dot`` into kernels of its own and
+# names them itself (``ragged-dot-none``, ``ragged-dot-metadata``): the name
+# stack is gone. ``table`` gives such an instruction its operands' name stack
+# with this scope at the end: a backward operand's, if it has one (the
+# product is then part of the backward pass), else the first that has one.
+_COMPILER_NAMED = "ragged-dot"
+_RAGGED_SCOPE = "moe_experts"
 # train/steps.py STEP_SCOPES outside ``grads`` -> part; all are phase update.
 _UPDATE_SCOPES = {"grad_reduce": "grad_reduce", "loss_scale": "loss_scale",
                   "optimizer": "optimizer", "ema": "ema_guard",
                   "guard": "ema_guard"}
 _LAYERNORM = re.compile(r"^(ln_?\w*|\w*layer_?norm\w*)$", re.IGNORECASE)
 _BLOCK = re.compile(r"^layers?_?\d+$")
+# jax names the boundary of a recomputed block ``remat`` / ``remat2`` /
+# ``checkpoint``; what is booked there and nowhere inside the block is the
+# boundary's own: copies and relayouts of the saved block inputs.
+_REMAT = re.compile(r"^(remat\d*|checkpoint)$")
 
 # "  ROOT %name = <shape> opcode(" — the shape is one token or a
 # parenthesised tuple, and may hold layout braces and memory-space
@@ -103,7 +126,13 @@ def table(hlo_text: str) -> dict[str, str]:
         if opcode == "parameter":
             continue
         meta = _OP_NAME.search(line)
-        if meta is not None:
+        if meta is not None and meta.group(1).startswith(_COMPILER_NAMED):
+            known = [names[o] for o in operands if o in names]
+            op_name = next((n for n in known if "transpose(" in n),
+                           known[0] if known else None)
+            if op_name is not None:
+                op_name += "/" + _RAGGED_SCOPE
+        elif meta is not None:
             op_name = meta.group(1).replace('\\"', '"').replace("\\'", "'")
         else:
             called = _CALLS.search(line)
@@ -135,11 +164,13 @@ def part_of(op_name: str) -> tuple[str, str]:
     ``transpose(...)/jvp(...)``) makes it ``backward``; else ``jvp`` or the
     ``grads`` scope (the forward's random keys are not differentiated) makes
     it ``forward``; what is outside every scope of the step (its key
-    fold-in, the step counter) is ``update``. Part, first match: a flash
-    kernel's name; an update scope; ``loss`` / ``head`` / ``embed``; a
+    fold-in, the step counter) is ``update``. Part, first match: an update
+    scope; a model's own scope (``_MODEL_SCOPES``: attention by layer kind,
+    routed experts); a flash kernel's name; ``loss`` / ``head`` / ``embed``; a
     LayerNorm module; the ``mlp`` scope or an ``mlp*`` module; anything else
     inside an attention module or bare in a decoder block (the attention
-    half's dropout and residual) is ``attention_other``.
+    half's dropout and residual) is ``attention_other``; what only the
+    boundary of a recomputed block names is ``remat``.
     """
     scopes = [s for s in re.split(r"[/()]", op_name) if s]
     have = set(scopes)
@@ -152,6 +183,9 @@ def part_of(op_name: str) -> tuple[str, str]:
         phase = "forward"
     else:
         phase = "update"
+    model = next((s for s in reversed(scopes) if s in _MODEL_SCOPES), None)
+    if model is not None:  # the innermost: ragged products end in theirs
+        return phase, _MODEL_SCOPES[model]
     for kernel in _KERNELS:
         if kernel in have:
             return phase, kernel
@@ -164,6 +198,8 @@ def part_of(op_name: str) -> tuple[str, str]:
         return phase, "mlp"
     if any("attention" in s.lower() or _BLOCK.match(s) for s in scopes):
         return phase, "attention_other"
+    if any(_REMAT.match(s) for s in scopes):
+        return phase, "remat"
     return phase, "unattributed"
 
 

@@ -28,8 +28,8 @@ class ModelSpec:
 
 
 def _registry() -> dict[str, ModelSpec]:
-    from distributeddeeplearning_tpu.models import (bert, densenet, gpt,
-                                                    llama, resnet, vit)
+    from distributeddeeplearning_tpu.models import (afmoe, bert, densenet,
+                                                    gpt, llama, resnet, vit)
 
     def img(build, name, params):
         return ModelSpec(name=name, build=build, input_kind="image",
@@ -80,6 +80,20 @@ def _registry() -> dict[str, ModelSpec]:
             objective="causal"),
         "llama_tiny": ModelSpec(
             name="llama_tiny", build=llama.tiny_llama, input_kind="tokens",
+            param_count=0, objective="causal"),
+        # Trinity-Mini (AFMoE) as published — 26B parameters, for shape
+        # tests — and one chip's share of it when eight chips share each
+        # layer (models/afmoe.py; the benchmark's trinity_mini cell).
+        "trinity_mini": ModelSpec(
+            name="trinity_mini", build=afmoe.trinity_mini,
+            input_kind="tokens", param_count=26_123_970_560,
+            objective="causal"),
+        "trinity_mini_ep8": ModelSpec(
+            name="trinity_mini_ep8", build=afmoe.trinity_mini_ep8,
+            input_kind="tokens", param_count=705_473_792,
+            objective="causal"),
+        "afmoe_tiny": ModelSpec(
+            name="afmoe_tiny", build=afmoe.tiny_afmoe, input_kind="tokens",
             param_count=0, objective="causal"),
         # Nano drafters for speculative decoding (serve/engine.py): a
         # shrunk config of the same family — cheap to step, same

@@ -149,3 +149,243 @@ class MoeMlp(nn.Module):
         # Combine back to token order — the return all-to-all.
         out = jnp.einsum("bsec,ebch->bsh", combine, xout)
         return out.astype(self.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Routed experts without dropped tokens, for one chip's share of the experts
+# ---------------------------------------------------------------------------
+
+# Mutable collections of :class:`RoutedExperts`. ``ROUTER_STATE`` holds each
+# layer's selection bias, which the train step carries beside the parameters
+# and which takes no gradient (train/steps.py threads it where BatchNorm
+# statistics go); ``MOE_METRICS`` is sown anew every step.
+ROUTER_STATE = "router_state"
+MOE_METRICS = "moe_metrics"
+
+
+def selection_bias_update(bias, counts, rate: float):
+    """The auxiliary-loss-free balancing rule: an expert chosen for fewer
+    tokens than the mean is made easier to choose, one chosen for more
+    harder, by ``rate``, and the update is centred so the biases keep their
+    mean. ``counts``: tokens each expert was chosen for in this step."""
+    delta = rate * jnp.sign(jnp.mean(counts) - counts)
+    return bias + delta - jnp.mean(delta)
+
+
+def route(scores, bias, k: int, *, norm: bool, scale: float):
+    """(indices, gates), both (T, k): the ``k`` experts of the largest
+    ``scores + bias`` a token, and the gates its outputs are weighted by.
+    The bias selects and does not weigh: the gates are the chosen scores,
+    normalised over the chosen (``norm``) and scaled."""
+    _, idx = jax.lax.top_k(scores + bias, k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return idx, gates * scale
+
+
+def _sum_slots(rows, weights):
+    """(T, k, H) rows weighted by (T, k) and summed over k, in float32."""
+    return jnp.einsum("tkh,tk->th", rows, weights.astype(rows.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def _dispatch(u, token_of, pos, here):
+    """Rows of ``u`` (T, H) in buffer order: row r is token ``token_of[r]``.
+    ``pos`` (T, k) says where in the buffer each of a token's assignments
+    lies and ``here`` (T, k) whether it lies there at all, which is what the
+    backward pass reads: it is then a gather as well, a token summing the k
+    rows that were made from it, and no scatter runs in either direction."""
+    return u[token_of]
+
+
+def _dispatch_fwd(u, token_of, pos, here):
+    return u[token_of], (pos, here)
+
+
+def _dispatch_bwd(res, g):
+    pos, here = res
+    du = _sum_slots(g[pos], here)
+    return du.astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, gates, token_of, pos, row_gate):
+    """(T, H) float32: token t's ``gates[t, j]``-weighted sum of the buffer
+    rows ``ys[pos[t, j]]`` (a gate of nought where the assignment is not in
+    the buffer). ``row_gate`` (R,) is the same gates in buffer order, for
+    the backward pass, which is again gathers only."""
+    return _sum_slots(ys[pos], gates)
+
+
+def _combine_fwd(ys, gates, token_of, pos, row_gate):
+    return _sum_slots(ys[pos], gates), (ys, gates, token_of, pos, row_gate)
+
+
+def _combine_bwd(res, g):
+    ys, gates, token_of, pos, row_gate = res
+    d_ys = (g[token_of] * row_gate[:, None]).astype(ys.dtype)
+    d_gates = jnp.einsum("tkh,th->tk", ys[pos], g.astype(ys.dtype),
+                         preferred_element_type=jnp.float32)
+    return d_ys, jnp.where(gates != 0, d_gates, 0.0), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+class _Kernel(nn.Module):
+    """A matrix this layer multiplies by itself (the router's, or the held
+    experts' kernels of one product stacked as (experts, in, out)), under
+    the name ``kernel`` so that the optimizer's weight decay finds it as it
+    finds every other matrix."""
+
+    shape: tuple
+    axes: tuple
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", nn.with_logical_partitioning(
+            nn.initializers.normal(0.02), self.axes), self.shape,
+            jnp.float32)
+
+
+class RoutedExperts(nn.Module):
+    """A token's ``experts_per_token`` of ``num_experts`` SwiGLU experts,
+    no token dropped, computed for the experts this chip holds.
+
+    The router scores every token over all ``num_experts`` in float32 and
+    picks as the whole model does; the layer then computes the part of the
+    result that its own experts give — ``experts_held = (first, count)``, a
+    contiguous range — and leaves out what the others would add. That is the
+    layer expert parallelism needs, run here without its exchange: nothing
+    stands in for the absent chips. With ``experts_held = (0, num_experts)``
+    it is the whole layer.
+
+    Dispatch: the (token, slot) assignments are sorted by expert, those
+    that land here first; rows are gathered in that order into a buffer of
+    ``T * min(k, count)`` rows, which is every assignment that can land
+    here, so none is dropped (``moe_dropped`` counts what would not fit and
+    stays 0). The three products run as grouped products over the stacked
+    kernels with the experts' row counts (``jax.lax.ragged_dot``, which
+    the TPU compiler turns into a grouped-matmul kernel that visits only
+    the tiles that hold rows); rows past the last group are nobody's and
+    are kept at zero. The gated
+    results return to token order by a gather and a weighted sum over a
+    token's slots. A shared expert, ``shared_width`` > 0, is a dense SwiGLU
+    every token passes through, held whole by every chip.
+
+    The selection bias (collection ``ROUTER_STATE``) is state without a
+    gradient: when ``train`` and the collection is mutable it moves by
+    :func:`selection_bias_update` from this call's counts.
+    """
+
+    hidden_size: int
+    expert_width: int
+    num_experts: int
+    experts_per_token: int
+    experts_held: tuple
+    score_func: str = "sigmoid"
+    route_norm: bool = True
+    route_scale: float = 1.0
+    shared_width: int = 0
+    bias_update_rate: float = 0.0
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, *, train: bool):
+        b, s, h = x.shape
+        t, e, k = b * s, self.num_experts, self.experts_per_token
+        first, held = self.experts_held
+        if not (0 <= first and first + held <= e and held > 0):
+            raise ValueError(f"experts_held={self.experts_held} is no range "
+                             f"of {e} experts")
+        if self.score_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown score_func {self.score_func!r}")
+        u = x.reshape(t, h)
+
+        with jax.named_scope("moe_router"):
+            w_router = _Kernel((h, e), ("embed", None), name="router")()
+            logits = jnp.dot(u.astype(jnp.float32), w_router,
+                             precision=jax.lax.Precision.HIGHEST)
+            scores = (jax.nn.sigmoid(logits) if self.score_func == "sigmoid"
+                      else jax.nn.softmax(logits, axis=-1))
+            bias = self.variable(ROUTER_STATE, "bias",
+                                 lambda: jnp.zeros((e,), jnp.float32))
+            idx, gates = route(scores, bias.value, k, norm=self.route_norm,
+                               scale=self.route_scale)
+            counts = jnp.sum(idx.reshape(-1, 1) == jnp.arange(e)[None],
+                             axis=0).astype(jnp.float32)
+            if train and self.is_mutable_collection(ROUTER_STATE):
+                bias.value = selection_bias_update(
+                    bias.value, counts, self.bias_update_rate)
+
+        with jax.named_scope("moe_dispatch"):
+            rows = t * min(k, held)
+            local = idx - first
+            here = (local >= 0) & (local < held)
+            key = jnp.where(here, local, held).reshape(-1)
+            order = jnp.argsort(key, stable=True)
+            pos = jnp.argsort(order).reshape(t, k)   # where each one went
+            group_sizes = counts[first:first + held].astype(jnp.int32)
+            landed = group_sizes.sum()
+            dropped = jnp.maximum(landed - rows, 0)
+            here = here & (pos < rows)
+            pos = jnp.minimum(pos, rows - 1)
+            token_of = order[:rows] // k
+            gates = jnp.where(here, gates, 0.0)
+            row_gate = gates.reshape(-1)[order[:rows]]
+            xs = _dispatch(u.astype(self.dtype), token_of, pos, here)
+
+        with jax.named_scope("moe_experts"):
+            def kernel(name, shape, axes):
+                return _Kernel((held,) + shape, ("experts",) + axes,
+                               name=name)().astype(self.dtype)
+
+            w_gate = kernel("experts_gate", (h, self.expert_width),
+                            ("embed", "mlp"))
+            w_up = kernel("experts_up", (h, self.expert_width),
+                          ("embed", "mlp"))
+            w_down = kernel("experts_down", (self.expert_width, h),
+                            ("mlp", "embed"))
+            live = (jnp.arange(rows) < landed)[:, None]
+
+            def product(x, w):
+                # The compiler's kernel writes only the rows of a group; the
+                # rows past the last group hold what the buffer held before
+                # (seen on the chip: infinities). Zeros in and out, so that
+                # forward and backward a nobody's row is nought, not 0 x inf.
+                y = jax.lax.ragged_dot(jnp.where(live, x, 0), w, group_sizes)
+                return jnp.where(live, y, 0)
+
+            hidden = nn.silu(product(xs, w_gate)) * product(xs, w_up)
+            ys = product(hidden, w_down)
+
+        with jax.named_scope("moe_combine"):
+            out = _combine(ys, gates, token_of, pos, row_gate)
+
+        if self.shared_width:
+            with jax.named_scope("mlp"):
+                def dense(features, axes, name):
+                    return nn.Dense(
+                        features, use_bias=False, dtype=self.dtype,
+                        param_dtype=jnp.float32,
+                        kernel_init=nn.with_logical_partitioning(
+                            nn.initializers.normal(0.02), axes), name=name)
+
+                xd = u.astype(self.dtype)
+                shared = dense(h, ("mlp", "embed"), "shared_down")(
+                    nn.silu(dense(self.shared_width, ("embed", "mlp"),
+                                  "shared_gate")(xd))
+                    * dense(self.shared_width, ("embed", "mlp"),
+                            "shared_up")(xd))
+                out = out + shared.astype(jnp.float32)
+
+        self.sow(MOE_METRICS, "tokens_here", landed.astype(jnp.float32))
+        self.sow(MOE_METRICS, "max_expert_share",
+                 counts.max() / jnp.maximum(counts.sum(), 1.0))
+        self.sow(MOE_METRICS, "dropped", dropped.astype(jnp.float32))
+        return out.astype(self.dtype).reshape(b, s, h)

@@ -2,7 +2,10 @@
 (models/bert.py, models/gpt.py, models/llama.py).
 
 Four impls, one semantic: dropout(softmax(QK^T * d^-1/2 + mask)) V with a
-key-padding mask, optionally causal.
+key-padding mask, optionally causal, optionally cut to a causal ``window``
+(``query - key < window``; dense and flash), with K/V heads that may be
+fewer than the Q heads (Q head h reads K/V head ``h // (H // Hkv)``; flash
+reads them in place, dense repeats them).
 
 - ``dense``: materialized (S, S) scores, f32 softmax, XLA-fused — right for
   short sequences.
@@ -39,8 +42,10 @@ def multihead_attention(q, k, v, pad_mask, *, impl: str, causal: bool,
                         dtype: Any,
                         dropout_rate: float = 0.0,
                         dropout_rng: Optional[Any] = None,
-                        deterministic: bool = True):
-    """q/k/v: (B, S, H, D); pad_mask: (B, S) bool (True = attend) or None.
+                        deterministic: bool = True,
+                        window: Optional[int] = None):
+    """q: (B, S, H, D), k/v: (B, S, Hkv, D); pad_mask: (B, S) bool (True =
+    attend) or None.
 
     Returns (B, S, H*D) in ``dtype``. ``dropout_rate`` is the
     attention-probability dropout rate, applied only when
@@ -64,12 +69,22 @@ def multihead_attention(q, k, v, pad_mask, *, impl: str, causal: bool,
             seed_from_key)
         seed = seed_from_key(dropout_rng)
 
+    if window is not None and not causal:
+        raise ValueError("an attention window is the causal band "
+                         "0 <= query - key < window; it needs causal=True")
     if impl == "flash":
         from distributeddeeplearning_tpu.ops.flash_attention import (
             flash_attention_sharded)
         out = flash_attention_sharded(q, k, v, pad_mask, causal=causal,
-                                      dropout_rate=rate, dropout_seed=seed)
-    elif impl == "ring":
+                                      dropout_rate=rate, dropout_seed=seed,
+                                      window=window)
+        return out.reshape(b, s, -1)
+    if window is not None and impl != "dense":
+        raise ValueError(f"attention_impl={impl!r} has no window; use "
+                         f"'flash' or 'dense'")
+    if k.shape[2] != h:
+        k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
+    if impl == "ring":
         from distributeddeeplearning_tpu.parallel import ring_attention
         out = ring_attention.ring_attention_sharded(
             q, k, v, pad_mask, causal=causal,
@@ -94,6 +109,9 @@ def multihead_attention(q, k, v, pad_mask, *, impl: str, causal: bool,
         keep = pad_mask[:, None, None, :]
         if causal:
             keep = keep & jnp.tril(jnp.ones((s, s), jnp.bool_))[None, None]
+        if window is not None:
+            keep = keep & ~jnp.tril(jnp.ones((s, s), jnp.bool_),
+                                    -window)[None, None]
         scores = jnp.where(keep, scores, jnp.finfo(jnp.float32).min)
         probs = jax.nn.softmax(scores.astype(jnp.float32),
                                axis=-1).astype(dtype)

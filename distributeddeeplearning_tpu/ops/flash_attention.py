@@ -41,7 +41,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributeddeeplearning_tpu import compat
-from distributeddeeplearning_tpu.ops.masks import block_causal_mask
+from distributeddeeplearning_tpu.ops.masks import (block_band_mask,
+                                                   block_causal_mask)
 from distributeddeeplearning_tpu.ops.pallas import pallas_call
 
 _NEG = -1e30
@@ -106,18 +107,27 @@ class TilePlan(NamedTuple):
     (S, S) score rectangle, how many of them the grids hold (the grids hold
     nothing else: ``visited`` is grid steps per batch·head), and how many of
     those the causal diagonal crosses (part of their scores is masked: the
-    work this tiling still does beyond the triangle)."""
+    work this tiling still does beyond the triangle). A call with a
+    ``window`` (``query - key < window``) visits only the tiles that meet
+    the band; ``edge`` counts those the window's far edge crosses."""
     bq: int
     bk: int
     total: int
     visited: int
     diagonal: int
+    window: Optional[int] = None
+    edge: int = 0
 
 
-def _needed(i, j, bq: int, bk: int):
+def _needed(i, j, bq: int, bk: int, window: Optional[int] = None):
     """Tile (i, j) holds a pair with key <= query: its first key column is
-    not past its last query row."""
-    return j * bk <= (i + 1) * bq - 1
+    not past its last query row. Under a window it must also hold a pair
+    with ``query - key < window``: its first query row less its last key
+    column is inside the window."""
+    need = j * bk <= (i + 1) * bq - 1
+    if window is not None:
+        need = need & (i * bq - ((j + 1) * bk - 1) < window)
+    return need
 
 
 def _crosses(i, j, bq: int, bk: int):
@@ -127,37 +137,70 @@ def _crosses(i, j, bq: int, bk: int):
     return (j + 1) * bk - 1 > i * bq
 
 
-def _tiles(s: int, bq: int, bk: int, causal: bool):
+def _crosses_edge(i, j, bq: int, bk: int, window: int):
+    """Tile (i, j) holds a pair with ``query - key >= window``: its last
+    query row less its first key column reaches the window's far edge."""
+    return (i + 1) * bq - 1 - j * bk >= window
+
+
+def _tiles(s: int, bq: int, bk: int, causal: bool,
+           window: Optional[int] = None):
     """(qi, kj): tile indices of every tile the kernels visit, Q-major (K
     innermost). The grids and the plan's counts are both made from this."""
     qi, kj = np.meshgrid(np.arange(s // bq, dtype=np.int32),
                          np.arange(s // bk, dtype=np.int32), indexing="ij")
-    keep = (_needed(qi, kj, bq, bk) if causal
+    keep = (_needed(qi, kj, bq, bk, window) if causal
             else np.ones_like(qi, dtype=bool))
     return qi[keep], kj[keep]
 
 
 def tile_plan(s: int, causal: bool, block_q: Optional[int] = None,
-              block_k: Optional[int] = None) -> TilePlan:
+              block_k: Optional[int] = None, *,
+              window: Optional[int] = None) -> TilePlan:
     """The plan for a call of sequence length ``s`` (padded as the call pads
     it). ``block_q`` / ``block_k`` override the derived tile sizes (the
     largest divisor of the padded ``s`` not above them is taken, as before).
-    Head size does not enter: 64 is the one measured on the chip."""
+    ``window`` (causal calls only) keeps the tiles that meet the band ``0 <=
+    query - key < window``; one that is no shorter than the sequence cuts
+    nothing off and plans as no window. Head size and the number of K/V heads
+    do not enter: measured on the chip are 64 at S = 1024 and 128 at
+    S = 8192 (PERF.md)."""
+    if window is not None and not causal:
+        raise ValueError("flash attention's window is the causal band "
+                         "0 <= query - key < window; it needs causal=True")
+    if window is not None and window < 1:
+        raise ValueError(f"window must be at least 1, got {window}")
     s = _padded_len(s)
+    if window is not None and window >= s:
+        window = None
     tq, tk = _CAUSAL_TILES if causal else _FULL_TILES
     bq, bk = _block(s, block_q or tq), _block(s, block_k or tk)
-    qi, kj = _tiles(s, bq, bk, causal)
-    return TilePlan(bq, bk, (s // bq) * (s // bk), int(qi.size),
-                    int(_crosses(qi, kj, bq, bk).sum()) if causal else 0)
+    qi, kj = _tiles(s, bq, bk, causal, window)
+    return TilePlan(
+        bq, bk, (s // bq) * (s // bk), int(qi.size),
+        int(_crosses(qi, kj, bq, bk).sum()) if causal else 0, window,
+        0 if window is None
+        else int(_crosses_edge(qi, kj, bq, bk, window).sum()))
 
 
-def _schedule(s: int, plan: TilePlan, causal: bool, k_major: bool = False):
+def _schedule(s: int, plan: TilePlan, causal: bool, k_major: bool = False,
+              groups: int = 1):
     """The two scalar-prefetch tables of a kernel: grid step t works on tile
     (qi[t], kj[t]). Q-major for forward and dQ (a Q tile's K tiles follow
-    each other, so its accumulators stay in scratch); K-major for dK/dV."""
-    qi, kj = _tiles(s, plan.bq, plan.bk, causal)
+    each other, so its accumulators stay in scratch); K-major for dK/dV.
+
+    ``groups`` > 1 (dK/dV under grouped K/V heads, whose grid rows are K/V
+    heads): a K tile's run holds its Q tiles once for each of the ``groups``
+    Q heads that read it, head after head, and the first table's entry is
+    ``g * (s // bq) + qi``, which the index maps and the kernel split
+    again."""
+    qi, kj = _tiles(s, plan.bq, plan.bk, causal, plan.window)
+    if groups > 1:
+        head = np.repeat(np.arange(groups, dtype=np.int32), qi.size)
+        qi = np.tile(qi, groups) + head * (s // plan.bq)
+        kj = np.tile(kj, groups)
     if k_major:
-        order = np.lexsort((qi, kj))
+        order = np.lexsort((qi, kj))  # K tile, then (head, then) Q tile
         qi, kj = qi[order], kj[order]
     return jnp.asarray(qi), jnp.asarray(kj)
 
@@ -214,13 +257,28 @@ def _causal_t(i, j, bq: int, bk: int):
     return kpos <= qpos
 
 
+def _band_t(i, j, bq: int, bk: int, window: int):
+    """ops/masks.py::block_band_mask of tile (i, j), transposed: (BK, BQ).
+    A test holds the two equal."""
+    dist = (i * bq - j * bk
+            + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+            - jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0))
+    return dist.astype(jnp.uint32) < jnp.uint32(window)
+
+
 def _valid(mask_ref, causal: bool, i, j, bq: int, bk: int,
-           transposed: bool = False):
+           transposed: bool = False, window: Optional[int] = None):
     """Which scores of tile (i, j) count: the key-padding mask, one vector
     over the keys that broadcasts, and the causal triangle. (A second tile
     body without the triangle for tiles wholly under the diagonal measured
     no faster at S = 1024 and 1.3 % faster at S = 8192: PERF.md, PR 26.)
-    ``transposed``: as (BK, BQ), keys down the rows."""
+    Under a ``window`` the triangle is the band of ops/masks.py, at the
+    triangle's price. ``transposed``: as (BK, BQ), keys down the rows."""
+    if window is not None:
+        band = (_band_t if transposed else block_band_mask)(i, j, bq, bk,
+                                                            window)
+        pad = mask_ref[0, 0][:, None] if transposed else mask_ref[0]
+        return (pad != 0) & band
     if transposed:
         # the relayout to a column wants the int32s, not the compared bools
         valid = mask_ref[0, 0][:, None] != 0
@@ -253,7 +311,8 @@ def _lane_sums(p, lanes: int):
 
 def _fwd_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
                 o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                causal: bool, dropout_rate: float):
+                causal: bool, dropout_rate: float,
+                window: Optional[int] = None):
     pid0, t = pl.program_id(0), pl.program_id(1)
     i, j = qi_ref[t], kj_ref[t]
     first, last = _run_edges(qi_ref, t)
@@ -275,7 +334,8 @@ def _fwd_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale       # (BQ, BK) f32
-    s = jnp.where(_valid(mask_ref, causal, i, j, bq, bk), s, _NEG)
+    s = jnp.where(_valid(mask_ref, causal, i, j, bq, bk, window=window), s,
+                  _NEG)
     m_prev = m_scr[:]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     # A masked score is _NEG, and exp(_NEG - m) is exactly 0 under any real
@@ -306,6 +366,12 @@ def _fwd_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
             l[:, 0] > 0, m_scr[:][:, 0] + jnp.log(safe_l[:, 0]), 0.0)
 
 
+def _window_kw(plan: TilePlan) -> dict:
+    """The kernels' ``window`` argument, named only where the plan has one:
+    a call without a window builds the kernels as before."""
+    return {} if plan.window is None else {"window": plan.window}
+
+
 def _grid_spec(bh: int, tables, in_specs, out_specs, scratch_shapes):
     """Grid (B*H, visited tiles) with the schedule tables and the dropout
     seed as scalar-prefetch operands: index maps and kernels read the tile
@@ -319,18 +385,56 @@ def _grid_spec(bh: int, tables, in_specs, out_specs, scratch_shapes):
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
-def _tile_specs(bq: int, bk: int, d: int):
+def _tile_specs(bq: int, bk: int, d: int, groups: int = 1,
+                kv_rows: bool = False, nq: int = 0):
     """Block specs by what a block follows: a Q tile and its per-row
-    vectors follow qi[t], a K tile and the key mask follow kj[t]."""
+    vectors follow qi[t], a K tile and the key mask follow kj[t].
+
+    ``groups`` > 1, grouped K/V heads (Q rows are B*H, K/V rows B*H/groups,
+    and Q row r reads K/V row r // groups): no K or V is repeated in memory,
+    the index maps send each grid row to the rows it reads. Forward and dQ
+    walk the Q rows; dK/dV walks the K/V rows (``kv_rows``) and finds the Q
+    row in the schedule's entry ``g * nq + qi`` (see :func:`_schedule`). The
+    key mask has a row a Q head, alike within a group."""
     # Rank-1-per-tile operands (mask, lse) ride as (BH, 1, S) so every block
     # shape is rank >= 2 with a compiled-lowering-legal tail: Mosaic requires
     # the last two block dims be (multiples of, or equal to) the array dims —
     # a (1, BK) block over a (BH, S) array is not (VERDICT r1 #6, found on
     # first real-TPU run).
-    q_tile = pl.BlockSpec((1, bq, d), lambda b, t, qi, kj, _: (b, qi[t], 0))
-    k_tile = pl.BlockSpec((1, bk, d), lambda b, t, qi, kj, _: (b, kj[t], 0))
-    vec_q = pl.BlockSpec((1, 1, bq), lambda b, t, qi, kj, _: (b, 0, qi[t]))
-    vec_k = pl.BlockSpec((1, 1, bk), lambda b, t, qi, kj, _: (b, 0, kj[t]))
+    # (row, tile) of the Q-side blocks, and the rows of K/V and of the mask,
+    # for grid row b at step t. Equal heads: all are b and qi[t], as before.
+    if groups > 1 and kv_rows:
+        def q_at(b, t, qi):
+            return b * groups + qi[t] // nq, qi[t] % nq
+
+        def k_row(b):
+            return b
+
+        def mask_row(b):
+            return b * groups
+    else:
+        def q_at(b, t, qi):
+            return b, qi[t]
+
+        def k_row(b):
+            return b // groups if groups > 1 else b
+
+        mask_row = k_row if groups == 1 else (lambda b: b)
+
+    def q_index(b, t, qi, kj, _):
+        row, tile = q_at(b, t, qi)
+        return row, tile, 0
+
+    def vec_q_index(b, t, qi, kj, _):
+        row, tile = q_at(b, t, qi)
+        return row, 0, tile
+
+    q_tile = pl.BlockSpec((1, bq, d), q_index)
+    k_tile = pl.BlockSpec((1, bk, d),
+                          lambda b, t, qi, kj, _: (k_row(b), kj[t], 0))
+    vec_q = pl.BlockSpec((1, 1, bq), vec_q_index)
+    vec_k = pl.BlockSpec((1, 1, bk),
+                         lambda b, t, qi, kj, _: (mask_row(b), 0, kj[t]))
     return q_tile, k_tile, vec_q, vec_k
 
 
@@ -338,10 +442,10 @@ def _fwd(q, k, v, mask, seed, *, scale, plan, causal, dropout_rate):
     bh, s, d = q.shape
     bq, bk = plan.bq, plan.bk
     tables = _schedule(s, plan, causal)
-    q_tile, k_tile, vec_q, vec_k = _tile_specs(bq, bk, d)
+    q_tile, k_tile, vec_q, vec_k = _tile_specs(bq, bk, d, bh // k.shape[0])
     out, lse = pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          dropout_rate=dropout_rate),
+                          dropout_rate=dropout_rate, **_window_kw(plan)),
         name="flash_fwd",
         grid_spec=_grid_spec(
             bh, tables, [q_tile, k_tile, k_tile, vec_k], [q_tile, vec_q],
@@ -364,7 +468,8 @@ def _fwd(q, k, v, mask, seed, *, scale, plan, causal, dropout_rate):
 
 def _dq_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
                do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *, scale: float,
-               causal: bool, dropout_rate: float):
+               causal: bool, dropout_rate: float,
+               window: Optional[int] = None):
     pid0, t = pl.program_id(0), pl.program_id(1)
     i, j = qi_ref[t], kj_ref[t]
     first, last = _run_edges(qi_ref, t)
@@ -383,7 +488,8 @@ def _dq_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    s = jnp.where(_valid(mask_ref, causal, i, j, bq, bk), s, _NEG)
+    s = jnp.where(_valid(mask_ref, causal, i, j, bq, bk, window=window), s,
+                  _NEG)
     p = jnp.exp(s - lse)                              # (BQ, BK)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -407,9 +513,15 @@ def _dq_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
 
 def _dkv_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
                 do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale: float, causal: bool, dropout_rate: float):
+                *, scale: float, causal: bool, dropout_rate: float,
+                window: Optional[int] = None, groups: int = 1, nq: int = 0):
     pid0, t = pl.program_id(0), pl.program_id(1)
     i, j = qi_ref[t], kj_ref[t]          # K-major: j stays, i accumulates
+    if groups > 1:
+        # Grouped K/V heads: this grid row is a K/V head and the table's
+        # entry names the Q head of its group beside the Q tile (_schedule);
+        # the dropout hash wants the Q head's row of the (batch, head) space.
+        pid0, i = pid0 * groups + i // nq, i % nq
     first, last = _run_edges(kj_ref, t)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
 
@@ -430,8 +542,8 @@ def _dkv_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
     s = jax.lax.dot_general(
         k, q, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    s = jnp.where(_valid(mask_ref, causal, i, j, bq, bk,
-                         transposed=True), s, _NEG)
+    s = jnp.where(_valid(mask_ref, causal, i, j, bq, bk, transposed=True,
+                         window=window), s, _NEG)
     p = jnp.exp(s - lse_ref[0])                       # (BK, BQ)
     if dropout_rate > 0.0:
         # (i, j) are the same logical (Q-tile, K-tile) indices the forward
@@ -470,14 +582,15 @@ def _bwd(scale, plan, causal, dropout_rate, residuals, g):
     mask3, lse3, delta3 = (x[:, None, :] for x in (mask, lse, delta))
 
     bq, bk = plan.bq, plan.bk
-    q_tile, k_tile, vec_q, vec_k = _tile_specs(bq, bk, d)
+    groups = bh // k.shape[0]
+    q_tile, k_tile, vec_q, vec_k = _tile_specs(bq, bk, d, groups)
     in_specs = [q_tile, k_tile, k_tile, vec_k, q_tile, vec_q, vec_q]
     operands = (seed, q, k, v, mask3, g, lse3, delta3)
 
     tables = _schedule(s, plan, causal)
     dq = pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          dropout_rate=dropout_rate),
+                          dropout_rate=dropout_rate, **_window_kw(plan)),
         name="flash_dq",
         grid_spec=_grid_spec(bh, tables, in_specs, [q_tile],
                              [pltpu.VMEM((bq, d), jnp.float32)]),
@@ -486,17 +599,25 @@ def _bwd(scale, plan, causal, dropout_rate, residuals, g):
     )(*tables, *operands)[0]
 
     # dk/dv: K tiles are the revisited outputs and Q the accumulation axis,
-    # so the same tiles are walked K-major.
-    tables = _schedule(s, plan, causal, k_major=True)
+    # so the same tiles are walked K-major. Under grouped K/V heads the grid
+    # rows are the K/V heads, and a K tile's run sums over the Q tiles of
+    # every Q head of its group.
+    kw = _window_kw(plan)
+    if groups > 1:
+        kw.update(groups=groups, nq=s // bq)
+        q_tile, k_tile, vec_q, vec_k = _tile_specs(
+            bq, bk, d, groups, kv_rows=True, nq=s // bq)
+        in_specs = [q_tile, k_tile, k_tile, vec_k, q_tile, vec_q, vec_q]
+    tables = _schedule(s, plan, causal, k_major=True, groups=groups)
     dk, dv = pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          dropout_rate=dropout_rate),
+                          dropout_rate=dropout_rate, **kw),
         name="flash_dkv",
-        grid_spec=_grid_spec(bh, tables, in_specs, [k_tile, k_tile],
+        grid_spec=_grid_spec(k.shape[0], tables, in_specs, [k_tile, k_tile],
                              [pltpu.VMEM((bk, d), jnp.float32),
                               pltpu.VMEM((bk, d), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
         compiler_params=_PARAMS,
     )(*tables, *operands)
     return dq, dk, dv, None, None
@@ -522,15 +643,20 @@ def flash_attention(q, k, v, kv_mask=None, *,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None, causal: bool = False,
                     dropout_rate: float = 0.0, dropout_seed=None,
-                    bh_offsets=None):
+                    bh_offsets=None, window: Optional[int] = None):
     """Fused attention with a key-padding mask; ``causal=True`` adds the
     autoregressive lower-triangular mask (tiles above the diagonal are not
-    in the grid). ``block_q`` / ``block_k`` override the tile sizes that
-    :func:`tile_plan` derives from (S, causal); leave them out.
+    in the grid), and ``window`` cuts it to the band ``query - key <
+    window`` (tiles wholly outside the band are not in the grid either).
+    ``block_q`` / ``block_k`` override the tile sizes that :func:`tile_plan`
+    derives from (S, causal); leave them out.
 
-    q/k/v: (B, S, H, D) — the models' layout; kv_mask: (B, S) (True/nonzero
-    = attend), or None for all-valid. Returns (B, S, H, D) in q.dtype.
-    Differentiable w.r.t. q/k/v via the flash backward kernels.
+    q: (B, S, H, D), k/v: (B, S, Hkv, D) — the models' layout, H a multiple
+    of Hkv: Q head h reads K/V head ``h // (H // Hkv)`` through the kernels'
+    index maps, and dK/dV sum over the Q heads of a group inside the kernel.
+    kv_mask: (B, S) (True/nonzero = attend), or None for all-valid. Returns
+    (B, S, H, D) in q.dtype. Differentiable w.r.t. q/k/v via the flash
+    backward kernels.
 
     ``dropout_rate`` > 0 applies attention-probability dropout INSIDE the
     kernels via a counter-based hash mask (ops/hash_dropout.py) that the
@@ -541,6 +667,10 @@ def flash_attention(q, k, v, kv_mask=None, *,
     defaults to the unsharded identity.
     """
     b, s, h, d = q.shape
+    if k.shape != v.shape or h % k.shape[2] or k.shape[3] != d:
+        raise ValueError(f"flash_attention: q {q.shape} cannot share k "
+                         f"{k.shape} / v {v.shape}; the K/V heads must "
+                         f"divide the Q heads")
     if kv_mask is None:
         kv_mask = jnp.ones((b, s), jnp.int32)
     if dropout_rate > 0.0 and dropout_seed is None:
@@ -569,12 +699,12 @@ def flash_attention(q, k, v, kv_mask=None, *,
     kv_mask = jnp.broadcast_to(
         kv_mask.astype(jnp.int32)[:, None, :], (b, h, s)).reshape(b * h, s)
 
-    def to_bh(x):  # (B, S, H, D) -> (B*H, S, D)
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    def to_bh(x):  # (B, S, H, D) -> (B*H, S, D), by x's own head count
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, d)
 
     out = _flash(to_bh(q), to_bh(k), to_bh(v), kv_mask, seed, d ** -0.5,
-                 tile_plan(s, causal, block_q, block_k), causal,
-                 float(dropout_rate))
+                 tile_plan(s, causal, block_q, block_k, window=window),
+                 causal, float(dropout_rate))
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)[:, :s_orig]
 
 

@@ -20,3 +20,15 @@ def block_causal_mask(q_block, k_block, sq: int, sk: int):
     qpos = q_block * sq + lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
     kpos = k_block * sk + lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
     return kpos <= qpos
+
+
+def block_band_mask(q_block, k_block, sq: int, sk: int, window: int):
+    """(sq, sk) bool: the causal triangle cut to a window, ``0 <= q - kv <
+    window`` in global positions. One unsigned comparison decides both ends
+    (a key past the query wraps to a huge distance), so a tile pays the same
+    for the band as :func:`block_causal_mask` takes for the triangle: the
+    window's edge costs nothing on the tiles it does not cross."""
+    dist = (q_block * sq - k_block * sk
+            + lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
+            - lax.broadcasted_iota(jnp.int32, (sq, sk), 1))
+    return dist.astype(jnp.uint32) < jnp.uint32(window)

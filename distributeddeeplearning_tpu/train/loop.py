@@ -299,7 +299,7 @@ def build(config: TrainConfig, total_steps: int):
                 params = opt_params
             return TrainState.create(
                 params=params, opt_state=tx.init(opt_params),
-                batch_stats=variables.get("batch_stats"),
+                batch_stats=steps.model_state(variables),
                 ema_params=(params if config.optimizer.ema_decay > 0
                             else None),
                 loss_scale=steps.init_loss_scale(config))

@@ -1,4 +1,4 @@
-"""Train state pytree: params + optimizer state + BN statistics + step.
+"""Train state pytree: params + optimizer state + model state + step.
 
 A plain ``flax.struct`` pytree (not TrainState from flax.training) so the
 whole state threads through ``jit``/``shard_map`` and orbax untouched.
@@ -44,7 +44,10 @@ class TrainState:
     step: jnp.ndarray                 # scalar int32
     params: Any                       # model parameters (f32)
     opt_state: Any                    # optax state
-    batch_stats: Any = None           # BN running stats (CNNs) or None
+    batch_stats: Any = None           # the model's state besides parameters:
+                                      # BN running stats (CNNs), the routed
+                                      # experts' selection biases
+                                      # (models/moe.py), or None
     ema_params: Any = None            # EMA shadow params (optimizer.ema_decay
                                       # > 0); evals read these when present
     loss_scale: Any = None            # dynamic loss-scale state

@@ -39,6 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from distributeddeeplearning_tpu import compat
 from distributeddeeplearning_tpu.analysis import anatomy
 from distributeddeeplearning_tpu.config import TrainConfig, resolve_precision
+from distributeddeeplearning_tpu.models import moe
 from distributeddeeplearning_tpu.parallel import collectives
 from distributeddeeplearning_tpu.parallel import sharding as shardlib
 from distributeddeeplearning_tpu.parallel import zero
@@ -249,19 +250,57 @@ def _token_loss_fn(model, config: TrainConfig):
     return loss_fn
 
 
+def model_state(variables):
+    """What a model keeps besides its parameters and the step must carry:
+    BatchNorm's running statistics, or the routed experts' selection biases
+    (models/moe.py), or None. It rides in ``TrainState.batch_stats``, which
+    holds ONE collection: a model that keeps both is refused here rather than
+    trained with one of them silently left behind."""
+    kept = [c for c in ("batch_stats", moe.ROUTER_STATE) if c in variables]
+    if len(kept) > 1:
+        raise ValueError(
+            f"the model keeps {kept}: TrainState.batch_stats carries one "
+            f"collection of model state, not both")
+    return variables[kept[0]] if kept else None
+
+
+def _moe_metrics(sown) -> dict:
+    """The step's three expert counters from what each RoutedExperts layer
+    sowed: assignments that landed on this chip's experts (all layers), the
+    largest share of a layer's assignments that one expert took, and
+    assignments that found no room in a row buffer (which is sized so that
+    there are none)."""
+    by_name: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
+        by_name.setdefault(path[-2].key, []).append(leaf)
+    return {"moe_tokens_here": sum(by_name["tokens_here"]),
+            "moe_max_expert_share": jnp.max(
+                jnp.stack(by_name["max_expert_share"])),
+            "moe_dropped": sum(by_name["dropped"])}
+
+
 def _causal_loss_fn(model, config: TrainConfig):
     del config
 
     def loss_fn(params, batch_stats, batch, rng):
-        del batch_stats
-        logits = model.apply(
-            {"params": params}, batch["input_ids"],
+        variables, mutable = {"params": params}, False
+        if batch_stats is not None:
+            # routed experts: the selection biases move, with no gradient
+            variables[moe.ROUTER_STATE] = batch_stats
+            mutable = [moe.ROUTER_STATE, moe.MOE_METRICS]
+        out = model.apply(
+            variables, batch["input_ids"],
             attention_mask=batch.get("attention_mask"),
-            train=True, rngs={"dropout": rng})
+            train=True, rngs={"dropout": rng}, mutable=mutable)
+        logits, mutated = out if mutable else (out, None)
         with jax.named_scope(LOSS_SCOPE):
             loss = losses.causal_lm_loss(
                 logits, batch["input_ids"], batch.get("attention_mask"))
-        return loss, (None, {"loss": loss})
+        if mutated is None:
+            return loss, (None, {"loss": loss})
+        return loss, (mutated[moe.ROUTER_STATE],
+                      {"loss": loss,
+                       **_moe_metrics(mutated[moe.MOE_METRICS])})
 
     return loss_fn
 
@@ -724,9 +763,12 @@ def make_token_eval_step(model, mesh: Mesh, config: TrainConfig,
         kw = {}
         if objective != "causal" and "masked_positions" in batch:
             kw["masked_positions"] = batch["masked_positions"]
+        variables = {"params": state.params}
+        if objective == "causal" and state.batch_stats is not None:
+            variables[moe.ROUTER_STATE] = state.batch_stats
         with _unreplicated_rules_ctx(config):
             logits = model.apply(
-                {"params": state.params}, batch["input_ids"],
+                variables, batch["input_ids"],
                 attention_mask=batch.get("attention_mask"), train=False, **kw)
         if objective == "causal":
             s, n = losses.causal_lm_loss_sums(
@@ -863,7 +905,7 @@ def init_sharded_state(model, tx, mesh: Mesh, config: TrainConfig,
         opt_state = tx.init(params)
         return TrainState.create(
             params=params, opt_state=opt_state,
-            batch_stats=variables.get("batch_stats"),
+            batch_stats=model_state(variables),
             ema_params=(params if config.optimizer.ema_decay > 0
                         else None),
             loss_scale=init_loss_scale(config))

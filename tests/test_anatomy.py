@@ -135,6 +135,33 @@ CASES = [
     (STEP + "grads/jvp(loss)/reduce_max", "forward", "loss"),
     (STEP + "grads/transpose(jvp(loss))/jit(take_along_axis)/scatter-add",
      "backward", "loss"),
+    # a model that scopes its attention by layer kind books the kernels there
+    (STEP + "grads/jvp(AfmoeLM)/layer1.<lambda>/layer1/attention/attn_window/"
+     "flash_fwd/cond/branch_0_fun/flash_fwd/pallas_call", "forward",
+     "attention_window"),
+    (STEP + "grads/transpose(grads)/jvp(AfmoeLM)/layer4/attention/attn_full/"
+     "flash_dkv/cond/branch_0_fun/flash_dkv/pallas_call", "backward",
+     "attention_full"),
+    (STEP + "grads/jvp(AfmoeLM)/layer4/attention/attn_full/transpose",
+     "forward", "attention_full"),
+    (STEP + "grads/jvp(AfmoeLM)/layer2/moe/moe_router/top_k", "forward",
+     "moe_routing"),
+    (STEP + "grads/jvp(AfmoeLM)/layer2/moe/moe_dispatch/sort", "forward",
+     "moe_routing"),
+    (STEP + "grads/transpose(jvp(AfmoeLM))/layer2/moe/moe_combine/gather",
+     "backward", "moe_routing"),
+    (STEP + "grads/jvp(AfmoeLM)/layer2/moe/moe_experts/ragged_dot", "forward",
+     "moe_experts"),
+    # a compiler-named ragged product, as `table` renames it: innermost wins
+    (STEP + "grads/transpose(jvp(AfmoeLM))/layer2/moe/moe_dispatch/"
+     "reduce_sum/moe_experts", "backward", "moe_experts"),
+    (STEP + "grads/jvp(AfmoeLM)/layer2/moe/mlp/shared_up/dot_general",
+     "forward", "mlp"),
+    (STEP + "grads/jvp(AfmoeLM)/layer0/pre_mlp_layernorm/rsqrt", "forward",
+     "layernorm"),
+    # the recompute boundary's own copies of a saved block input
+    (STEP + "grads/transpose(jvp(AfmoeLM))/grads/jvp(AfmoeLM)/remat2",
+     "backward", "remat"),
     (STEP + "loss_scale/reduce_sum", "update", "loss_scale"),
     (STEP + "loss_scale/jit(_where)/select_n", "update", "loss_scale"),
     (STEP + "optimizer/sqrt", "update", "optimizer"),
@@ -261,3 +288,33 @@ def test_a_live_step_and_the_saved_file_give_the_same_table(cache_here):
     del step, state
     assert aot.anatomy("gspmd_train_step") == live
     assert aot.anatomy("no_such_step") is None
+
+
+def test_table_names_the_compilers_ragged_products_by_their_operands():
+    """The TPU compiler makes kernels of its own of ``jax.lax.ragged_dot`` and
+    names them itself; ``table`` gives them their operands' name stack (a
+    backward operand's first) with the experts' scope at the end, and
+    ``part_of`` then books the innermost model scope."""
+    fwd = "jit(f)/grads/jvp(M)/layer1/moe/moe_dispatch/gather"
+    bwd = "jit(f)/grads/transpose(jvp(M))/layer1/moe/moe_combine/mul"
+    text = f"""HloModule jit_f
+
+ENTRY %main.9 (x.1: bf16[8,4]) -> bf16[8,4] {{
+  %x.1 = bf16[8,4]{{1,0}} parameter(0)
+  %sizes.1 = s32[2]{{0}} reduce(%x.1), metadata={{op_name="{fwd}"}}
+  %ragged-dot-metadata.1 = (s32[3]{{0}}, s32[1]{{0}}) custom-call(%sizes.1), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-metadata"}}
+  %gte.1 = s32[3]{{0}} get-tuple-element(%ragged-dot-metadata.1), index=0
+  %rows.1 = bf16[8,4]{{1,0}} fusion(%x.1), kind=kLoop, calls=%f.1, metadata={{op_name="{fwd}"}}
+  %ragged-dot-none.1 = bf16[8,4]{{1,0}} custom-call(%gte.1, %rows.1), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-none"}}
+  %cot.1 = bf16[8,4]{{1,0}} fusion(%ragged-dot-none.1), kind=kLoop, calls=%f.2, metadata={{op_name="{bwd}"}}
+  ROOT %ragged-dot-none.2 = bf16[8,4]{{1,0}} custom-call(%gte.1, %cot.1), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-none"}}
+}}
+"""
+    got = anatomy.table(text)
+    assert got["ragged-dot-metadata.1"] == fwd + "/moe_experts"
+    assert got["ragged-dot-none.1"] == fwd + "/moe_experts/moe_experts"
+    assert got["ragged-dot-none.2"] == bwd + "/moe_experts"
+    assert anatomy.part_of(got["ragged-dot-none.1"]) == ("forward",
+                                                         "moe_experts")
+    assert anatomy.part_of(got["ragged-dot-none.2"]) == ("backward",
+                                                         "moe_experts")

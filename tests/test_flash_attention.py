@@ -274,3 +274,175 @@ def test_bert_flash_matches_dense_forward():
     out_f = flash.apply(variables, ids, attention_mask=mask, train=False)
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# A window in the causal mask, grouped K/V heads, head size 128 (PR 27)
+# ---------------------------------------------------------------------------
+
+def _band_reference(q, k, v, window):
+    """Dense softmax attention, causal and cut to ``query - key < window``
+    (None: causal only), Q head h on K/V head h // (H // Hkv)."""
+    s, h = q.shape[1], q.shape[2]
+    rep = h // k.shape[2]
+    k, v = (jnp.repeat(x, rep, axis=2) for x in (k, v))
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    keep = j <= i
+    if window is not None:
+        keep = keep & (i - j < window)
+    p = jax.nn.softmax(jnp.where(keep[None, None], sc, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+GROUPED_CASES = [
+    # s, h, hkv, d, window, block
+    pytest.param(128, 4, 4, 16, 48, 32, id="window"),
+    pytest.param(128, 4, 4, 16, 32, 32, id="window-on-a-tile-edge"),
+    pytest.param(128, 4, 4, 16, 1, 32, id="window-of-one"),
+    pytest.param(128, 8, 2, 16, None, 32, id="grouped"),
+    pytest.param(128, 8, 1, 16, 40, 32, id="grouped-window-one-kv-head"),
+    pytest.param(128, 8, 2, 128, 40, 32, id="grouped-window-d128"),
+    pytest.param(300, 4, 2, 16, 100, 128, id="grouped-window-padded-s"),
+    pytest.param(1024, 2, 1, 16, 300, None, id="derived-tiles"),
+    pytest.param(128, 4, 2, 16, 500, 32, id="window-wider-than-s"),
+]
+
+
+@pytest.mark.parametrize("s,h,hkv,d,window,block", GROUPED_CASES)
+def test_window_and_grouped_heads_match_dense(s, h, hkv, d, window, block):
+    """Forward and all three gradients; dK and dV sum over a group's Q heads
+    inside the kernel."""
+    ks = jax.random.split(jax.random.key(6), 3)
+    q = jax.random.normal(ks[0], (2, s, h, d))
+    k = jax.random.normal(ks[1], (2, s, hkv, d))
+    v = jax.random.normal(ks[2], (2, s, hkv, d))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=block, block_k=block)
+
+    def dense(q, k, v):
+        return _band_reference(q, k, v, window)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+    gf = jax.grad(lambda *a: (flash(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    gd = jax.grad(lambda *a: (dense(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, name in zip(gf, gd, ("dq", "dk", "dv")):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+def test_grouped_heads_with_dropout_match_the_dense_impl():
+    """The dK/dV kernel walks K/V heads and keys the dropout hash by the Q
+    head it is summing: the same mask as the forward's, and as the dense
+    impl's over repeated heads."""
+    from distributeddeeplearning_tpu.ops.attention import multihead_attention
+
+    ks = jax.random.split(jax.random.key(8), 3)
+    q = jax.random.normal(ks[0], (2, 128, 8, 16))
+    k = jax.random.normal(ks[1], (2, 128, 2, 16))
+    v = jax.random.normal(ks[2], (2, 128, 2, 16))
+
+    def run(impl):
+        def f(q, k, v):
+            out = multihead_attention(
+                q, k, v, None, impl=impl, causal=True, dtype=jnp.float32,
+                window=50, dropout_rate=0.2, deterministic=False,
+                dropout_rng=jax.random.key(3))
+            return (out ** 2).sum(), out
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    (_, out_f), gf = run("flash")
+    (_, out_d), gd = run("dense")
+    np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("i,j,bq,bk,window", [
+    (0, 0, 32, 32, 8), (3, 1, 16, 64, 40), (1, 2, 64, 32, 100),
+    (5, 1, 128, 128, 512), (4, 0, 64, 64, 257)])
+def test_band_rule_is_the_shared_one(i, j, bq, bk, window):
+    """ops/masks.py::block_band_mask is the causal triangle and the window
+    at once, and the dK/dV kernel's transposed build of it is the same."""
+    from distributeddeeplearning_tpu.ops.flash_attention import _band_t
+    from distributeddeeplearning_tpu.ops.masks import (block_band_mask,
+                                                       block_causal_mask)
+
+    band = np.asarray(block_band_mask(i, j, bq, bk, window))
+    qpos = i * bq + np.arange(bq)[:, None]
+    kpos = j * bk + np.arange(bk)[None, :]
+    np.testing.assert_array_equal(
+        band, np.asarray(block_causal_mask(i, j, bq, bk))
+        & (qpos - kpos < window))
+    np.testing.assert_array_equal(np.asarray(_band_t(i, j, bq, bk, window)),
+                                  band.T)
+
+
+def _band_plan_by_hand(s, bq, bk, window):
+    """(visited, diagonal, edge) pair by pair from the definition."""
+    visited = diagonal = edge = 0
+    for i in range(s // bq):
+        for j in range(s // bk):
+            q = np.arange(i * bq, (i + 1) * bq)[:, None]
+            k = np.arange(j * bk, (j + 1) * bk)[None, :]
+            inside = (k <= q) & (q - k < window)
+            if inside.any():
+                visited += 1
+                diagonal += bool((k > q).any())
+                edge += bool((q - k >= window).any())
+    return visited, diagonal, edge
+
+
+@pytest.mark.parametrize("s,window,visited", [
+    (1024, None, 3), (8192, None, 136), (8192, 2048, 70)])
+def test_tile_plan_visited_counts_of_the_models_shapes(s, window, visited):
+    """By hand, at the derived 512 x 512 tiles: S = 1024 visits 3 of 4
+    tiles; S = 8192 visits 16 * 17 / 2 = 136 of 256; under a window of 2048
+    a row of Q tiles meets its own tile and the four before it (the fourth
+    holds the pairs 1537 .. 2047 apart), so rows 0-3 visit 1 + 2 + 3 + 4 and
+    the other twelve 5 each: 70. A call without a window plans as PR 26's."""
+    from distributeddeeplearning_tpu.ops.flash_attention import (
+        _schedule, tile_plan)
+
+    plan = tile_plan(s, True, window=window)
+    assert (plan.bq, plan.bk, plan.visited) == (512, 512, visited)
+    if window is None:
+        assert plan == tile_plan(s, True) and plan.edge == 0
+        return
+    assert plan.window == window and (plan.diagonal, plan.edge) == (16, 12)
+    for groups in (1, 8):
+        qi, kj = (np.asarray(t) for t in
+                  _schedule(s, plan, True, k_major=True, groups=groups))
+        assert len(qi) == groups * visited
+        assert (np.diff(kj) >= 0).all()       # a K tile's run is one run
+        heads = qi // (s // plan.bq)
+        assert sorted(set(heads.tolist())) == list(range(groups))
+
+
+@pytest.mark.parametrize("s,bq,bk,window", [
+    (1024, 128, 128, 300), (1024, 256, 128, 128), (2048, 512, 512, 513),
+    (2048, 128, 512, 1), (1024, 512, 512, 2048)])
+def test_tile_plan_under_a_window_counts_by_hand(s, bq, bk, window):
+    from distributeddeeplearning_tpu.ops.flash_attention import tile_plan
+
+    plan = tile_plan(s, True, bq, bk, window=window)
+    assert (plan.visited, plan.diagonal, plan.edge) == _band_plan_by_hand(
+        s, bq, bk, window)
+
+
+def test_a_window_needs_a_causal_call():
+    q, k, v = random_qkv(jax.random.key(9))
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, window=8)
+    q, k, v = random_qkv(jax.random.key(9), h=3)
+    with pytest.raises(ValueError, match="divide"):
+        flash_attention(q, k[:, :, :2], v[:, :, :2], causal=True)

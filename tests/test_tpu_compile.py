@@ -102,6 +102,30 @@ def test_flash_attention_compiles_for_v5e(compile_for, shape, causal, rate,
         assert f"%{name}" in text
 
 
+# Trinity-Mini's attention at the benchmark cell's size: 32 Q heads of 128 on
+# 4 K/V heads at S = 8192, full (causal) and under the 2048 window; and the
+# grouped heads with dropout, whose hash the dK/dV kernel keys by Q head.
+@pytest.mark.parametrize("window,rate", [(None, 0.0), (2048, 0.0),
+                                         (2048, 0.1)],
+                         ids=["full", "window", "window-dropout"])
+def test_flash_attention_grouped_window_compiles_for_v5e(compile_for, window,
+                                                         rate):
+    from distributeddeeplearning_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, window=window, dropout_rate=rate,
+            dropout_seed=jnp.int32(7) if rate else None)
+        return out.astype(F32).sum()
+
+    text = compile_for(jax.grad(loss, argnums=(0, 1, 2)),
+                       ((1, 8192, 32, 128), BF16), ((1, 8192, 4, 128), BF16),
+                       ((1, 8192, 4, 128), BF16))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert f"%{name}" in text
+
+
 def test_fused_batchnorm_compiles_for_v5e(compile_for):
     """bn_act_train fwd+bwd at resnet50's stem activation, batch 256:
     (256*56*56, 64) — the lane-folded narrow-channel case."""

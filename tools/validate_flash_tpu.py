@@ -6,11 +6,17 @@ materialized ``dense_keep_mask`` reference — then (unless --skip-timing)
 time flash against dense, and read each kernel's device time from a trace.
 
     python tools/validate_flash_tpu.py [--shape 8,512,12,64] [--causal]
-        [--skip-timing] [--tiles 512x512,256x256]
+        [--window 2048] [--skip-timing] [--tiles 512x512,256x256]
 
 With no ``--shape`` it does all of that at the two shapes the models run:
 the benchmark cell's (16,1024,12,64, causal) and BERT's (8,512,12,64, not
 causal); both with a key-padding mask and dropout 0.1 in the kernel times.
+A heads field ``32/4`` is 32 Q heads on 4 K/V heads, and ``--window`` cuts
+the causal triangle to a band: ``--shape 1,8192,32/4,128 --causal --window
+2048`` is a sliding layer of the trinity_mini cell, and without ``--window``
+its full layer. The dense reference goes a head at a time, so S = 8192 fits;
+where the (B,H,S,S) dropout mask of the reference would not, the dropout
+comparison is left out and the kernels are timed without dropout.
 ``--tiles`` times further tile sizes (block_q x block_k overrides) beside
 the derived ones, which is how a default in ``ops/flash_attention.py`` is
 chosen.
@@ -42,34 +48,56 @@ import numpy as np
 RATE, SEED = 0.1, 20260731
 
 
-def dense_ref(q, k, v, mask, *, causal=False, keep=None):
-    """softmax(QK^T)V in float32; ``keep`` (B,H,S,S) applies dropout the
-    way the kernels do: after the softmax, kept probs scaled by 1/(1-r)."""
-    s_len, d = q.shape[1], q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (d ** -0.5)
-    valid = mask[:, None, None, :]
+def dense_ref(q, k, v, mask, *, causal=False, keep=None, window=None):
+    """softmax(QK^T)V in float32, a Q head at a time (Q head h on K/V head
+    h // (H // Hkv)), each recomputed when differentiated; ``keep``
+    (B,H,S,S) applies dropout the way the kernels do: after the softmax,
+    kept probs scaled by 1/(1-r)."""
+    s_len, h, d = q.shape[1:]
+    groups = h // k.shape[2]
+    valid = mask[:, None, :]
     if causal:
-        valid = valid & jnp.tril(jnp.ones((s_len, s_len), bool))[None, None]
-    p = jax.nn.softmax(jnp.where(valid, s, jnp.finfo(jnp.float32).min),
-                       axis=-1)
-    if keep is not None:
-        p = jnp.where(keep, p / (1.0 - RATE), 0.0)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+        valid = valid & jnp.tril(jnp.ones((s_len, s_len), bool))[None]
+    if window is not None:
+        valid = valid & ~jnp.tril(jnp.ones((s_len, s_len), bool),
+                                  -window)[None]
+
+    @jax.checkpoint
+    def head(i):
+        qh = q[:, :, i].astype(jnp.float32)
+        kh, vh = (x[:, :, i // groups].astype(jnp.float32) for x in (k, v))
+        s = jnp.einsum("bqd,bkd->bqk", qh, kh) * (d ** -0.5)
+        p = jax.nn.softmax(jnp.where(valid, s, jnp.finfo(jnp.float32).min),
+                           axis=-1)
+        if keep is not None:
+            p = jnp.where(keep[:, i], p / (1.0 - RATE), 0.0)
+        return jnp.einsum("bqk,bkd->bqd", p, vh)
+
+    return jnp.moveaxis(jax.lax.map(head, jnp.arange(h)), 0, 2)
 
 
-def _inputs(shape):
+MASK_ELEMENTS_MAX = 2 ** 30  # the reference's (B,H,S,S) dropout mask
+
+
+def _dropout_fits(shape) -> bool:
+    b, s, h, _ = shape
+    return b * h * s * s <= MASK_ELEMENTS_MAX
+
+
+def _inputs(shape, kv_heads=None):
     b, s, h, d = shape
     rng = np.random.default_rng(0)
-    q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-               for _ in range(3))
+    kv_shape = (b, s, kv_heads or h, d)
+    q, k, v = (jnp.asarray(rng.standard_normal(sh), jnp.bfloat16)
+               for sh in (shape, kv_shape, kv_shape))
     # Padding mask with ragged valid lengths, incl. one fully-valid row.
     lens = np.r_[s, rng.integers(s // 4, s, b - 1)]
     mask = jnp.asarray(np.arange(s)[None, :] < lens[:, None])
     return q, k, v, mask
 
 
-def check_correctness(shape=(8, 512, 12, 64), causal=False) -> bool:
+def check_correctness(shape=(8, 512, 12, 64), causal=False, window=None,
+                      kv_heads=None) -> bool:
     """Compiled flash vs the dense reference at ``shape`` (B,S,H,D), bf16:
     forward and gradients, without and with dropout. One JSON line each."""
     from distributeddeeplearning_tpu.ops.flash_attention import (
@@ -77,12 +105,13 @@ def check_correctness(shape=(8, 512, 12, 64), causal=False) -> bool:
     from distributeddeeplearning_tpu.ops.hash_dropout import dense_keep_mask
 
     b, s, h, _ = shape
-    q, k, v, mask = _inputs(shape)
+    q, k, v, mask = _inputs(shape, kv_heads)
     valid = mask[:, :, None, None].astype(jnp.float32)
     ok = True
-    for label, rate in (("", 0.0), ("dropout_", RATE)):
+    cases = (("", 0.0), ("dropout_", RATE))
+    for label, rate in cases if _dropout_fits(shape) else cases[:1]:
         flash = functools.partial(
-            flash_attention, causal=causal, dropout_rate=rate,
+            flash_attention, causal=causal, window=window, dropout_rate=rate,
             dropout_seed=jnp.int32(SEED) if rate else None)
 
         # The (B,H,S,S) keep mask is built inside the jitted reference: as
@@ -90,7 +119,8 @@ def check_correctness(shape=(8, 512, 12, 64), causal=False) -> bool:
         def ref(q, k, v, mask, rate=rate):
             keep = (dense_keep_mask(jnp.int32(SEED), b, h, s, s, RATE)
                     if rate else None)
-            return dense_ref(q, k, v, mask, causal=causal, keep=keep)
+            return dense_ref(q, k, v, mask, causal=causal, keep=keep,
+                             window=window)
 
         def loss(fn, q, k, v):
             return (fn(q, k, v, mask).astype(jnp.float32) * valid).sum()
@@ -100,7 +130,8 @@ def check_correctness(shape=(8, 512, 12, 64), causal=False) -> bool:
         fwd_err = float(jnp.abs((out_f - out_r) * valid).max())
         ok_fwd = fwd_err < 2e-2  # bf16 inputs, f32 accumulation
         rec = {"check": f"flash_{label}forward", "shape": list(shape),
-               "causal": causal, "max_abs_err": fwd_err, "ok": ok_fwd}
+               "kv_heads": k.shape[2], "causal": causal, "window": window,
+               "max_abs_err": fwd_err, "ok": ok_fwd}
         if rate:
             rec["dropped_frac_ref"] = round(1.0 - float(jax.jit(
                 lambda: dense_keep_mask(jnp.int32(SEED), b, h, s, s,
@@ -118,7 +149,8 @@ def check_correctness(shape=(8, 512, 12, 64), causal=False) -> bool:
                                / jnp.maximum(jnp.abs(r).max(), 1.0))
             ok_bwd &= errs[name] < 3e-2
         print(json.dumps({"check": f"flash_{label}backward",
-                          "shape": list(shape), "causal": causal,
+                          "shape": list(shape), "kv_heads": k.shape[2],
+                          "causal": causal, "window": window,
                           "rel_err": errs, "ok": ok_bwd}), flush=True)
         ok &= ok_fwd and ok_bwd
     return ok
@@ -133,16 +165,18 @@ def _timed(fn, *args, iters=20):
     return (time.perf_counter() - t0) / iters
 
 
-def time_kernels(shape, causal) -> None:
+def time_kernels(shape, causal, window=None, kv_heads=None) -> None:
     from distributeddeeplearning_tpu.ops.flash_attention import (
         flash_attention)
 
-    q, k, v, mask = _inputs(shape)
-    flash = jax.jit(functools.partial(flash_attention, causal=causal))
+    q, k, v, mask = _inputs(shape, kv_heads)
+    flash = jax.jit(functools.partial(flash_attention, causal=causal,
+                                      window=window))
     flash_do = jax.jit(functools.partial(
-        flash_attention, causal=causal, dropout_rate=RATE,
+        flash_attention, causal=causal, window=window, dropout_rate=RATE,
         dropout_seed=jnp.int32(SEED)))
-    dense = jax.jit(functools.partial(dense_ref, causal=causal))
+    dense = jax.jit(functools.partial(dense_ref, causal=causal,
+                                      window=window))
 
     def grad_of(fn):
         return jax.jit(jax.grad(
@@ -150,7 +184,8 @@ def time_kernels(shape, causal) -> None:
             argnums=(0, 1, 2)))
 
     print(json.dumps({
-        "check": "timing", "shape": list(shape), "causal": causal,
+        "check": "timing", "shape": list(shape), "kv_heads": k.shape[2],
+        "causal": causal, "window": window,
         "device_kind": jax.devices()[0].device_kind,
         "fwd_ms": {"flash": round(_timed(flash, q, k, v, mask) * 1e3, 3),
                    "flash_dropout": round(
@@ -165,8 +200,8 @@ def time_kernels(shape, causal) -> None:
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
-def kernel_times(shape, causal, block_q=None, block_k=None,
-                 iters=10) -> None:
+def kernel_times(shape, causal, block_q=None, block_k=None, iters=10,
+                 window=None, kv_heads=None) -> None:
     """The plan at ``shape`` and the device time of each of the three
     kernels, from a profiler trace of ``iters`` forward+backward calls with
     the key-padding mask and dropout in the kernels: ms a call, and us a
@@ -176,11 +211,13 @@ def kernel_times(shape, causal, block_q=None, block_k=None,
         flash_attention, tile_plan)
 
     b, s, h, _ = shape
-    q, k, v, mask = _inputs(shape)
+    q, k, v, mask = _inputs(shape, kv_heads)
+    rate = RATE if _dropout_fits(shape) else 0.0
     step = jax.jit(jax.grad(
         lambda q, k, v: flash_attention(
-            q, k, v, mask, causal=causal, block_q=block_q, block_k=block_k,
-            dropout_rate=RATE, dropout_seed=jnp.int32(SEED),
+            q, k, v, mask, causal=causal, window=window, block_q=block_q,
+            block_k=block_k, dropout_rate=rate,
+            dropout_seed=jnp.int32(SEED) if rate else None,
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
     jax.block_until_ready(step(q, k, v))  # compile + warm
     with tempfile.TemporaryDirectory() as log_dir:
@@ -201,10 +238,11 @@ def kernel_times(shape, causal, block_q=None, block_k=None,
             for ev in line.events:
                 found = re.search(r"flash_(fwd|dq|dkv)\b", ev.name)
                 ns[found.group(0) if found else "other"] += ev.duration_ns
-    plan = tile_plan(s, causal, block_q, block_k)
+    plan = tile_plan(s, causal, block_q, block_k, window=window)
     ms = {name: ns[name] / iters / 1e6 for name in KERNELS}
     print(json.dumps({
-        "check": "kernel_times", "shape": list(shape), "causal": causal,
+        "check": "kernel_times", "shape": list(shape),
+        "kv_heads": k.shape[2], "causal": causal, "dropout": rate,
         "plan": plan._asdict(),
         "visited_share": round(plan.visited / plan.total, 4),
         "ms_a_call": {n: round(t, 4) for n, t in ms.items()},
@@ -223,8 +261,11 @@ MODEL_SHAPES = (((16, 1024, 12, 64), True), ((8, 512, 12, 64), False))
 def main(argv=None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--shape", default=None,
-                   help="B,S,H,D (default: the two shapes the models run)")
+                   help="B,S,H,D or B,S,H/Hkv,D (default: the two shapes "
+                        "the models run)")
     p.add_argument("--causal", action="store_true")
+    p.add_argument("--window", type=int, default=None,
+                   help="query - key < WINDOW (needs --causal)")
     p.add_argument("--skip-timing", action="store_true")
     p.add_argument("--tiles", default="",
                    help="further block_q x block_k to time, e.g. "
@@ -234,17 +275,24 @@ def main(argv=None) -> int:
     if platform != "tpu":
         print(json.dumps({"error": f"need TPU, got {platform}"}))
         return 1
-    cases = MODEL_SHAPES if args.shape is None else (
-        (tuple(int(x) for x in args.shape.split(",")), args.causal),)
+    kv_heads = None
+    if args.shape is not None:
+        b, s, heads, d = args.shape.split(",")
+        heads, _, kv = heads.partition("/")
+        kv_heads = int(kv) if kv else None
+        shape = (int(b), int(s), int(heads), int(d))
+    cases = MODEL_SHAPES if args.shape is None else ((shape, args.causal),)
+    kw = dict(window=args.window, kv_heads=kv_heads)
     tiles = [(None, None)] + [tuple(int(x) for x in t.split("x"))
                               for t in args.tiles.split(",") if t]
     ok = True
     for shape, causal in cases:
-        ok &= check_correctness(shape, causal)
+        ok &= check_correctness(shape, causal, **kw)
         if not args.skip_timing:
-            time_kernels(shape, causal)
+            if _dropout_fits(shape):  # its dense side makes (B,H,S,S) too
+                time_kernels(shape, causal, **kw)
             for block_q, block_k in tiles:
-                kernel_times(shape, causal, block_q, block_k)
+                kernel_times(shape, causal, block_q, block_k, **kw)
     return 0 if ok else 1
 
 

@@ -51,6 +51,12 @@ _MODEL_SCOPES = {"attn_window": "attention_window",
 # product is then part of the backward pass), else the first that has one.
 _COMPILER_NAMED = "ragged-dot"
 _RAGGED_SCOPE = "moe_experts"
+# A ``conditional`` runs the instructions of one of its branches, and a device
+# trace holds it as an operation of its own that spans theirs (seen on the
+# chip, PR 28: a step's operations add up to its busy time only without the
+# routed experts' conditionals). ``table`` gives it this in place of an
+# ``op_name``, and ``by_part`` leaves it out: its time is its branch's.
+SPANS_ITS_BRANCH = "(conditional: its time is its branch's operations')"
 # train/steps.py STEP_SCOPES outside ``grads`` -> part; all are phase update.
 _UPDATE_SCOPES = {"grad_reduce": "grad_reduce", "loss_scale": "loss_scale",
                   "optimizer": "optimizer", "ema": "ema_guard",
@@ -104,15 +110,21 @@ def table(hlo_text: str) -> dict[str, str]:
     explains it: a fusion (anything with ``calls=``) its called
     computation's root's; any other (the copies, bitcasts and tuples the
     compiler inserts) its first operand's that has one, or, where it only
-    reads parameters, its first user's. HLO text lists callees before
-    callers and operands before users, so one pass resolves all three.
+    reads parameters, its first user's, which a chain of such instructions
+    (the asynchronous copy of a ``conditional`` branch's argument: element
+    of the tuple, start, done) hands down to its beginning. HLO text lists
+    callees before callers and operands before users, so one pass resolves
+    all three.
     Parameters keep no name: an argument's path names no part of the
-    program. Instructions nothing explains are left out. Tolerant of torn
+    program. A ``conditional`` explains its neighbours like any other
+    instruction and is itself given :data:`SPANS_ITS_BRANCH`. Instructions
+    nothing explains are left out. Tolerant of torn
     text: a line that does not parse is skipped.
     """
     names: dict[str, str] = {}
     roots: dict[str, str] = {}     # computation -> its root's op_name
-    unexplained: set[str] = set()  # waiting for a user
+    unexplained: dict[str, list[str]] = {}  # waiting for a user: operands
+    conditionals: list[str] = []
     computation = None
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -141,14 +153,19 @@ def table(hlo_text: str) -> dict[str, str]:
                 op_name = next((names[o] for o in operands if o in names),
                                None)
         if op_name is None:
-            unexplained.add(name)
+            unexplained[name] = operands
             continue
         names[name] = op_name
-        for operand in unexplained.intersection(operands):
-            names[operand] = op_name
-            unexplained.discard(operand)
+        if opcode == "conditional":
+            conditionals.append(name)
+        while operands:
+            operand = operands.pop()
+            if operand in unexplained:
+                names[operand] = op_name
+                operands.extend(unexplained.pop(operand))
         if is_root and computation is not None:
             roots[computation] = op_name
+    names.update(dict.fromkeys(conditionals, SPANS_ITS_BRANCH))
     return names
 
 
@@ -208,10 +225,14 @@ def by_part(durations: dict[str, float], table: dict[str, str]
     """``{(phase, part): time}`` of a trace's ``{operation: time}``. A trace
     names an operation by its instruction or by its whole HLO line, which
     starts with it (``%fusion.7 = ...``). An instruction that ``table`` does
-    not hold has no phase (``"-"``) and the part ``unattributed``."""
+    not hold has no phase (``"-"``) and the part ``unattributed``; a
+    ``conditional`` is left out, its branch's operations being in the trace
+    themselves."""
     out: dict[tuple[str, str], float] = {}
     for line, time in durations.items():
         op_name = table.get(_LEADING_NAME.match(line).group(1))
+        if op_name == SPANS_ITS_BRANCH:
+            continue
         key = part_of(op_name) if op_name else ("-", "unattributed")
         out[key] = out.get(key, 0.0) + time
     return out

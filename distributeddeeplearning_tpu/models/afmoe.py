@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from distributeddeeplearning_tpu.models.llama import apply_rope
-from distributeddeeplearning_tpu.models.moe import RoutedExperts
+from distributeddeeplearning_tpu.models.moe import ROUTED_OUT, RoutedExperts
 from distributeddeeplearning_tpu.ops.attention import multihead_attention
 from distributeddeeplearning_tpu.ops.embedding import embedding_lookup
 
@@ -184,8 +184,12 @@ class AfmoeLM(nn.Module):
         for i in range(cfg.num_layers):
             block = AfmoeBlock(cfg, i, self.dtype, name=f"layer{i}")
             if cfg.remat:
-                x = nn.remat(lambda mdl, h, m: mdl(h, m, train=train))(
-                    block, x, pad_mask)
+                # nothing is kept but what the routed experts name: their
+                # backward rule runs their forward pass itself (moe.py)
+                x = nn.remat(
+                    lambda mdl, h, m: mdl(h, m, train=train),
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        ROUTED_OUT))(block, x, pad_mask)
             else:
                 x = block(x, pad_mask, train=train)
             x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))

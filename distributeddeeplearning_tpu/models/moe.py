@@ -25,6 +25,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 Dtype = Any
 
@@ -161,6 +162,10 @@ class MoeMlp(nn.Module):
 # statistics go); ``MOE_METRICS`` is sown anew every step.
 ROUTER_STATE = "router_state"
 MOE_METRICS = "moe_metrics"
+# ``checkpoint_name`` of the layer's result where its routed experts have two
+# bodies (:func:`_sized_to_what_lands`), for a recomputed block to keep:
+# ``jax.checkpoint_policies.save_only_these_names(ROUTED_OUT)``.
+ROUTED_OUT = "routed_experts_out"
 
 
 def selection_bias_update(bias, counts, rate: float):
@@ -237,6 +242,102 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+# The row buffer holds this many times the rows the layer expects to land
+# here (T * k * held / e, from its shapes alone) when no more than that did.
+# Under a freshly initialised router, the least balanced a sound one gets,
+# the fullest of four layers held 1.22 times the expected rows at the median
+# of 198 steps on the chip and 1.88 times at most (PERF.md §6, PR 28): twice
+# is seldom reached, and is a quarter of the worst case for a share of an
+# eighth of the experts.
+EXPECTED_ROWS_FACTOR = 2
+
+
+def _dispatch_to_combine(rows, u, gates, w_gate, w_up, w_down,
+                         order, pos, here, group_sizes):
+    """(T, H) float32: the routed experts' part of the layer's result, from
+    the gather into a buffer of ``rows`` rows to the weighted sum back in
+    token order. ``order`` lists the (token, slot) assignments sorted by
+    expert, those that land here first, ``pos`` (T, k) is its inverse,
+    ``here`` (T, k) says which land here, ``group_sizes`` how many on each
+    held expert. Every assignment that lands here has a row when
+    ``group_sizes.sum() <= rows``."""
+    k = pos.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        here = here & (pos < rows)
+        pos = jnp.minimum(pos, rows - 1)
+        token_of = order[:rows] // k
+        gates = jnp.where(here, gates, 0.0)
+        row_gate = gates.reshape(-1)[order[:rows]]
+        xs = _dispatch(u, token_of, pos, here)
+
+    with jax.named_scope("moe_experts"):
+        live = (jnp.arange(rows) < group_sizes.sum())[:, None]
+
+        def product(x, w):
+            # The compiler's kernel writes only the rows of a group; the
+            # rows past the last group hold what the buffer held before
+            # (seen on the chip: infinities). Zeros in and out, so that
+            # forward and backward a nobody's row is nought, not 0 x inf.
+            y = jax.lax.ragged_dot(jnp.where(live, x, 0), w, group_sizes)
+            return jnp.where(live, y, 0)
+
+        hidden = nn.silu(product(xs, w_gate)) * product(xs, w_up)
+        ys = product(hidden, w_down)
+
+    with jax.named_scope("moe_combine"):
+        return _combine(ys, gates, token_of, pos, row_gate)
+
+
+def _sized_to_what_lands(rows: int, worst: int):
+    """:func:`_dispatch_to_combine` as ``f(differentiable, integer)``
+    arguments, over a buffer of ``rows`` rows where what landed fits it and
+    of ``worst`` rows, which nothing can overflow, where it does not: one
+    ``cond`` on the rows that landed, the same numbers from either branch.
+    Where ``rows`` is no smaller than ``worst`` there is one body and no
+    ``cond``.
+
+    The ``cond`` is inside a ``custom_vjp`` whose residuals are the body's
+    arguments and whose backward rule is a second ``cond``, each branch the
+    ``vjp`` of its own body. Differentiated plainly, a ``cond`` whose
+    branches keep residuals of different shapes makes every branch return
+    every branch's residuals, so the small body would write the worst
+    case's buffers as zeros on every step. The backward rule thus runs the
+    body's forward pass itself, and a block that recomputes its forward pass
+    (``jax.checkpoint``) has no use for this one's but its result, which the
+    layer names ``ROUTED_OUT`` for the block's policy to keep: the products
+    then run once forward, once recomputed and once backward, as a single
+    body's do."""
+    def body(size):
+        return lambda args, ints: _dispatch_to_combine(size, *args, *ints)
+
+    if rows >= worst:
+        return body(worst)
+
+    def body_vjp(size):
+        def grads(args, ints, g):
+            return jax.vjp(lambda a: body(size)(a, ints), args)[1](g)[0]
+        return grads
+
+    def either(sized, args, ints, *cotangent):
+        *_, group_sizes = ints
+        with jax.named_scope("moe_dispatch"):  # the predicate is routing
+            return jax.lax.cond(group_sizes.sum() <= rows, sized(rows),
+                                sized(worst), args, ints, *cotangent)
+
+    @jax.custom_vjp
+    def run(args, ints):
+        return either(body, args, ints)
+
+    def run_fwd(args, ints):
+        return either(body, args, ints), (args, ints)
+
+    def run_bwd(res, g):
+        return either(body_vjp, *res, g), None
+
+    run.defvjp(run_fwd, run_bwd)
+    return run
+
+
 class _Kernel(nn.Module):
     """A matrix this layer multiplies by itself (the router's, or the held
     experts' kernels of one product stacked as (experts, in, out)), under
@@ -266,14 +367,25 @@ class RoutedExperts(nn.Module):
     it is the whole layer.
 
     Dispatch: the (token, slot) assignments are sorted by expert, those
-    that land here first; rows are gathered in that order into a buffer of
-    ``T * min(k, count)`` rows, which is every assignment that can land
-    here, so none is dropped (``moe_dropped`` counts what would not fit and
-    stays 0). The three products run as grouped products over the stacked
-    kernels with the experts' row counts (``jax.lax.ragged_dot``, which
-    the TPU compiler turns into a grouped-matmul kernel that visits only
-    the tiles that hold rows); rows past the last group are nobody's and
-    are kept at zero. The gated
+    that land here first, and rows are gathered in that order into a row
+    buffer. Every assignment that can land here is ``T * min(k, count)``
+    rows, the worst case; ``T * k * count / num_experts`` are expected, and
+    every gather, select and elementwise pass round the products is paid
+    for the buffer's rows, landed or not. So the body from the gather into
+    the buffer to the weighted sum runs over ``EXPECTED_ROWS_FACTOR`` times
+    the expected rows where no more than that landed and over the worst
+    case otherwise, under one ``jax.lax.cond`` on the rows that landed
+    (the step's ``moe_worst_case_layers`` counts the layers that took the
+    second); where that many rows are no fewer than the worst case (the
+    whole layer, or half the experts held) there is one body and no
+    ``cond``. No token is dropped either way (``moe_dropped`` counts what
+    would not fit the worst case and stays 0), and both bodies give the
+    same numbers. The ``cond`` sits inside a ``custom_vjp``
+    (:func:`_sized_to_what_lands` says why). The three products run as
+    grouped products over the stacked kernels with the experts' row counts
+    (``jax.lax.ragged_dot``, which the TPU compiler turns into a
+    grouped-matmul kernel that visits only the tiles that hold rows); rows
+    past the last group are nobody's and are kept at zero. The gated
     results return to token order by a gather and a weighted sum over a
     token's slots. A shared expert, ``shared_width`` > 0, is a dense SwiGLU
     every token passes through, held whole by every chip.
@@ -324,7 +436,6 @@ class RoutedExperts(nn.Module):
                     bias.value, counts, self.bias_update_rate)
 
         with jax.named_scope("moe_dispatch"):
-            rows = t * min(k, held)
             local = idx - first
             here = (local >= 0) & (local < held)
             key = jnp.where(here, local, held).reshape(-1)
@@ -332,13 +443,9 @@ class RoutedExperts(nn.Module):
             pos = jnp.argsort(order).reshape(t, k)   # where each one went
             group_sizes = counts[first:first + held].astype(jnp.int32)
             landed = group_sizes.sum()
-            dropped = jnp.maximum(landed - rows, 0)
-            here = here & (pos < rows)
-            pos = jnp.minimum(pos, rows - 1)
-            token_of = order[:rows] // k
-            gates = jnp.where(here, gates, 0.0)
-            row_gate = gates.reshape(-1)[order[:rows]]
-            xs = _dispatch(u.astype(self.dtype), token_of, pos, here)
+            worst = t * min(k, held)   # every assignment that can land here
+            rows = min(worst, -(-EXPECTED_ROWS_FACTOR * t * k * held // e))
+            dropped = jnp.maximum(landed - worst, 0)
 
         with jax.named_scope("moe_experts"):
             def kernel(name, shape, axes):
@@ -351,21 +458,10 @@ class RoutedExperts(nn.Module):
                           ("embed", "mlp"))
             w_down = kernel("experts_down", (self.expert_width, h),
                             ("mlp", "embed"))
-            live = (jnp.arange(rows) < landed)[:, None]
 
-            def product(x, w):
-                # The compiler's kernel writes only the rows of a group; the
-                # rows past the last group hold what the buffer held before
-                # (seen on the chip: infinities). Zeros in and out, so that
-                # forward and backward a nobody's row is nought, not 0 x inf.
-                y = jax.lax.ragged_dot(jnp.where(live, x, 0), w, group_sizes)
-                return jnp.where(live, y, 0)
-
-            hidden = nn.silu(product(xs, w_gate)) * product(xs, w_up)
-            ys = product(hidden, w_down)
-
-        with jax.named_scope("moe_combine"):
-            out = _combine(ys, gates, token_of, pos, row_gate)
+        out = _sized_to_what_lands(rows, worst)(
+            (u.astype(self.dtype), gates, w_gate, w_up, w_down),
+            (order, pos, here, group_sizes))
 
         if self.shared_width:
             with jax.named_scope("mlp"):
@@ -388,4 +484,7 @@ class RoutedExperts(nn.Module):
         self.sow(MOE_METRICS, "max_expert_share",
                  counts.max() / jnp.maximum(counts.sum(), 1.0))
         self.sow(MOE_METRICS, "dropped", dropped.astype(jnp.float32))
-        return out.astype(self.dtype).reshape(b, s, h)
+        self.sow(MOE_METRICS, "worst_case",
+                 (landed > rows).astype(jnp.float32))
+        out = out.astype(self.dtype).reshape(b, s, h)
+        return checkpoint_name(out, ROUTED_OUT) if rows < worst else out

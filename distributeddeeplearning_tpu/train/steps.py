@@ -265,18 +265,20 @@ def model_state(variables):
 
 
 def _moe_metrics(sown) -> dict:
-    """The step's three expert counters from what each RoutedExperts layer
+    """The step's four expert counters from what each RoutedExperts layer
     sowed: assignments that landed on this chip's experts (all layers), the
-    largest share of a layer's assignments that one expert took, and
+    largest share of a layer's assignments that one expert took,
     assignments that found no room in a row buffer (which is sized so that
-    there are none)."""
+    there are none), and the layers in which more landed than the buffer
+    sized to what is expected holds, so that the worst-case body ran."""
     by_name: dict = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
         by_name.setdefault(path[-2].key, []).append(leaf)
     return {"moe_tokens_here": sum(by_name["tokens_here"]),
             "moe_max_expert_share": jnp.max(
                 jnp.stack(by_name["max_expert_share"])),
-            "moe_dropped": sum(by_name["dropped"])}
+            "moe_dropped": sum(by_name["dropped"]),
+            "moe_worst_case_layers": sum(by_name["worst_case"])}
 
 
 def _causal_loss_fn(model, config: TrainConfig):

@@ -296,6 +296,123 @@ def test_the_products_cover_the_landed_rows_and_no_more(
                                    rtol=0, atol=1e-6)
 
 
+# Two of the 16 experts held, four a token: 48 of the 96 x 4 assignments are
+# expected here, so the row buffer is 96 rows where no more than that landed
+# and the worst case's 192 otherwise (models/moe.py::_sized_to_what_lands).
+TWO_HELD = 2   # experts 0 and 1
+DIFFERENTIATED = ("router/kernel", "experts_gate/kernel",
+                  "experts_up/kernel", "experts_down/kernel")
+
+
+def _two_body_case(one_layer, routing):
+    p, u, bias, _, _, _ = one_layer
+    if routing == "onto_the_held":  # every token's first two choices
+        bias = jnp.zeros((E_ALL,)).at[:TWO_HELD].set(10.0)
+    cot = jax.random.normal(jax.random.key(5), u.shape)
+    mine = {k: (v[:TWO_HELD] if k.startswith("experts_") else v)
+            for k, v in p.items()}
+    return mine, u, bias, cot
+
+
+def _program_value_and_grads(mine, u, bias, cot):
+    """(result, sown counters), gradients by the tokens and the kernels;
+    ``mine`` holds the two held experts' kernels, which program_layer's own
+    cut to experts 0 and 1 leaves as they are."""
+    def loss(diff, u):
+        out, sown = program_layer({**mine, **diff}, u, bias, 0, TWO_HELD)
+        return jnp.sum(out * cot), (out, sown)
+
+    (_, aux), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+        {k: mine[k] for k in DIFFERENTIATED}, u)
+    return aux, grads
+
+
+@pytest.fixture(scope="module", params=["fits", "onto_the_held"])
+def two_bodies(request, one_layer):
+    """The layer with a small and a worst-case body, under routing that fits
+    the small one and under routing that does not, beside the reference."""
+    mine, u, bias, cot = _two_body_case(one_layer, request.param)
+    sz = dict(LAYER_SZ, held=TWO_HELD, first_expert=0)
+    with jax.default_matmul_precision("highest"):
+        (out, sown), grads = _program_value_and_grads(mine, u, bias, cot)
+        want_out = ref.expert_ffn(sz, mine, u, bias)[0]
+        want_grads = jax.grad(
+            lambda diff, u: jnp.sum(
+                ref.expert_ffn(sz, {**mine, **diff}, u, bias)[0] * cot),
+            argnums=(0, 1))({k: mine[k] for k in DIFFERENTIATED}, u)
+    return dict(routing=request.param, out=out, sown=sown, grads=grads,
+                want_out=want_out, want_grads=want_grads)
+
+
+def test_two_bodies_result_and_counters(two_bodies):
+    fits = two_bodies["routing"] == "fits"
+    sown = two_bodies["sown"]
+    landed = float(sown["tokens_here"][0])
+    assert (0 < landed <= 96) if fits else landed == 192
+    assert float(sown["dropped"][0]) == 0.0
+    assert float(sown["worst_case"][0]) == (0.0 if fits else 1.0)
+    want = two_bodies["want_out"]
+    np.testing.assert_allclose(np.asarray(two_bodies["out"]),
+                               np.asarray(want), rtol=0,
+                               atol=2e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("leaf", ("tokens",) + DIFFERENTIATED)
+def test_two_bodies_gradients_match_the_reference(two_bodies, leaf):
+    pick = (lambda g: g[1]) if leaf == "tokens" else (lambda g: g[0][leaf])
+    got, want = pick(two_bodies["grads"]), pick(two_bodies["want_grads"])
+    scale = float(jnp.abs(want).max())
+    assert scale > 0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=2e-5 * scale)
+
+
+def test_the_small_body_and_the_worst_case_body_agree(one_layer, monkeypatch):
+    """The same input, whose rows fit the small body, through the small body
+    (the ``cond`` picks it) and through the worst-case body alone (a factor
+    that leaves no smaller one): the same groups go through the same
+    products, so result and gradients are the same numbers."""
+    case = _two_body_case(one_layer, "fits")
+    with jax.default_matmul_precision("highest"):
+        (small, sown), small_grads = _program_value_and_grads(*case)
+        monkeypatch.setattr(moe, "EXPECTED_ROWS_FACTOR", 16)
+        (worst, _), worst_grads = _program_value_and_grads(*case)
+    assert float(sown["worst_case"][0]) == 0.0
+    for a, b in zip(jax.tree_util.tree_leaves((small, small_grads)),
+                    jax.tree_util.tree_leaves((worst, worst_grads))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=1e-6 * float(jnp.abs(b).max()))
+
+
+def _conditionals(fn, *args):
+    return jax.jit(fn).lower(*args).as_text(dialect="hlo").count(
+        " conditional(")
+
+
+@pytest.mark.parametrize("held,conditionals", [
+    ((0, TWO_HELD), 2),   # forward and backward
+    ((0, E_ALL), 0), ((4, 8), 0)])  # twice the expected rows is the worst case
+def test_a_layer_has_two_bodies_only_under_its_worst_case(one_layer, held,
+                                                          conditionals):
+    p, u, bias, _, _, _ = one_layer
+
+    def loss(p, u):
+        return program_layer(p, u, bias, *held)[0].sum()
+
+    assert _conditionals(jax.value_and_grad(loss), p, u) == conditionals
+
+
+def test_tiny_afmoe_lowers_to_one_body(seeded):
+    params, extra, batch = seeded
+    model = models.get_model("afmoe_tiny", dtype=jnp.float32,
+                             vocab_size=SZ["vocab"])
+    assert _conditionals(
+        jax.value_and_grad(
+            lambda p: program_loss(model, p, router_state(extra),
+                                   batch["input_ids"])[0]),
+        unflatten(params)) == 0
+
+
 def test_the_bias_rule():
     counts = jnp.array([4.0, 0.0, 2.0, 2.0, 7.0, 1.0, 0.0, 0.0])
     got = moe.selection_bias_update(jnp.zeros(8), counts, 0.001)
@@ -390,6 +507,7 @@ def test_the_trainer_carries_the_bias_and_logs_the_counters(trained):
         assert leaf.shape == (8,) and float(np.abs(leaf).max()) > 0
     for m in history:
         assert m["moe_dropped"] == 0.0
+        assert m["moe_worst_case_layers"] == 0.0  # one body: nothing to fall to
         assert 0 < m["moe_tokens_here"] <= 2 * 2 * SEQ * 2  # layers x T x k
         assert 2 / 8 / 2 <= m["moe_max_expert_share"] <= 0.5
     assert history[-1]["loss"] < history[0]["loss"]

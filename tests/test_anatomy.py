@@ -318,3 +318,57 @@ ENTRY %main.9 (x.1: bf16[8,4]) -> bf16[8,4] {{
                                                          "moe_experts")
     assert anatomy.part_of(got["ragged-dot-none.2"]) == ("backward",
                                                          "moe_experts")
+
+
+@pytest.mark.parametrize("phase,outer", [
+    ("forward", "jit(step_fn)/grads/jvp(AfmoeLM)/layer1/moe/moe_dispatch/"
+                "cond/branch_1_fun/"),
+    ("backward", "jit(step_fn)/grads/transpose(jvp(AfmoeLM))/grads/"
+                 "jvp(AfmoeLM)/checkpoint/layer1/moe/moe_dispatch/cond/"
+                 "branch_1_fun/jvp(moe_experts)/")])
+def test_table_books_what_a_conditionals_branches_hold(phase, outer):
+    """The routed experts' two bodies are the branches of a ``conditional``
+    (models/moe.py): a branch's gather into the row buffer, the select round
+    a product and the compiler's own kernel keep the parts they have in a
+    single body, forward and backward; the kernel's asynchronously copied
+    argument, which reads only the branch's parameter, is handed the kernel's
+    name down its whole chain; the ``conditional`` itself is in the trace as
+    an operation that spans its branch's, and its time is left out."""
+    gather = outer + "moe_dispatch/gather"
+    select = outer + "moe_experts/jit(_where)/select_n"
+    pred = outer.split("cond/")[0] + "le"
+    text = f"""HloModule jit_step_fn
+
+%branch.1 (arg.1: (bf16[8,4], s32[2], bf16[2,4,4])) -> (bf16[8,4]) {{
+  %arg.1 = (bf16[8,4]{{1,0}}, s32[2]{{0}}, bf16[2,4,4]{{2,1,0}}) parameter(0)
+  %gte.1 = bf16[8,4]{{1,0}} get-tuple-element(%arg.1), index=0
+  %gte.2 = s32[2]{{0}} get-tuple-element(%arg.1), index=1
+  %gte.3 = bf16[2,4,4]{{2,1,0}} get-tuple-element(%arg.1), index=2
+  %rows.1 = bf16[8,4]{{1,0}} fusion(%gte.1), kind=kCustom, calls=%f.1, metadata={{op_name="{gather}"}}
+  %live.1 = bf16[8,4]{{1,0}} fusion(%rows.1), kind=kLoop, calls=%f.2, metadata={{op_name="{select}"}}
+  %copy-start.1 = (bf16[2,4,4]{{2,1,0:S(1)}}, bf16[2,4,4]{{2,1,0}}, u32[]{{:S(2)}}) copy-start(%gte.3)
+  %copy-done.1 = bf16[2,4,4]{{2,1,0:S(1)}} copy-done(%copy-start.1)
+  %ragged-dot-none.1 = bf16[8,4]{{1,0}} custom-call(%gte.2, %live.1, %copy-done.1), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-none"}}
+  ROOT %tuple.1 = (bf16[8,4]{{1,0}}) tuple(%ragged-dot-none.1)
+}}
+
+ENTRY %main.9 (x.1: (bf16[8,4], s32[2], bf16[2,4,4])) -> (bf16[8,4]) {{
+  %x.1 = (bf16[8,4]{{1,0}}, s32[2]{{0}}, bf16[2,4,4]{{2,1,0}}) parameter(0)
+  %fits.1 = s32[] fusion(%x.1), kind=kLoop, calls=%f.3, metadata={{op_name="{pred}"}}
+  ROOT %conditional.1 = (bf16[8,4]{{1,0}}) conditional(%fits.1, %x.1, %x.1), branch_computations={{%branch.0, %branch.1}}
+}}
+"""
+    got = anatomy.table(text)
+    want = {"rows.1": "moe_routing", "live.1": "moe_experts",
+            "ragged-dot-none.1": "moe_experts", "copy-done.1": "moe_experts",
+            "copy-start.1": "moe_experts", "gte.3": "moe_experts"}
+    assert {k: anatomy.part_of(got[k]) for k in want} == \
+        {k: (phase, part) for k, part in want.items()}
+    assert got["ragged-dot-none.1"] == select + "/moe_experts"
+    assert got["conditional.1"] == anatomy.SPANS_ITS_BRANCH
+    durations = {"%conditional.1 = (bf16[8,4]) conditional(%fits.1)": 3.5,
+                 "%rows.1 = bf16[8,4] fusion(%gte.1)": 1.0,
+                 "%ragged-dot-none.1 = bf16[8,4] custom-call(%gte.2)": 2.0,
+                 "%fits.1 = s32[] fusion(%x.1)": 0.5}
+    assert anatomy.by_part(durations, got) == {
+        (phase, "moe_routing"): 1.5, (phase, "moe_experts"): 2.0}

@@ -16,7 +16,9 @@ only one process may load libtpu and every xdist worker imports this file.
 """
 
 import functools
+import re
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import pytest
@@ -38,11 +40,11 @@ def one_chip():
 
 
 @pytest.fixture(scope="module")
-def compile_for(one_chip):
-    """``compile_for(fn, *shapes)`` -> compiled HLO text of ``fn`` lowered
-    for the described chip. The persistent compile cache is off around it:
-    an entry written for a described device cannot be read back without
-    one, and every later compile would warn about it."""
+def compiled_for(one_chip):
+    """``compiled_for(fn, *shapes)`` -> ``fn`` lowered and compiled for the
+    described chip. The persistent compile cache is off around it: an entry
+    written for a described device cannot be read back without one, and
+    every later compile would warn about it."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     was = jax.config.jax_enable_compilation_cache
@@ -52,11 +54,17 @@ def compile_for(one_chip):
     def run(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in shapes]
-        return jax.jit(fn).lower(*args).compile().as_text()
+        return jax.jit(fn).lower(*args).compile()
 
     yield run
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def compile_for(compiled_for):
+    """``compile_for(fn, *shapes)`` -> the compiled HLO text."""
+    return lambda fn, *shapes: compiled_for(fn, *shapes).as_text()
 
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -124,6 +132,47 @@ def test_flash_attention_grouped_window_compiles_for_v5e(compile_for, window,
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert f"%{name}" in text
+
+
+def test_routed_experts_two_bodies_compile_for_v5e(compiled_for):
+    """One expert layer of the benchmark's trinity cell (8192 tokens of 2048,
+    16 of 128 experts of width 1024 held, 8 a token), forward, recomputed and
+    backward. The row buffer is 16 384 rows with the worst case's 65 536 as
+    the other branch: one ``cond`` forward and one backward (the one in the
+    recomputed forward is dead here: nothing reads its result), each branch
+    with the 12 grouped products the single body has. Differentiated plainly
+    the ``cond`` compiles too, and the small branch then writes the worst
+    case's residuals as zeros on every step: 3 conditionals, and temporaries
+    of 3.88 GB where the single body (``EXPECTED_ROWS_FACTOR`` 8, compiled
+    the same way) has 1.16 GB and the two bodies 1.43 GB (a ``conditional``'s
+    operands and results are buffers of their own), which is what the last
+    line is for."""
+    from distributeddeeplearning_tpu.models import moe
+
+    layer = moe.RoutedExperts(
+        hidden_size=2048, expert_width=1024, num_experts=128,
+        experts_per_token=8, experts_held=(0, 16), route_scale=2.826,
+        bias_update_rate=0.001)
+    x = ((1, 8192, 2048), BF16)
+    variables = jax.eval_shape(
+        lambda: nn.unbox(layer.init(jax.random.key(0), jnp.zeros(*x),
+                                    train=False)))
+    leaves, tree = jax.tree_util.tree_flatten(variables)
+
+    def loss(x, *leaves):
+        apply = jax.checkpoint(
+            lambda v, x: layer.apply(v, x, train=False).astype(F32).sum())
+        return apply(jax.tree_util.tree_unflatten(tree, leaves), x)
+
+    two = compiled_for(
+        jax.value_and_grad(loss, argnums=tuple(range(1 + len(leaves)))),
+        x, *[(leaf.shape, leaf.dtype) for leaf in leaves])
+    text = two.as_text()
+    assert text.count(" conditional(") == 2
+    assert len(re.findall(r"= \S+ custom-call\(.*ragged-dot-none", text)) == 24
+    temporaries = two.memory_analysis().temp_size_in_bytes
+    print("temporaries:", temporaries)
+    assert temporaries < 1.3 * 1.16e9
 
 
 def test_fused_batchnorm_compiles_for_v5e(compile_for):

@@ -14,7 +14,7 @@ cannot quietly regress it:
   loses the one event that explains the death.
 - ``unpaired-telemetry-span``: ``telemetry.span(...)`` returns a context
   manager; a call whose result is discarded times nothing and silently
-  drops the phase from every trace and perf-gate phase-mix check.
+  drops the phase from every trace.
 - ``perf-record-provenance``: every serialized perf record (a dict with
   a ``"metric"`` key) carries a ``perf_report.annotate`` provenance stamp
   — PR 6's rule that perf claims are dated, attributed, and
@@ -190,8 +190,7 @@ def check_unpaired_spans(tree: ast.Module, path: str) -> list[dict]:
             "lints", "unpaired-telemetry-span",
             "span(...) result discarded — it is a context manager; a "
             "span never entered times nothing and the phase vanishes "
-            "from traces and the perf gate's phase mix "
-            "(use `with tele.span(...):`)",
+            "from traces (use `with tele.span(...):`)",
             file=path, line=node.lineno))
     return findings
 

@@ -158,8 +158,8 @@ def _registry() -> dict[str, ModelSpec]:
             input_kind="tokens", param_count=0),
         # 4-layer variant: layers_per_stage=2 admits interleaved 1f1b with
         # pipeline_virtual_stages=2 — the schedule A/B geometry used by
-        # tests/test_pipeline.py, bench.py and the pipeline_1f1b perf-gate
-        # workload.
+        # tests/test_pipeline.py, bench.py and the pipeline_1f1b workload of
+        # tests/test_step_invariants.py.
         "bert_tiny_pp4": ModelSpec(
             name="bert_tiny_pp4", objective="mlm",
             build=lambda **kw: bert.tiny_bert_mlm(

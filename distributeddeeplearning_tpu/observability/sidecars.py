@@ -21,7 +21,6 @@ point is a shared shape, not a gatekeeper):
     last_run_sharding      train/loop.py — sharding/overlap of the last run
     last_elastic_event     train/loop.py — last elastic re-formation
     last_bench             bench.py — per-metric last good measurements
-    perf_gate_last         observability/perf_gate.py — last gate result
     last_ddl_lint          tools/ddl_lint.py — last analyzer run + schedule
                            fingerprints
     schedule_fingerprints  analysis/collectives.py — config-fp -> schedule-fp
@@ -41,8 +40,7 @@ from typing import Any, Optional
 SCHEMA_VERSION = 1
 
 KNOWN = ("last_run_sharding", "last_elastic_event", "last_bench",
-         "perf_gate_last", "last_ddl_lint", "schedule_fingerprints",
-         "last_serve")
+         "last_ddl_lint", "schedule_fingerprints", "last_serve")
 
 
 def cache_dir() -> str:

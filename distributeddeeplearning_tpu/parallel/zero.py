@@ -285,6 +285,26 @@ def _scatter_members(fulls, layout: Zero1Layout, axis_names: AxisNames,
     return tuple(out)
 
 
+def _gather_wire_dtypes(layout: Zero1Layout, members, out_dtype) -> list:
+    """What each member of a bucket travels as, and comes back as.
+
+    ``out_dtype`` None: every member in the bucket's common (master) dtype,
+    restored to its own afterwards. ``out_dtype`` set (zero3 under a bf16
+    policy): a leaf of rank >= 2 (a dense or conv kernel, an embedding
+    table) travels in ``out_dtype``, which is the cast its layer makes
+    anyway, so the layer reads the same bits from half the bytes; a leaf of
+    rank < 2 (a norm scale, a bias) travels as its master, because the norm
+    layers consume those in float32 and a bf16 wire would round what no
+    other stage rounds: zero3 alone would leave the ladder's trajectory
+    (``tests/test_mixed_precision.py::test_mixed_zero_ladder_parity_band``
+    holds every stage to the same bits)."""
+    if out_dtype is None:
+        common = jnp.result_type(*(layout.plan.dtypes[i] for i in members))
+        return [common] * len(members)
+    return [jnp.dtype(out_dtype) if len(layout.plan.shapes[i]) >= 2
+            else jnp.dtype(layout.plan.dtypes[i]) for i in members]
+
+
 def _gather_members(chunks, layout: Zero1Layout, axis_names: AxisNames,
                     b: int, scope_prefix: str = "zero1",
                     out_dtype=None) -> tuple:
@@ -294,10 +314,12 @@ def _gather_members(chunks, layout: Zero1Layout, axis_names: AxisNames,
     chunks; slicing a member's column block and raveling row-major
     restores its padded flat leaf in natural order.
 
-    ``out_dtype`` (mixed precision, zero3): cast each chunk to the compute
-    dtype BEFORE the collective — halving the wire bytes when the masters
-    are fp32 and compute is bf16 — and leave the gathered full leaves in
-    that dtype instead of restoring the plan (master) dtypes."""
+    ``out_dtype`` (mixed precision, zero3): each chunk is cast to its wire
+    dtype (:func:`_gather_wire_dtypes`) BEFORE the collective and the
+    gathered full leaf stays in it. Where a bucket's members travel in
+    dtypes of two widths, the payload is their bits side by side (as
+    unsigned integers of the narrower width), so that it is still one
+    collective a bucket and every member moves exactly its own bytes."""
     members = layout.plan.buckets[b]
     n = layout.axis_size
     tele = telemetry.get()
@@ -306,26 +328,34 @@ def _gather_members(chunks, layout: Zero1Layout, axis_names: AxisNames,
                         bucket=b, leaves=len(members))
     with tele.span(f"collective:{scope}", cat="trace",
                    leaves=len(members)), jax.named_scope(scope):
-        if out_dtype is not None:
-            common = jnp.dtype(out_dtype)
-        else:
-            common = jnp.result_type(
-                *(layout.plan.dtypes[i] for i in members))
-        parts = [chunks[j].astype(common) for j in range(len(members))]
+        wire = _gather_wire_dtypes(layout, members, out_dtype)
+        carrier = wire[0]
+        if len(set(wire)) > 1:
+            carrier = jnp.dtype(
+                f"uint{8 * min(w.itemsize for w in wire)}")
+        parts = []
+        for j, w in enumerate(wire):
+            part = chunks[j].astype(w)
+            if w != carrier:  # (c,) -> (c, k) narrower words -> (c * k,)
+                part = jax.lax.bitcast_convert_type(part, carrier).ravel()
+            parts.append(part)
         row = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         full = jax.lax.all_gather(row, axis_names, tiled=True)
         mat = full.reshape(n, -1)
         out = []
         off = 0
-        for i in members:
+        for i, w in zip(members, wire):
             c = layout.chunk_sizes[i]
             shape = layout.plan.shapes[i]
-            piece = jax.lax.slice_in_dim(mat, off, off + c, axis=1)
-            leaf_dtype = (out_dtype if out_dtype is not None
-                          else layout.plan.dtypes[i])
+            k = w.itemsize // carrier.itemsize  # carrier words an element
+            piece = jax.lax.slice_in_dim(mat, off, off + c * k, axis=1)
+            if w != carrier:
+                piece = jax.lax.bitcast_convert_type(
+                    piece.reshape((n, c, k) if k > 1 else (n, c)), w)
+            leaf_dtype = w if out_dtype is not None else layout.plan.dtypes[i]
             out.append(piece.reshape(n * c)[:_numel(shape)]
                        .reshape(shape).astype(leaf_dtype))
-            off += c
+            off += c * k
     return tuple(out)
 
 
@@ -356,8 +386,8 @@ def all_gather_chunks(chunks, layout: Zero1Layout, axis_names: AxisNames,
 
     One ``all_gather`` per fusion bucket (see :func:`_gather_members`) —
     the second half of the ring all-reduce, moved AFTER the optimizer
-    update. ``out_dtype`` casts before the wire and skips the restore to
-    master dtypes (mixed-precision zero3 forward gathers).
+    update. ``out_dtype`` casts the matrices before the wire and leaves
+    them in it (mixed-precision zero3 forward gathers).
     """
     leaves, treedef = jax.tree_util.tree_flatten(chunks)
     _check_leaves(layout, len(leaves))
@@ -383,11 +413,11 @@ def all_gather_chunks(chunks, layout: Zero1Layout, axis_names: AxisNames,
 def _gather_vjp(layout: Zero1Layout, axis_names, b: int, payload_dtype,
                 scope_prefix: str, out_dtype=None):
     """ZeRO-3 bucket primitive: fwd all-gathers this shard's chunks into
-    full leaves (in ``out_dtype`` when set — bf16 compute params from fp32
-    masters, cast before the wire); bwd reduce-scatters the full-shaped
-    cotangents back to chunk cotangents in the plan (master) dtypes (the
-    exact transpose of a tiled all-gather whose output feeds every shard's
-    loss term)."""
+    full leaves (the matrices in ``out_dtype`` when set — bf16 compute
+    params from fp32 masters, cast before the wire); bwd reduce-scatters
+    the full-shaped cotangents back to chunk cotangents in the plan
+    (master) dtypes (the exact transpose of a tiled all-gather whose output
+    feeds every shard's loss term)."""
 
     def _primal(*chunks):
         return _gather_members(chunks, layout, axis_names, b, scope_prefix,

@@ -427,9 +427,10 @@ def make_dp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     # every derived value below collapses to the legacy behavior —
     # ar_options IS config.allreduce, no loss scaling, fp32 gathers — so
     # policy-free configs compile the exact seed program (and keep the
-    # zero1<->replicated bitwise pin). An explicit policy re-points the
+    # zero1<->replicated parity pin). An explicit policy re-points the
     # reduction payload at policy.reduce_dtype and, for bf16 compute,
-    # gathers zero3 params on the wire in bf16 while the persistent chunks
+    # gathers zero3's matrices on the wire in bf16 (norm scales and biases
+    # as they are: zero._gather_wire_dtypes) while the persistent chunks
     # (the masters the optimizer updates) stay fp32.
     policy = resolve_precision(config)
     scaling = config.precision is not None and policy.loss_scale > 0

@@ -16,7 +16,7 @@ from distributeddeeplearning_tpu.config import (
     DataConfig, OptimizerConfig, ParallelConfig, PrecisionPolicy, TrainConfig)
 from distributeddeeplearning_tpu.models import model_spec
 from distributeddeeplearning_tpu.parallel.mesh import use_mesh
-from distributeddeeplearning_tpu.perf import aot, compile_cache
+from distributeddeeplearning_tpu.perf import aot
 from distributeddeeplearning_tpu.train import loop
 
 LAYERS = 2  # gpt_tiny
@@ -269,9 +269,7 @@ def test_step_scopes_are_the_rules_update_scopes():
 @pytest.fixture
 def cache_here(tmp_path, monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
-    yield str(tmp_path / "cache")
-    monkeypatch.undo()
-    compile_cache.activate()
+    return str(tmp_path / "cache")
 
 
 def test_a_live_step_and_the_saved_file_give_the_same_table(cache_here):

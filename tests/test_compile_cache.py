@@ -50,13 +50,6 @@ def _cfg(**kw):
 # Cache-dir policy
 # ---------------------------------------------------------------------------
 
-@pytest.fixture
-def restore_jax_cache():
-    """activate() re-points the process-global jax cache; put it back."""
-    yield
-    compile_cache.activate()
-
-
 @pytest.mark.core
 def test_cache_dir_is_placed_from_outside(monkeypatch, tmp_path):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
@@ -71,8 +64,7 @@ def test_cache_dir_is_placed_from_outside(monkeypatch, tmp_path):
 
 @pytest.mark.core
 @pytest.mark.parametrize("placed", [True, False])
-def test_activate_never_touches_the_variable(monkeypatch, tmp_path, placed,
-                                             restore_jax_cache):
+def test_activate_never_touches_the_variable(monkeypatch, tmp_path, placed):
     """Set => jax's cache and aot/ land there and the variable is left as
     it was; unset => the repo default, and the variable stays unset."""
     want = str(tmp_path / "placed")
@@ -93,8 +85,7 @@ def test_activate_never_touches_the_variable(monkeypatch, tmp_path, placed,
 
 
 @pytest.mark.core
-def test_activate_off_disables_both_layers(monkeypatch, tmp_path,
-                                           restore_jax_cache):
+def test_activate_off_disables_both_layers(monkeypatch, tmp_path):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert compile_cache.activate(False) is None
     assert not jax.config.jax_enable_compilation_cache
@@ -282,7 +273,7 @@ def test_entry_saved_by_another_tree_is_not_loaded(tmp_path, monkeypatch):
 
 
 def test_an_entrys_compile_never_gets_another_trees_names(
-        tmp_path, monkeypatch, restore_jax_cache):
+        tmp_path, monkeypatch):
     """Two programs that differ only in a scope name are one program to
     JAX's persistent cache (metadata is not in its key), so the second
     would come back with the first's names in it. `aot.compile_lowered`
@@ -388,33 +379,27 @@ def test_loop_warm_start_summary_and_zero_retrace(tmp_path, monkeypatch):
     monkeypatch.delenv(faults.ENV_PLAN, raising=False)
     monkeypatch.delenv(faults.ENV_ATTEMPT, raising=False)
     cfg = _cfg(log_every=1)
-    try:
-        stream = io.StringIO()
-        s1 = loop.run(cfg, total_steps=2, eval_batches=1,
-                      logger=MetricLogger(stream=stream, enabled=True))
-        assert s1["compile_time_s"] > 0
-        assert s1["time_to_first_step_s"] >= s1["compile_time_s"]
-        cc = s1["compile_cache"]
-        assert cc["sources"]["dp_train_step"] == "compiled"
-        assert cc["aot_saves"] >= 1
-        first = json.loads(stream.getvalue().splitlines()[0])
-        assert first["compile_time_s"] > 0
-        assert first["time_to_first_step_s"] > 0
+    stream = io.StringIO()
+    s1 = loop.run(cfg, total_steps=2, eval_batches=1,
+                  logger=MetricLogger(stream=stream, enabled=True))
+    assert s1["compile_time_s"] > 0
+    assert s1["time_to_first_step_s"] >= s1["compile_time_s"]
+    cc = s1["compile_cache"]
+    assert cc["sources"]["dp_train_step"] == "compiled"
+    assert cc["aot_saves"] >= 1
+    first = json.loads(stream.getvalue().splitlines()[0])
+    assert first["compile_time_s"] > 0
+    assert first["time_to_first_step_s"] > 0
 
-        before = steps.TRACE_COUNTS["dp_train_step"]
-        s2 = loop.run(cfg, total_steps=2, eval_batches=1,
-                      logger=MetricLogger(enabled=False))
-        assert steps.TRACE_COUNTS["dp_train_step"] == before  # ZERO retraces
-        assert s2["compile_cache"]["sources"]["dp_train_step"] == "aot_hit"
-        assert s2["compile_cache"]["aot_hits"] >= 1
-        assert s2["compile_time_s"] < s1["compile_time_s"]
-        # both runs trained the same program: identical final loss
-        assert s1["final_metrics"]["loss"] == s2["final_metrics"]["loss"]
-    finally:
-        # loop.run pointed the process-global jax persistent cache at the
-        # tmp dir; re-point it at the repo default for the rest of the suite.
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-        compile_cache.activate()
+    before = steps.TRACE_COUNTS["dp_train_step"]
+    s2 = loop.run(cfg, total_steps=2, eval_batches=1,
+                  logger=MetricLogger(enabled=False))
+    assert steps.TRACE_COUNTS["dp_train_step"] == before  # ZERO retraces
+    assert s2["compile_cache"]["sources"]["dp_train_step"] == "aot_hit"
+    assert s2["compile_cache"]["aot_hits"] >= 1
+    assert s2["compile_time_s"] < s1["compile_time_s"]
+    # both runs trained the same program: identical final loss
+    assert s1["final_metrics"]["loss"] == s2["final_metrics"]["loss"]
 
 
 @pytest.mark.usefixtures("devices8")
@@ -437,36 +422,32 @@ def test_warm_resume_with_checkpointing_is_donation_safe(tmp_path, monkeypatch):
     monkeypatch.delenv(faults.ENV_PLAN, raising=False)
     monkeypatch.delenv(faults.ENV_ATTEMPT, raising=False)
     kw = dict(checkpoint_every_steps=1)
-    try:
-        # Uninterrupted reference: cold-compiles and populates the cache.
-        ref = loop.run(_cfg(checkpoint_dir=str(tmp_path / "ck_ref"), **kw),
-                       total_steps=4, eval_batches=0,
-                       logger=MetricLogger(enabled=False))
-        assert ref["compile_cache"]["sources"]["dp_train_step"] == "compiled"
+    # Uninterrupted reference: cold-compiles and populates the cache.
+    ref = loop.run(_cfg(checkpoint_dir=str(tmp_path / "ck_ref"), **kw),
+                   total_steps=4, eval_batches=0,
+                   logger=MetricLogger(enabled=False))
+    assert ref["compile_cache"]["sources"]["dp_train_step"] == "compiled"
 
-        # Attempt 0: warm, saves at 1 and 2, then the injected crash.
-        faulted = _cfg(checkpoint_dir=str(tmp_path / "ck"),
-                       fault_plan="crash@2", **kw)
-        with pytest.raises(SystemExit):
-            loop.run(faulted, total_steps=4, eval_batches=0,
-                     logger=MetricLogger(enabled=False))
+    # Attempt 0: warm, saves at 1 and 2, then the injected crash.
+    faulted = _cfg(checkpoint_dir=str(tmp_path / "ck"),
+                   fault_plan="crash@2", **kw)
+    with pytest.raises(SystemExit):
+        loop.run(faulted, total_steps=4, eval_batches=0,
+                 logger=MetricLogger(enabled=False))
 
-        # The restart: crash@2 is attempt-0-scoped, so the fingerprint
-        # matches the clean one and the serialized executable is reused on
-        # the restored state — restore, AOT-hit donating dispatches, and
-        # async saves all interleaved.
-        monkeypatch.setenv(faults.ENV_ATTEMPT, "1")
-        before = steps.TRACE_COUNTS["dp_train_step"]
-        s = loop.run(faulted, total_steps=4, eval_batches=0,
-                     logger=MetricLogger(enabled=False))
-        assert steps.TRACE_COUNTS["dp_train_step"] == before
-        assert s["compile_cache"]["sources"]["dp_train_step"] == "aot_hit"
-        assert s["start_step"] == 2 and s["final_step"] == 4
-        # Recovery is bitwise: kill + restore + warm executable fully erased.
-        assert s["final_metrics"]["loss"] == ref["final_metrics"]["loss"]
-    finally:
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-        compile_cache.activate()
+    # The restart: crash@2 is attempt-0-scoped, so the fingerprint
+    # matches the clean one and the serialized executable is reused on
+    # the restored state — restore, AOT-hit donating dispatches, and
+    # async saves all interleaved.
+    monkeypatch.setenv(faults.ENV_ATTEMPT, "1")
+    before = steps.TRACE_COUNTS["dp_train_step"]
+    s = loop.run(faulted, total_steps=4, eval_batches=0,
+                 logger=MetricLogger(enabled=False))
+    assert steps.TRACE_COUNTS["dp_train_step"] == before
+    assert s["compile_cache"]["sources"]["dp_train_step"] == "aot_hit"
+    assert s["start_step"] == 2 and s["final_step"] == 4
+    # Recovery is bitwise: kill + restore + warm executable fully erased.
+    assert s["final_metrics"]["loss"] == ref["final_metrics"]["loss"]
 
 
 # ---------------------------------------------------------------------------

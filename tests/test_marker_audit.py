@@ -57,82 +57,6 @@ def test_cli_exit_codes(tmp_path):
     assert subprocess.run(cmd + [str(bad), "600"]).returncode == 0
 
 
-# --- perf_gate presence audit (ISSUE 6 satellite) ---------------------------
-
-from tools.marker_audit import audit_perf_gate  # noqa: E402
-
-
-def test_audit_perf_gate_clean_run():
-    records = [_rec("t::fast", 1.0),
-               {**_rec("t::gate", 5.0), "perf_gate": True},
-               {**_rec("t::gate_zero2_overlap", 5.0), "perf_gate": True}]
-    assert audit_perf_gate(records) == []
-
-
-def test_audit_perf_gate_flags_missing_gate():
-    problems = audit_perf_gate([_rec("t::fast", 1.0)])
-    assert len(problems) == 1
-    assert problems[0].startswith("no perf_gate")
-
-
-def test_audit_perf_gate_flags_missing_zero2_workload():
-    """Both gate workloads must run: the headline proxy alone no longer
-    counts as full coverage once the sharded-schedule gate exists."""
-    problems = audit_perf_gate([{**_rec("t::gate", 5.0), "perf_gate": True}])
-    assert len(problems) == 1
-    assert "zero2_overlap" in problems[0]
-
-
-def test_audit_perf_gate_flags_slow_double_marking():
-    """perf_gate + slow together silently removes the gate from tier-1
-    (-m 'not slow') — the one static mistake that disarms it while every
-    individual run still looks green."""
-    records = [{**_rec("t::gate_zero2_overlap", 5.0, slow=True),
-                "perf_gate": True}]
-    problems = audit_perf_gate(records)
-    assert len(problems) == 1
-    assert "BOTH perf_gate and slow" in problems[0]
-    assert "t::gate_zero2_overlap" in problems[0]
-
-
-def test_cli_expect_perf_gate_flag(tmp_path):
-    no_gate = tmp_path / "no_gate.json"
-    no_gate.write_text(json.dumps([_rec("t::fast", 1.0)]))
-    cmd = [sys.executable, "tools/marker_audit.py"]
-    # Partial runs legitimately lack the gate: quiet by default...
-    assert subprocess.run(cmd + [str(no_gate)]).returncode == 0
-    # ...but the tier-1 chain opts in and must then fail loudly.
-    proc = subprocess.run(cmd + [str(no_gate), "--expect-perf-gate"],
-                          capture_output=True, text=True)
-    assert proc.returncode == 1
-    assert "no perf_gate-marked test ran" in proc.stdout
-    # With only the headline gate present: quiet by default, but the
-    # opt-in run fails — the zero2_overlap workload is part of coverage.
-    headline_only = tmp_path / "headline_only.json"
-    headline_only.write_text(json.dumps(
-        [{**_rec("t::gate", 5.0), "perf_gate": True}]))
-    assert subprocess.run(cmd + [str(headline_only)]).returncode == 0
-    proc = subprocess.run(cmd + [str(headline_only), "--expect-perf-gate"],
-                          capture_output=True, text=True)
-    assert proc.returncode == 1
-    assert "zero2_overlap" in proc.stdout
-    # With both gate workloads present the opt-in run is clean.
-    with_gate = tmp_path / "gate.json"
-    with_gate.write_text(json.dumps(
-        [{**_rec("t::gate", 5.0), "perf_gate": True},
-         {**_rec("t::gate_zero2_overlap", 5.0), "perf_gate": True}]))
-    assert subprocess.run(
-        cmd + [str(with_gate), "--expect-perf-gate"]).returncode == 0
-    # slow+perf_gate double-marking fails even WITHOUT the opt-in.
-    double = tmp_path / "double.json"
-    double.write_text(json.dumps(
-        [{**_rec("t::gate", 5.0, slow=True), "perf_gate": True}]))
-    proc = subprocess.run(cmd + [str(double)], capture_output=True,
-                          text=True)
-    assert proc.returncode == 1
-    assert "BOTH perf_gate and slow" in proc.stdout
-
-
 # --- elastic coverage audit (ISSUE 9 satellite) -----------------------------
 
 from tools.marker_audit import audit_elastic  # noqa: E402
@@ -201,17 +125,14 @@ def test_cli_expect_elastic_flag(tmp_path):
                           capture_output=True, text=True)
     assert proc.returncode == 1
     assert "no elastic-marked test ran" in proc.stdout
-    # Both flags compose on one invocation.
+    # With the coverage present the opt-in run is clean.
     full = tmp_path / "full.json"
     full.write_text(json.dumps(
-        [{**_rec("t::gate", 5.0), "perf_gate": True},
-         {**_rec("t::gate_zero2_overlap", 5.0), "perf_gate": True},
-         {**_rec("t::fast_cross_degree", 20.0), "elastic": True},
+        [{**_rec("t::fast_cross_degree", 20.0), "elastic": True},
          {**_rec("t::test_survivor_selection_grid", 1.0),
           "elastic": True}]))
     assert subprocess.run(
-        cmd + [str(full), "--expect-perf-gate", "--expect-elastic"],
-    ).returncode == 0
+        cmd + [str(full), "--expect-elastic"]).returncode == 0
 
 
 # --- large-batch recipe audit (ISSUE 20 satellite) --------------------------
@@ -221,8 +142,6 @@ from tools.marker_audit import audit_largebatch  # noqa: E402
 
 def test_audit_largebatch_clean_run():
     records = [
-        {**_rec("t::test_perf_gate_live_largebatch_bf16", 5.0),
-         "perf_gate": True},
         _rec("t::test_loss_scale_overflow_skips_and_halves", 3.0),
         _rec("t::test_ramp_boundary_resume_bitwise", 8.0),
     ]
@@ -231,23 +150,9 @@ def test_audit_largebatch_clean_run():
 
 def test_audit_largebatch_flags_all_missing():
     problems = audit_largebatch([_rec("t::fast", 1.0)])
-    assert len(problems) == 3
-    assert any("largebatch_bf16" in p for p in problems)
+    assert len(problems) == 2
     assert any("loss-scale" in p for p in problems)
     assert any("batch-ramp" in p for p in problems)
-
-
-def test_audit_largebatch_gate_must_be_perf_gate_marked():
-    """A largebatch-named test WITHOUT the perf_gate marker does not count
-    as the gate — the workload check keys on the marker, not the name."""
-    records = [
-        _rec("t::test_largebatch_helper", 1.0),
-        _rec("t::test_loss_scale_x", 1.0),
-        _rec("t::test_ramp_y", 1.0),
-    ]
-    problems = audit_largebatch(records)
-    assert len(problems) == 1
-    assert "largebatch_bf16" in problems[0]
 
 
 def test_cli_expect_largebatch_flag(tmp_path):
@@ -260,12 +165,10 @@ def test_cli_expect_largebatch_flag(tmp_path):
     proc = subprocess.run(cmd + [str(partial), "--expect-largebatch"],
                           capture_output=True, text=True)
     assert proc.returncode == 1
-    assert "largebatch_bf16" in proc.stdout
+    assert "no loss-scale test ran" in proc.stdout
     full = tmp_path / "full.json"
     full.write_text(json.dumps(
-        [{**_rec("t::test_perf_gate_live_largebatch_bf16", 5.0),
-          "perf_gate": True},
-         _rec("t::test_loss_scale_overflow", 2.0),
+        [_rec("t::test_loss_scale_overflow", 2.0),
          _rec("t::test_ramp_boundary_resume", 2.0)]))
     assert subprocess.run(
         cmd + [str(full), "--expect-largebatch"]).returncode == 0

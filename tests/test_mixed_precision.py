@@ -5,11 +5,13 @@ resume identity, and the mixed-vs-fp32 parity band across the ZeRO ladder.
 Parity tolerances: bf16 compute quantizes every activation/gradient to 8
 mantissa bits, so mixed-vs-fp32 trajectories diverge from step 1 — the
 band is deliberately LOOSE (same loss neighborhood, still learning), not
-tight. Mixed-vs-mixed across sharding stages is the tight comparison: the
-fp32 masters make the update math identical, and only the bf16 wire
-reduction order differs (reduce-scatter chunks vs fused all-reduce), so
-sharded and replicated mixed runs must land within a narrow band of each
-other.
+tight. Mixed-vs-mixed across sharding stages is the exact comparison: at
+every stage a layer reads the bf16 of the same fp32 master matrix (zero3's
+gather makes that cast before the wire, the layer makes it elsewhere) and
+the same fp32 norm scales and biases, the bf16 payloads are summed in the
+same order by ``psum`` and by ``psum_scatter``, and the masters are updated
+by the same arithmetic, so sharded and replicated mixed runs land on the
+same bits.
 """
 
 import jax
@@ -249,21 +251,25 @@ def test_ramp_summary_stamps_input_pipeline(devices8):
 
 @pytest.mark.parametrize("sharding", ["zero2", "zero3"])
 def test_mixed_zero_ladder_parity_band(devices8, sharding):
-    """Mixed-vs-mixed across the ZeRO ladder is the TIGHT comparison
-    (identical fp32 master update math; only the bf16 wire reduction
-    order differs), and mixed-vs-fp32 the LOOSE one (bf16 quantization
+    """Mixed-vs-mixed across the ZeRO ladder is the EXACT comparison
+    (module docstring), and mixed-vs-fp32 the LOOSE one (bf16 quantization
     compounds per step but must stay in the same loss neighborhood)."""
     steps = 3
     mixed = dict(precision=PrecisionPolicy.mixed(), dtype="bfloat16")
     s_rep, m_rep, _ = _run(_cfg(**mixed), steps)
     s_shd, m_shd, step_shd = _run(
         _cfg(**mixed, optimizer_sharding=sharding), steps)
-    # Params: the bf16 wire-order seed (~1 ulp) amplifies chaotically
-    # through BN like the LAMB case in tests/test_zero1.py — bounded, not
-    # tight (measured ~5e-2 after 3 steps); the LOSS stays tight.
+    # Until PR 29 this was a band (0.2 on the params, 0.05 on the loss) and
+    # zero3 read 0.062 and 0.0557 under it: its gathers cast every chunk to
+    # bf16 before the wire, norm scales and biases included, which the
+    # layers consume in float32 at every other stage. With those gathered
+    # as they are (and the matrices still in bf16, which is the cast their
+    # layers make anyway), every stage reads 0.0. Should a compiler ever
+    # round the update here as it does in float32 (tests/zero_parity.py),
+    # hold it to that allowance; a band hides the next such fault.
     assert _max_abs_diff(jax.device_get(s_rep.params),
-                         _full_params(s_shd, step_shd)) < 2e-1
-    assert abs(float(m_rep["loss"]) - float(m_shd["loss"])) < 5e-2
+                         _full_params(s_shd, step_shd)) == 0.0
+    assert float(m_rep["loss"]) == float(m_shd["loss"])
     # fp32 reference: same data, same seed, full-precision compute.
     _, m_fp32, _ = _run(_cfg(precision=PrecisionPolicy.fp32()), steps)
     for m in (m_rep, m_shd):

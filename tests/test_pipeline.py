@@ -444,7 +444,6 @@ def test_aot_warm_boot_zero_retrace(tmp_path, monkeypatch, schedule, v):
     gspmd step executable — ZERO retraces of the tick loop — and, because
     the pipeline_tick instants fire only at trace time, the warm summary
     honestly reports bubble_fraction as absent rather than 0."""
-    from distributeddeeplearning_tpu.perf import compile_cache
     from distributeddeeplearning_tpu.robustness import faults
     from distributeddeeplearning_tpu.train import loop
 
@@ -453,21 +452,17 @@ def test_aot_warm_boot_zero_retrace(tmp_path, monkeypatch, schedule, v):
     monkeypatch.delenv(faults.ENV_PLAN, raising=False)
     monkeypatch.delenv(faults.ENV_ATTEMPT, raising=False)
     cfg = _loop_cfg(tmp_path, schedule, v)
-    try:
-        s1 = loop.run(cfg, total_steps=2)
-        assert s1["compile_cache"]["sources"]["gspmd_train_step"] == \
-            "compiled"
-        before = steps.TRACE_COUNTS["gspmd_train_step"]
-        s2 = loop.run(cfg, total_steps=2)
-        assert steps.TRACE_COUNTS["gspmd_train_step"] == before  # ZERO
-        assert s2["compile_cache"]["sources"]["gspmd_train_step"] == \
-            "aot_hit"
-        assert s1["final_metrics"]["loss"] == s2["final_metrics"]["loss"]
-        assert s2["pipeline"]["schedule"] == schedule
-        assert s2["pipeline"]["bubble_fraction"] is None  # no trace, no lie
-    finally:
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-        compile_cache.activate()
+    s1 = loop.run(cfg, total_steps=2)
+    assert s1["compile_cache"]["sources"]["gspmd_train_step"] == \
+        "compiled"
+    before = steps.TRACE_COUNTS["gspmd_train_step"]
+    s2 = loop.run(cfg, total_steps=2)
+    assert steps.TRACE_COUNTS["gspmd_train_step"] == before  # ZERO
+    assert s2["compile_cache"]["sources"]["gspmd_train_step"] == \
+        "aot_hit"
+    assert s1["final_metrics"]["loss"] == s2["final_metrics"]["loss"]
+    assert s2["pipeline"]["schedule"] == schedule
+    assert s2["pipeline"]["bubble_fraction"] is None  # no trace, no lie
 
 
 @pytest.mark.pipeline

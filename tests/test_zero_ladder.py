@@ -5,12 +5,13 @@ Parity contract, same grounds as tests/test_zero1.py: ZeRO-2 is BITWISE
 against zero1 for elementwise optimizers — its backward scatter runs the
 IDENTICAL per-bucket ops as zero1's post-backward scatter, only earlier in
 the schedule, and the update math never changes. ZeRO-3 is BITWISE against
-the replicated path for SGD/AdamW on the CPU mesh (same psum chunk values,
-same per-element update); LAMB is bounded-not-tight for the same
-norm-summation-order reason test_zero1.py documents. The bitwise pins hold
-at accum=1 (the configs here); gradient accumulation under the overlapped
-schedule sums per-microbatch scatters in a different fp order (see
-steps.accumulated_grads).
+zero1 likewise (same chunk values, the same update on them); against the
+replicated path every sharded stage is held to what tests/zero_parity.py
+states, bit for bit where the update has no multiply feeding an add. LAMB
+is bounded-not-tight for the norm-summation-order reason test_zero1.py
+documents. The bitwise pins hold at accum=1 (the configs here); gradient
+accumulation under the overlapped schedule sums per-microbatch scatters in
+a different fp order (see steps.accumulated_grads).
 
 Memory ladder (with AdamW, N=8): replicated ~4P resident per device ->
 zero1 2.25P -> zero2 1.375P -> zero3 0.5P — asserted monotonically on the
@@ -22,17 +23,16 @@ import json
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from distributeddeeplearning_tpu import data as datalib
-from distributeddeeplearning_tpu.config import (
-    DataConfig, OptimizerConfig, ParallelConfig, TrainConfig)
-from distributeddeeplearning_tpu.models import model_spec
+from distributeddeeplearning_tpu.config import ParallelConfig
 from distributeddeeplearning_tpu.observability import telemetry
 from distributeddeeplearning_tpu.parallel import zero
 from distributeddeeplearning_tpu.train import checkpoint as ckptlib
 from distributeddeeplearning_tpu.train import loop
+from tests import zero_parity
+from tests.zero_parity import (build as _build, cfg as _cfg,
+                               full_params as _full_params)
 
 DATA_AXES = ("data", "fsdp")
 
@@ -46,26 +46,6 @@ def _max_abs_diff(a, b) -> float:
                for x, y in zip(_leaves(a), _leaves(b)))
 
 
-def _cfg(opt_kw, sharding, **kw):
-    base = dict(
-        model="resnet18_thin", global_batch_size=16, dtype="float32",
-        log_every=10**9, parallel=ParallelConfig(data=8),
-        data=DataConfig(synthetic=True, image_size=32, num_classes=10),
-        optimizer=OptimizerConfig(schedule="constant", **opt_kw),
-        optimizer_sharding=sharding)
-    base.update(kw)
-    return TrainConfig(**base)
-
-
-def _build(cfg, total_steps=4):
-    spec = model_spec(cfg.model)
-    mesh, model, batch_shd, state, train_step, sched, rng = loop.build(
-        cfg, total_steps)
-    source = datalib.make_source(cfg, spec.input_kind, batch_shd,
-                                 objective=spec.objective)
-    return state, train_step, source, rng
-
-
 def _run(cfg, steps):
     state, train_step, source, rng = _build(cfg, steps)
     for i in range(steps):
@@ -73,32 +53,19 @@ def _run(cfg, steps):
     return state, train_step
 
 
-def _full_params(state, train_step):
-    """Replicated full-shape params regardless of stage (zero3 states hold
-    1/N chunks; the converter gathers them)."""
-    conv = getattr(train_step, "zero_converter", None)
-    if conv is not None:
-        state = conv.full_params_state(state)
-    return jax.device_get(state.params)
-
-
 # --------------------------------------------------------------------------
 # Trajectory parity across the ladder.
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("opt_kw", [
-    dict(name="sgd", learning_rate=0.1, momentum=0.9, weight_decay=1e-4),
-    dict(name="adamw", learning_rate=1e-3, weight_decay=0.01),
-], ids=["sgd_momentum", "adamw"])
-def test_zero2_matches_zero1_bitwise(devices8, opt_kw):
+@pytest.mark.parametrize("optimizer", ["sgd_momentum", "adamw"])
+def test_zero2_matches_zero1_bitwise(devices8, optimizer):
     """zero2's overlapped backward scatter is the SAME per-bucket ops as
     zero1's post-backward scatter — params must agree bitwise, while the
     modeled resident grad bytes drop to 1/N (the full grad tree is never
     materialized)."""
-    s1, step1 = _run(_cfg(opt_kw, "zero1"), 3)
-    s2, step2 = _run(_cfg(opt_kw, "zero2"), 3)
-    assert _max_abs_diff(_full_params(s1, step1),
-                         _full_params(s2, step2)) == 0.0
+    s1, step1, params1 = zero_parity.trajectory(optimizer, "zero1")
+    s2, step2, params2 = zero_parity.trajectory(optimizer, "zero2")
+    assert _max_abs_diff(params1[-1], params2[-1]) == 0.0
     assert step2.zero_stage == "zero2" and step2.overlap
     assert step1.zero_stage == "zero1" and not step1.overlap
     assert step2.grad_bytes_per_device < step1.grad_bytes_per_device
@@ -121,22 +88,32 @@ def test_zero2_serialized_schedule_bitwise(devices8):
                          _full_params(s2, step2)) == 0.0
 
 
-@pytest.mark.parametrize("opt_kw", [
-    dict(name="sgd", learning_rate=0.1, momentum=0.9, weight_decay=1e-4),
-    dict(name="adamw", learning_rate=1e-3, weight_decay=0.01),
-], ids=["sgd_momentum", "adamw"])
-def test_zero3_matches_replicated_bitwise(devices8, opt_kw):
+@pytest.mark.parametrize("optimizer", ["sgd_momentum", "adamw"])
+def test_zero3_matches_zero1_bitwise(devices8, optimizer):
     """Full FSDP-style sharding: params live 1/N-chunked, gathered per
-    bucket on demand — and the trajectory still matches the replicated
-    path bitwise for elementwise optimizers (the gathered params ARE the
-    replicated params; the scattered grads ARE the psum chunks)."""
-    sr, step_r = _run(_cfg(opt_kw, "none"), 3)
-    s3, step3 = _run(_cfg(opt_kw, "zero3"), 3)
+    bucket on demand — and the trajectory is zero1's in every bit of
+    parameters AND optimizer state (the gathered params ARE zero1's
+    params; the scattered grads ARE the same chunks; the update runs on
+    the same chunk values)."""
+    s1, step1, params1 = zero_parity.trajectory(optimizer, "zero1")
+    s3, step3, params3 = zero_parity.trajectory(optimizer, "zero3")
     assert step3.zero_stage == "zero3" and step3.overlap
-    assert _max_abs_diff(_full_params(sr, step_r),
-                         _full_params(s3, step3)) == 0.0
+    assert _max_abs_diff(params1[-1], params3[-1]) == 0.0
+    assert _max_abs_diff(
+        jax.device_get(step1.zero_converter.to_canonical(s1).opt_state),
+        jax.device_get(step3.zero_converter.to_canonical(s3).opt_state),
+    ) == 0.0
+
+
+@pytest.mark.parametrize("optimizer", list(zero_parity.OPTIMIZERS))
+def test_zero3_matches_replicated(devices8, optimizer):
+    """Against the replicated path, after each of three steps, within what
+    tests/zero_parity.py allows (bit for bit where the update has no
+    multiply feeding an add)."""
+    zero_parity.assert_matches_replicated(optimizer, "zero3")
     # Live zero3 param leaves really are 1/N resident per device.
-    for leaf in _leaves(s3.params):
+    for leaf in _leaves(zero_parity.trajectory(optimizer, "zero3").state
+                        .params):
         assert leaf.addressable_shards[0].data.size == leaf.size // 8
 
 

@@ -173,23 +173,6 @@ def check_caches(prune_days: float = 0.0) -> None:
     emit("caches", ok=True, **fields)
 
 
-def check_perf_gate() -> None:
-    """CPU-proxy perf-gate state WITHOUT running the proxy (that is
-    tier-1's job): baseline presence/recording info + the last recorded
-    check from .cache/perf_gate_last.json — so a failing gate is
-    diagnosable (which phase, how far out of band) straight from doctor
-    output, no pytest rerun needed."""
-    try:
-        from distributeddeeplearning_tpu.observability import perf_gate
-        st = perf_gate.status()
-        last = st.get("last_check")
-        ok = bool(st["baseline_present"]) and (last is None
-                                              or bool(last.get("ok")))
-        emit("perf_gate", ok=ok, **st)
-    except Exception as e:
-        emit("perf_gate", ok=False, error=str(e)[:200])
-
-
 def check_sharding() -> None:
     """Optimizer-sharding state of the LAST run (loop.py drops
     .cache/last_run_sharding.json on process 0): active ZeRO stage,
@@ -413,7 +396,6 @@ def main(argv=None) -> int:
     check_native()
     check_loader()
     check_caches(prune_days=args.prune)
-    check_perf_gate()
     check_sharding()
     check_pipeline()
     check_precision()

@@ -48,48 +48,12 @@ def find_violations(records, threshold_s: float = DEFAULT_THRESHOLD_S):
     return sorted(out, key=lambda r: -float(r["duration"]))
 
 
-def audit_perf_gate(records) -> list[str]:
-    """Problems with the CPU-proxy perf gate's presence in this run.
-
-    The gate (tests marked ``perf_gate``, observability/perf_gate.py)
-    only protects anything while it actually executes in tier-1 — two
-    silent failure modes would disarm it without failing anything:
-    the marked tests disappear from the selection (renamed, deselected,
-    collection error), or someone marks them ``slow`` and tier-1's
-    ``-m 'not slow'`` filters the gate out. Both become loud here.
-    """
-    problems = []
-    gate = [r for r in records if r.get("perf_gate")]
-    if not gate:
-        problems.append(
-            "no perf_gate-marked test ran — the CPU-proxy perf gate is "
-            "not protecting this run (tests/test_perf_gate.py missing, "
-            "renamed, or deselected?)")
-    elif not any("zero2" in (r.get("nodeid") or "") for r in gate):
-        # The gate is two workloads since the ZeRO ladder landed: the
-        # headline proxy AND the overlapped zero2 schedule (its extras
-        # baseline in perf_baselines.json). Losing the sharded one is the
-        # same silent-disarm failure mode as losing the gate entirely.
-        problems.append(
-            "perf_gate tests ran but none covers the zero2_overlap "
-            "workload — the sharded-schedule gate "
-            "(tests/test_perf_gate.py::test_perf_gate_live_zero2_overlap) "
-            "is missing, renamed, or deselected")
-    for rec in gate:
-        if rec.get("slow"):
-            problems.append(
-                f"{rec.get('nodeid')} is marked BOTH perf_gate and slow — "
-                f"tier-1 runs -m 'not slow', so this silently removes the "
-                f"perf gate from tier-1")
-    return problems
-
-
 def audit_elastic(records) -> list[str]:
     """Problems with elastic-resume coverage in this run.
 
-    The cross-degree resume path (tests marked ``elastic``) has the same
-    silent-disarm failure modes as the perf gate: the marked tests vanish
-    from the selection, or every one of them is also marked ``slow`` and
+    The cross-degree resume path (tests marked ``elastic``) has two
+    silent-disarm failure modes: the marked tests vanish from the
+    selection, or every one of them is also marked ``slow`` and
     tier-1's ``-m 'not slow'`` filters elastic coverage out entirely (the
     soak is legitimately slow — but a FAST variant must survive in
     tier-1; tests/test_elastic_resume.py keeps one).
@@ -198,10 +162,7 @@ def audit_serve(records) -> list[str]:
     silent-disarm failure modes: the marked tests vanish from the
     selection, or every one is also marked ``slow`` and tier-1's
     ``-m 'not slow'`` stops pinning engine token-identity against
-    sequential generate(). The serve_decode AND serve_prefix_prefill
-    perf-gate workloads (tests/test_perf_gate.py) must also have run —
-    losing either quietly un-gates the engine's per-step or
-    admission-path cost — and the fast-path identity tests
+    sequential generate(). The fast-path identity tests
     (tests/test_serve_fastpath.py: prefix cache + speculative decoding
     vs sequential generate()) must be present, or the COW/spec paths
     regress to "configured but unproven"."""
@@ -217,23 +178,6 @@ def audit_serve(records) -> list[str]:
             "every serve-marked test is also marked slow — tier-1 runs "
             "-m 'not slow', so engine token-identity is silently unpinned "
             "in tier-1 (keep a fast serve variant unmarked)")
-    if not any(r.get("perf_gate") and "serve_decode" in (r.get("nodeid")
-                                                         or "")
-               for r in records):
-        problems.append(
-            "no perf_gate test covering the serve_decode workload ran — "
-            "the engine's decode-step cost is ungated "
-            "(tests/test_perf_gate.py::test_perf_gate_live_serve_decode "
-            "missing, renamed, or deselected?)")
-    if not any(r.get("perf_gate") and "serve_prefix" in (r.get("nodeid")
-                                                         or "")
-               for r in records):
-        problems.append(
-            "no perf_gate test covering the serve_prefix_prefill workload "
-            "ran — the prefix-cache admission path is ungated "
-            "(tests/test_perf_gate.py::"
-            "test_perf_gate_live_serve_prefix_prefill missing, renamed, "
-            "or deselected?)")
     if serve and not any("fastpath" in (r.get("nodeid") or "")
                          for r in serve):
         problems.append(
@@ -276,10 +220,7 @@ def audit_pipeline(records) -> list[str]:
     final-params identity, ZeRO-2 composition, cross-schedule resume)
     have the same silent-disarm failure modes: the marked tests vanish
     from the selection, or every one is also marked ``slow`` and tier-1's
-    ``-m 'not slow'`` stops pinning schedule equivalence. The
-    pipeline_1f1b perf-gate workload (tests/test_perf_gate.py) must also
-    have run — losing it quietly un-gates the interleaved tick loop's
-    step cost."""
+    ``-m 'not slow'`` stops pinning schedule equivalence."""
     problems = []
     pipe = [r for r in records if r.get("pipeline")]
     if not pipe:
@@ -292,13 +233,6 @@ def audit_pipeline(records) -> list[str]:
             "every pipeline-marked test is also marked slow — tier-1 runs "
             "-m 'not slow', so schedule equivalence is silently unpinned "
             "in tier-1 (keep a fast pipeline variant unmarked)")
-    if not any(r.get("perf_gate") and "pipeline" in (r.get("nodeid") or "")
-               for r in records):
-        problems.append(
-            "no perf_gate test covering the pipeline_1f1b workload ran — "
-            "the interleaved schedule's step cost is ungated "
-            "(tests/test_perf_gate.py::test_perf_gate_live_pipeline_1f1b "
-            "missing, renamed, or deselected?)")
     return problems
 
 
@@ -306,21 +240,10 @@ def audit_largebatch(records) -> list[str]:
     """Problems with large-batch / mixed-precision coverage in this run.
 
     The large-batch recipe (ISSUE 20: mixed-precision PrecisionPolicy,
-    dynamic loss scaling, batch ramp) is gated by the largebatch_bf16
-    CPU-proxy workload in tests/test_perf_gate.py — losing that test
-    quietly un-gates the mixed-precision step's cost and phase mix. The
-    loss-scale skip path and the ramp-boundary resume pin must also have
-    run, or the recipe regresses to "configured but unproven"."""
+    dynamic loss scaling, batch ramp): the loss-scale skip path and the
+    ramp-boundary resume pin must have run, or the recipe regresses to
+    "configured but unproven"."""
     problems = []
-    if not any(r.get("perf_gate") and "largebatch" in (r.get("nodeid")
-                                                       or "")
-               for r in records):
-        problems.append(
-            "no perf_gate test covering the largebatch_bf16 workload ran "
-            "— the mixed-precision large-batch step is ungated "
-            "(tests/test_perf_gate.py::"
-            "test_perf_gate_live_largebatch_bf16 missing, renamed, or "
-            "deselected?)")
     if not any("loss_scale" in (r.get("nodeid") or "") for r in records):
         problems.append(
             "no loss-scale test ran — the overflow->skip->halve->recover "
@@ -340,12 +263,11 @@ def main(argv=None) -> int:
     if not argv or argv[0] in ("-h", "--help"):
         print(__doc__)
         print(f"usage: marker_audit.py <durations.json> [threshold_s="
-              f"{DEFAULT_THRESHOLD_S:g}] [--expect-perf-gate] "
-              f"[--expect-elastic] [--expect-flight] [--expect-lint] "
-              f"[--expect-serve] [--expect-serve-chaos] "
-              f"[--expect-pipeline] [--expect-largebatch]")
+              f"{DEFAULT_THRESHOLD_S:g}] [--expect-elastic] "
+              f"[--expect-flight] [--expect-lint] [--expect-serve] "
+              f"[--expect-serve-chaos] [--expect-pipeline] "
+              f"[--expect-largebatch]")
         return 0 if argv else 2
-    expect_gate = "--expect-perf-gate" in argv
     expect_elastic = "--expect-elastic" in argv
     expect_flight = "--expect-flight" in argv
     expect_lint = "--expect-lint" in argv
@@ -354,10 +276,10 @@ def main(argv=None) -> int:
     expect_pipeline = "--expect-pipeline" in argv
     expect_largebatch = "--expect-largebatch" in argv
     argv = [a for a in argv
-            if a not in ("--expect-perf-gate", "--expect-elastic",
-                         "--expect-flight", "--expect-lint",
-                         "--expect-serve", "--expect-serve-chaos",
-                         "--expect-pipeline", "--expect-largebatch")]
+            if a not in ("--expect-elastic", "--expect-flight",
+                         "--expect-lint", "--expect-serve",
+                         "--expect-serve-chaos", "--expect-pipeline",
+                         "--expect-largebatch")]
     threshold = float(argv[1]) if len(argv) > 1 else DEFAULT_THRESHOLD_S
     try:
         with open(argv[0]) as f:
@@ -366,41 +288,24 @@ def main(argv=None) -> int:
         print(f"marker-audit: cannot read {argv[0]}: {e}", file=sys.stderr)
         return 2
     violations = find_violations(records, threshold)
-    # slow+perf_gate double-marking is checked on EVERY audit (it is a
-    # static mistake); the presence checks (gate ran at all, both gate
-    # workloads covered) are opt-in, because partial runs
-    # (pytest tests/test_flops.py) legitimately lack the gate.
-    gate_problems = audit_perf_gate(records)
-    if not expect_gate:
-        gate_problems = [p for p in gate_problems
-                         if not p.startswith(("no perf_gate",
-                                              "perf_gate tests ran but"))]
-    # Elastic coverage is entirely opt-in (both of its problems are
-    # presence checks, meaningless on partial runs).
+    # Every coverage audit is opt-in: its problems are presence checks,
+    # meaningless on partial runs (pytest tests/test_flops.py).
+    problems = []
     if expect_elastic:
-        gate_problems += audit_elastic(records)
-    # Flight-record coverage likewise (both problems are presence checks).
+        problems += audit_elastic(records)
     if expect_flight:
-        gate_problems += audit_flight(records)
-    # ddl-lint gate coverage likewise (presence + registration checks).
+        problems += audit_flight(records)
     if expect_lint:
-        gate_problems += audit_lint(records)
-    # Serve-engine coverage likewise (presence + serve_decode gate checks).
+        problems += audit_lint(records)
     if expect_serve:
-        gate_problems += audit_serve(records)
-    # Serve-chaos soak coverage likewise (presence of the serve+chaos
-    # combo-marked token-identical-recovery test).
+        problems += audit_serve(records)
     if expect_serve_chaos:
-        gate_problems += audit_serve_chaos(records)
-    # Pipeline-schedule coverage likewise (parity pins + the
-    # pipeline_1f1b gate workload).
+        problems += audit_serve_chaos(records)
     if expect_pipeline:
-        gate_problems += audit_pipeline(records)
-    # Large-batch recipe coverage likewise (gate workload + loss-scale
-    # + ramp pins).
+        problems += audit_pipeline(records)
     if expect_largebatch:
-        gate_problems += audit_largebatch(records)
-    if not violations and not gate_problems:
+        problems += audit_largebatch(records)
+    if not violations and not problems:
         print(f"marker-audit: OK — {len(records)} tests, none over "
               f"{threshold:g}s unmarked")
         return 0
@@ -409,7 +314,7 @@ def main(argv=None) -> int:
               f"without @pytest.mark.slow ({BUDGET_NOTE}):")
         for rec in violations:
             print(f"  {rec['duration']:7.1f}s  {rec['nodeid']}")
-    for p in gate_problems:
+    for p in problems:
         print(f"marker-audit: {p}")
     return 1
 

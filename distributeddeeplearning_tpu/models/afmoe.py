@@ -39,6 +39,8 @@ from distributeddeeplearning_tpu.models.llama import apply_rope
 from distributeddeeplearning_tpu.models.moe import ROUTED_OUT, RoutedExperts
 from distributeddeeplearning_tpu.ops.attention import multihead_attention
 from distributeddeeplearning_tpu.ops.embedding import embedding_lookup
+from distributeddeeplearning_tpu.ops.flash_attention import (FLASH_LSE,
+                                                             FLASH_OUT)
 
 Dtype = Any
 
@@ -184,12 +186,17 @@ class AfmoeLM(nn.Module):
         for i in range(cfg.num_layers):
             block = AfmoeBlock(cfg, i, self.dtype, name=f"layer{i}")
             if cfg.remat:
-                # nothing is kept but what the routed experts name: their
-                # backward rule runs their forward pass itself (moe.py)
+                # a block keeps the two things whose recomputation is a
+                # kernel pass over the whole sequence: the routed experts'
+                # result (their backward rule runs their forward pass
+                # itself, moe.py) and the flash forward kernel's result with
+                # its log-sum-exp, which is all the backward kernels ask of
+                # a second forward. q, k, v are remade: more bytes than
+                # both, for three projections' time (docs/afmoe.md)
                 x = nn.remat(
                     lambda mdl, h, m: mdl(h, m, train=train),
                     policy=jax.checkpoint_policies.save_only_these_names(
-                        ROUTED_OUT))(block, x, pad_mask)
+                        ROUTED_OUT, FLASH_OUT, FLASH_LSE))(block, x, pad_mask)
             else:
                 x = block(x, pad_mask, train=train)
             x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))

@@ -37,6 +37,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -46,6 +47,15 @@ from distributeddeeplearning_tpu.ops.masks import (block_band_mask,
 from distributeddeeplearning_tpu.ops.pallas import pallas_call
 
 _NEG = -1e30
+
+# ``checkpoint_name`` of what the forward kernel hands its backward rule, in
+# the kernel's own layout ((B*H, S, D) and (B*H, S)), for a recomputed block
+# to keep: ``jax.checkpoint_policies.save_only_these_names(FLASH_OUT,
+# FLASH_LSE)`` takes the second ``flash_fwd`` out of such a block's backward
+# pass. A policy that lists neither keeps nothing, and without remat the
+# names lower to nothing.
+FLASH_OUT = "flash_attention_out"
+FLASH_LSE = "flash_attention_lse"
 
 
 _PAD_GRANULE = 128  # TPU lane width; also the floor _block can return after
@@ -633,6 +643,8 @@ def _flash(q, k, v, mask, seed, scale, plan, causal, dropout_rate):
 def _flash_fwd(q, k, v, mask, seed, scale, plan, causal, dropout_rate):
     out, lse = _fwd(q, k, v, mask, seed, scale=scale, plan=plan,
                     causal=causal, dropout_rate=dropout_rate)
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, mask, seed, out, lse)
 
 

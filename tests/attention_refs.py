@@ -26,3 +26,17 @@ def random_qkv(key, b=2, s=32, h=4, d=8, dtype=jnp.float32):
     return (jax.random.normal(kq, shape, dtype),
             jax.random.normal(kk, shape, dtype),
             jax.random.normal(kv, shape, dtype))
+
+
+FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+
+
+def flash_kernel_calls(fn, *args):
+    """How often each flash kernel stands in ``fn``'s program, lowered for
+    the TPU platform from the CPU (nothing compiles, no libtpu is loaded):
+    there a kernel is one Mosaic call under its own name, and what a
+    recomputed block's policy kept is already out of its backward pass."""
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return {name: text.count(f'kernel_name = "{name}"')
+            for name in FLASH_KERNELS}

@@ -26,10 +26,12 @@ from distributeddeeplearning_tpu.models import afmoe, moe  # noqa: E402
 ref = harness.load_module("references", "afmoe")
 sys.path.insert(0, os.path.join(REPO, "tests", "benchmark"))
 import tiny_afmoe  # noqa: E402
+from tests.attention_refs import flash_kernel_calls  # noqa: E402
 
 SZ = ref.sizes(tiny_afmoe.AFMOE_TINY)
 BATCH, SEQ = 2, 64
 MOE_LAYERS = ("layer1", "layer2")
+LEAVES = sorted(ref.init_params(SZ, jax.random.key(0)))
 
 
 def unflatten(flat):
@@ -109,8 +111,7 @@ def test_logits_and_loss_match_the_reference(both):
                                                 rel=1e-6)
 
 
-@pytest.mark.parametrize("leaf", sorted(
-    k for k in ref.init_params(SZ, jax.random.key(0))))
+@pytest.mark.parametrize("leaf", LEAVES)
 def test_every_gradient_leaf_matches_the_reference(both, leaf):
     got, want = both["grads"][leaf], both["want_grads"][leaf]
     scale = float(jnp.abs(want).max())
@@ -129,6 +130,47 @@ def test_the_selection_bias_after_a_step_matches_the_reference(both, name):
     sown = both["mutated"][moe.MOE_METRICS][name]["moe"]
     assert float(sown["dropped"][0]) == 0.0
     assert 0 < float(sown["tokens_here"][0]) < BATCH * SEQ * SZ["top_k"]
+
+
+# A recomputed block keeps the routed experts' result and the flash kernel's
+# (models/afmoe.py): the forward kernel stands once a layer in the gradient's
+# program, not twice, and the gradients are those of the blocks kept whole.
+
+def _flash_model(remat):
+    return models.get_model("afmoe_tiny", dtype=jnp.float32,
+                            vocab_size=SZ["vocab"], attention_impl="flash",
+                            remat=remat)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "recomputed"])
+def test_each_flash_kernel_stands_once_a_layer(seeded, remat):
+    params, extra, batch = seeded
+    model = _flash_model(remat)
+    layers = model.cfg.num_layers
+    assert flash_kernel_calls(
+        jax.grad(lambda p: program_loss(model, p, router_state(extra),
+                                        batch["input_ids"])[0]),
+        unflatten(params)) == {"flash_fwd": layers, "flash_dq": layers,
+                               "flash_dkv": layers}
+
+
+@pytest.fixture(scope="module")
+def recomputed(seeded):
+    """`both`'s program with every block recomputed: its gradients."""
+    params, extra, batch = seeded
+    model = _flash_model(True)
+    with jax.default_matmul_precision("highest"):
+        return flatten(jax.grad(
+            lambda p: program_loss(model, p, router_state(extra),
+                                   batch["input_ids"])[0])(
+            unflatten(params)))
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_a_recomputed_blocks_gradient_is_the_kept_ones_bit_for_bit(
+        both, recomputed, leaf):
+    np.testing.assert_array_equal(np.asarray(recomputed[leaf]),
+                                  np.asarray(both["grads"][leaf]))
 
 
 def test_mixed_precision_stays_in_its_band(seeded, both):

@@ -13,7 +13,10 @@ import pytest
 import functools
 
 from distributeddeeplearning_tpu.ops import flash_attention
-from tests.attention_refs import dense_reference, random_qkv
+from distributeddeeplearning_tpu.ops.flash_attention import (FLASH_LSE,
+                                                             FLASH_OUT)
+from tests.attention_refs import (dense_reference, flash_kernel_calls,
+                                  random_qkv)
 
 random_qkv = functools.partial(random_qkv, s=64, h=2, d=16)
 
@@ -446,3 +449,79 @@ def test_a_window_needs_a_causal_call():
     q, k, v = random_qkv(jax.random.key(9), h=3)
     with pytest.raises(ValueError, match="divide"):
         flash_attention(q, k[:, :, :2], v[:, :, :2], causal=True)
+
+
+# ---------------------------------------------------------------------------
+# What a recomputed block keeps: the forward rule names the kernel's result
+# and its log-sum-exp (FLASH_OUT, FLASH_LSE), and a policy that lists both
+# takes the second forward kernel out of the block's backward pass.
+# ---------------------------------------------------------------------------
+
+def _block_loss(policy):
+    """A stand-in for a transformer block round the kernel: projections in
+    and out, recomputed under ``policy`` ("kept" = not recomputed at all)."""
+    def block(x, w):
+        q, k, v = (jnp.einsum("bshd,de->bshe", x, w[i]) for i in range(3))
+        return jnp.tanh(flash_attention(q, k, v, causal=True))
+
+    if policy != "kept":
+        block = jax.checkpoint(block, policy=policy)
+    return lambda x, w: (block(x, w) ** 2).sum()
+
+
+_names = jax.checkpoint_policies.save_only_these_names
+RECOMPUTED_CASES = [
+    pytest.param("kept", 1, id="not-recomputed"),
+    pytest.param(None, 2, id="recomputed-no-policy"),
+    pytest.param(_names("somebody_elses"), 2, id="policy-of-other-names"),
+    pytest.param(_names(FLASH_OUT), 2, id="result-without-lse"),
+    pytest.param(_names(FLASH_LSE), 2, id="lse-without-result"),
+    pytest.param(_names(FLASH_OUT, FLASH_LSE), 1, id="result-and-lse"),
+]
+
+
+@pytest.fixture(scope="module")
+def block_inputs():
+    x = jax.random.normal(jax.random.key(11), (2, 64, 2, 16))
+    w = jax.random.normal(jax.random.key(12), (3, 16, 16)) * 0.25
+    return x, w
+
+
+@pytest.fixture(scope="module")
+def kept_block_grads(block_inputs):
+    return jax.grad(_block_loss("kept"), argnums=(0, 1))(*block_inputs)
+
+
+@pytest.mark.parametrize("policy,forwards", RECOMPUTED_CASES)
+def test_a_recomputed_block_runs_the_forward_kernel_as_its_policy_says(
+        block_inputs, kept_block_grads, policy, forwards):
+    """The names alone change nothing; both of them kept, the backward
+    kernels read the first pass's buffers. The gradients are the same bits
+    whatever was kept."""
+    grad = jax.grad(_block_loss(policy), argnums=(0, 1))
+    assert flash_kernel_calls(grad, *block_inputs) == {
+        "flash_fwd": forwards, "flash_dq": 1, "flash_dkv": 1}
+    for got, want in zip(grad(*block_inputs), kept_block_grads):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("remat,forwards", [(False, 1), (True, 2)],
+                         ids=["kept", "recomputed"])
+def test_gpt_blocks_recomputed_without_a_policy_run_the_forward_twice(
+        remat, forwards):
+    """``gpt_tiny``'s ``nn.remat`` passes no policy: a user who switched
+    ``--remat`` on to save memory keeps the block's input and nothing as
+    large again."""
+    from distributeddeeplearning_tpu.models import model_spec
+
+    model = model_spec("gpt_tiny").build(
+        vocab_size=256, dtype=jnp.float32, attention_impl="flash",
+        remat=remat)
+    ids = jax.random.randint(jax.random.key(0), (2, 64), 0, 256)
+    params = model.init(jax.random.key(1), ids, train=False)["params"]
+    layers = model.cfg.num_layers
+    calls = flash_kernel_calls(
+        jax.grad(lambda p: (model.apply({"params": p}, ids,
+                                        train=False) ** 2).mean()), params)
+    assert calls == {"flash_fwd": forwards * layers, "flash_dq": layers,
+                     "flash_dkv": layers}

@@ -6,7 +6,8 @@ does: every instruction carries ``metadata={op_name="..."}``, the jax name
 stack it was traced under — ``jvp(...)`` / ``transpose(...)`` for the pass,
 the flax module path, every ``jax.named_scope`` (train/steps.py's
 ``STEP_SCOPES`` and ``LOSS_SCOPE``, models/gpt.py's ``embed`` / ``mlp`` /
-``head``, models/afmoe.py's ``attn_window`` / ``attn_full``, models/moe.py's
+``head``, models/afmoe.py's ``attn_window`` / ``attn_full``,
+models/kimi_linear.py's ``attn_kda`` / ``attn_mla``, models/moe.py's
 ``moe_router`` / ``moe_dispatch`` / ``moe_experts`` / ``moe_combine``) and
 every Pallas kernel's ``name`` (ops/pallas.py). This module reads those names
 back:
@@ -30,18 +31,22 @@ PHASES = ("forward", "backward", "update")
 # no rule placed (instructions the compiler made without metadata, scopes the
 # rule does not know — a CNN's convolutions, today).
 PARTS = ("flash_fwd", "flash_dq", "flash_dkv", "attention_window",
-         "attention_full", "moe_routing", "moe_experts", "attention_other",
+         "attention_full", "attention_kda", "attention_mla", "moe_routing",
+         "moe_experts", "attention_other",
          "mlp", "layernorm", "embed", "head", "loss", "remat", "loss_scale",
          "optimizer", "ema_guard", "grad_reduce", "unattributed")
 
 _KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 # Scopes a model puts round its own parts -> part. A model whose layers
 # differ in attention kind scopes the kernel calls by kind, and the three
-# kernels then book under the kind; one with routed experts has the router,
+# kernels then book under the kind (so do a delta-rule layer's convolutions,
+# gates, chunked scan and gated norm, which have no kernel of their own
+# name); one with routed experts has the router,
 # the sort, the gathers and the weighted sum as ``moe_routing`` and the
 # grouped products as ``moe_experts``.
 _MODEL_SCOPES = {"attn_window": "attention_window",
                  "attn_full": "attention_full",
+                 "attn_kda": "attention_kda", "attn_mla": "attention_mla",
                  "moe_router": "moe_routing", "moe_dispatch": "moe_routing",
                  "moe_combine": "moe_routing", "moe_experts": "moe_experts"}
 # The TPU compiler turns ``jax.lax.ragged_dot`` into kernels of its own and
@@ -54,9 +59,13 @@ _RAGGED_SCOPE = "moe_experts"
 # A ``conditional`` runs the instructions of one of its branches, and a device
 # trace holds it as an operation of its own that spans theirs (seen on the
 # chip, PR 28: a step's operations add up to its busy time only without the
-# routed experts' conditionals). ``table`` gives it this in place of an
-# ``op_name``, and ``by_part`` leaves it out: its time is its branch's.
+# routed experts' conditionals). ``table`` puts this before its ``op_name``
+# (which still says what it belongs to), and ``by_part`` leaves it out: its
+# time is its branch's. A ``while`` (a ``lax.scan``: the chunked delta rule's
+# loops) is held the same way, spanning every turn of its body, and is left
+# out the same way.
 SPANS_ITS_BRANCH = "(conditional: its time is its branch's operations')"
+_SPANNING_OPCODES = ("conditional", "while")
 # train/steps.py STEP_SCOPES outside ``grads`` -> part; all are phase update.
 _UPDATE_SCOPES = {"grad_reduce": "grad_reduce", "loss_scale": "loss_scale",
                   "optimizer": "optimizer", "ema": "ema_guard",
@@ -116,15 +125,16 @@ def table(hlo_text: str) -> dict[str, str]:
     callees before callers and operands before users, so one pass resolves
     all three.
     Parameters keep no name: an argument's path names no part of the
-    program. A ``conditional`` explains its neighbours like any other
-    instruction and is itself given :data:`SPANS_ITS_BRANCH`. Instructions
+    program. A ``conditional`` or a ``while`` explains its neighbours like
+    any other instruction and has :data:`SPANS_ITS_BRANCH` put before its
+    own name. Instructions
     nothing explains are left out. Tolerant of torn
     text: a line that does not parse is skipped.
     """
     names: dict[str, str] = {}
     roots: dict[str, str] = {}     # computation -> its root's op_name
     unexplained: dict[str, list[str]] = {}  # waiting for a user: operands
-    conditionals: list[str] = []
+    spanning: list[str] = []       # conditionals and whiles
     computation = None
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -152,12 +162,12 @@ def table(hlo_text: str) -> dict[str, str]:
             if op_name is None:
                 op_name = next((names[o] for o in operands if o in names),
                                None)
+        if opcode in _SPANNING_OPCODES:  # named or not: never booked
+            spanning.append(name)
         if op_name is None:
             unexplained[name] = operands
             continue
         names[name] = op_name
-        if opcode == "conditional":
-            conditionals.append(name)
         while operands:
             operand = operands.pop()
             if operand in unexplained:
@@ -165,7 +175,8 @@ def table(hlo_text: str) -> dict[str, str]:
                 operands.extend(unexplained.pop(operand))
         if is_root and computation is not None:
             roots[computation] = op_name
-    names.update(dict.fromkeys(conditionals, SPANS_ITS_BRANCH))
+    names.update({name: SPANS_ITS_BRANCH + names.get(name, "")
+                  for name in spanning})
     return names
 
 
@@ -226,12 +237,12 @@ def by_part(durations: dict[str, float], table: dict[str, str]
     names an operation by its instruction or by its whole HLO line, which
     starts with it (``%fusion.7 = ...``). An instruction that ``table`` does
     not hold has no phase (``"-"``) and the part ``unattributed``; a
-    ``conditional`` is left out, its branch's operations being in the trace
-    themselves."""
+    ``conditional`` or ``while`` is left out, its branch's or body's
+    operations being in the trace themselves."""
     out: dict[tuple[str, str], float] = {}
     for line, time in durations.items():
         op_name = table.get(_LEADING_NAME.match(line).group(1))
-        if op_name == SPANS_ITS_BRANCH:
+        if op_name and op_name.startswith(SPANS_ITS_BRANCH):
             continue
         key = part_of(op_name) if op_name else ("-", "unattributed")
         out[key] = out.get(key, 0.0) + time

@@ -29,7 +29,8 @@ class ModelSpec:
 
 def _registry() -> dict[str, ModelSpec]:
     from distributeddeeplearning_tpu.models import (afmoe, bert, densenet,
-                                                    gpt, llama, resnet, vit)
+                                                    gpt, kimi_linear, llama,
+                                                    resnet, vit)
 
     def img(build, name, params):
         return ModelSpec(name=name, build=build, input_kind="image",
@@ -95,6 +96,18 @@ def _registry() -> dict[str, ModelSpec]:
         "afmoe_tiny": ModelSpec(
             name="afmoe_tiny", build=afmoe.tiny_afmoe, input_kind="tokens",
             param_count=0, objective="causal"),
+        # Kimi-Linear-48B-A3B as published, for shape tests, and one chip's
+        # share of it when 32 chips share each layer (models/kimi_linear.py;
+        # the benchmark's kimi_linear cell).
+        "kimi_linear_48b": ModelSpec(
+            name="kimi_linear_48b", build=kimi_linear.kimi_linear_48b,
+            input_kind="tokens", param_count=0, objective="causal"),
+        "kimi_linear_ep32": ModelSpec(
+            name="kimi_linear_ep32", build=kimi_linear.kimi_linear_ep32,
+            input_kind="tokens", param_count=0, objective="causal"),
+        "kimi_linear_tiny": ModelSpec(
+            name="kimi_linear_tiny", build=kimi_linear.kimi_linear_tiny,
+            input_kind="tokens", param_count=0, objective="causal"),
         # Nano drafters for speculative decoding (serve/engine.py): a
         # shrunk config of the same family — cheap to step, same
         # tokenizer/vocab, verified by the full target model so output

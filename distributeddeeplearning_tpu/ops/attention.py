@@ -5,7 +5,9 @@ Four impls, one semantic: dropout(softmax(QK^T * d^-1/2 + mask)) V with a
 key-padding mask, optionally causal, optionally cut to a causal ``window``
 (``query - key < window``; dense and flash), with K/V heads that may be
 fewer than the Q heads (Q head h reads K/V head ``h // (H // Hkv)``; flash
-reads them in place, dense repeats them).
+reads them in place, dense repeats them) and values that may be of another
+width than the queries and keys (latent attention; dense and flash: the
+scale is the query width's).
 
 - ``dense``: materialized (S, S) scores, f32 softmax, XLA-fused — right for
   short sequences.
@@ -44,10 +46,10 @@ def multihead_attention(q, k, v, pad_mask, *, impl: str, causal: bool,
                         dropout_rng: Optional[Any] = None,
                         deterministic: bool = True,
                         window: Optional[int] = None):
-    """q: (B, S, H, D), k/v: (B, S, Hkv, D); pad_mask: (B, S) bool (True =
-    attend) or None.
+    """q: (B, S, H, D), k: (B, S, Hkv, D), v: (B, S, Hkv, Dv); pad_mask:
+    (B, S) bool (True = attend) or None.
 
-    Returns (B, S, H*D) in ``dtype``. ``dropout_rate`` is the
+    Returns (B, S, H*Dv) in ``dtype``. ``dropout_rate`` is the
     attention-probability dropout rate, applied only when
     ``deterministic=False``; ``dropout_rng`` (a JAX PRNG key, e.g.
     ``self.make_rng('dropout')``) is required then.
@@ -82,6 +84,11 @@ def multihead_attention(q, k, v, pad_mask, *, impl: str, causal: bool,
     if window is not None and impl != "dense":
         raise ValueError(f"attention_impl={impl!r} has no window; use "
                          f"'flash' or 'dense'")
+    if v.shape[3] != d and impl in ("ring", "zigzag"):
+        raise ValueError(
+            f"attention_impl={impl!r} takes values as wide as the queries "
+            f"and keys ({d}), got {v.shape[3]}: the ring's blocks and "
+            f"accumulators have one width; use 'flash' or 'dense'")
     if k.shape[2] != h:
         k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
     if impl == "ring":

@@ -396,9 +396,13 @@ _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def _tile_specs(bq: int, bk: int, d: int, groups: int = 1,
-                kv_rows: bool = False, nq: int = 0):
+                kv_rows: bool = False, nq: int = 0,
+                dv: Optional[int] = None):
     """Block specs by what a block follows: a Q tile and its per-row
-    vectors follow qi[t], a K tile and the key mask follow kj[t].
+    vectors follow qi[t], a K tile and the key mask follow kj[t]. Returns
+    (q_tile, k_tile, vec_q, vec_k, o_tile, v_tile): Q, dQ, K and dK are
+    ``d`` wide, and V, dV, O and dO ``dv`` wide (``d`` where none is given:
+    the two pairs of specs are then the same).
 
     ``groups`` > 1, grouped K/V heads (Q rows are B*H, K/V rows B*H/groups,
     and Q row r reads K/V row r // groups): no K or V is repeated in memory,
@@ -439,31 +443,37 @@ def _tile_specs(bq: int, bk: int, d: int, groups: int = 1,
         row, tile = q_at(b, t, qi)
         return row, 0, tile
 
+    def k_index(b, t, qi, kj, _):
+        return k_row(b), kj[t], 0
+
+    dv = d if dv is None else dv
     q_tile = pl.BlockSpec((1, bq, d), q_index)
-    k_tile = pl.BlockSpec((1, bk, d),
-                          lambda b, t, qi, kj, _: (k_row(b), kj[t], 0))
+    k_tile = pl.BlockSpec((1, bk, d), k_index)
     vec_q = pl.BlockSpec((1, 1, bq), vec_q_index)
     vec_k = pl.BlockSpec((1, 1, bk),
                          lambda b, t, qi, kj, _: (mask_row(b), 0, kj[t]))
-    return q_tile, k_tile, vec_q, vec_k
+    return (q_tile, k_tile, vec_q, vec_k, pl.BlockSpec((1, bq, dv), q_index),
+            pl.BlockSpec((1, bk, dv), k_index))
 
 
 def _fwd(q, k, v, mask, seed, *, scale, plan, causal, dropout_rate):
     bh, s, d = q.shape
+    dv = v.shape[2]
     bq, bk = plan.bq, plan.bk
     tables = _schedule(s, plan, causal)
-    q_tile, k_tile, vec_q, vec_k = _tile_specs(bq, bk, d, bh // k.shape[0])
+    q_tile, k_tile, vec_q, vec_k, o_tile, v_tile = _tile_specs(
+        bq, bk, d, bh // k.shape[0], dv=dv)
     out, lse = pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate, **_window_kw(plan)),
         name="flash_fwd",
         grid_spec=_grid_spec(
-            bh, tables, [q_tile, k_tile, k_tile, vec_k], [q_tile, vec_q],
+            bh, tables, [q_tile, k_tile, v_tile, vec_k], [o_tile, vec_q],
             [pltpu.VMEM((bq, 1), jnp.float32),
              pltpu.VMEM((bq, _l_lanes(bk)), jnp.float32),
-             pltpu.VMEM((bq, d), jnp.float32)]),
+             pltpu.VMEM((bq, dv), jnp.float32)]),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         compiler_params=_PARAMS,
@@ -593,8 +603,16 @@ def _bwd(scale, plan, causal, dropout_rate, residuals, g):
 
     bq, bk = plan.bq, plan.bk
     groups = bh // k.shape[0]
-    q_tile, k_tile, vec_q, vec_k = _tile_specs(bq, bk, d, groups)
-    in_specs = [q_tile, k_tile, k_tile, vec_k, q_tile, vec_q, vec_q]
+    width_v = v.shape[2]
+
+    def specs(**kv_rows):
+        """(the seven operands' specs, the Q, K and V tiles' own)"""
+        q_tile, k_tile, vec_q, vec_k, o_tile, v_tile = _tile_specs(
+            bq, bk, d, groups, dv=width_v, **kv_rows)
+        return ([q_tile, k_tile, v_tile, vec_k, o_tile, vec_q, vec_q],
+                q_tile, k_tile, v_tile)
+
+    in_specs, q_tile, k_tile, v_tile = specs()
     operands = (seed, q, k, v, mask3, g, lse3, delta3)
 
     tables = _schedule(s, plan, causal)
@@ -615,17 +633,15 @@ def _bwd(scale, plan, causal, dropout_rate, residuals, g):
     kw = _window_kw(plan)
     if groups > 1:
         kw.update(groups=groups, nq=s // bq)
-        q_tile, k_tile, vec_q, vec_k = _tile_specs(
-            bq, bk, d, groups, kv_rows=True, nq=s // bq)
-        in_specs = [q_tile, k_tile, k_tile, vec_k, q_tile, vec_q, vec_q]
+        in_specs, _, k_tile, v_tile = specs(kv_rows=True, nq=s // bq)
     tables = _schedule(s, plan, causal, k_major=True, groups=groups)
     dk, dv = pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           dropout_rate=dropout_rate, **kw),
         name="flash_dkv",
-        grid_spec=_grid_spec(k.shape[0], tables, in_specs, [k_tile, k_tile],
+        grid_spec=_grid_spec(k.shape[0], tables, in_specs, [k_tile, v_tile],
                              [pltpu.VMEM((bk, d), jnp.float32),
-                              pltpu.VMEM((bk, d), jnp.float32)]),
+                              pltpu.VMEM((bk, width_v), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         compiler_params=_PARAMS,
@@ -663,11 +679,15 @@ def flash_attention(q, k, v, kv_mask=None, *,
     ``block_q`` / ``block_k`` override the tile sizes that :func:`tile_plan`
     derives from (S, causal); leave them out.
 
-    q: (B, S, H, D), k/v: (B, S, Hkv, D) — the models' layout, H a multiple
-    of Hkv: Q head h reads K/V head ``h // (H // Hkv)`` through the kernels'
-    index maps, and dK/dV sum over the Q heads of a group inside the kernel.
+    q: (B, S, H, D), k: (B, S, Hkv, D), v: (B, S, Hkv, Dv) — the models'
+    layout, H a multiple of Hkv: Q head h reads K/V head ``h // (H // Hkv)``
+    through the kernels' index maps, and dK/dV sum over the Q heads of a
+    group inside the kernel. The values may be of another width than the
+    queries and keys (latent attention: 192 and 128): the V tiles, the
+    accumulator, the result and dV are then ``Dv`` wide, dQ and dK ``D``,
+    and the scale is the query width's, ``D ** -0.5``.
     kv_mask: (B, S) (True/nonzero = attend), or None for all-valid. Returns
-    (B, S, H, D) in q.dtype. Differentiable w.r.t. q/k/v via the flash
+    (B, S, H, Dv) in q.dtype. Differentiable w.r.t. q/k/v via the flash
     backward kernels.
 
     ``dropout_rate`` > 0 applies attention-probability dropout INSIDE the
@@ -679,10 +699,11 @@ def flash_attention(q, k, v, kv_mask=None, *,
     defaults to the unsharded identity.
     """
     b, s, h, d = q.shape
-    if k.shape != v.shape or h % k.shape[2] or k.shape[3] != d:
+    if k.shape[:3] != v.shape[:3] or h % k.shape[2] or k.shape[3] != d:
         raise ValueError(f"flash_attention: q {q.shape} cannot share k "
                          f"{k.shape} / v {v.shape}; the K/V heads must "
-                         f"divide the Q heads")
+                         f"divide the Q heads, and the keys be as wide as "
+                         f"the queries")
     if kv_mask is None:
         kv_mask = jnp.ones((b, s), jnp.int32)
     if dropout_rate > 0.0 and dropout_seed is None:
@@ -711,13 +732,13 @@ def flash_attention(q, k, v, kv_mask=None, *,
     kv_mask = jnp.broadcast_to(
         kv_mask.astype(jnp.int32)[:, None, :], (b, h, s)).reshape(b * h, s)
 
-    def to_bh(x):  # (B, S, H, D) -> (B*H, S, D), by x's own head count
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, d)
+    def to_bh(x):  # (B, S, H, D) -> (B*H, S, D), by x's own heads and width
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, x.shape[3])
 
     out = _flash(to_bh(q), to_bh(k), to_bh(v), kv_mask, seed, d ** -0.5,
                  tile_plan(s, causal, block_q, block_k, window=window),
                  causal, float(dropout_rate))
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)[:, :s_orig]
+    return out.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)[:, :s_orig]
 
 
 def flash_attention_sharded(q, k, v, kv_mask=None, *,

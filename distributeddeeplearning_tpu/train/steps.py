@@ -40,6 +40,7 @@ from distributeddeeplearning_tpu import compat
 from distributeddeeplearning_tpu.analysis import anatomy
 from distributeddeeplearning_tpu.config import TrainConfig, resolve_precision
 from distributeddeeplearning_tpu.models import moe
+from distributeddeeplearning_tpu.ops import kda
 from distributeddeeplearning_tpu.parallel import collectives
 from distributeddeeplearning_tpu.parallel import sharding as shardlib
 from distributeddeeplearning_tpu.parallel import zero
@@ -281,28 +282,36 @@ def _moe_metrics(sown) -> dict:
             "moe_worst_case_layers": sum(by_name["worst_case"])}
 
 
+def _kda_metrics(mutated) -> dict:
+    """Where delta-rule attention layers sowed it (ops/kda.py): the most
+    negative cumulative gate inside a chunk, over the layers."""
+    if kda.KDA_METRICS not in mutated:
+        return {}
+    return {"kda_min_chunk_log_decay": jnp.min(jnp.stack(
+        jax.tree_util.tree_leaves(mutated[kda.KDA_METRICS])))}
+
+
 def _causal_loss_fn(model, config: TrainConfig):
     del config
 
     def loss_fn(params, batch_stats, batch, rng):
-        variables, mutable = {"params": params}, False
+        variables, mutable = {"params": params}, [kda.KDA_METRICS]
         if batch_stats is not None:
             # routed experts: the selection biases move, with no gradient
             variables[moe.ROUTER_STATE] = batch_stats
-            mutable = [moe.ROUTER_STATE, moe.MOE_METRICS]
-        out = model.apply(
+            mutable += [moe.ROUTER_STATE, moe.MOE_METRICS]
+        logits, mutated = model.apply(
             variables, batch["input_ids"],
             attention_mask=batch.get("attention_mask"),
             train=True, rngs={"dropout": rng}, mutable=mutable)
-        logits, mutated = out if mutable else (out, None)
         with jax.named_scope(LOSS_SCOPE):
             loss = losses.causal_lm_loss(
                 logits, batch["input_ids"], batch.get("attention_mask"))
-        if mutated is None:
-            return loss, (None, {"loss": loss})
+        metrics = {"loss": loss, **_kda_metrics(mutated)}
+        if batch_stats is None:
+            return loss, (None, metrics)
         return loss, (mutated[moe.ROUTER_STATE],
-                      {"loss": loss,
-                       **_moe_metrics(mutated[moe.MOE_METRICS])})
+                      {**metrics, **_moe_metrics(mutated[moe.MOE_METRICS])})
 
     return loss_fn
 

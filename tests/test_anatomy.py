@@ -144,6 +144,27 @@ CASES = [
      "attention_full"),
     (STEP + "grads/jvp(AfmoeLM)/layer4/attention/attn_full/transpose",
      "forward", "attention_full"),
+    # a delta-rule layer's convolutions, gates, chunked scan and gated norm
+    # book under its scope, through the scan's loops and their recomputation
+    (STEP + "grads/jvp(KimiLinearLM)/layer0/attention/attn_kda/q_conv/mul",
+     "forward", "attention_kda"),
+    (STEP + "grads/jvp(KimiLinearLM)/layer2/attention/attn_kda/while/body/"
+     "checkpoint/while/body/dot_general", "forward", "attention_kda"),
+    (STEP + "grads/transpose(jvp(KimiLinearLM))/layer2/attention/attn_kda/"
+     "while/body/checkpoint/rematted_computation/exp", "backward",
+     "attention_kda"),
+    (STEP + "grads/jvp(KimiLinearLM)/layer2/attention/attn_kda/o_norm/rsqrt",
+     "forward", "attention_kda"),
+    # latent attention's kernels under its scope; its projections outside
+    (STEP + "grads/jvp(KimiLinearLM)/layer3/attention/attn_mla/flash_fwd/"
+     "cond/branch_0_fun/flash_fwd/pallas_call", "forward", "attention_mla"),
+    (STEP + "grads/transpose(grads)/jvp(KimiLinearLM)/layer3/attention/"
+     "attn_mla/flash_dq/cond/branch_0_fun/flash_dq/pallas_call", "backward",
+     "attention_mla"),
+    (STEP + "grads/jvp(KimiLinearLM)/layer3/attention/kv_b_proj/dot_general",
+     "forward", "attention_other"),
+    (STEP + "grads/jvp(KimiLinearLM)/layer3/attention/kv_a_norm/rsqrt",
+     "forward", "attention_other"),
     (STEP + "grads/jvp(AfmoeLM)/layer2/moe/moe_router/top_k", "forward",
      "moe_routing"),
     (STEP + "grads/jvp(AfmoeLM)/layer2/moe/moe_dispatch/sort", "forward",
@@ -363,10 +384,53 @@ ENTRY %main.9 (x.1: (bf16[8,4], s32[2], bf16[2,4,4])) -> (bf16[8,4]) {{
     assert {k: anatomy.part_of(got[k]) for k in want} == \
         {k: (phase, part) for k, part in want.items()}
     assert got["ragged-dot-none.1"] == select + "/moe_experts"
-    assert got["conditional.1"] == anatomy.SPANS_ITS_BRANCH
+    assert got["conditional.1"].startswith(anatomy.SPANS_ITS_BRANCH)
     durations = {"%conditional.1 = (bf16[8,4]) conditional(%fits.1)": 3.5,
                  "%rows.1 = bf16[8,4] fusion(%gte.1)": 1.0,
                  "%ragged-dot-none.1 = bf16[8,4] custom-call(%gte.2)": 2.0,
                  "%fits.1 = s32[] fusion(%x.1)": 0.5}
     assert anatomy.by_part(durations, got) == {
         (phase, "moe_routing"): 1.5, (phase, "moe_experts"): 2.0}
+
+
+def test_a_while_spans_its_body_and_is_left_out():
+    """A ``lax.scan`` is a ``while`` in the compiled step, and a device trace
+    holds it as one operation that spans every turn of its body (seen on the
+    chip, PR 31): the body's operations book under their own names, each as
+    often as it ran, and the loop's own time is left out, as a
+    ``conditional``'s is. A loop inside a loop is left out the same way."""
+    scope = ("jit(step_fn)/grads/jvp(KimiLinearLM)/layer1/attention/attn_kda/"
+             "while/body/")
+    text = f"""HloModule jit_step_fn
+
+%inner_body.1 (arg.2: (f32[8,4])) -> (f32[8,4]) {{
+  %arg.2 = (f32[8,4]{{1,0}}) parameter(0)
+  %gte.2 = f32[8,4]{{1,0}} get-tuple-element(%arg.2), index=0
+  %state.1 = f32[8,4]{{1,0}} fusion(%gte.2), kind=kLoop, calls=%f.1, metadata={{op_name="{scope}while/body/dot_general"}}
+  ROOT %tuple.2 = (f32[8,4]{{1,0}}) tuple(%state.1)
+}}
+
+%body.1 (arg.1: (f32[8,4])) -> (f32[8,4]) {{
+  %arg.1 = (f32[8,4]{{1,0}}) parameter(0)
+  %gte.1 = f32[8,4]{{1,0}} get-tuple-element(%arg.1), index=0
+  %pairs.1 = f32[8,4]{{1,0}} fusion(%gte.1), kind=kLoop, calls=%f.2, metadata={{op_name="{scope}checkpoint/exp"}}
+  %while.2 = (f32[8,4]{{1,0}}) while(%pairs.1), condition=%cond.2, body=%inner_body.1, metadata={{op_name="{scope}checkpoint/while"}}
+  ROOT %tuple.1 = (f32[8,4]{{1,0}}) tuple(%while.2)
+}}
+
+ENTRY %main.9 (x.1: (f32[8,4])) -> (f32[8,4]) {{
+  %x.1 = (f32[8,4]{{1,0}}) parameter(0)
+  ROOT %while.1 = (f32[8,4]{{1,0}}) while(%x.1), condition=%cond.1, body=%body.1, metadata={{op_name="{scope[:-5]}"}}
+}}
+"""
+    got = anatomy.table(text)
+    assert got["while.1"].startswith(anatomy.SPANS_ITS_BRANCH)
+    assert got["while.2"].startswith(anatomy.SPANS_ITS_BRANCH)
+    # it still says what it belongs to
+    assert anatomy.part_of(got["while.1"]) == ("forward", "attention_kda")
+    durations = {"%while.1 = (f32[8,4]) while(%x.1)": 10.0,
+                 "%while.2 = (f32[8,4]) while(%pairs.1)": 6.0,
+                 "%pairs.1 = f32[8,4] fusion(%gte.1)": 3.0,
+                 "%state.1 = f32[8,4] fusion(%gte.2)": 6.0}
+    assert anatomy.by_part(durations, got) == {
+        ("forward", "attention_kda"): 9.0}

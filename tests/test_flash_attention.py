@@ -525,3 +525,133 @@ def test_gpt_blocks_recomputed_without_a_policy_run_the_forward_twice(
                                         train=False) ** 2).mean()), params)
     assert calls == {"flash_fwd": forwards * layers, "flash_dq": layers,
                      "flash_dkv": layers}
+
+
+# ---------------------------------------------------------------------------
+# Values of another width than the queries and keys (latent attention: 192
+# and 128): V tiles, the accumulator, the result and dV at the value width,
+# dQ and dK at the key width, the scale from the query width.
+# ---------------------------------------------------------------------------
+
+UNEQUAL_CASES = [
+    # s, h, hkv, d, dv, block, lens
+    pytest.param(128, 2, 2, 24, 16, 32, None, id="narrower-values"),
+    pytest.param(192, 2, 2, 16, 32, 64, None, id="wider-values"),
+    pytest.param(256, 4, 2, 24, 16, 64, [256, 200], id="grouped-ragged"),
+    pytest.param(100, 2, 2, 24, 16, 32, None, id="padded-sequence"),
+]
+
+
+def _unequal_qkv(s, h, hkv, d, dv, b=2):
+    kq, kk, kv, kw = jax.random.split(jax.random.key(21), 4)
+    return (jax.random.normal(kq, (b, s, h, d)),
+            jax.random.normal(kk, (b, s, hkv, d)),
+            jax.random.normal(kv, (b, s, hkv, dv)),
+            jax.random.normal(kw, (b, s, h, dv)))
+
+
+@pytest.mark.parametrize("s,h,hkv,d,dv,block,lens", UNEQUAL_CASES)
+def test_values_of_another_width_match_dense(s, h, hkv, d, dv, block, lens):
+    q, k, v, w = _unequal_qkv(s, h, hkv, d, dv)
+    mask = None if lens is None else _ragged_mask(2, s, lens)
+
+    def dense(q, k, v):
+        kk, vv = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
+        return dense_reference(q, kk, vv, mask, causal=True)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask, causal=True, block_q=block,
+                               block_k=block)
+
+    got, want = flash(q, k, v), dense(q, k, v)
+    assert got.shape == (2, s, h, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    grads = [jax.grad(lambda *a, f=f: (f(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v) for f in (flash, dense)]
+    for name, a, b in zip("qkv", *grads):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=5e-5, err_msg=f"d{name}")
+
+
+def test_keys_must_be_as_wide_as_the_queries():
+    q, k, v, _ = _unequal_qkv(64, 2, 2, 24, 16)
+    with pytest.raises(ValueError, match="as wide as"):
+        flash_attention(q, v, v, causal=True)
+    from distributeddeeplearning_tpu.ops.attention import multihead_attention
+    for impl in ("ring", "zigzag"):
+        with pytest.raises(ValueError, match="values as wide as"):
+            multihead_attention(q, k, v, None, impl=impl, causal=True,
+                                dtype=jnp.float32)
+
+
+def _unequal_block_loss(policy):
+    """`_block_loss` with values half as wide as the queries and keys."""
+    def block(x, w, wv):
+        q, k = (jnp.einsum("bshd,de->bshe", x, w[i]) for i in range(2))
+        v = jnp.einsum("bshd,de->bshe", x, wv)
+        return jnp.tanh(flash_attention(q, k, v, causal=True))
+
+    if policy != "kept":
+        block = jax.checkpoint(block, policy=policy)
+    return lambda x, w, wv: (block(x, w, wv) ** 2).sum()
+
+
+@pytest.mark.parametrize("policy,forwards", [
+    pytest.param(None, 2, id="recomputed-no-policy"),
+    pytest.param(_names(FLASH_OUT, FLASH_LSE), 1, id="result-and-lse")])
+def test_the_kept_result_keeps_its_meaning_at_unequal_widths(
+        block_inputs, policy, forwards):
+    x, w = block_inputs
+    wv = jax.random.normal(jax.random.key(13), (16, 8)) * 0.25
+    want = jax.grad(_unequal_block_loss("kept"), argnums=(0, 1, 2))(x, w, wv)
+    grad = jax.grad(_unequal_block_loss(policy), argnums=(0, 1, 2))
+    assert flash_kernel_calls(grad, x, w, wv) == {
+        "flash_fwd": forwards, "flash_dq": 1, "flash_dkv": 1}
+    for got, kept in zip(grad(x, w, wv), want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(kept))
+
+
+def _kernel_types(fn, *args):
+    """{kernel: (operand types, result types)} of ``fn``'s program lowered
+    for the TPU platform: the tensors each Mosaic call reads and writes."""
+    import re
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    out = {}
+    for line in text.splitlines():
+        m = re.search(r'kernel_name = "(flash_\w+)"', line)
+        if m:
+            sig = line.rsplit(" : ", 1)[1]
+            ins, outs = sig.split(" -> ")
+            out[m.group(1)] = (re.findall(r"tensor<([^>]+)>", ins)[3:],
+                               re.findall(r"tensor<([^>]+)>", outs))
+    return out
+
+
+def test_equal_widths_trace_to_the_kernels_they_traced_to():
+    """The benchmark's two older cells call with one width; what each kernel
+    reads and writes there is what it read and wrote before values could be
+    of another width (the shapes PERF_LEDGER.jsonl names the kernels by),
+    and at 192 / 128 only the V side, the result and dV change."""
+    def grads(d, dv, hkv):
+        shapes = [(1, 1024, 4, d), (1, 1024, hkv, d), (1, 1024, hkv, dv)]
+        args = [jnp.zeros(s, jnp.bfloat16) for s in shapes]
+        return _kernel_types(
+            jax.grad(lambda q, k, v: flash_attention(
+                q, k, v, causal=True).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2)), *args)
+
+    def expect(d, dv, hkv):
+        q, o = f"4x1024x{d}xbf16", f"4x1024x{dv}xbf16"
+        k, v = f"{hkv}x1024x{d}xbf16", f"{hkv}x1024x{dv}xbf16"
+        mask, vec = "4x1x1024xi32", "4x1x1024xf32"
+        backward = [q, k, v, mask, o, vec, vec]
+        return {"flash_fwd": ([q, k, v, mask], [o, vec]),
+                "flash_dq": (backward, [q]),
+                "flash_dkv": (backward, [k, v])}
+
+    assert grads(64, 64, 4) == expect(64, 64, 4)       # gpt2's heads
+    assert grads(128, 128, 1) == expect(128, 128, 1)   # trinity's, grouped
+    assert grads(192, 128, 4) == expect(192, 128, 4)   # latent attention's

@@ -206,3 +206,91 @@ def test_fused_linear_bn_compiles_for_v5e(compile_for, m, k, n):
     text = compile_for(jax.grad(loss, argnums=(0, 3, 4, 5)),
                        ((m, k), BF16), vec, vec, vec, vec, ((k, n), BF16))
     assert text.count("tpu_custom_call") >= 3  # fwd, dx, dw
+
+
+def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
+    """The benchmark's kimi_linear cell: the whole mixed-precision AdamW step
+    of `kimi_linear_ep32` at one sequence of 8192 tokens, built as
+    `train/loop.build` builds it (`make_gspmd_train_step` on a mesh of the
+    described chip), lowered with shapes and compiled. What the chip's
+    compiler says of it: it fits (7.23 GB of state: float32 masters and
+    Adam's two moments of 602M parameters; 5.94 GB of temporaries, the
+    float32 gradients among them), the latent layer's three flash kernels
+    are there at 192 / 128, and the chunked delta rule's loops are `while`s:
+    five a KDA layer (forward over groups and over a group's chunks; backward
+    over groups, a group's chunks remade, and back through them) and none
+    for a recomputed forward, whose result and entering states the block
+    keeps."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributeddeeplearning_tpu.config import (
+        DataConfig, OptimizerConfig, ParallelConfig, PrecisionPolicy,
+        TrainConfig)
+    from distributeddeeplearning_tpu.models import model_spec
+    from distributeddeeplearning_tpu.parallel import mesh as meshlib
+    from distributeddeeplearning_tpu.train import optim, steps
+    from distributeddeeplearning_tpu.train.state import TrainState
+
+    seq, vocab = 8192, 20480
+    policy = PrecisionPolicy.mixed()
+    cfg = TrainConfig(
+        model="kimi_linear_ep32", backend=None, global_batch_size=1, seed=0,
+        dtype=policy.compute_dtype, precision=policy, log_every=10 ** 9,
+        attention_impl="flash", parallel=ParallelConfig(data=1),
+        data=DataConfig(synthetic=True, dataset="mlm", seq_len=seq,
+                        vocab_size=vocab),
+        optimizer=OptimizerConfig(
+            name="adamw", learning_rate=1e-5, reference_batch=1,
+            weight_decay=0.1, schedule="constant", warmup_epochs=0.0,
+            beta1=0.9, beta2=0.95))
+    model = model_spec(cfg.model).build(
+        vocab_size=vocab, dtype=BF16, seq_len=seq, attention_impl="flash")
+    mesh = meshlib.make_mesh(cfg.parallel, devices=list(one_chip.device_set))
+    tx, _ = optim.make_optimizer(cfg.optimizer, 1, 10 ** 6, 1)
+
+    def init_fn(rng):
+        variables = model.init({"params": rng, "dropout": rng},
+                               jnp.zeros((1, seq), I32), train=False)
+        return TrainState.create(
+            params=variables["params"],
+            opt_state=tx.init(variables["params"]),
+            batch_stats=steps.model_state(variables), ema_params=None,
+            loss_scale=steps.init_loss_scale(cfg))
+
+    abstract = jax.eval_shape(init_fn, jax.random.key(0))
+    parameters = sum(x.size for x in jax.tree_util.tree_leaves(
+        abstract.params))
+    assert parameters == 602_449_792
+    shardings = jax.tree_util.tree_map(
+        lambda spec: NamedSharding(mesh, spec),
+        nn.logical_to_mesh(nn.get_partition_spec(abstract)),
+        is_leaf=lambda x: isinstance(x, P))
+    state = jax.tree_util.tree_map(   # the shardings are a prefix tree
+        lambda s, sub: jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            sub),
+        shardings, abstract, is_leaf=lambda x: isinstance(x, NamedSharding))
+    everywhere = NamedSharding(mesh, P())
+    ids = jax.ShapeDtypeStruct((1, seq), I32, sharding=everywhere)
+    rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=everywhere)
+    step = steps.make_gspmd_train_step(model, tx, mesh, cfg, shardings,
+                                       "tokens", "causal")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = step.lower(
+            state, {"input_ids": ids, "attention_mask": ids}, rng).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    memory = compiled.memory_analysis()
+    print("state:", memory.argument_size_in_bytes, "temporaries:",
+          memory.temp_size_in_bytes)
+    assert memory.argument_size_in_bytes == pytest.approx(
+        12 * parameters, rel=0.001)
+    assert memory.temp_size_in_bytes < 1.1 * 5.94e9
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert len(re.findall(rf"%{name}\S* = ", text)) == 1, name
+    assert re.search(r"%flash_fwd\S* = \(bf16\[32,8192,128\]", text)
+    assert len(re.findall(r" while\(", text)) == 4 * 5

@@ -8,9 +8,11 @@ time flash against dense, and read each kernel's device time from a trace.
     python tools/validate_flash_tpu.py [--shape 8,512,12,64] [--causal]
         [--window 2048] [--skip-timing] [--tiles 512x512,256x256]
 
-With no ``--shape`` it does all of that at the two shapes the models run:
-the benchmark cell's (16,1024,12,64, causal) and BERT's (8,512,12,64, not
-causal); both with a key-padding mask and dropout 0.1 in the kernel times.
+With no ``--shape`` it does all of that at the shapes the models run
+(``MODEL_SHAPES``): the gpt2 cell's (16,1024,12,64, causal) and BERT's
+(8,512,12,64, not causal), both with a key-padding mask and dropout 0.1 in
+the kernel times, and latent attention's in the kimi_linear cell
+(1,8192,32,192/128, causal: values 128 wide) beside the same call at 128/128.
 A heads field ``32/4`` is 32 Q heads on 4 K/V heads, and ``--window`` cuts
 the causal triangle to a band: ``--shape 1,8192,32/4,128 --causal --window
 2048`` is a sliding layer of the trinity_mini cell, and without ``--window``
@@ -84,12 +86,12 @@ def _dropout_fits(shape) -> bool:
     return b * h * s * s <= MASK_ELEMENTS_MAX
 
 
-def _inputs(shape, kv_heads=None):
+def _inputs(shape, kv_heads=None, v_dim=None):
     b, s, h, d = shape
     rng = np.random.default_rng(0)
     kv_shape = (b, s, kv_heads or h, d)
     q, k, v = (jnp.asarray(rng.standard_normal(sh), jnp.bfloat16)
-               for sh in (shape, kv_shape, kv_shape))
+               for sh in (shape, kv_shape, kv_shape[:3] + (v_dim or d,)))
     # Padding mask with ragged valid lengths, incl. one fully-valid row.
     lens = np.r_[s, rng.integers(s // 4, s, b - 1)]
     mask = jnp.asarray(np.arange(s)[None, :] < lens[:, None])
@@ -97,7 +99,7 @@ def _inputs(shape, kv_heads=None):
 
 
 def check_correctness(shape=(8, 512, 12, 64), causal=False, window=None,
-                      kv_heads=None) -> bool:
+                      kv_heads=None, v_dim=None) -> bool:
     """Compiled flash vs the dense reference at ``shape`` (B,S,H,D), bf16:
     forward and gradients, without and with dropout. One JSON line each."""
     from distributeddeeplearning_tpu.ops.flash_attention import (
@@ -105,7 +107,7 @@ def check_correctness(shape=(8, 512, 12, 64), causal=False, window=None,
     from distributeddeeplearning_tpu.ops.hash_dropout import dense_keep_mask
 
     b, s, h, _ = shape
-    q, k, v, mask = _inputs(shape, kv_heads)
+    q, k, v, mask = _inputs(shape, kv_heads, v_dim)
     valid = mask[:, :, None, None].astype(jnp.float32)
     ok = True
     cases = (("", 0.0), ("dropout_", RATE))
@@ -130,7 +132,8 @@ def check_correctness(shape=(8, 512, 12, 64), causal=False, window=None,
         fwd_err = float(jnp.abs((out_f - out_r) * valid).max())
         ok_fwd = fwd_err < 2e-2  # bf16 inputs, f32 accumulation
         rec = {"check": f"flash_{label}forward", "shape": list(shape),
-               "kv_heads": k.shape[2], "causal": causal, "window": window,
+               "kv_heads": k.shape[2], "v_dim": v.shape[3],
+               "causal": causal, "window": window,
                "max_abs_err": fwd_err, "ok": ok_fwd}
         if rate:
             rec["dropped_frac_ref"] = round(1.0 - float(jax.jit(
@@ -150,8 +153,9 @@ def check_correctness(shape=(8, 512, 12, 64), causal=False, window=None,
             ok_bwd &= errs[name] < 3e-2
         print(json.dumps({"check": f"flash_{label}backward",
                           "shape": list(shape), "kv_heads": k.shape[2],
-                          "causal": causal, "window": window,
-                          "rel_err": errs, "ok": ok_bwd}), flush=True)
+                          "v_dim": v.shape[3], "causal": causal,
+                          "window": window, "rel_err": errs,
+                          "ok": ok_bwd}), flush=True)
         ok &= ok_fwd and ok_bwd
     return ok
 
@@ -165,11 +169,12 @@ def _timed(fn, *args, iters=20):
     return (time.perf_counter() - t0) / iters
 
 
-def time_kernels(shape, causal, window=None, kv_heads=None) -> None:
+def time_kernels(shape, causal, window=None, kv_heads=None,
+                 v_dim=None) -> None:
     from distributeddeeplearning_tpu.ops.flash_attention import (
         flash_attention)
 
-    q, k, v, mask = _inputs(shape, kv_heads)
+    q, k, v, mask = _inputs(shape, kv_heads, v_dim)
     flash = jax.jit(functools.partial(flash_attention, causal=causal,
                                       window=window))
     flash_do = jax.jit(functools.partial(
@@ -201,7 +206,7 @@ KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
 def kernel_times(shape, causal, block_q=None, block_k=None, iters=10,
-                 window=None, kv_heads=None) -> None:
+                 window=None, kv_heads=None, v_dim=None) -> None:
     """The plan at ``shape`` and the device time of each of the three
     kernels, from a profiler trace of ``iters`` forward+backward calls with
     the key-padding mask and dropout in the kernels: ms a call, and us a
@@ -211,7 +216,7 @@ def kernel_times(shape, causal, block_q=None, block_k=None, iters=10,
         flash_attention, tile_plan)
 
     b, s, h, _ = shape
-    q, k, v, mask = _inputs(shape, kv_heads)
+    q, k, v, mask = _inputs(shape, kv_heads, v_dim)
     rate = RATE if _dropout_fits(shape) else 0.0
     step = jax.jit(jax.grad(
         lambda q, k, v: flash_attention(
@@ -242,7 +247,8 @@ def kernel_times(shape, causal, block_q=None, block_k=None, iters=10,
     ms = {name: ns[name] / iters / 1e6 for name in KERNELS}
     print(json.dumps({
         "check": "kernel_times", "shape": list(shape),
-        "kv_heads": k.shape[2], "causal": causal, "dropout": rate,
+        "kv_heads": k.shape[2], "v_dim": v.shape[3], "causal": causal,
+        "dropout": rate,
         "plan": plan._asdict(),
         "visited_share": round(plan.visited / plan.total, 4),
         "ms_a_call": {n: round(t, 4) for n, t in ms.items()},
@@ -254,14 +260,22 @@ def kernel_times(shape, causal, block_q=None, block_k=None, iters=10,
 
 
 # What the models run: the benchmark cell's attention (gpt2_small, 16 x
-# 1024, causal) and BERT-base's (8 x 512, key-padding mask only).
-MODEL_SHAPES = (((16, 1024, 12, 64), True), ((8, 512, 12, 64), False))
+# 1024, causal), BERT-base's (8 x 512, key-padding mask only), and latent
+# attention's in the kimi_linear cell (32 heads, queries and keys 192 wide,
+# values 128, one causal sequence of 8192) with the same call at one width
+# of 128 beside it, so that the kernels' times at the new widths stand
+# beside those at the old. (shape, causal, values' width if another)
+MODEL_SHAPES = (((16, 1024, 12, 64), True, None),
+                ((8, 512, 12, 64), False, None),
+                ((1, 8192, 32, 128), True, None),
+                ((1, 8192, 32, 192), True, 128))
 
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--shape", default=None,
-                   help="B,S,H,D or B,S,H/Hkv,D (default: the two shapes "
+                   help="B,S,H,D, with H/Hkv for fewer K/V heads and D/Dv "
+                        "for values of another width (default: the shapes "
                         "the models run)")
     p.add_argument("--causal", action="store_true")
     p.add_argument("--window", type=int, default=None,
@@ -279,14 +293,16 @@ def main(argv=None) -> int:
     if args.shape is not None:
         b, s, heads, d = args.shape.split(",")
         heads, _, kv = heads.partition("/")
+        d, _, dv = d.partition("/")
         kv_heads = int(kv) if kv else None
         shape = (int(b), int(s), int(heads), int(d))
-    cases = MODEL_SHAPES if args.shape is None else ((shape, args.causal),)
-    kw = dict(window=args.window, kv_heads=kv_heads)
+    cases = (MODEL_SHAPES if args.shape is None
+             else ((shape, args.causal, int(dv) if dv else None),))
     tiles = [(None, None)] + [tuple(int(x) for x in t.split("x"))
                               for t in args.tiles.split(",") if t]
     ok = True
-    for shape, causal in cases:
+    for shape, causal, v_dim in cases:
+        kw = dict(window=args.window, kv_heads=kv_heads, v_dim=v_dim)
         ok &= check_correctness(shape, causal, **kw)
         if not args.skip_timing:
             if _dropout_fits(shape):  # its dense side makes (B,H,S,S) too

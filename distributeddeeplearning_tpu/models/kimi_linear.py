@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 from distributeddeeplearning_tpu.models.moe import ROUTED_OUT, RoutedExperts
 from distributeddeeplearning_tpu.ops import kda as kda_ops
+from distributeddeeplearning_tpu.ops import kda_stages
 from distributeddeeplearning_tpu.ops.attention import multihead_attention
 from distributeddeeplearning_tpu.ops.embedding import embedding_lookup
 from distributeddeeplearning_tpu.ops.flash_attention import (FLASH_LSE,
@@ -51,7 +52,6 @@ from distributeddeeplearning_tpu.ops.flash_attention import (FLASH_LSE,
 Dtype = Any
 
 KDA, MLA = "kda", "mla"
-_L2_EPS = 1e-6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,27 +102,20 @@ def _rms_norm(cfg: KimiLinearConfig, dtype, name: str):
                       param_dtype=jnp.float32, name=name)
 
 
-class ShortConv(nn.Module):
-    """Depthwise causal convolution along the sequence, no bias: ``y_t[c] =
-    sum_j w[j, c] x_{t-K+1+j}[c]``, zeros before the first token."""
+class _Held(nn.Module):
+    """One float32 parameter under a module's name of its own. The
+    convolutions' taps and the gated norm's scale are applied inside the
+    fused stages (ops/kda_stages.py), and keep the paths they were
+    initialised, stored and compared by: ``q_conv/kernel``,
+    ``o_norm/scale``."""
 
-    size: int
-    dtype: Dtype
+    leaf: str
+    shape: tuple
+    init: Any
 
     @nn.compact
-    def __call__(self, x):
-        taps = self.param(
-            "kernel", nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), (None, "heads")),
-            (self.size, x.shape[-1]), jnp.float32).astype(self.dtype)
-        s = x.shape[1]
-        xp = jnp.pad(x, ((0, 0), (self.size - 1, 0), (0, 0)))
-        return sum(taps[j] * xp[:, j:j + s] for j in range(self.size))
-
-
-def _l2_normalise(x):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + _L2_EPS)
+    def __call__(self):
+        return self.param(self.leaf, self.init, self.shape, jnp.float32)
 
 
 def _a_log_init(key, shape, dtype):
@@ -144,7 +137,6 @@ class KdaAttention(nn.Module):
     @nn.compact
     def __call__(self, x, pad_mask):
         cfg = self.cfg
-        b, s, _ = x.shape
         h, d = cfg.kda_heads, cfg.kda_head_dim
         f32 = jnp.float32
         # a padded token reads as the zeros before the sequence do: it puts
@@ -163,23 +155,28 @@ class KdaAttention(nn.Module):
             _dense(d, ("embed", None), "g_a_proj", self.dtype)(x))
         a_log = self.param("A_log", _a_log_init, (h,), f32)
         dt_bias = self.param("dt_bias", _dt_bias_init, (h * d,), f32)
-        o_norm = _rms_norm(cfg, self.dtype, "o_norm")
+        # depthwise causal convolutions along the sequence, no bias: (taps,
+        # channels), ``y_t[c] = sum_j w[j, c] x_{t-K+1+j}[c]``
+        taps = [_Held("kernel", (cfg.conv_size, h * d),
+                      nn.with_logical_partitioning(
+                          nn.initializers.normal(0.02), (None, "heads")),
+                      name=n + "_conv")() for n in ("q", "k", "v")]
+        o_scale = _Held("scale", (d,), nn.initializers.ones, name="o_norm")()
         with jax.named_scope("attn_kda"):
-            q, k, v = (nn.silu(ShortConv(cfg.conv_size, self.dtype,
-                                         name=n + "_conv")(proj[n]))
-                       .reshape(b, s, h, d) for n in ("q", "k", "v"))
-            q = (_l2_normalise(q) * d ** -0.5).astype(self.dtype)
-            k = _l2_normalise(k).astype(self.dtype)
-            g = (-jnp.exp(a_log)[:, None]
-                 * nn.softplus(f.astype(f32) + dt_bias).reshape(b, s, h, d)
-                 * pad_mask[..., None, None])
-            beta = nn.sigmoid(beta.astype(f32)) * pad_mask[..., None]
+            # conv, SiLU, the L2 norms and the gate in one pass, written as
+            # the operands the chunked operator scans over; the gated norm
+            # in another, back to (B, S, H*D) (ops/kda_stages.py)
+            q, k, v, g = kda_stages.kda_in(
+                (proj["q"], proj["k"], proj["v"], f), taps, a_log, dt_bias,
+                pad_mask)
+            beta = kda_ops.lay_out(
+                nn.sigmoid(beta.astype(f32)) * pad_mask[..., None])
             self.sow(kda_ops.KDA_METRICS, "min_chunk_log_decay",
                      jax.lax.stop_gradient(kda_ops.min_chunk_log_decay(g)))
-            o = kda_ops.kda_chunked(q, k, v, g, beta)
-            o = o_norm(o) * nn.sigmoid(gate.reshape(b, s, h, d))
+            o = kda_ops.kda_groups(q, k, v, g, beta)
+            o = kda_stages.kda_out(o, gate, o_scale, eps=cfg.rms_eps)
         return _dense(cfg.hidden_size, ("heads", "embed"), "o_proj",
-                      self.dtype)(o.reshape(b, s, h * d))
+                      self.dtype)(o)
 
 
 class MlaAttention(nn.Module):

@@ -59,9 +59,27 @@ states are named (``KDA_OUT``, ``KDA_STATES``) for a recomputed block to keep.
 A last short chunk is padded with ``g = 0, beta = 0``, which leaves the state
 as it is.
 
-Layout: the models' ``(B, S, H, D)``; ``g`` is ``(B, S, H, d_k)`` float32,
-``beta`` ``(B, S, H)``. Products take their operands in ``q``'s type and add
-up in float32; the state, the gates and the inverse are float32.
+Layout: :func:`kda_recurrent` and :func:`kda_chunked` take the models' ``(B,
+S, H, D)``; ``g`` is ``(B, S, H, d_k)`` float32, ``beta`` ``(B, S, H)``.
+Products take their operands in ``q``'s type and add up in float32; the
+state, the gates and the inverse are float32.
+
+**The layout the groups are scanned in, and who writes it.** ``_groups``
+scans operands ``(groups, group, B*H, C, d)``: group of chunks, chunk of the
+group, batch row and head merged (``b * H + h``), token of the chunk, channel
+(:func:`layout` has the counts, :func:`lay_out` the transpose; a sequence is
+padded to whole groups with ``g = 0, beta = 0`` and zeros elsewhere).
+:func:`kda_groups` is the operator on operands that are already so, and gives
+its result so; :func:`kda_chunked` lays out with XLA, calls it, and lays the
+result back. A model does neither relayout as a pass of its own: the
+pointwise stages on either side of the operator (ops/kda_stages.py: the
+short convolutions with SiLU, the L2 norms and the gate before it, the gated
+RMSNorm after it) are fused kernels whose block index maps read ``(B, S,
+H*D)`` and write this layout, and back. What they owe each other: q, k, v in
+the model's compute type and g float32, every padded or masked row with ``g
+= 0``; ``beta`` (B, S, H: small) is laid out by :func:`lay_out`; the result
+comes back in ``v``'s type, padded rows and all, and the output stage drops
+them.
 """
 
 from __future__ import annotations
@@ -81,6 +99,8 @@ _PRECISION = jax.lax.Precision.HIGH
 # tokens a chunk of the chunked form, and of the counter that bounds its
 # exponents (:func:`min_chunk_log_decay`)
 CHUNK = 64
+# chunks prepared at once, a group of the outer scan
+GROUP = 8
 # the collection a layer sows :func:`min_chunk_log_decay` into; the step's
 # metrics carry the smallest over the layers (train/steps.py)
 KDA_METRICS = "kda_metrics"
@@ -112,16 +132,14 @@ def kda_recurrent(q, k, v, g, beta, initial_state=None, *,
     return (out, state) if return_state else out
 
 
-def min_chunk_log_decay(g, chunk: int = CHUNK):
-    """The most negative cumulative gate any chunk of :func:`kda_chunked`
-    reaches: the smallest, over chunks, heads and channels, of a chunk's
+def min_chunk_log_decay(g):
+    """The most negative cumulative gate any chunk of the chunked form
+    reaches, from the gates as its groups are scanned (:func:`lay_out`: ...,
+    C, d_k): the smallest, over chunks, heads and channels, of a chunk's
     summed gates (gates are never positive, so a chunk's sum is its lowest
     point). It is what bounds the chunked form's arithmetic: every
     ``exp`` there is of a difference of cumulative gates inside one chunk."""
-    b, s, h, dk = g.shape
-    pad = -s % chunk
-    g = jnp.pad(g.astype(jnp.float32), ((0, 0), (0, pad), (0, 0), (0, 0)))
-    return g.reshape(b, (s + pad) // chunk, chunk, h, dk).sum(2).min()
+    return g.astype(jnp.float32).sum(-2).min()
 
 
 def _mm(a, b):
@@ -346,37 +364,71 @@ def _groups_bwd(sub, residuals, cotangents):
 _groups.defvjp(_groups_fwd, _groups_bwd)
 
 
-def kda_chunked(q, k, v, g, beta, initial_state=None, *, chunk: int = CHUNK,
-                sub: int = 16, group: int = 8, return_state: bool = False):
-    """The chunked form (module text); same arguments and results as
-    :func:`kda_recurrent`. ``chunk`` tokens a chunk (``sub`` times a power
-    of two), ``group`` chunks prepared at once."""
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
-    if chunk % sub or (chunk // sub) & (chunk // sub - 1):
-        raise ValueError(f"chunk {chunk} must be sub {sub} times a power of "
-                         f"two")
+def layout(s: int, chunk: int = CHUNK, group: int = GROUP):
+    """How ``s`` tokens go into groups of chunks: (chunks a group, groups,
+    rows of padding after the last token). A sequence shorter than a group
+    is one group of its own chunks."""
     n = -(-s // chunk)
     group = min(group, n)
     groups = -(-n // group)
-    pad = groups * group * chunk - s
+    return group, groups, groups * group * chunk - s
 
-    def split(x):
-        """(B, S, H, ...) -> (groups, group, B*H, C, ...), in one transpose"""
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape((b, groups, group, chunk) + x.shape[2:])
-        x = jnp.moveaxis(x, (1, 2, 4), (0, 1, 3))
-        return x.reshape(x.shape[:2] + (b * h,) + x.shape[4:])
 
-    state = (jnp.zeros((b * h, dk, dv), jnp.float32) if initial_state is None
-             else initial_state.astype(jnp.float32).reshape(b * h, dk, dv))
-    state, out = _groups(
-        state, (split(q), split(k), split(v), split(g.astype(jnp.float32)),
-                split(beta.astype(jnp.float32))), sub)
-    # (groups, group, B*H, C, dv) -> (B, S, H, dv)
-    out = out.reshape(groups, group, b, h, chunk, dv)
-    out = jnp.moveaxis(out, (0, 1, 3), (1, 2, 4)).reshape(
-        b, groups * group * chunk, h, dv)[:, :s]
-    state = state.reshape(b, h, dk, dv)
+def lay_out(x, chunk: int = CHUNK, group: int = GROUP):
+    """(B, S, H, ...) -> (groups, group, B*H, C, ...), the layout the groups
+    are scanned in: padded with zeros to whole groups, in one transpose."""
+    b, s, h = x.shape[:3]
+    group, groups, pad = layout(s, chunk, group)
+    x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    x = x.reshape((b, groups, group, chunk) + x.shape[2:])
+    x = jnp.moveaxis(x, (1, 2, 4), (0, 1, 3))
+    return x.reshape(x.shape[:2] + (b * h,) + x.shape[4:])
+
+
+def lay_back(x, b: int, s: int):
+    """:func:`lay_out`'s inverse for a result: (groups, group, B*H, C, d) ->
+    (B, S, H, d), the padded rows dropped."""
+    groups, group, bh, chunk, d = x.shape
+    x = x.reshape(groups, group, b, bh // b, chunk, d)
+    return jnp.moveaxis(x, (0, 1, 3), (1, 2, 4)).reshape(
+        b, groups * group * chunk, bh // b, d)[:, :s]
+
+
+def kda_groups(q, k, v, g, beta, initial_state=None, *, sub: int = 16,
+               return_state: bool = False):
+    """The chunked form on operands already laid out (:func:`lay_out`;
+    ops/kda_stages.py writes them so): q, k, g (groups, group, B*H, C, d_k),
+    v (..., d_v), beta (groups, group, B*H, C); g and beta float32, padded
+    rows ``g = 0, beta = 0``; ``initial_state`` (B*H, d_k, d_v) or None for
+    zeros. Returns o (groups, group, B*H, C, d_v) in ``v``'s type, and the
+    last state (B*H, d_k, d_v) with ``return_state``."""
+    chunk = q.shape[3]
+    if chunk % sub or (chunk // sub) & (chunk // sub - 1):
+        raise ValueError(f"chunk {chunk} must be sub {sub} times a power of "
+                         f"two")
+    state = (jnp.zeros((q.shape[2], q.shape[4], v.shape[4]), jnp.float32)
+             if initial_state is None else initial_state.astype(jnp.float32))
+    state, out = _groups(state, (q, k, v, g, beta), sub)
     return (out, state) if return_state else out
 
+
+def kda_chunked(q, k, v, g, beta, initial_state=None, *, chunk: int = CHUNK,
+                sub: int = 16, group: int = GROUP,
+                return_state: bool = False):
+    """The chunked form (module text); same arguments and results as
+    :func:`kda_recurrent`. ``chunk`` tokens a chunk (``sub`` times a power
+    of two), ``group`` chunks prepared at once. Lays the operands out with
+    XLA and runs :func:`kda_groups`."""
+    b, s, h, dk = q.shape
+    group = layout(s, chunk, group)[0]
+    if initial_state is not None:
+        initial_state = initial_state.reshape((b * h,)
+                                              + initial_state.shape[2:])
+    out, state = kda_groups(
+        *(lay_out(x, chunk, group)
+          for x in (q, k, v, g.astype(jnp.float32),
+                    beta.astype(jnp.float32))),
+        initial_state, sub=sub, return_state=True)
+    out = lay_back(out, b, s)
+    state = state.reshape((b, h) + state.shape[1:])
+    return (out, state) if return_state else out

@@ -84,7 +84,8 @@ def test_nothing_overflows_where_a_chunks_decay_would():
     assert not np.isfinite(np.exp(np.float32(20.0 * 64)))
     out, state = kda.kda_chunked(*args, chunk=64, return_state=True)
     assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(state).all())
-    assert float(kda.min_chunk_log_decay(args[3], 64)) == -1280.0
+    laid = kda.lay_out(args[3], 64)
+    assert float(kda.min_chunk_log_decay(laid)) == -1280.0
 
 
 @pytest.mark.parametrize("cut", [64, 40, 100])

@@ -187,14 +187,14 @@ def test_a_padded_tail_leaves_each_kda_layers_last_state(seeded, monkeypatch):
     """What a decode step would start from: the state after a row padded at
     the end is the state after the row alone, in every KDA layer."""
     params, extra, batch = seeded
-    states, chunked = [], kda.kda_chunked
+    states, groups = [], kda.kda_groups
 
     def keep_state(*args):
-        out, state = chunked(*args, return_state=True)
+        out, state = groups(*args, return_state=True)
         states.append(state)
         return out
 
-    monkeypatch.setattr(kda, "kda_chunked", keep_state)
+    monkeypatch.setattr(kda, "kda_groups", keep_state)
     with jax.default_matmul_precision("highest"):
         for ids, mask in (padded(batch, 0, 0), padded(batch, 0, 7)):
             program_loss(tiny_model(), unflatten(params),
@@ -235,15 +235,26 @@ def recomputed(seeded):
             unflatten(params)))
 
 
+# the leaves that the gates' cotangent alone reaches
+GATE_LEAVES = ("/dt_bias", "/A_log", "/f_a_proj/kernel", "/f_b_proj/kernel")
+
+
 @pytest.mark.parametrize("leaf", LEAVES)
 def test_a_recomputed_blocks_gradient_is_the_kept_ones(both, recomputed,
                                                        leaf):
     """To rounding: the chunked scan's recomputed groups are compiled apart
-    from the first pass, unlike a kernel's bits."""
+    from the first pass, unlike a kernel's bits. The operator's cotangent
+    for the gates comes out of the two passes 4e-7 to 5e-7 of its largest
+    entry apart, and the leaves it alone reaches are sums of it over every
+    token with both signs: they read 1.3e-6 to 3.6e-6 of their largest entry
+    over seeds 0 to 5 with the stages as array lines and 2.2e-6 to 3.8e-6
+    as kernels (the same cotangent's gap, summed in another order), every
+    other leaf under 1.3e-6 either way (PERF.md, PR 32)."""
     want = both["grads"][leaf]
+    limit = 5e-6 if leaf.endswith(GATE_LEAVES) else 2e-6
     np.testing.assert_allclose(
         np.asarray(recomputed[leaf]), np.asarray(want), rtol=0,
-        atol=2e-6 * float(jnp.abs(want).max()))
+        atol=limit * float(jnp.abs(want).max()))
 
 
 def test_mixed_precision_stays_in_its_band(seeded, both):

@@ -16,6 +16,7 @@ only one process may load libtpu and every xdist worker imports this file.
 """
 
 import functools
+import math
 import re
 
 import flax.linen as nn
@@ -208,14 +209,49 @@ def test_fused_linear_bn_compiles_for_v5e(compile_for, m, k, n):
     assert text.count("tpu_custom_call") >= 3  # fwd, dx, dw
 
 
+@pytest.mark.parametrize("stage,grad,kernel", [
+    ("in", False, "kda_in_fwd"), ("in", True, "kda_in_bwd"),
+    ("out", False, "kda_out_fwd"), ("out", True, "kda_out_bwd")])
+def test_kda_stages_compile_for_v5e(compile_for, stage, grad, kernel):
+    """The fused stages round the chunked delta rule at the kimi cell's
+    widths: one sequence of 8192 tokens, 32 heads of 128, 4 taps, bf16
+    projections; a grid step is four heads' group of 8 chunks of 64."""
+    from distributeddeeplearning_tpu.ops import kda_stages
+
+    model, laid = (1, 8192, 4096), (16, 8, 32, 64, 128)
+    if stage == "in":
+        def fn(pq, pk, pv, pf, wq, wk, wv, a_log, dt_bias, mask):
+            return kda_stages.kda_in((pq, pk, pv, pf), (wq, wk, wv), a_log,
+                                     dt_bias, mask)
+        shapes = ([(model, BF16)] * 4 + [((4, 4096), F32)] * 3
+                  + [((32,), F32), ((4096,), F32), (model[:2], jnp.bool_)])
+        wrt = tuple(range(9))
+    else:
+        def fn(o, gate, scale):
+            return kda_stages.kda_out(o, gate, scale, eps=1e-5)
+        shapes = [(laid, BF16), (model, BF16), ((128,), F32)]
+        wrt = (0, 1, 2)
+    if grad:
+        # weighted, so that the forward is not dead under the gradient
+        value = fn
+        fn = jax.grad(lambda *a: sum(
+            (o.astype(F32) ** 2).sum()
+            for o in jax.tree_util.tree_leaves(value(*a))), argnums=wrt)
+    text = compile_for(fn, *shapes)
+    assert f"%{kernel}" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        2 if grad else 1)
+
+
 def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
     """The benchmark's kimi_linear cell: the whole mixed-precision AdamW step
     of `kimi_linear_ep32` at one sequence of 8192 tokens, built as
     `train/loop.build` builds it (`make_gspmd_train_step` on a mesh of the
     described chip), lowered with shapes and compiled. What the chip's
     compiler says of it: it fits (7.23 GB of state: float32 masters and
-    Adam's two moments of 602M parameters; 5.94 GB of temporaries, the
-    float32 gradients among them), the latent layer's three flash kernels
+    Adam's two moments of 602M parameters; 4.83 GB of temporaries, the
+    float32 gradients among them; 5.40 before the pointwise stages round
+    the delta rule were kernels), the latent layer's three flash kernels
     are there at 192 / 128, and the chunked delta rule's loops are `while`s:
     five a KDA layer (forward over groups and over a group's chunks; backward
     over groups, a group's chunks remade, and back through them) and none
@@ -288,9 +324,29 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
           memory.temp_size_in_bytes)
     assert memory.argument_size_in_bytes == pytest.approx(
         12 * parameters, rel=0.001)
-    assert memory.temp_size_in_bytes < 1.1 * 5.94e9
+    assert memory.temp_size_in_bytes < 1.1 * 4.83e9
     text = compiled.as_text()
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert len(re.findall(rf"%{name}\S* = ", text)) == 1, name
     assert re.search(r"%flash_fwd\S* = \(bf16\[32,8192,128\]", text)
     assert len(re.findall(r" while\(", text)) == 4 * 5
+    # the pointwise stages round the operator (ops/kda_stages.py): a KDA
+    # layer's input stage forward, again under the block's remat, and
+    # backward; its output stage likewise (the recomputed forward feeds the
+    # output projection's weight gradient); each under the model's scope,
+    # so the trace books it as `attention_kda`
+    calls = {name: re.findall(rf"%{name}\S* = .*", text)
+             for name in ("kda_in_fwd", "kda_in_bwd", "kda_out_fwd",
+                          "kda_out_bwd")}
+    assert {k: len(v) for k, v in calls.items()} == {
+        "kda_in_fwd": 8, "kda_in_bwd": 4, "kda_out_fwd": 8, "kda_out_bwd": 4}
+    assert all("attn_kda" in line for lines in calls.values()
+               for line in lines)
+    # and with the relayouts in their index maps, no pass of its own lays a
+    # float32 (8192, 4096) array of a KDA layer out anew
+    entry = text[text.index("\nENTRY "):]
+    relaid = [m.group(0) for m in re.finditer(
+        r"%\S+ = f32\[([\d,]+)\]\S* (copy|transpose|reshape)\(.*", entry)
+        if "attn_kda" in m.group(0)
+        and math.prod(map(int, m.group(1).split(","))) == seq * 4096]
+    assert not relaid, relaid

@@ -62,14 +62,36 @@ def step(opt: dict, decays, params: dict, grads: dict, state: dict):
     return p, {"trace": trace}
 
 
-def first_gradient(opt: dict, decays, moment: dict, params0: dict) -> dict:
-    """The gradient the optimizer was given at its first step, worked out
-    from its state after that step: Adam's first moment is (1 - beta1) * g;
-    SGD's momentum buffer is g plus the decay term of the starting weights."""
-    if opt["name"] == "adamw":
-        return {k: m / (1 - opt["beta1"]) for k, m in moment.items()}
-    return {k: m - (opt["weight_decay"] * params0[k] if decays(k) else 0.0)
+def _norm(x):
+    return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
+
+
+@jax.jit
+def _adamw_first_norms(moment, scale):
+    return {k: _norm(m / scale) for k, m in moment.items()}
+
+
+@jax.jit
+def _sgd_first_norms(moment, params0, decay, wd):
+    return {k: _norm(m - wd * decay[k] * params0[k])
             for k, m in moment.items()}
+
+
+def first_gradient_norms(opt: dict, decays, moment: dict, params0) -> dict:
+    """Leaf norms of the gradient the optimizer was given at its first step,
+    worked out from its state after that step: Adam's first moment is
+    (1 - beta1) * g; SGD's momentum buffer is g plus the decay term of the
+    starting weights (`params0()` makes them, and is called only there).
+
+    One program, in which a leaf's gradient lives only inside its own
+    reduction: no tree of the parameters' size is made beside the state. The
+    scalars go in as arguments: a division by a constant is compiled as a
+    product with the constant's rounded reciprocal, which gives other bits."""
+    if opt["name"] == "adamw":
+        return _adamw_first_norms(moment, jnp.float32(1 - opt["beta1"]))
+    decay = {k: jnp.float32(bool(decays(k))) for k in moment}
+    return _sgd_first_norms(moment, params0(), decay,
+                            jnp.float32(opt["weight_decay"]))
 
 
 def moment_field(opt: dict) -> str:

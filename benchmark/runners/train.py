@@ -75,7 +75,6 @@ def _install_weights(state, flat_params: dict, ref):
     """Put the benchmark's weights into the program's state, leaf for leaf.
     The two trees must agree in names, shapes and types."""
     import jax
-    import jax.numpy as jnp
 
     leaves, treedef = jax.tree_util.tree_flatten_with_path(state.params)
     theirs = {_name(p): v for p, v in leaves}
@@ -90,8 +89,9 @@ def _install_weights(state, flat_params: dict, ref):
             raise harness.CellError(
                 f"parameter {_name(p)}: reference {leaf.shape} {leaf.dtype},"
                 f" program {old.shape} {old.dtype}")
-        # a copy: the step donates its state, the reference's stays whole
-        new.append(jax.device_put(jnp.copy(leaf), old.sharding))
+        # no copy: the step donates its state, and the runner keeps no
+        # second set of the weights beside it
+        new.append(jax.device_put(leaf, old.sharding))
     return state.replace(params=jax.tree_util.tree_unflatten(treedef, new))
 
 
@@ -136,14 +136,12 @@ def prepare(cell: dict, args, devices, wrap_step=None):
 
     seed_key = jax.random.key(args.seed)
     rng = jax.random.fold_in(seed_key, 0x5EED)  # the dropout stream's key
-    params0 = jax.jit(lambda k: ref.init_params(sz, k))(seed_key)
-    state = _install_weights(state, params0, ref)
+    init = jax.jit(lambda k: ref.init_params(sz, k))
+    state = _install_weights(state, init(seed_key), ref)
     global_traffic = dict(tr, batch=tr["batch"] * len(devices))
     gen = jax.jit(lambda k, i: ref.make_batch(global_traffic, sz, k, i),
                   out_shardings=batch_shd)
 
-    norms = jax.jit(lambda t: jax.tree_util.tree_map(
-        lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))), t))
     diff_norms = jax.jit(lambda a, b: jax.tree_util.tree_map(
         lambda x, y: jnp.sqrt(jnp.sum(jnp.square(
             x.astype(jnp.float32) - y.astype(jnp.float32)))), a, b))
@@ -153,14 +151,15 @@ def prepare(cell: dict, args, devices, wrap_step=None):
         program["loss"].append(metrics["loss"])
         if i == 0:
             moment = _moment(state.opt_state, ref_optim.moment_field(opt))
-            grads = ref_optim.first_gradient(opt, ref.decays, moment,
-                                             params0)
-            program["grad"] = norms(grads)
-            del moment, grads
-    program["change"] = diff_norms(_flat(state.params), params0)
-    _note_memory("three steps taken", devices)
-    del params0
+            program["grad"] = ref_optim.first_gradient_norms(
+                opt, ref.decays, moment, lambda: init(seed_key))
+            del moment
+    # The seed's weights are made again, by the program that made them (the
+    # same bits), and live only until the norms are taken: kept across the
+    # checked steps they would be 4 B a parameter beside the step's memory.
+    program["change"] = diff_norms(_flat(state.params), init(seed_key))
     program = jax.device_get(program)
+    _note_memory("three steps taken", devices)
     program = {"loss": [float(x) for x in program["loss"]],
                "grad": {k: float(v) for k, v in program["grad"].items()},
                "change": {k: float(v) for k, v in program["change"].items()}}
@@ -250,6 +249,15 @@ def run(cell: dict, args, devices, t_start: float, wrap_step=None):
     t_ref = time.time()
     print(f"phases: setup {setup_s:.1f}s window {win['elapsed']:.2f}s "
           f"steps {win['steps']}", file=sys.stderr)
+    # where the host was: a window that reads far off says by this line
+    # whether one call stalled (a longest far above the others) or every
+    # step ran slow (a sum that grew with none standing out)
+    held = {n: spans.durations(n) for n in ("batch", "dispatch", "block")}
+    print("window: " + " ".join(
+        f"{n} sum {sum(d):.3f}s longest {max(d, default=0.0):.3f}s"
+        for n, d in held.items()) + f" outside them "
+        f"{win['elapsed'] - sum(map(sum, held.values())):.3f}s",
+        file=sys.stderr)
     _note_memory("window closed", devices)
     chips = len(devices)
     examples = win["steps"] * tr["batch"] * chips

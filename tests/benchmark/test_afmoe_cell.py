@@ -31,22 +31,13 @@ def last_line(out: str) -> dict:
     return json.loads(out.strip().splitlines()[-1])
 
 
+def check_the_cells_metrics(spec):
+    tiny.check_cell_metrics(spec, tiny_afmoe.TRINITY_CELL, NEW_METRICS)
+
+
 def test_the_new_metrics_are_the_cells_and_only_the_cells():
     with open(os.path.join(tiny.REPO, "BENCHMARK.json")) as fh:
-        spec = json.load(fh)
-    mine = {m["name"]: m for m in spec["per_layer"]
-            if m.get("workloads") == [tiny_afmoe.TRINITY_CELL]}
-    assert set(mine) == NEW_METRICS
-    for m in mine.values():
-        assert m["moves"] == "train_examples_per_s"
-        assert m["source"] == "device_trace"
-        assert m["unit"] == ("%" if "roofline" in m["name"] else "ms")
-    cell = [w for w in spec["workloads"]
-            if w["name"] == tiny_afmoe.TRINITY_CELL]
-    assert len(cell) == 1 and cell[0]["chips"] == 1
-    e2e = {m["name"]: m for m in spec["end_to_end"]}
-    assert tiny_afmoe.TRINITY_CELL in \
-        e2e["train_examples_per_s"]["workloads"]
+        check_the_cells_metrics(json.load(fh))
 
 
 def test_rehearsal_prints_the_result_line(checkout):

@@ -1,7 +1,11 @@
 """`BENCHMARK.json` and the files it names: everything exists and loads, names
-and units keep to the allowed characters, the operation counts agree with a
-hand count, and a cell, a configuration, a traffic mix and a per-layer metric
-can each be added as new files plus one entry."""
+and units keep to the allowed characters, the accepted cells are still there,
+the operation counts agree with a hand count, and a cell, a configuration, a
+traffic mix and a per-layer metric can each be added as new files plus one
+entry. What is asserted of `BENCHMARK.json` is a rule and never a count of
+its entries: each check is a function of `(spec, root)`, run on the repo and,
+by `test_a_further_configuration_goes_in_as_new_files_and_entries`, on a copy
+that a further configuration was added to."""
 
 import importlib.util
 import json
@@ -20,8 +24,16 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
-def spec():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+# What the accepted benchmark holds: a later PR adds to it and takes nothing
+# away (only a `benchmark` PR may, and it changes this with it).
+ACCEPTED = {"gpt2_small.train_b16_s1024": "gpt2_small",
+            "trinity_mini.train_b1_s8192": "trinity_mini",
+            "kimi_linear.train_b1_s8192_ep32": "kimi_linear"}
+MAX_CELLS = 24
+
+
+def spec(root=REPO):
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
         return json.load(fh)
 
 
@@ -33,8 +45,7 @@ def load(path):
     return mod
 
 
-def test_keys_names_and_units():
-    sp = spec()
+def check_keys_names_and_units(sp):
     assert set(sp) == {"command", "paths", "run_seconds", "configs",
                        "workloads", "end_to_end", "per_layer"}
     assert isinstance(sp["run_seconds"], int) and 1 <= sp["run_seconds"] <= 51
@@ -64,20 +75,30 @@ def test_keys_names_and_units():
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
     four = sum(w["chips"] == 4 for w in sp["workloads"])
     assert four <= max(1, len(sp["workloads"]) // 4)
+    assert len(sp["workloads"]) <= MAX_CELLS
 
 
-def test_every_named_file_exists_and_loads():
-    sp = spec()
+def check_the_accepted_cells_are_still_there(sp):
+    cells = {w["name"]: w for w in sp["workloads"]}
+    assert set(ACCEPTED) <= set(cells)
+    assert set(ACCEPTED.values()) <= {c["name"] for c in sp["configs"]}
+    rate = {m["name"]: m for m in sp["end_to_end"]}["train_examples_per_s"]
+    for name, config in ACCEPTED.items():
+        assert cells[name]["config"] == config and cells[name]["chips"] == 1
+        assert name in rate["workloads"]
+
+
+def check_every_named_file_exists_and_loads(sp, root):
     configs = {c["name"]: c for c in sp["configs"]}
     used = set()
     for c in sp["configs"]:
         assert c["file"].startswith(tuple(p + "/" for p in sp["paths"]))
-        with open(os.path.join(REPO, c["file"])) as fh:
+        with open(os.path.join(root, c["file"])) as fh:
             cfg = json.load(fh)
-        ref = os.path.join(REPO, "benchmark", "references",
+        ref = os.path.join(root, "benchmark", "references",
                            cfg["reference"] + ".py")
         assert hasattr(load(ref), "init_params")
-        counts = os.path.join(REPO, "benchmark", "counts",
+        counts = os.path.join(root, "benchmark", "counts",
                               cfg["counts"] + ".py")
         assert hasattr(load(counts), "train_ops_per_example")
     cells = set()
@@ -86,23 +107,35 @@ def test_every_named_file_exists_and_loads():
         used.add(w["config"])
         assert (w["config"], w["traffic"]) not in cells
         cells.add((w["config"], w["traffic"]))
-        with open(os.path.join(REPO, "benchmark", "traffic",
+        with open(os.path.join(root, "benchmark", "traffic",
                                w["traffic"] + ".json")) as fh:
             traffic = json.load(fh)
         assert os.path.isfile(os.path.join(
-            REPO, "benchmark", "runners", traffic["runner"] + ".py"))
+            root, "benchmark", "runners", traffic["runner"] + ".py"))
         assert traffic["limits"] and traffic["who"]
     assert used == set(configs), "a configuration no cell uses"
     names = {w["name"] for w in sp["workloads"]}
     for m in sp["per_layer"]:
-        reader = os.path.join(REPO, "benchmark", "metrics",
+        reader = os.path.join(root, "benchmark", "metrics",
                               m["name"] + ".py")
         assert hasattr(load(reader), "read"), m["name"]
     for m in sp["end_to_end"] + sp["per_layer"]:
         assert set(m.get("workloads", [])) <= names
-    with open(os.path.join(REPO, "benchmark", "peaks.json")) as fh:
+    with open(os.path.join(root, "benchmark", "peaks.json")) as fh:
         peaks = json.load(fh)
     assert peaks["source"] and "TPU v5 lite" in peaks["by_device_kind"]
+
+
+def test_keys_names_and_units():
+    check_keys_names_and_units(spec())
+
+
+def test_the_accepted_cells_are_still_there():
+    check_the_accepted_cells_are_still_there(spec())
+
+
+def test_every_named_file_exists_and_loads():
+    check_every_named_file_exists_and_loads(spec(), REPO)
 
 
 def test_unknown_device_is_an_error():
@@ -163,3 +196,60 @@ def test_things_are_added_as_new_files_plus_one_entry(tmp_path):
         with open(os.path.join(co, rel)) as a, \
                 open(os.path.join(REPO, rel)) as b:
             assert a.read() == b.read()
+
+
+
+FURTHER_CELL = "further_tiny.train_b2_s32"
+
+
+def test_a_further_configuration_goes_in_as_new_files_and_entries(tmp_path):
+    """What a `model_config` PR does to the repo, done to a copy: three new
+    files, two new entries, the cell's name on the list of the end-to-end
+    metric it reports, one per-layer entry of its own. Every rule the
+    benchmark's tests hold the repo's `BENCHMARK.json` to then holds for the
+    copy's, and the new cell runs, found by its names alone."""
+    import test_afmoe_cell
+    import test_anatomy_readers
+    import test_kimi_cell
+
+    co = tiny.make_checkout(str(tmp_path))
+    tiny.add(co, "benchmark/configs/further_tiny.json",
+             json.dumps(tiny.GPT_TINY))
+    tiny.add(co, "benchmark/traffic/train_b2_s32.json",
+             json.dumps(dict(tiny.TRAIN_TINY, batch=2, seq_len=32)))
+    tiny.add(co, "benchmark/metrics/dispatch_count.further.py",
+             tiny.TINY_METRIC)
+    sp = spec(co)
+    sp["configs"].append({
+        "name": "further_tiny", "source": tiny.GPT_TINY["source"],
+        "file": "benchmark/configs/further_tiny.json", "reduced": [],
+        "why": "tests only"})
+    sp["workloads"].append({
+        "name": FURTHER_CELL, "config": "further_tiny",
+        "traffic": "train_b2_s32", "chips": 1, "why": "tests only"})
+    rate = {m["name"]: m for m in sp["end_to_end"]}["train_examples_per_s"]
+    rate["workloads"].append(FURTHER_CELL)
+    sp["per_layer"].append({
+        "name": "dispatch_count.further", "unit": "calls", "better": "higher",
+        "source": "program_counter", "layer": "loop",
+        "moves": "train_examples_per_s", "workloads": [FURTHER_CELL]})
+    with open(os.path.join(co, "BENCHMARK.json"), "w") as fh:
+        json.dump(sp, fh)
+
+    sp = spec(co)
+    check_keys_names_and_units(sp)
+    check_the_accepted_cells_are_still_there(sp)
+    check_every_named_file_exists_and_loads(sp, co)
+    test_afmoe_cell.check_the_cells_metrics(sp)
+    test_kimi_cell.check_the_cells_metrics(sp)
+    test_anatomy_readers.check_the_device_ms_entries(sp)
+
+    rc, out, err = tiny.run_cell(
+        co, "--workload", FURTHER_CELL, "--seed", str(2 ** 31 + 33),
+        "--seconds", "5", "--trace", "1", "--rehearsal")
+    assert rc == 0, err[-3000:]
+    line = json.loads(out.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    assert {"dispatch_count.further", "dispatch_ms.train"} <= \
+        set(line["metrics"])
+    assert "dispatch_count.train" not in line["metrics"]

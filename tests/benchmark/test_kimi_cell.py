@@ -2,7 +2,7 @@
 `kimi_linear_tiny` preset under the tiny training traffic, added as new files
 plus entries): the result line, the traced run's per-layer metrics that a CPU
 can give, the planted fault and the lower-precision control; the counts
-against a hand count; the cell's six readers on a made-up reduction; the
+against a hand count; the cell's eight readers on a made-up reduction; the
 configuration file against what the issue states."""
 
 import json
@@ -21,8 +21,12 @@ from benchmark import harness  # noqa: E402
 
 counts = harness.load_module("counts", "kimi_linear")
 NEW_METRICS = {"kda_device_ms", "kda_roofline", "mla_device_ms",
-               "flash_mla_roofline", "kimi_moe_device_ms",
-               "kimi_rest_device_ms"}
+               "flash_mla_roofline"}
+# The parts this step shares with the trinity cell's are read under the
+# names they have there: one name a part.
+SHARED_METRICS = {"moe_routing_device_ms", "moe_experts_device_ms",
+                  "blocks_other_device_ms", "head_loss_device_ms"}
+CELL_METRICS = NEW_METRICS | SHARED_METRICS
 
 
 def kimi():
@@ -46,27 +50,13 @@ def _cpu_env():
     return env
 
 
+def check_the_cells_metrics(spec):
+    tiny.check_cell_metrics(spec, tiny_kimi.KIMI_CELL, CELL_METRICS)
+
+
 def test_the_new_metrics_are_the_cells_and_only_the_cells():
     with open(os.path.join(tiny.REPO, "BENCHMARK.json")) as fh:
-        spec = json.load(fh)
-    mine = {m["name"]: m for m in spec["per_layer"]
-            if m.get("workloads") == [tiny_kimi.KIMI_CELL]}
-    assert set(mine) == NEW_METRICS
-    for m in mine.values():
-        assert m["moves"] == "train_examples_per_s"
-        assert m["source"] == "device_trace"
-        assert m["unit"] == ("%" if "roofline" in m["name"] else "ms")
-    # the cell is on no list of a metric that was there: those are a
-    # `benchmark` PR's to change
-    assert not [m["name"] for m in spec["per_layer"]
-                if tiny_kimi.KIMI_CELL in m.get("workloads", [])
-                and m["name"] not in NEW_METRICS]
-    cell = [w for w in spec["workloads"] if w["name"] == tiny_kimi.KIMI_CELL]
-    assert len(cell) == 1 and cell[0]["chips"] == 1
-    assert len(cell[0]["why"]) <= 200
-    e2e = {m["name"]: m for m in spec["end_to_end"]}
-    assert tiny_kimi.KIMI_CELL in e2e["train_examples_per_s"]["workloads"]
-    assert len(spec["workloads"]) == 3 and len(spec["configs"]) == 3
+        check_the_cells_metrics(json.load(fh))
 
 
 def test_rehearsal_prints_the_result_line(checkout):
@@ -93,7 +83,7 @@ def test_traced_rehearsal_reports_what_a_cpu_can(checkout):
     line = last_line(out)
     assert line["correct"] is True
     assert "dispatch_ms.train" in line["metrics"]
-    assert not NEW_METRICS & set(line["metrics"])
+    assert not CELL_METRICS & set(line["metrics"])
     assert "setup_s" not in line["metrics"]
 
 
@@ -247,7 +237,7 @@ def test_the_configuration_is_the_published_one_cut_as_the_issue_says():
 # --------------------------------------------------------------------------
 
 def test_the_readers_read_a_recorded_step(monkeypatch):
-    """The four device-time readers and the two rooflines over a made-up
+    """The six device-time readers and the two rooflines over a made-up
     trace of one step, by the program's own rule (`analysis/anatomy.py`):
     times book under the new parts, the loops' own time is left out, and
     each share is least over measured."""
@@ -297,8 +287,11 @@ def test_the_readers_read_a_recorded_step(monkeypatch):
 
     assert read("kda_device_ms") == pytest.approx(60.0)   # not the while's
     assert read("mla_device_ms") == pytest.approx(30.0)
-    assert read("kimi_moe_device_ms") == pytest.approx(2.0 + 5.0)
-    assert read("kimi_rest_device_ms") == pytest.approx(8.0 + 6.0 + 3.0 + 1.0)
+    assert read("moe_routing_device_ms") == pytest.approx(2.0)
+    assert read("moe_experts_device_ms") == pytest.approx(5.0)
+    # the shared expert (scope mlp) and a projection; the head and the loss
+    assert read("blocks_other_device_ms") == pytest.approx(8.0 + 6.0)
+    assert read("head_loss_device_ms") == pytest.approx(3.0 + 1.0)
     # four KDA layers at 1.479 ms least, one latent layer at 10.466
     assert read("kda_roofline") == pytest.approx(100 * 4 * 1.47876 / 60.0,
                                                  rel=1e-4)
@@ -312,4 +305,5 @@ def test_the_readers_read_a_recorded_step(monkeypatch):
     ctx["trace"] = None
     ctx.pop("anatomy_ms")
     assert read("kda_device_ms") is None
-    assert read("kimi_rest_device_ms") is None
+    for name in SHARED_METRICS:
+        assert read(name) is None

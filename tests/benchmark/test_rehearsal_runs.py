@@ -58,6 +58,29 @@ def test_rehearsal_prints_the_result_line(checkout, cell):
     assert tail[-2].startswith("compared ") and " limit " in tail[-2]
 
 
+HELD = os.path.join(os.path.dirname(os.path.abspath(__file__)), "held.py")
+
+
+@pytest.mark.parametrize("cell", ["gpt_tiny.train_b4_s64",
+                                  "resnet_tiny.train_b8_i32"])
+def test_set_up_holds_no_second_tree_of_the_parameters_size(checkout, cell):
+    """While the checked steps run the runner holds the step's state, the
+    batch and scalars: the seed's weights are not kept beside the state
+    (they are made again when the change is taken) and the first gradient is
+    never a tree. Of the memory a cell reports, the step's own is all."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    proc = subprocess.run([sys.executable, HELD, cell, "7"], cwd=checkout,
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    calls = last_line(proc.stdout)
+    assert len(calls) == 3                       # train_check.STEPS
+    for held in calls:
+        beside = held["live"] - held["state"] - held["batch"]
+        assert 0 <= beside < held["largest_leaf"] < held["params"]
+
+
 TRACED = {
     # spans and counters exist on a CPU; a share of a TPU's peak or of its
     # trace does not, and its reader returns nothing rather than 0

@@ -93,6 +93,15 @@ def read(ctx):
 '''
 
 
+def add(dst: str, rel: str, text: str) -> None:
+    """Write a new file of the copy under `dst`; never over one that is
+    there."""
+    path = os.path.join(dst, rel)
+    assert not os.path.exists(path), f"{rel} would edit an existing file"
+    with open(path, "w") as fh:
+        fh.write(text)
+
+
 def make_checkout(dst: str) -> str:
     """Copy of the benchmark under `dst` with the tiny cells added."""
     shutil.copytree(os.path.join(REPO, "benchmark"),
@@ -103,17 +112,11 @@ def make_checkout(dst: str) -> str:
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         spec = json.load(fh)
 
-    def add(rel, text):
-        path = os.path.join(dst, rel)
-        assert not os.path.exists(path), f"{rel} would edit an existing file"
-        with open(path, "w") as fh:
-            fh.write(text)
-
-    add("benchmark/configs/gpt_tiny.json", json.dumps(GPT_TINY))
-    add("benchmark/traffic/train_b4_s64.json", json.dumps(TRAIN_TINY))
-    add("benchmark/metrics/dispatch_count.train.py", TINY_METRIC)
-    add("benchmark/configs/resnet_tiny.json", json.dumps(RESNET_TINY))
-    add("benchmark/traffic/train_b8_i32.json", json.dumps(TRAIN_IMG_TINY))
+    add(dst, "benchmark/configs/gpt_tiny.json", json.dumps(GPT_TINY))
+    add(dst, "benchmark/traffic/train_b4_s64.json", json.dumps(TRAIN_TINY))
+    add(dst, "benchmark/metrics/dispatch_count.train.py", TINY_METRIC)
+    add(dst, "benchmark/configs/resnet_tiny.json", json.dumps(RESNET_TINY))
+    add(dst, "benchmark/traffic/train_b8_i32.json", json.dumps(TRAIN_IMG_TINY))
     spec["configs"].append({
         "name": "resnet_tiny", "source": RESNET_TINY["source"],
         "file": "benchmark/configs/resnet_tiny.json", "reduced": [],
@@ -128,7 +131,7 @@ def make_checkout(dst: str) -> str:
     spec["workloads"].append({
         "name": "gpt_tiny.train_b4_s64", "config": "gpt_tiny",
         "traffic": "train_b4_s64", "chips": 1, "why": "tests only"})
-    add("benchmark/traffic/serve_tiny.json", json.dumps(SERVE_TINY))
+    add(dst, "benchmark/traffic/serve_tiny.json", json.dumps(SERVE_TINY))
     spec["workloads"].append({
         "name": "gpt_tiny.serve_tiny", "config": "gpt_tiny",
         "traffic": "serve_tiny", "chips": 1, "why": "tests only"})
@@ -154,6 +157,23 @@ def make_checkout(dst: str) -> str:
     with open(os.path.join(dst, "BENCHMARK.json"), "w") as fh:
         json.dump(spec, fh)
     return dst
+
+
+def check_cell_metrics(spec: dict, cell: str, metrics: set) -> None:
+    """`cell` is one one-chip training cell of `spec`, and the per-layer
+    metrics that list it are `metrics`, device times and roofline shares read
+    from the trace (other cells may be on their lists beside it)."""
+    mine = {m["name"]: m for m in spec["per_layer"]
+            if cell in m.get("workloads", [])}
+    assert set(mine) == metrics
+    for m in mine.values():
+        assert m["moves"] == "train_examples_per_s"
+        assert m["source"] == "device_trace"
+        assert m["unit"] == ("%" if "roofline" in m["name"] else "ms")
+    entry = [w for w in spec["workloads"] if w["name"] == cell]
+    assert len(entry) == 1 and entry[0]["chips"] == 1
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
+    assert cell in e2e["train_examples_per_s"]["workloads"]
 
 
 def run_cell(checkout: str, *extra, timeout: int = 600):

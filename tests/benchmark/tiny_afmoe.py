@@ -36,10 +36,8 @@ TRINITY_CELL = "trinity_mini.train_b1_s8192"
 
 def add_cell(checkout: str) -> str:
     """Add the tiny AFMoE cell to a checkout `tiny.make_checkout` made."""
-    path = os.path.join(checkout, "benchmark", "configs", "afmoe_tiny.json")
-    assert not os.path.exists(path)
-    with open(path, "w") as fh:
-        json.dump(AFMOE_TINY, fh)
+    tiny.add(checkout, "benchmark/configs/afmoe_tiny.json",
+             json.dumps(AFMOE_TINY))
     spec_path = os.path.join(checkout, "BENCHMARK.json")
     with open(spec_path) as fh:
         spec = json.load(fh)

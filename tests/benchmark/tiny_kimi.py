@@ -40,10 +40,8 @@ KIMI_CELL = "kimi_linear.train_b1_s8192_ep32"
 def add_cell(checkout: str) -> str:
     """Add the tiny Kimi Linear cell to a checkout `tiny.make_checkout`
     made."""
-    path = os.path.join(checkout, "benchmark", "configs", "kimi_tiny.json")
-    assert not os.path.exists(path)
-    with open(path, "w") as fh:
-        json.dump(KIMI_TINY, fh)
+    tiny.add(checkout, "benchmark/configs/kimi_tiny.json",
+             json.dumps(KIMI_TINY))
     spec_path = os.path.join(checkout, "BENCHMARK.json")
     with open(spec_path) as fh:
         spec = json.load(fh)

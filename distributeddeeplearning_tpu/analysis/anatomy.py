@@ -7,7 +7,8 @@ stack it was traced under — ``jvp(...)`` / ``transpose(...)`` for the pass,
 the flax module path, every ``jax.named_scope`` (train/steps.py's
 ``STEP_SCOPES`` and ``LOSS_SCOPE``, models/gpt.py's ``embed`` / ``mlp`` /
 ``head``, models/afmoe.py's ``attn_window`` / ``attn_full``,
-models/kimi_linear.py's ``attn_kda`` / ``attn_mla``, models/moe.py's
+models/kimi_linear.py's ``attn_kda`` / ``attn_mla``,
+models/hyper_connections.py's ``mhc``, models/moe.py's
 ``moe_router`` / ``moe_dispatch`` / ``moe_experts`` / ``moe_combine``) and
 every Pallas kernel's ``name`` (ops/pallas.py). This module reads those names
 back:
@@ -31,8 +32,8 @@ PHASES = ("forward", "backward", "update")
 # no rule placed (instructions the compiler made without metadata, scopes the
 # rule does not know — a CNN's convolutions, today).
 PARTS = ("flash_fwd", "flash_dq", "flash_dkv", "attention_window",
-         "attention_full", "attention_kda", "attention_mla", "moe_routing",
-         "moe_experts", "attention_other",
+         "attention_full", "attention_kda", "attention_mla", "residual_mhc",
+         "moe_routing", "moe_experts", "attention_other",
          "mlp", "layernorm", "embed", "head", "loss", "remat", "loss_scale",
          "optimizer", "ema_guard", "grad_reduce", "unattributed")
 
@@ -43,10 +44,14 @@ _KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 # gates, chunked scan and gated norm, which have no kernel of their own
 # name); one with routed experts has the router,
 # the sort, the gathers and the weighted sum as ``moe_routing`` and the
-# grouped products as ``moe_experts``.
+# grouped products as ``moe_experts``; one whose residual is several streams
+# has everything its hyper-connections add (the norm over the streams, the
+# product that makes the coefficients, Sinkhorn's iterations and the three
+# mixes) as ``residual_mhc``.
 _MODEL_SCOPES = {"attn_window": "attention_window",
                  "attn_full": "attention_full",
                  "attn_kda": "attention_kda", "attn_mla": "attention_mla",
+                 "mhc": "residual_mhc",
                  "moe_router": "moe_routing", "moe_dispatch": "moe_routing",
                  "moe_combine": "moe_routing", "moe_experts": "moe_experts"}
 # The TPU compiler turns ``jax.lax.ragged_dot`` into kernels of its own and
@@ -194,7 +199,7 @@ def part_of(op_name: str) -> tuple[str, str]:
     it ``forward``; what is outside every scope of the step (its key
     fold-in, the step counter) is ``update``. Part, first match: an update
     scope; a model's own scope (``_MODEL_SCOPES``: attention by layer kind,
-    routed experts); a flash kernel's name; ``loss`` / ``head`` / ``embed``; a
+    routed experts, hyper-connections); a flash kernel's name; ``loss`` / ``head`` / ``embed``; a
     LayerNorm module; the ``mlp`` scope or an ``mlp*`` module; anything else
     inside an attention module or bare in a decoder block (the attention
     half's dropout and residual) is ``attention_other``; what only the

@@ -30,7 +30,7 @@ class ModelSpec:
 def _registry() -> dict[str, ModelSpec]:
     from distributeddeeplearning_tpu.models import (afmoe, bert, densenet,
                                                     gpt, kimi_linear, llama,
-                                                    resnet, vit)
+                                                    resnet, vit, xing4)
 
     def img(build, name, params):
         return ModelSpec(name=name, build=build, input_kind="image",
@@ -108,6 +108,18 @@ def _registry() -> dict[str, ModelSpec]:
         "kimi_linear_tiny": ModelSpec(
             name="kimi_linear_tiny", build=kimi_linear.kimi_linear_tiny,
             input_kind="tokens", param_count=0, objective="causal"),
+        # Xing4.0-29B-A4B as published (without its MTP module), for shape
+        # tests, and one chip's share of it when 8 chips share each layer
+        # (models/xing4.py; the benchmark's xing4 cell).
+        "xing4_29b": ModelSpec(
+            name="xing4_29b", build=xing4.xing4_29b, input_kind="tokens",
+            param_count=29_506_649_712, objective="causal"),
+        "xing4_ep8": ModelSpec(
+            name="xing4_ep8", build=xing4.xing4_ep8, input_kind="tokens",
+            param_count=759_489_550, objective="causal"),
+        "xing4_tiny": ModelSpec(
+            name="xing4_tiny", build=xing4.xing4_tiny, input_kind="tokens",
+            param_count=0, objective="causal"),
         # Nano drafters for speculative decoding (serve/engine.py): a
         # shrunk config of the same family — cheap to step, same
         # tokenizer/vocab, verified by the full target model so output
@@ -209,7 +221,10 @@ def get_model(name: str, *, dtype: Any = jnp.bfloat16, **kw: Any):
 def model_spec(name: str) -> ModelSpec:
     reg = _registry()
     if name not in reg:
-        raise KeyError(f"unknown model {name!r}; have {sorted(reg)}")
+        # the name comes last: what is kept of a failed child's error is
+        # its tail (bench.py), and the registry's list outgrew it
+        raise KeyError(f"the registry has {sorted(reg)}: unknown model "
+                       f"{name!r}")
     return reg[name]
 
 

@@ -41,6 +41,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributeddeeplearning_tpu.models.llama import Held
 from distributeddeeplearning_tpu.models.moe import ROUTED_OUT, RoutedExperts
 from distributeddeeplearning_tpu.ops import kda as kda_ops
 from distributeddeeplearning_tpu.ops import kda_stages
@@ -102,22 +103,6 @@ def _rms_norm(cfg: KimiLinearConfig, dtype, name: str):
                       param_dtype=jnp.float32, name=name)
 
 
-class _Held(nn.Module):
-    """One float32 parameter under a module's name of its own. The
-    convolutions' taps and the gated norm's scale are applied inside the
-    fused stages (ops/kda_stages.py), and keep the paths they were
-    initialised, stored and compared by: ``q_conv/kernel``,
-    ``o_norm/scale``."""
-
-    leaf: str
-    shape: tuple
-    init: Any
-
-    @nn.compact
-    def __call__(self):
-        return self.param(self.leaf, self.init, self.shape, jnp.float32)
-
-
 def _a_log_init(key, shape, dtype):
     """log U(1, 16) a head: decay rates spread over a factor of 16."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
@@ -157,11 +142,10 @@ class KdaAttention(nn.Module):
         dt_bias = self.param("dt_bias", _dt_bias_init, (h * d,), f32)
         # depthwise causal convolutions along the sequence, no bias: (taps,
         # channels), ``y_t[c] = sum_j w[j, c] x_{t-K+1+j}[c]``
-        taps = [_Held("kernel", (cfg.conv_size, h * d),
-                      nn.with_logical_partitioning(
-                          nn.initializers.normal(0.02), (None, "heads")),
-                      name=n + "_conv")() for n in ("q", "k", "v")]
-        o_scale = _Held("scale", (d,), nn.initializers.ones, name="o_norm")()
+        taps = [Held("kernel", (cfg.conv_size, h * d),
+                     nn.initializers.normal(0.02), (None, "heads"),
+                     name=n + "_conv")() for n in ("q", "k", "v")]
+        o_scale = Held("scale", (d,), nn.initializers.ones, name="o_norm")()
         with jax.named_scope("attn_kda"):
             # conv, SiLU, the L2 norms and the gate in one pass, written as
             # the operands the chunked operator scans over; the gated norm

@@ -13,6 +13,7 @@ eval_shape in tests/test_llama.py).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -54,12 +55,68 @@ def _dense(features, logical_axes, name, dtype):
         name=name)
 
 
+class Held(nn.Module):
+    """One float32 parameter under a module's name of its own, for a layer
+    that applies the parameter itself (inside a fused stage, or in float32
+    beside bfloat16 products) and keeps the path it is initialised, stored
+    and compared by: ``q_conv/kernel``, ``o_norm/scale``, ``phi/kernel`` (a
+    matrix, which the optimizer's weight decay finds by that name)."""
+
+    leaf: str
+    shape: tuple
+    init: Any
+    axes: tuple = ()
+
+    @nn.compact
+    def __call__(self):
+        init = (nn.with_logical_partitioning(self.init, self.axes)
+                if self.axes else self.init)
+        return self.param(self.leaf, init, self.shape, jnp.float32)
+
+
 def _rms_norm(cfg: LlamaConfig, dtype, name: str):
     return nn.RMSNorm(epsilon=cfg.rms_eps, dtype=dtype,
                       param_dtype=jnp.float32, name=name)
 
 
-def apply_rope(x, *, theta: float, offset=0, positions=None):
+def yarn_frequencies(dim: int, *, theta: float, factor: float,
+                     original_max_position: int, beta_fast: float,
+                     beta_slow: float):
+    """YaRN's ``dim // 2`` rotary frequencies (Peng et al. 2023, as
+    DeepSeek-V2's modelling code computes them), for :func:`apply_rope`'s
+    ``freqs``. Pair ``i`` of plain RoPE turns at ``theta_i = theta ** (-2i /
+    dim)``. Pairs that turn more than ``beta_fast`` times over the original
+    context keep that frequency, pairs that turn fewer than ``beta_slow``
+    times have it divided by ``factor`` (positions interpolated), and a
+    linear ramp joins the two between ``low`` and ``high``::
+
+        low  = floor(dim ln(L / (2 pi beta_fast)) / (2 ln theta))
+        high = ceil (dim ln(L / (2 pi beta_slow)) / (2 ln theta))
+        r_i  = clip((i - low) / (high - low), 0, 1)
+        f_i  = theta_i (1 - r_i) + (theta_i / factor) r_i
+
+    both clamped to [0, dim // 2 - 1]. Returns ``(frequencies, low, high)``,
+    the frequencies as a float32 array."""
+    def turns_to_pair(turns):
+        return (dim * math.log(original_max_position / (2 * math.pi * turns))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_to_pair(beta_fast)), 0)
+    high = min(math.ceil(turns_to_pair(beta_slow)), dim // 2 - 1)
+    plain = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return plain * (1.0 - ramp) + plain / factor * ramp, low, high
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 mscale ln(factor) + 1``, 1 where
+    the context is not stretched."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def apply_rope(x, *, theta: Optional[float] = None, offset=0, positions=None,
+               freqs=None):
     """Rotary embedding, half-split (rotate_half) convention: x (B, S, H, D)
     rotated by (offset + index) along dim 1 — ``offset`` (may be traced)
     positions a decode-mode single token at its absolute index, while
@@ -68,9 +125,11 @@ def apply_rope(x, *, theta: float, offset=0, positions=None):
     zigzag permutation) or a (B, S) array when every row sits at its own
     position (paged decode — each serve slot's length). f32 rotation
     regardless of storage dtype (sin/cos in bf16 visibly degrades
-    long-range phase)."""
+    long-range phase). The D // 2 frequencies are plain RoPE's at base
+    ``theta``, or the caller's own ``freqs`` (:func:`yarn_frequencies`)."""
     b, s, h, d = x.shape
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if freqs is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     pos = (jnp.asarray(positions, jnp.float32) if positions is not None
            else offset + jnp.arange(s, dtype=jnp.float32))
     ang = pos[..., None] * freqs              # (S, d/2) or (B, S, d/2)

@@ -7,7 +7,11 @@ key-padding mask, optionally causal, optionally cut to a causal ``window``
 fewer than the Q heads (Q head h reads K/V head ``h // (H // Hkv)``; flash
 reads them in place, dense repeats them) and values that may be of another
 width than the queries and keys (latent attention; dense and flash: the
-scale is the query width's).
+scale is the query width's). ``scale`` puts another factor in ``d^-1/2``'s
+place (dense and flash): a model whose softmax temperature is not its head
+width's (YaRN's ``mscale^2 / sqrt(d)``) hands it over, and the scores are
+scaled in float32 where they are made, which a factor folded into bfloat16
+queries would round once more.
 
 - ``dense``: materialized (S, S) scores, f32 softmax, XLA-fused — right for
   short sequences.
@@ -45,9 +49,11 @@ def multihead_attention(q, k, v, pad_mask, *, impl: str, causal: bool,
                         dropout_rate: float = 0.0,
                         dropout_rng: Optional[Any] = None,
                         deterministic: bool = True,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None,
+                        scale: Optional[float] = None):
     """q: (B, S, H, D), k: (B, S, Hkv, D), v: (B, S, Hkv, Dv); pad_mask:
-    (B, S) bool (True = attend) or None.
+    (B, S) bool (True = attend) or None. ``scale``: the scores' factor,
+    ``D ** -0.5`` where None.
 
     Returns (B, S, H*Dv) in ``dtype``. ``dropout_rate`` is the
     attention-probability dropout rate, applied only when
@@ -79,8 +85,11 @@ def multihead_attention(q, k, v, pad_mask, *, impl: str, causal: bool,
             flash_attention_sharded)
         out = flash_attention_sharded(q, k, v, pad_mask, causal=causal,
                                       dropout_rate=rate, dropout_seed=seed,
-                                      window=window)
+                                      window=window, scale=scale)
         return out.reshape(b, s, -1)
+    if scale is not None and impl != "dense":
+        raise ValueError(f"attention_impl={impl!r} scales the scores by the "
+                         f"head width alone; use 'flash' or 'dense'")
     if window is not None and impl != "dense":
         raise ValueError(f"attention_impl={impl!r} has no window; use "
                          f"'flash' or 'dense'")
@@ -111,8 +120,8 @@ def multihead_attention(q, k, v, pad_mask, *, impl: str, causal: bool,
         out = ring_attention.zigzag_ring_attention_sharded(
             q, k, v, pad_mask, dropout_rate=rate, dropout_seed=seed)
     elif impl == "dense":
-        scale = d ** -0.5
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (
+            d ** -0.5 if scale is None else scale)
         keep = pad_mask[:, None, None, :]
         if causal:
             keep = keep & jnp.tril(jnp.ones((s, s), jnp.bool_))[None, None]

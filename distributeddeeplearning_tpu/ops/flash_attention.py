@@ -671,7 +671,8 @@ def flash_attention(q, k, v, kv_mask=None, *,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None, causal: bool = False,
                     dropout_rate: float = 0.0, dropout_seed=None,
-                    bh_offsets=None, window: Optional[int] = None):
+                    bh_offsets=None, window: Optional[int] = None,
+                    scale: Optional[float] = None):
     """Fused attention with a key-padding mask; ``causal=True`` adds the
     autoregressive lower-triangular mask (tiles above the diagonal are not
     in the grid), and ``window`` cuts it to the band ``query - key <
@@ -685,7 +686,8 @@ def flash_attention(q, k, v, kv_mask=None, *,
     group inside the kernel. The values may be of another width than the
     queries and keys (latent attention: 192 and 128): the V tiles, the
     accumulator, the result and dV are then ``Dv`` wide, dQ and dK ``D``,
-    and the scale is the query width's, ``D ** -0.5``.
+    and the scale is the query width's, ``D ** -0.5``, or the caller's
+    ``scale`` (the three kernels multiply the float32 scores by it).
     kv_mask: (B, S) (True/nonzero = attend), or None for all-valid. Returns
     (B, S, H, Dv) in q.dtype. Differentiable w.r.t. q/k/v via the flash
     backward kernels.
@@ -735,7 +737,8 @@ def flash_attention(q, k, v, kv_mask=None, *,
     def to_bh(x):  # (B, S, H, D) -> (B*H, S, D), by x's own heads and width
         return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, x.shape[3])
 
-    out = _flash(to_bh(q), to_bh(k), to_bh(v), kv_mask, seed, d ** -0.5,
+    out = _flash(to_bh(q), to_bh(k), to_bh(v), kv_mask, seed,
+                 d ** -0.5 if scale is None else float(scale),
                  tile_plan(s, causal, block_q, block_k, window=window),
                  causal, float(dropout_rate))
     return out.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)[:, :s_orig]

@@ -40,6 +40,7 @@ from distributeddeeplearning_tpu import compat
 from distributeddeeplearning_tpu.analysis import anatomy
 from distributeddeeplearning_tpu.config import TrainConfig, resolve_precision
 from distributeddeeplearning_tpu.models import moe
+from distributeddeeplearning_tpu.models.hyper_connections import MHC_METRICS
 from distributeddeeplearning_tpu.ops import kda
 from distributeddeeplearning_tpu.parallel import collectives
 from distributeddeeplearning_tpu.parallel import sharding as shardlib
@@ -282,20 +283,28 @@ def _moe_metrics(sown) -> dict:
             "moe_worst_case_layers": sum(by_name["worst_case"])}
 
 
-def _kda_metrics(mutated) -> dict:
-    """Where delta-rule attention layers sowed it (ops/kda.py): the most
-    negative cumulative gate inside a chunk, over the layers."""
-    if kda.KDA_METRICS not in mutated:
-        return {}
-    return {"kda_min_chunk_log_decay": jnp.min(jnp.stack(
-        jax.tree_util.tree_leaves(mutated[kda.KDA_METRICS])))}
+# What layers sow for the step's metrics beside the experts' counters: the
+# collection, the metric's name, and how the layers' values become one.
+# `kda_min_chunk_log_decay` (ops/kda.py): the most negative cumulative gate
+# inside a chunk, over the delta-rule layers. `mhc_max_row_sum_gap`
+# (models/hyper_connections.py): the largest |row sum - 1| of any residual
+# mixing matrix after its Sinkhorn iterations, over tokens and
+# hyper-connections.
+_SOWN = ((kda.KDA_METRICS, "kda_min_chunk_log_decay", jnp.min),
+         (MHC_METRICS, "mhc_max_row_sum_gap", jnp.max))
+
+
+def _sown_metrics(mutated) -> dict:
+    """One number a collection of `_SOWN` that the model sowed into."""
+    return {name: merge(jnp.stack(jax.tree_util.tree_leaves(mutated[col])))
+            for col, name, merge in _SOWN if col in mutated}
 
 
 def _causal_loss_fn(model, config: TrainConfig):
     del config
 
     def loss_fn(params, batch_stats, batch, rng):
-        variables, mutable = {"params": params}, [kda.KDA_METRICS]
+        variables, mutable = {"params": params}, [c for c, _, _ in _SOWN]
         if batch_stats is not None:
             # routed experts: the selection biases move, with no gradient
             variables[moe.ROUTER_STATE] = batch_stats
@@ -307,7 +316,7 @@ def _causal_loss_fn(model, config: TrainConfig):
         with jax.named_scope(LOSS_SCOPE):
             loss = losses.causal_lm_loss(
                 logits, batch["input_ids"], batch.get("attention_mask"))
-        metrics = {"loss": loss, **_kda_metrics(mutated)}
+        metrics = {"loss": loss, **_sown_metrics(mutated)}
         if batch_stats is None:
             return loss, (None, metrics)
         return loss, (mutated[moe.ROUTER_STATE],

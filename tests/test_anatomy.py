@@ -165,6 +165,18 @@ CASES = [
      "forward", "attention_other"),
     (STEP + "grads/jvp(KimiLinearLM)/layer3/attention/kv_a_norm/rsqrt",
      "forward", "attention_other"),
+    # hyper-connections: the scope wins over the flax path round it, inside
+    # the Sinkhorn loop's body and under a recomputed block alike; the
+    # sub-layers inside a round keep their own parts
+    (STEP + "grads/jvp(Xing4LM)/layer3/attn_hc/mhc/bsk,km->mbs/dot_general",
+     "forward", "residual_mhc"),
+    (STEP + "grads/jvp(Xing4LM)/layer3/ffn_hc/mhc/while/body/div", "forward",
+     "residual_mhc"),
+    (STEP + "grads/transpose(jvp(Xing4LM))/grads/jvp(Xing4LM)/checkpoint/"
+     "rematted_computation/layer3/mhc/add", "backward", "residual_mhc"),
+    (STEP + "grads/jvp(Xing4LM)/mhc/concatenate", "forward", "residual_mhc"),
+    (STEP + "grads/jvp(Xing4LM)/layer3/attention/q_a_norm/rsqrt", "forward",
+     "attention_other"),
     (STEP + "grads/jvp(AfmoeLM)/layer2/moe/moe_router/top_k", "forward",
      "moe_routing"),
     (STEP + "grads/jvp(AfmoeLM)/layer2/moe/moe_dispatch/sort", "forward",

@@ -243,20 +243,11 @@ def test_kda_stages_compile_for_v5e(compile_for, stage, grad, kernel):
         2 if grad else 1)
 
 
-def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
-    """The benchmark's kimi_linear cell: the whole mixed-precision AdamW step
-    of `kimi_linear_ep32` at one sequence of 8192 tokens, built as
+def _compiled_share_step(one_chip, name: str, seq: int, vocab: int):
+    """(compiled step, parameters) of one chip's share `name` at one sequence
+    of `seq` tokens: the mixed-precision AdamW step built as
     `train/loop.build` builds it (`make_gspmd_train_step` on a mesh of the
-    described chip), lowered with shapes and compiled. What the chip's
-    compiler says of it: it fits (7.23 GB of state: float32 masters and
-    Adam's two moments of 602M parameters; 4.83 GB of temporaries, the
-    float32 gradients among them; 5.40 before the pointwise stages round
-    the delta rule were kernels), the latent layer's three flash kernels
-    are there at 192 / 128, and the chunked delta rule's loops are `while`s:
-    five a KDA layer (forward over groups and over a group's chunks; backward
-    over groups, a group's chunks remade, and back through them) and none
-    for a recomputed forward, whose result and entering states the block
-    keeps."""
+    described chip), lowered with shapes and compiled."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from distributeddeeplearning_tpu.config import (
@@ -267,10 +258,9 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
     from distributeddeeplearning_tpu.train import optim, steps
     from distributeddeeplearning_tpu.train.state import TrainState
 
-    seq, vocab = 8192, 20480
     policy = PrecisionPolicy.mixed()
     cfg = TrainConfig(
-        model="kimi_linear_ep32", backend=None, global_batch_size=1, seed=0,
+        model=name, backend=None, global_batch_size=1, seed=0,
         dtype=policy.compute_dtype, precision=policy, log_every=10 ** 9,
         attention_impl="flash", parallel=ParallelConfig(data=1),
         data=DataConfig(synthetic=True, dataset="mlm", seq_len=seq,
@@ -296,7 +286,6 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
     abstract = jax.eval_shape(init_fn, jax.random.key(0))
     parameters = sum(x.size for x in jax.tree_util.tree_leaves(
         abstract.params))
-    assert parameters == 602_449_792
     shardings = jax.tree_util.tree_map(
         lambda spec: NamedSharding(mesh, spec),
         nn.logical_to_mesh(nn.get_partition_spec(abstract)),
@@ -319,6 +308,26 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
             state, {"input_ids": ids, "attention_mask": ids}, rng).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
+    return compiled, parameters
+
+
+def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
+    """The benchmark's kimi_linear cell: the whole mixed-precision AdamW step
+    of `kimi_linear_ep32` at one sequence of 8192 tokens, built as
+    `train/loop.build` builds it (`make_gspmd_train_step` on a mesh of the
+    described chip), lowered with shapes and compiled. What the chip's
+    compiler says of it: it fits (7.23 GB of state: float32 masters and
+    Adam's two moments of 602M parameters; 4.83 GB of temporaries, the
+    float32 gradients among them; 5.40 before the pointwise stages round
+    the delta rule were kernels), the latent layer's three flash kernels
+    are there at 192 / 128, and the chunked delta rule's loops are `while`s:
+    five a KDA layer (forward over groups and over a group's chunks; backward
+    over groups, a group's chunks remade, and back through them) and none
+    for a recomputed forward, whose result and entering states the block
+    keeps."""
+    compiled, parameters = _compiled_share_step(
+        one_chip, "kimi_linear_ep32", seq=8192, vocab=20480)
+    assert parameters == 602_449_792
     memory = compiled.memory_analysis()
     print("state:", memory.argument_size_in_bytes, "temporaries:",
           memory.temp_size_in_bytes)
@@ -348,5 +357,36 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
     relaid = [m.group(0) for m in re.finditer(
         r"%\S+ = f32\[([\d,]+)\]\S* (copy|transpose|reshape)\(.*", entry)
         if "attn_kda" in m.group(0)
-        and math.prod(map(int, m.group(1).split(","))) == seq * 4096]
+        and math.prod(map(int, m.group(1).split(","))) == 8192 * 4096]
     assert not relaid, relaid
+
+
+
+def test_xing4_ep8_step_compiles_for_v5e(one_chip):
+    """The benchmark's xing4 cell: the whole mixed-precision AdamW step of
+    `xing4_ep8` at one sequence of 4096 tokens. What the chip's compiler says
+    of it: it fits (9.11 GB of state: float32 masters and Adam's two moments
+    of 759M parameters; 4.45 GB of temporaries, the float32 gradients and
+    the four residual streams' kept block inputs among them), every layer's
+    three flash kernels are there once, at queries of 192 and values of 128
+    (a recomputed block keeps the forward kernel's result), and the Sinkhorn
+    iterations are `while`s under the hyper-connections' scope: one a
+    hyper-connection forward, one recomputed, one backward."""
+    compiled, parameters = _compiled_share_step(
+        one_chip, "xing4_ep8", seq=4096, vocab=16384)
+    assert parameters == 759_489_550
+    memory = compiled.memory_analysis()
+    print("state:", memory.argument_size_in_bytes, "temporaries:",
+          memory.temp_size_in_bytes)
+    assert memory.argument_size_in_bytes == pytest.approx(
+        12 * parameters, rel=0.001)
+    assert memory.temp_size_in_bytes < 1.1 * 4.45e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 0.9 * 16 * 1024 ** 3)
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert len(re.findall(rf"%{name}\S* = ", text)) == 5, name
+    assert re.search(r"%flash_fwd\S* = \(bf16\[32,4096,128\]", text)
+    loops = re.findall(r" while\(.*", text)
+    assert len(loops) == 10 * 3
+    assert all("/mhc/" in line for line in loops)

@@ -23,46 +23,63 @@ k_i)`` the row each token writes,
     S_C  = Diag(exp G_C) S_0 + (K * exp(G_C - G))^T U
 
 so with ``T = (I + Diag(beta) A)^-1 Diag(beta)``, ``U = T V - (T (K * exp
-G)) S_0``: ``T V`` and ``T (K * exp G)`` need no state and are made for many
-chunks at once, and the loop over chunks holds four products.
+G)) S_0``: ``T V`` and ``T (K * exp G)`` need no state and are made for a
+group of chunks at once, and the loop over chunks holds four products.
 
 **Staying finite.** ``A`` and ``B`` hold ``exp(G_i - G_j)`` with ``j <= i``,
 never above 1, but as a product of two factors ``exp(G_i) exp(-G_j)`` the
 second overflows float32 once a chunk's gates pass -88 (a gate of -1.6 a
-token does it in 64; the model's initial gates reach that). So the
-difference is taken before the exponential. A chunk is cut into sub-chunks
-of ``sub`` tokens: a block of ``A`` below the diagonal, rows in sub-chunk a
-and columns before it, factors round ``r_a``, the cumulative gate at a's
-first row, as ``exp(G_i - r_a) exp(r_a - G_j)``, both at most 1; a
-block on the diagonal is summed pair by pair, ``exp(G_i - G_j)`` itself. An
-underflow to 0 is the true value to rounding. Nothing divides by a decay.
+token does it in 64; the model's initial gates reach that). So every
+exponent is a difference of cumulative gates that is at most 0. The chunk is
+halved level by level: a block of ``2 m`` tokens gives its lower left (m, m)
+quarter, whose every row comes after its every column, as a product of two
+factors round ``r``, the cumulative gate at the block's row ``m``: ``exp(G_i
+- r) exp(r - G_j)``, both at most 1; its two quarters on the diagonal go to
+the next level, down to blocks of 4 tokens, which are summed pair by pair,
+``exp(G_i - G_j)`` itself. An underflow to 0 is the true value to rounding.
+Nothing divides by a decay.
 
 **The inverse.** ``I + Diag(beta) A`` is unit lower triangular. Its ``sub``
 wide diagonal blocks ``I + L_d`` are inverted by the finite series ``(I -
 L_d)(I + L_d^2)(I + L_d^4)...`` (``L_d^sub = 0``), and the rest by the same
 series in ``M = (I + L_d)^-1 L_off``, which is nilpotent in blocks
-(``M^(C/sub) = 0``); all in float32 at three bfloat16 passes a product, a
-few (C, C, C) products a chunk and head.
+(``M^(C/sub) = 0``); all in float32 at three bfloat16 passes a product.
+
+**What runs where.** Everything above that no state enters -- ``G``, ``A``,
+``B``, ``T``, ``T (K exp G)``, ``T V``, ``Q exp G``, ``K exp(G_C - G)`` and
+``exp G_C`` -- is one Pallas kernel forward and one backward
+(ops/kda_chunk.py, :func:`ops.kda_chunk.prepare`: a grid step is a chunk of
+the group and eight heads, and what lies between the operands and those
+results never leaves VMEM). Its precision: the gates summed in float32
+(exact products with a triangle of ones); the scores' and the inverse's
+float32 products at three bfloat16 passes (each operand a bfloat16 head and
+tail, ``hi hi + hi lo + lo hi``, written out since Mosaic lowers no
+``Precision.HIGH``: a CPU computes the same three); the sums over a pair's
+channels in float32; ``T`` rounded to q's type before it meets K and V, and
+those products added up in float32. What XLA still runs of the operator is
+the loop over a group's chunks (:func:`_group`: four products a chunk with
+the state in float32) and the scan over groups.
 
 **Memory and the backward pass.** Chunks are taken ``group`` at a time under
-a ``lax.scan``; inside a group, what needs no state is made for all its
-chunks at once and a second scan hands the state through them. The backward
-rule (:func:`_groups_bwd`) keeps the operands and the state that enters each
+a ``lax.scan``; the kernel makes a group's stateless operands at once (in
+HBM a group holds just those six results, (group, B*H, C, d) each) and a
+second scan hands the state through its chunks. The backward rule
+(:func:`_groups_bwd`) keeps the operands and the state that enters each
 group ((S / (C * group)) x B x H x d_k x d_v float32) and walks the groups
-last to first, remaking each from those and differentiating it there, so
-neither pass holds more than one group's (B*H, group, sub, sub, d_k) pair
-sums. Inside a group the gradient is jax's own of these products, except the
-two pieces whose own derivative is far cheaper than their series': the
-inverse (``dL = -X^T dX X^T``) and the in-chunk scores (the gates' gradient
-is ``x * dx - y * dy``, with no pass of its own). The result and the entering
-states are named (``KDA_OUT``, ``KDA_STATES``) for a recomputed block to keep.
-A last short chunk is padded with ``g = 0, beta = 0``, which leaves the state
-as it is.
+last to first, remaking each from those and differentiating it there with
+``jax.vjp``: the loop over chunks by jax's own rule, the kernel by its
+``custom_vjp``, whose residuals are its operands and whose backward kernel
+remakes ``G``, ``A`` and ``T`` in VMEM and applies each piece's own rule
+(the inverse's ``dL = -X^T dX X^T``; the scores' gates' gradient ``x * dx - y
+* dy``, with no pass of its own). The result and the entering states are
+named (``KDA_OUT``, ``KDA_STATES``) for a recomputed block to keep. A last
+short chunk is padded with ``g = 0, beta = 0``, which leaves the state as it
+is.
 
 Layout: :func:`kda_recurrent` and :func:`kda_chunked` take the models' ``(B,
 S, H, D)``; ``g`` is ``(B, S, H, d_k)`` float32, ``beta`` ``(B, S, H)``.
-Products take their operands in ``q``'s type and add up in float32; the
-state, the gates and the inverse are float32.
+Products with q, k, v or the state take their operands in ``q``'s type and
+add up in float32; the state, the gates and the inverse are float32.
 
 **The layout the groups are scanned in, and who writes it.** ``_groups``
 scans operands ``(groups, group, B*H, C, d)``: group of chunks, chunk of the
@@ -70,8 +87,9 @@ group, batch row and head merged (``b * H + h``), token of the chunk, channel
 (:func:`layout` has the counts, :func:`lay_out` the transpose; a sequence is
 padded to whole groups with ``g = 0, beta = 0`` and zeros elsewhere).
 :func:`kda_groups` is the operator on operands that are already so, and gives
-its result so; :func:`kda_chunked` lays out with XLA, calls it, and lays the
-result back. A model does neither relayout as a pass of its own: the
+its result so (a group's five operands go to ops/kda_chunk.py's kernels as
+they lie, blocks of (heads, C, d)); :func:`kda_chunked` lays out with XLA,
+calls it, and lays the result back. A model does neither relayout as a pass of its own: the
 pointwise stages on either side of the operator (ops/kda_stages.py: the
 short convolutions with SiLU, the L2 norms and the gate before it, the gated
 RMSNorm after it) are fused kernels whose block index maps read ``(B, S,
@@ -90,12 +108,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from distributeddeeplearning_tpu.ops import kda_chunk
+
 _HIGHEST = jax.lax.Precision.HIGHEST
-# of the float32 products that make the in-chunk matrices and the inverse:
-# three bfloat16 passes (about float32's own rounding once the operands are
-# at most 1 in size, as these are), at half the six of "highest"; their
-# results are rounded to the operands' type before they meet q, k and v
-_PRECISION = jax.lax.Precision.HIGH
 # tokens a chunk of the chunked form, and of the counter that bounds its
 # exponents (:func:`min_chunk_log_decay`)
 CHUNK = 64
@@ -142,159 +157,6 @@ def min_chunk_log_decay(g):
     return g.astype(jnp.float32).sum(-2).min()
 
 
-def _mm(a, b):
-    return jnp.matmul(a, b, precision=_PRECISION)
-
-
-def _nilpotent_inverse(lower, steps: int):
-    """(I + L)^-1 for ``L`` with ``L^(2^steps) = 0``: (I - L)(I + L^2)(I +
-    L^4)..., ``steps`` factors."""
-    eye = jnp.eye(lower.shape[-1], dtype=lower.dtype)
-    inv, power = eye - lower, lower
-    for _ in range(steps - 1):
-        power = _mm(power, power)
-        inv = inv + _mm(inv, power)
-    return inv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _unit_lower_inverse(lower, sub: int):
-    """(I + L)^-1 of a strictly lower triangular (..., C, C) ``L``: the
-    ``sub``-wide diagonal blocks by their own series, then the blocks below
-    them (module text). Its backward rule is the inverse's own, ``dL = -X^T
-    dX X^T``: two products, where the series' derivative is twenty."""
-    c = lower.shape[-1]
-    block = jnp.arange(c) // sub
-    on_diagonal = block[:, None] == block[None, :]
-    diag_inv = _nilpotent_inverse(jnp.where(on_diagonal, lower, 0.0),
-                                  max(1, (sub - 1).bit_length()))
-    if c == sub:
-        return diag_inv
-    m = _mm(diag_inv, jnp.where(on_diagonal, 0.0, lower))
-    return _mm(_nilpotent_inverse(m, max(1, (c // sub - 1).bit_length())),
-               diag_inv)
-
-
-def _unit_lower_inverse_fwd(lower, sub):
-    inverse = _unit_lower_inverse(lower, sub)
-    return inverse, inverse
-
-
-def _unit_lower_inverse_bwd(sub, inverse, d_inverse):
-    t = jnp.swapaxes(inverse, -1, -2)
-    return (-_mm(_mm(t, d_inverse), t),)
-
-
-_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
-
-
-def _pair_factors(x, y, cum, sub: int):
-    """The pieces :func:`_pair_scores` and its backward rule share: the
-    operands by sub-chunk, the pair decays of the diagonal blocks, and the
-    two factors round each row sub-chunk's first cumulative gate."""
-    c, d = x.shape[-2:]
-    lead = x.shape[:-2]
-    n = c // sub
-    xb, yb, gb = (t.reshape(lead + (n, sub, d)) for t in (x, y, cum))
-    diff = gb[..., :, None, :] - gb[..., None, :, :]
-    i, j = jnp.arange(sub)[:, None], jnp.arange(sub)[None, :]
-    decay = jnp.exp(jnp.where((j < i)[..., None], diff, -jnp.inf))
-    first = gb[..., :, :1, :]                             # (..., n, 1, d)
-    row_factor = jnp.exp(gb - first)                      # (..., n, sub, d)
-    before = (jnp.arange(c)[None, :] // sub) < jnp.arange(n)[:, None]
-    col_factor = jnp.exp(jnp.where(                       # (..., n, C, d)
-        before[..., None], first - cum[..., None, :, :], -jnp.inf))
-    return xb, yb, decay, row_factor, col_factor
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _pair_scores(x, y, cum, sub: int):
-    """P_ij = sum_c x_i[c] y_j[c] exp(cum_i[c] - cum_j[c]) for j < i, else 0,
-    over the last two axes (C, d) of float32 operands, with every exponent
-    at most 0 (module text): diagonal blocks pair by pair, the blocks below
-    them as products of the rows of sub-chunk a and the columns before it,
-    both round the cumulative gate at a's first row."""
-    c = x.shape[-2]
-    lead = x.shape[:-2]
-    n = c // sub
-    xb, yb, decay, row_factor, col_factor = _pair_factors(x, y, cum, sub)
-    diag = (xb[..., :, None, :] * yb[..., None, :, :] * decay).sum(-1)
-    if n == 1:
-        return diag.reshape(lead + (c, c))
-    below = jnp.einsum("...aid,...ajd->...aij", xb * row_factor,
-                       y[..., None, :, :] * col_factor,
-                       precision=_PRECISION)              # (..., n, sub, C)
-    # block a of the diagonal to rows and columns a of the (C, C) result
-    same = jnp.eye(n, dtype=bool)[:, None, :, None]
-    full = jnp.where(same, diag[..., :, :, None, :],
-                     below.reshape(lead + (n, sub, n, sub)))
-    return full.reshape(lead + (c, c))
-
-
-def _pair_scores_fwd(x, y, cum, sub):
-    return _pair_scores(x, y, cum, sub), (x, y, cum)
-
-
-def _pair_scores_bwd(sub, residuals, d_scores):
-    """dx_i = sum_j dP_ij y_j E_ij and dy_j = sum_i dP_ij x_i E_ij by the
-    forward's own factors; every term of P holds exp(G_i - G_j) once, so
-    dG = x * dx - y * dy, a channel at a time, with no pass of its own."""
-    x, y, cum = residuals
-    c = x.shape[-2]
-    lead = x.shape[:-2]
-    n = c // sub
-    xb, yb, decay, row_factor, col_factor = _pair_factors(x, y, cum, sub)
-    d4 = d_scores.reshape(lead + (n, sub, n, sub))
-    same = jnp.eye(n, dtype=bool)[:, None, :, None]
-    d_diag = jnp.where(same, d4, 0.0).sum(-2)             # (..., n, sub, sub)
-    weighted = d_diag[..., None] * decay                  # (..., n, i, j, d)
-    dx = (weighted * yb[..., None, :, :]).sum(-2)
-    dy = (weighted * xb[..., :, None, :]).sum(-3)
-    if n > 1:
-        d_below = d_scores.reshape(lead + (n, sub, c))
-        dx = dx + row_factor * jnp.einsum(
-            "...aij,...ajd->...aid", d_below, y[..., None, :, :] * col_factor,
-            precision=_PRECISION)
-        d_cols = jnp.einsum("...aij,...aid->...ajd", d_below,
-                            xb * row_factor, precision=_PRECISION)
-        dy = dy + (d_cols * col_factor).sum(-3).reshape(yb.shape)
-    dx, dy = dx.reshape(x.shape), dy.reshape(y.shape)
-    return dx, dy, x * dx - y * dy
-
-
-_pair_scores.defvjp(_pair_scores_fwd, _pair_scores_bwd)
-
-
-def _prepare(q, k, v, g, beta, sub: int):
-    """What a group of chunks needs that no state enters. Operands (n, B*H,
-    C, d) (beta without d; g and beta float32); everything float32 here.
-    Returns the operands of the loop over chunks, chunk first."""
-    f32 = jnp.float32
-    dtype = q.dtype
-    c = q.shape[-2]
-    qf, kf = q.astype(f32), k.astype(f32)
-    # G_t: the gates summed from the chunk's start, as a product with a
-    # triangle of ones (a reduce-window is many passes on the chip)
-    cum = jnp.einsum("ts,...sd->...td", jnp.tril(jnp.ones((c, c), f32)), g,
-                     precision=_HIGHEST)
-    a = _pair_scores(kf, kf, cum, sub)                     # (.., C, C)
-    # a token with itself carries no gate, so none in its gradient either
-    # (as a difference of cumulative gates it would cancel to rounding only)
-    bm = (_pair_scores(qf, kf, cum, sub)
-          + (qf * kf).sum(-1)[..., None] * jnp.eye(c, dtype=f32))
-    t = _unit_lower_inverse(beta[..., None] * a, sub) * beta[..., None, :]
-    td = t.astype(dtype)
-    decayed = jnp.exp(cum)
-    k_in = (kf * decayed).astype(dtype)                    # K * exp G
-    w = jnp.matmul(td, k_in, preferred_element_type=f32)   # T (K exp G)
-    tv = jnp.matmul(td, v, preferred_element_type=f32)     # T V
-    q_in = (qf * decayed).astype(dtype)
-    last = cum[..., -1:, :]
-    k_out = (kf * jnp.exp(last - cum)).astype(dtype)       # K exp(G_C - G)
-    return (w.astype(dtype), tv, bm.astype(dtype), q_in, k_out,
-            jnp.exp(last[..., 0, :]))
-
-
 def _group(state, xs, sub: int):
     """One group of chunks, operands (n, B*H, C, d): state (B*H, d_k, d_v)
     in, (state out, the group's outputs (n, B*H, C, d_v))."""
@@ -313,7 +175,8 @@ def _group(state, xs, sub: int):
             "hck,hcv->hkv", k_out, ud, preferred_element_type=f32)
         return state, o.astype(v.dtype)
 
-    return jax.lax.scan(chunk, state, _prepare(q, k, v, g, beta, sub))
+    return jax.lax.scan(chunk, state,
+                        kda_chunk.prepare(q, k, v, g, beta, sub))
 
 
 # ``checkpoint_name`` of what the chunked operator's forward rule hands its
@@ -394,6 +257,7 @@ def lay_back(x, b: int, s: int):
         b, groups * group * chunk, bh // b, d)[:, :s]
 
 
+@functools.partial(jax.jit, static_argnames=("sub", "return_state"))
 def kda_groups(q, k, v, g, beta, initial_state=None, *, sub: int = 16,
                return_state: bool = False):
     """The chunked form on operands already laid out (:func:`lay_out`;

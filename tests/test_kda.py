@@ -4,12 +4,14 @@ float32, at chunks of 16 and 64, at a sequence that is no multiple of the
 chunk, at gates of 0 and of -20 a token (where exp(-G) of a whole chunk would
 overflow), at beta 0 and 1; a state handed on equals one long sequence."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributeddeeplearning_tpu.ops import kda
+from distributeddeeplearning_tpu.ops import kda, kda_chunk
 
 B, H, DK, DV = 2, 3, 8, 12
 NAMES = ("q", "k", "v", "g", "beta")
@@ -25,11 +27,11 @@ def operands(s, gate, beta, seed=1):
     if gate == "model":   # spread as the model's initial gates are
         g = -jnp.exp(1.5 * jax.random.normal(ks[3], (B, s, H, DK)) - 1.0)
     else:
-        g = jnp.full((B, s, H, DK), float(gate))
+        g = jnp.full((B, s, H, DK), float(gate), jnp.float32)
     if beta == "model":
         b = jax.nn.sigmoid(jax.random.normal(ks[4], (B, s, H)))
     else:
-        b = jnp.full((B, s, H), float(beta))
+        b = jnp.full((B, s, H), float(beta), jnp.float32)
     return q, k, v, g, b, jax.random.normal(ks[5], (B, s, H, DV))
 
 
@@ -38,30 +40,43 @@ CASES = [(gate, beta, chunk, s)
          for chunk, s in ((16, 100), (64, 100), (64, 128))]
 
 
+@functools.lru_cache(maxsize=None)
+def _compiled(chunk):
+    """(values, values and gradients) of the recurrence (``chunk`` None) or
+    the chunked form, compiled once a chunk size and sequence length: the
+    cases differ in their operands' values alone."""
+    fn = (kda.kda_recurrent if chunk is None else
+          functools.partial(kda.kda_chunked, chunk=chunk, group=2))
+    return jax.jit(fn), jax.jit(jax.value_and_grad(
+        lambda q, k, v, g, beta, w: (fn(q, k, v, g, beta) * w).sum(),
+        argnums=(0, 1, 2, 3, 4)))
+
+
 @pytest.fixture(scope="module", params=CASES,
                 ids=[f"g{g}-b{b}-c{c}-s{s}" for g, b, c, s in CASES])
 def both(request):
     gate, beta, chunk, s = request.param
     *args, w = operands(s, gate, beta)
     with jax.default_matmul_precision("highest"):
-        def run(fn):
-            return jax.value_and_grad(
-                lambda *a: (fn(*a) * w).sum(), argnums=(0, 1, 2, 3, 4))(*args)
-
-        want = kda.kda_recurrent(*args)
-        got = kda.kda_chunked(*args, chunk=chunk, group=2)
-        _, want_grads = run(kda.kda_recurrent)
-        _, got_grads = run(lambda *a: kda.kda_chunked(*a, chunk=chunk,
-                                                      group=2))
+        want = _compiled(None)[0](*args)
+        got = _compiled(chunk)[0](*args)
+        _, want_grads = _compiled(None)[1](*args, w)
+        _, got_grads = _compiled(chunk)[1](*args, w)
     return got, want, got_grads, want_grads
 
 
 def test_values(both):
+    """To 6e-5 of the largest entry. The chunked form's float32 products are
+    three bfloat16 passes, which its kernels (ops/kda_chunk.py) take on
+    every platform: the worst case here reads 2.4e-5 (gates of 0, where
+    nothing decays the scores). The array lines before them asked XLA for
+    ``Precision.HIGH``, which a CPU runs as whole float32: 1.9e-6 on PR 34's
+    tree, under the 1e-5 this pin was."""
     got, want, _, _ = both
     assert bool(jnp.isfinite(got).all())
     scale = float(jnp.abs(want).max()) or 1.0
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
-                               atol=1e-5 * scale)
+                               atol=6e-5 * scale)
 
 
 @pytest.mark.parametrize("leaf", range(5), ids=NAMES)
@@ -69,12 +84,15 @@ def test_gradients(both, leaf):
     """Each against the recurrence's own. A gate's gradient at -20 a token is
     of the size of exp(-20): it is held to its own scale too, which the
     chunked form keeps because a token's product with itself carries no gate
-    (ops/kda.py::_pair_scores)."""
+    (ops/kda_chunk.py). To 1.5e-4 of the largest entry: the three passes
+    read, worst case of each leaf, q 3.3e-5, k 3.2e-5, v 1.8e-5, g 6.0e-5,
+    beta 2.5e-5 (PR 34's tree on a CPU, whole float32: 2.7e-6, 2.6e-6,
+    1.7e-6, 3.2e-6, 2.0e-6, under the 2e-5 this pin was)."""
     _, _, got, want = both
     assert bool(jnp.isfinite(got[leaf]).all())
     scale = float(jnp.abs(want[leaf]).max())
     np.testing.assert_allclose(np.asarray(got[leaf]), np.asarray(want[leaf]),
-                               rtol=0, atol=2e-5 * scale + 1e-30)
+                               rtol=0, atol=1.5e-4 * scale + 1e-30)
 
 
 def test_nothing_overflows_where_a_chunks_decay_would():
@@ -141,13 +159,13 @@ def test_the_chunk_must_be_whole_sub_chunks():
 
 
 def test_the_inverse_of_a_unit_lower_triangle():
-    """ops/kda.py::_unit_lower_inverse against numpy's, at entries as large
+    """ops/kda_chunk.py::unit_lower_inverse against numpy's, at entries as large
     as keys that all point one way give (beta 1, no decay)."""
     rng = np.random.default_rng(0)
     for scale in (0.1, 1.0):
         lower = np.tril(rng.uniform(-scale, scale, (3, 64, 64)), -1)
         lower = lower.astype(np.float32)
-        got = kda._unit_lower_inverse(jnp.asarray(lower), 16)
+        got = kda_chunk.unit_lower_inverse(jnp.asarray(lower), 16)
         want = np.linalg.inv(np.eye(64) + lower.astype(np.float64))
         np.testing.assert_allclose(np.asarray(got), want, rtol=0,
                                    atol=1e-4 * np.abs(want).max())
@@ -163,12 +181,15 @@ def test_the_inverse_of_a_unit_lower_triangle():
 _names = jax.checkpoint_policies.save_only_these_names
 # loops in the gradient's compiled program at 4 groups of 8 chunks: forward
 # (groups, chunks), backward (groups, a group's chunks remade, and back
-# through them): 5; a recomputed forward adds its 2
+# through them): 5; a recomputed forward adds its 2. And the kernels of a
+# group's stateless work (ops/kda_chunk.py), whose grid is a loop each where
+# they are interpreted: forward, remade, backward, and the recomputed
+# forward's
 LOOPS_CASES = [
-    pytest.param("kept", 5, id="not-recomputed"),
-    pytest.param(None, 7, id="recomputed-no-policy"),
-    pytest.param(_names(kda.KDA_OUT), 7, id="result-without-states"),
-    pytest.param(_names(kda.KDA_OUT, kda.KDA_STATES), 5,
+    pytest.param("kept", 5, 3, id="not-recomputed"),
+    pytest.param(None, 7, 4, id="recomputed-no-policy"),
+    pytest.param(_names(kda.KDA_OUT), 7, 4, id="result-without-states"),
+    pytest.param(_names(kda.KDA_OUT, kda.KDA_STATES), 5, 3,
                  id="result-and-states"),
 ]
 
@@ -193,13 +214,13 @@ def kept_block_grads(block_inputs):
         *block_inputs)
 
 
-@pytest.mark.parametrize("policy,loops", LOOPS_CASES)
+@pytest.mark.parametrize("policy,loops,kernels", LOOPS_CASES)
 def test_a_recomputed_block_runs_the_loops_its_policy_says(
-        block_inputs, kept_block_grads, policy, loops):
+        block_inputs, kept_block_grads, policy, loops, kernels):
     import re
     grad = jax.jit(jax.grad(_block_loss(policy), argnums=(0, 1, 2, 3, 4)))
     text = grad.lower(*block_inputs).compile().as_text()
-    assert len(re.findall(r" while\(", text)) == loops
+    assert len(re.findall(r" while\(", text)) == loops + kernels
     for got, want in zip(grad(*block_inputs), kept_block_grads):
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=0,

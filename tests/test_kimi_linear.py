@@ -37,6 +37,10 @@ KDA_LAYERS = ("layer0", "layer1", "layer3")
 LEAVES = sorted(ref.init_params(SZ, jax.random.key(0)))
 
 
+# the leaves that the gates' cotangent alone reaches
+GATE_LEAVES = ("/dt_bias", "/A_log", "/f_a_proj/kernel", "/f_b_proj/kernel")
+
+
 def unflatten(flat):
     return flax.traverse_util.unflatten_dict(
         {tuple(k.split("/")): v for k, v in flat.items()})
@@ -111,21 +115,30 @@ def test_names_and_shapes_are_the_references(seeded):
 
 def test_logits_and_loss_match_the_reference(both):
     # float32 both sides; the chunked form and the recurrence, the kernels'
-    # online softmax and the plain one, part by rounding only
+    # online softmax and the plain one, part by rounding only: 2.9e-6 with
+    # the chunked form's three bfloat16 passes (2.4e-7 on PR 34's tree,
+    # whose products a CPU ran whole; the pin was 5e-6)
     np.testing.assert_allclose(np.asarray(both["logits"]),
                                np.asarray(both["want_logits"]),
-                               rtol=0, atol=5e-6)
+                               rtol=0, atol=1e-5)
     assert float(both["loss"]) == pytest.approx(float(both["want_loss"]),
                                                 rel=1e-6)
 
 
 @pytest.mark.parametrize("leaf", LEAVES)
 def test_every_gradient_leaf_matches_the_reference(both, leaf):
+    """Of its largest entry: 3e-5, and 1.2e-4 for the leaves that the gates'
+    cotangent alone reaches. The chunked operator's float32 products are
+    three bfloat16 passes on a CPU too since PR 35 (ops/kda_chunk.py writes
+    them out): the worst leaf of either kind reads 1.1e-5 and 5.2e-5 (PR
+    34's tree, whole float32 on a CPU: 8.4e-7 and 1.8e-6, under the 2e-5
+    this pin was)."""
     got, want = both["grads"][leaf], both["want_grads"][leaf]
     scale = float(jnp.abs(want).max())
     assert scale > 0, "a leaf without a gradient is a part that never ran"
+    limit = 1.2e-4 if leaf.endswith(GATE_LEAVES) else 3e-5
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
-                               atol=2e-5 * scale)
+                               atol=limit * scale)
 
 
 @pytest.mark.parametrize("name", MOE_LAYERS)
@@ -235,10 +248,6 @@ def recomputed(seeded):
             unflatten(params)))
 
 
-# the leaves that the gates' cotangent alone reaches
-GATE_LEAVES = ("/dt_bias", "/A_log", "/f_a_proj/kernel", "/f_b_proj/kernel")
-
-
 @pytest.mark.parametrize("leaf", LEAVES)
 def test_a_recomputed_blocks_gradient_is_the_kept_ones(both, recomputed,
                                                        leaf):
@@ -249,9 +258,15 @@ def test_a_recomputed_blocks_gradient_is_the_kept_ones(both, recomputed,
     token with both signs: they read 1.3e-6 to 3.6e-6 of their largest entry
     over seeds 0 to 5 with the stages as array lines and 2.2e-6 to 3.8e-6
     as kernels (the same cotangent's gap, summed in another order), every
-    other leaf under 1.3e-6 either way (PERF.md, PR 32)."""
+    other leaf under 1.3e-6 either way (PERF.md, PR 32). Since PR 35 the
+    operator's float32 products are three bfloat16 passes on a CPU too (its
+    kernels write them out, ops/kda_chunk.py), and a last bit's difference
+    in a cotangent that enters them comes out as a pass's rounding: over
+    seeds 0 to 5 the gates' leaves read 4.5e-6 to 2.8e-5 and every other
+    leaf 3.9e-6 to 6.1e-6 (PR 34's tree: the readings above, under the 5e-6
+    and 2e-6 these pins were)."""
     want = both["grads"][leaf]
-    limit = 5e-6 if leaf.endswith(GATE_LEAVES) else 2e-6
+    limit = 6e-5 if leaf.endswith(GATE_LEAVES) else 1.5e-5
     np.testing.assert_allclose(
         np.asarray(recomputed[leaf]), np.asarray(want), rtol=0,
         atol=limit * float(jnp.abs(want).max()))

@@ -243,6 +243,32 @@ def test_kda_stages_compile_for_v5e(compile_for, stage, grad, kernel):
         2 if grad else 1)
 
 
+@pytest.mark.parametrize("grad,kernel", [(False, "kda_chunk_fwd"),
+                                         (True, "kda_chunk_bwd")])
+def test_kda_chunk_compiles_for_v5e(compile_for, grad, kernel):
+    """A chunk's stateless work (ops/kda_chunk.py) at the kimi cell's block
+    shapes: a group of 8 chunks of 64 tokens, 32 heads of 128 channels,
+    sub-chunks of 16, bf16 q, k and v; a grid step is four heads of one
+    chunk."""
+    from distributeddeeplearning_tpu.ops import kda_chunk
+
+    laid = (8, 32, 64, 128)
+
+    def fn(q, k, v, g, beta):
+        return kda_chunk.prepare(q, k, v, g, beta, 16)
+
+    if grad:
+        value = fn
+        fn = jax.grad(lambda *a: sum(
+            (o.astype(F32) ** 2).sum() for o in value(*a)),
+            argnums=tuple(range(5)))
+    text = compile_for(fn, *([(laid, BF16)] * 3
+                             + [(laid, F32), (laid[:3], F32)]))
+    assert f"%{kernel}" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        2 if grad else 1)
+
+
 def _compiled_share_step(one_chip, name: str, seq: int, vocab: int):
     """(compiled step, parameters) of one chip's share `name` at one sequence
     of `seq` tokens: the mixed-precision AdamW step built as
@@ -317,23 +343,27 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
     `train/loop.build` builds it (`make_gspmd_train_step` on a mesh of the
     described chip), lowered with shapes and compiled. What the chip's
     compiler says of it: it fits (7.23 GB of state: float32 masters and
-    Adam's two moments of 602M parameters; 4.83 GB of temporaries, the
-    float32 gradients among them; 5.40 before the pointwise stages round
-    the delta rule were kernels), the latent layer's three flash kernels
-    are there at 192 / 128, and the chunked delta rule's loops are `while`s:
-    five a KDA layer (forward over groups and over a group's chunks; backward
-    over groups, a group's chunks remade, and back through them) and none
-    for a recomputed forward, whose result and entering states the block
-    keeps."""
+    Adam's two moments of 602M parameters; 4.82 GB of temporaries, the
+    float32 gradients among them: 4.83 before a chunk's stateless work was
+    two kernels, whose (8, 32, 4, 16, 16, 128) pair intermediates were never
+    the peak; 5.40 before the pointwise stages round the delta rule were
+    kernels), the latent layer's three flash kernels are there at 192 / 128,
+    and the chunked delta rule's loops are `while`s: five a KDA layer
+    (forward over groups, with `kda_chunk_fwd` in its body, and over a
+    group's chunks; backward over groups, with `kda_chunk_fwd` remaking the
+    group and `kda_chunk_bwd` in its body, a group's chunks remade, and back
+    through them) and none for a recomputed forward, whose result and
+    entering states the block keeps."""
     compiled, parameters = _compiled_share_step(
         one_chip, "kimi_linear_ep32", seq=8192, vocab=20480)
     assert parameters == 602_449_792
     memory = compiled.memory_analysis()
     print("state:", memory.argument_size_in_bytes, "temporaries:",
-          memory.temp_size_in_bytes)
+          memory.temp_size_in_bytes, "whiles:",
+          len(re.findall(r" while\(", compiled.as_text())))
     assert memory.argument_size_in_bytes == pytest.approx(
         12 * parameters, rel=0.001)
-    assert memory.temp_size_in_bytes < 1.1 * 4.83e9
+    assert memory.temp_size_in_bytes < 1.1 * 4.82e9
     text = compiled.as_text()
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert len(re.findall(rf"%{name}\S* = ", text)) == 1, name
@@ -344,13 +374,25 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
     # backward; its output stage likewise (the recomputed forward feeds the
     # output projection's weight gradient); each under the model's scope,
     # so the trace books it as `attention_kda`
+    # and what a chunk needs that no state enters (ops/kda_chunk.py): the
+    # forward kernel once in the body of the forward loop over groups and
+    # once where the backward loop remakes a group, the backward kernel once
+    # in that loop's body
     calls = {name: re.findall(rf"%{name}\S* = .*", text)
              for name in ("kda_in_fwd", "kda_in_bwd", "kda_out_fwd",
-                          "kda_out_bwd")}
+                          "kda_out_bwd", "kda_chunk_fwd", "kda_chunk_bwd")}
     assert {k: len(v) for k, v in calls.items()} == {
-        "kda_in_fwd": 8, "kda_in_bwd": 4, "kda_out_fwd": 8, "kda_out_bwd": 4}
+        "kda_in_fwd": 8, "kda_in_bwd": 4, "kda_out_fwd": 8, "kda_out_bwd": 4,
+        "kda_chunk_fwd": 8, "kda_chunk_bwd": 4}
     assert all("attn_kda" in line for lines in calls.values()
                for line in lines)
+    # so no float32 array of a group's pair intermediates is left under the
+    # scope: (8, 32, 4, 16, 16, 128) a pair and channel, (8, 32, 4, 16, 64,
+    # 128) a sub-chunk's column factors
+    pairs = [m.group(0)[:200] for m in re.finditer(
+        r"%\S+ = f32\[[\d,]*4,16,(16|64),128\]\S* .*", text)
+        if "attn_kda" in m.group(0)]
+    assert not pairs, pairs
     # and with the relayouts in their index maps, no pass of its own lays a
     # float32 (8192, 4096) array of a KDA layer out anew
     entry = text[text.index("\nENTRY "):]
@@ -377,7 +419,8 @@ def test_xing4_ep8_step_compiles_for_v5e(one_chip):
     assert parameters == 759_489_550
     memory = compiled.memory_analysis()
     print("state:", memory.argument_size_in_bytes, "temporaries:",
-          memory.temp_size_in_bytes)
+          memory.temp_size_in_bytes, "whiles:",
+          len(re.findall(r" while\(", compiled.as_text())))
     assert memory.argument_size_in_bytes == pytest.approx(
         12 * parameters, rel=0.001)
     assert memory.temp_size_in_bytes < 1.1 * 4.45e9

@@ -30,17 +30,26 @@ Design constraints, in order:
 
 The module-level singleton (:func:`get` / :func:`configure`) is what the
 instrumentation sites use; tests construct :class:`Telemetry` directly.
+
+Set-up is recorded apart from the singleton, always, in one bounded
+process-wide *phase log* (:func:`phase`, :func:`phases`): the program's own
+one-off phases (``build``, ``aot_load``, ``aot_save``, ``compile``,
+``warm_compile``) and, once :func:`watch_compiles` has run, each program JAX
+traces (``trace``), lowers (``lower``), compiles (``xla_compile``) or takes
+from its persistent cache (``cache_load``), by the program's name.
 """
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 try:  # POSIX advisory locking for multi-process export merges
     import fcntl
@@ -368,6 +377,128 @@ def reset() -> None:
     """Back to the disabled singleton (tests)."""
     global _active
     _active = Telemetry()
+
+
+# ---------------------------------------------------------------------------
+# Phase log — set-up, recorded whether or not the singleton is enabled.
+# ---------------------------------------------------------------------------
+
+#: Set-up makes tens to hundreds of records (up to three a program JAX
+#: builds), so the bound holds many set-ups; past it the oldest go.
+MAX_PHASES = 50_000
+
+
+class Phase(NamedTuple):
+    """One record of the phase log."""
+
+    name: str
+    start_s: float  # both ends from now_s()
+    end_s: float
+    args: dict
+
+
+_phase_lock = threading.Lock()
+_phase_log: deque = deque(maxlen=MAX_PHASES)
+_phases_dropped = 0
+_watching_compiles = False
+
+
+def _log_phase(name: str, start_s: float, end_s: float, args: dict) -> None:
+    global _phases_dropped
+    with _phase_lock:
+        if len(_phase_log) == _phase_log.maxlen:
+            _phases_dropped += 1
+        _phase_log.append(Phase(name, start_s, end_s, args))
+
+
+def phases() -> Optional[list[Phase]]:
+    """The phase log, oldest first; None once it has dropped a record, so
+    that no reader sums part of it as if it were all."""
+    with _phase_lock:
+        return None if _phases_dropped else list(_phase_log)
+
+
+def clear_phases() -> None:
+    """Empty the phase log (tests)."""
+    global _phases_dropped
+    with _phase_lock:
+        _phase_log.clear()
+        _phases_dropped = 0
+
+
+@contextlib.contextmanager
+def phase(name: str, **args: Any):
+    """Time a one-off phase into the phase log: ``with phase("aot_save",
+    program=name): ...``, or ``@phase("build")`` on a function. An
+    exception that leaves the body adds its type under ``error``. Where telemetry is enabled the phase is also the same ``X``
+    event as :meth:`Telemetry.span`, and where jax is imported it is a
+    profiler annotation ``ddl:<name>``, so a device trace that covers set-up
+    shows it on the profiler's clock."""
+    jax = sys.modules.get("jax")
+    ann = (jax.profiler.TraceAnnotation("ddl:" + name)
+           if jax is not None else None)
+    if ann is not None:
+        ann.__enter__()
+    t0 = now_s()
+    try:
+        yield
+    except BaseException as e:
+        args["error"] = type(e).__name__
+        raise
+    finally:
+        t1 = now_s()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        _log_phase(name, t0, t1, args)
+        _active.record_span(name, t0, t1, **args)
+
+
+# JAX's compile pipeline reports each stage's duration through
+# jax.monitoring, with the program's name, as the stage ends.
+_JAX_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+               "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower"}
+_JAX_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JAX_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_retrieval = threading.local()
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kwargs) -> None:
+    end = now_s()
+    if event == _JAX_CACHE_RETRIEVAL:
+        # Only a persistent-cache hit reports a retrieval, inside the
+        # backend compile that then ends on the same thread.
+        _retrieval.span = (end - duration_secs, end)
+        return
+    fun = kwargs.get("fun_name")
+    if event == _JAX_BACKEND_COMPILE:
+        hit, _retrieval.span = getattr(_retrieval, "span", None), None
+        if hit is not None:
+            _log_phase("cache_load", hit[0], hit[1], {"fun": fun})
+        else:
+            _log_phase("xla_compile", end - duration_secs, end, {"fun": fun})
+        return
+    stage = _JAX_STAGES.get(event)
+    if stage is not None:
+        _log_phase(stage, end - duration_secs, end, {"fun": fun})
+
+
+def watch_compiles() -> None:
+    """Record JAX's trace / lower / compile / cache-load of every program
+    into the phase log from now on (idempotent; imports jax). The listener
+    runs only while JAX builds a program: nothing per step."""
+    global _watching_compiles
+    with _phase_lock:
+        if _watching_compiles:
+            return
+        _watching_compiles = True
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def watching_compiles() -> bool:
+    """Whether :func:`watch_compiles` has run in this process: without it
+    the log holds no JAX record, and a count of them reads nothing."""
+    return _watching_compiles
 
 
 # ---------------------------------------------------------------------------

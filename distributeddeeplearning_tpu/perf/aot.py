@@ -52,6 +52,7 @@ import time
 from typing import Any, Optional
 
 from distributeddeeplearning_tpu.analysis import anatomy as anatomy_lib
+from distributeddeeplearning_tpu.observability import telemetry
 from distributeddeeplearning_tpu.perf import compile_cache
 
 FORMAT_VERSION = 1
@@ -295,32 +296,34 @@ class StepExecutableCache:
             self.sources[name] = "compiled"
             return None
         try:
-            with open(path, "rb") as fh:
-                payload = pickle.load(fh)
-            if payload.get("format") != FORMAT_VERSION:
-                raise ValueError(f"format {payload.get('format')!r}")
-            if payload.get("versions") != versions():
-                raise ValueError(
-                    f"built under jax {payload.get('versions')}, "
-                    f"running {versions()}")
-            from jax.experimental import serialize_executable
-            fn = serialize_executable.deserialize_and_load(
-                payload["executable"], payload["in_tree"],
-                payload["out_tree"],
-                execution_devices=self.devices)
-            # Donation backstop (the PR 5 bug class, cheap runtime form of
-            # analysis/donation.py): the deserialized executable must
-            # donate exactly the inputs it donated when saved. A drifted
-            # donation set means a dispatch through this hit could donate
-            # buffers the caller still aliases — delete + recompile cold.
-            saved_donation = payload.get("donation")
-            live_donation = donation_signature(fn)
-            if (saved_donation is not None and live_donation is not None
-                    and saved_donation != live_donation):
-                raise ValueError(
-                    f"donation set drifted: saved "
-                    f"input_output_alias {saved_donation} != deserialized "
-                    f"{live_donation}")
+            with telemetry.phase("aot_load", program=name):
+                with open(path, "rb") as fh:
+                    payload = pickle.load(fh)
+                if payload.get("format") != FORMAT_VERSION:
+                    raise ValueError(f"format {payload.get('format')!r}")
+                if payload.get("versions") != versions():
+                    raise ValueError(
+                        f"built under jax {payload.get('versions')}, "
+                        f"running {versions()}")
+                from jax.experimental import serialize_executable
+                fn = serialize_executable.deserialize_and_load(
+                    payload["executable"], payload["in_tree"],
+                    payload["out_tree"],
+                    execution_devices=self.devices)
+                # Donation backstop (the PR 5 bug class, cheap runtime form
+                # of analysis/donation.py): the deserialized executable must
+                # donate exactly the inputs it donated when saved. A drifted
+                # donation set means a dispatch through this hit could
+                # donate buffers the caller still aliases — delete +
+                # recompile cold.
+                saved_donation = payload.get("donation")
+                live_donation = donation_signature(fn)
+                if (saved_donation is not None and live_donation is not None
+                        and saved_donation != live_donation):
+                    raise ValueError(
+                        f"donation set drifted: saved "
+                        f"input_output_alias {saved_donation} != "
+                        f"deserialized {live_donation}")
         except Exception as exc:  # noqa: BLE001 - any mismatch = cold path
             self.failures += 1
             self.misses += 1
@@ -344,31 +347,32 @@ class StepExecutableCache:
         if self.dir is None:
             return False
         try:
-            from jax.experimental import serialize_executable
-            executable, in_tree, out_tree = serialize_executable.serialize(
-                compiled_exec)
-            blob = pickle.dumps({
-                "format": FORMAT_VERSION,
-                "versions": versions(),
-                "runtime": runtime_tag(self.devices),
-                "name": name,
-                "fingerprint": self.fingerprint,
-                "executable": executable,
-                "in_tree": in_tree,
-                "out_tree": out_tree,
-                "donation": donation_signature(compiled_exec),
-                "saved_at": time.time(),
-            })
-            table = anatomy_lib.table(compiled_exec.as_text())
-            os.makedirs(self.dir, exist_ok=True)
-            path = self._path(key)
-            for target, mode, data in (
-                    (_anatomy_path(path), "w", json.dumps(table)),
-                    (path, "wb", blob)):
-                tmp = f"{target}.tmp.{os.getpid()}"
-                with open(tmp, mode) as fh:
-                    fh.write(data)
-                os.replace(tmp, target)
+            with telemetry.phase("aot_save", program=name):
+                from jax.experimental import serialize_executable
+                executable, in_tree, out_tree = serialize_executable.serialize(
+                    compiled_exec)
+                blob = pickle.dumps({
+                    "format": FORMAT_VERSION,
+                    "versions": versions(),
+                    "runtime": runtime_tag(self.devices),
+                    "name": name,
+                    "fingerprint": self.fingerprint,
+                    "executable": executable,
+                    "in_tree": in_tree,
+                    "out_tree": out_tree,
+                    "donation": donation_signature(compiled_exec),
+                    "saved_at": time.time(),
+                })
+                table = anatomy_lib.table(compiled_exec.as_text())
+                os.makedirs(self.dir, exist_ok=True)
+                path = self._path(key)
+                for target, mode, data in (
+                        (_anatomy_path(path), "w", json.dumps(table)),
+                        (path, "wb", blob)):
+                    tmp = f"{target}.tmp.{os.getpid()}"
+                    with open(tmp, mode) as fh:
+                        fh.write(data)
+                    os.replace(tmp, target)
             _RESOLVED[name] = path
         except Exception as exc:  # noqa: BLE001 - saving is optional
             print(f"[aot] could not serialize {name} "

@@ -30,6 +30,8 @@ import os
 import time
 from typing import Any, Optional
 
+from distributeddeeplearning_tpu.observability import telemetry
+
 ENV_CACHE = "JAX_COMPILATION_CACHE_DIR"
 STATS_FILE = "ddl_cache_stats.json"
 AOT_SUBDIR = "aot"
@@ -54,10 +56,13 @@ def cache_dir(enabled: bool = True) -> Optional[str]:
 def activate(enabled: bool = True) -> Optional[str]:
     """Point JAX's persistent compilation cache at :func:`cache_dir` (or
     switch it off for this process) before the first compile. Returns the
-    active directory, or None when disabled."""
+    active directory, or None when disabled. Every entry point calls this
+    first, so it also starts recording each program JAX builds into the
+    phase log (``telemetry.watch_compiles``), cache on or off."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache as cc
 
+    telemetry.watch_compiles()
     path = cache_dir(enabled)
     if (jax.config.jax_enable_compilation_cache != (path is not None)
             or (path and jax.config.jax_compilation_cache_dir != path)):

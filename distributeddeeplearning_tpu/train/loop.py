@@ -91,6 +91,7 @@ def uses_gspmd(config: TrainConfig, input_kind: str) -> bool:
     return p.fsdp > 1 and config.optimizer_sharding != "zero3"
 
 
+@telemetry.phase("build")
 def build(config: TrainConfig, total_steps: int):
     """Construct (mesh, model, batch sharding, state, train_step, sched, rng)
     for a config. The data source is NOT built here — real pipelines must be
@@ -1547,29 +1548,29 @@ class _EvaluatorBase:
         state_struct = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=x.sharding), state)
-        tele = telemetry.get()
 
         def work():
             try:
-                t0 = telemetry.now_s()
-                # One throwaway batch fixes the eval batch avals; synthetic
-                # sources are indexable (nothing is consumed) and real
-                # sources are rebuilt fresh per eval invocation anyway.
-                source, offset = self._source_and_offset()
-                batch = source.batch(offset)
-                fn = None
-                key = None
-                if aot is not None and aot.enabled:
-                    key = aot.key("eval_step", (state_struct, batch))
-                    fn = aot.load("eval_step", key)
-                if fn is None:
-                    lower = getattr(self.eval_step, "lower_for",
-                                    None) or self.eval_step.lower
-                    fn = aotlib.compile_lowered(lower(state_struct, batch))
-                    if key is not None:
-                        aot.save("eval_step", key, fn)
+                with telemetry.phase("warm_compile"):
+                    # One throwaway batch fixes the eval batch avals;
+                    # synthetic sources are indexable (nothing is consumed)
+                    # and real sources are rebuilt fresh per eval
+                    # invocation anyway.
+                    source, offset = self._source_and_offset()
+                    batch = source.batch(offset)
+                    fn = None
+                    key = None
+                    if aot is not None and aot.enabled:
+                        key = aot.key("eval_step", (state_struct, batch))
+                        fn = aot.load("eval_step", key)
+                    if fn is None:
+                        lower = getattr(self.eval_step, "lower_for",
+                                        None) or self.eval_step.lower
+                        fn = aotlib.compile_lowered(
+                            lower(state_struct, batch))
+                        if key is not None:
+                            aot.save("eval_step", key, fn)
                 self._warm_exec = fn
-                tele.record_span("warm_compile", t0, telemetry.now_s())
             except Exception:  # noqa: BLE001 - warm-up is optional
                 self._warm_exec = None
 

@@ -27,7 +27,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import time
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -73,23 +72,19 @@ TRACE_COUNTS: collections.Counter = collections.Counter()
 def _aot_acquire(aot, name: str, jitted, args):
     """Resolve an ahead-of-time executable for ``jitted`` at ``args``' avals.
 
-    Fingerprint hit: deserialize the saved executable (telemetry span
-    ``aot_load``) — zero tracing. Miss: ``lower().compile()`` cold
-    (telemetry span ``compile``) and serialize for the next attempt. The
-    lowered ``Compiled`` object must be called directly — invoking the jit
-    wrapper afterwards would re-trace, since AOT compilation bypasses jit's
+    Fingerprint hit: deserialize the saved executable (phase ``aot_load``)
+    — zero tracing. Miss: ``lower().compile()`` cold (phase ``compile``)
+    and serialize for the next attempt (phase ``aot_save``). The lowered
+    ``Compiled`` object must be called directly — invoking the jit wrapper
+    afterwards would re-trace, since AOT compilation bypasses jit's
     internal cache.
     """
-    tele = telemetry.get()
     key = aot.key(name, args)
-    t0 = time.perf_counter()
     fn = aot.load(name, key)
     if fn is not None:
-        tele.record_span("aot_load", t0, time.perf_counter())
         return fn
-    t0 = time.perf_counter()
-    compiled_exec = aotlib.compile_lowered(jitted.lower(*args))
-    tele.record_span("compile", t0, time.perf_counter())
+    with telemetry.phase("compile", program=name):
+        compiled_exec = aotlib.compile_lowered(jitted.lower(*args))
     aot.save(name, key, compiled_exec)
     return compiled_exec
 
@@ -737,10 +732,8 @@ def make_dp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 fn = _aot_acquire(aot, "dp_train_step", jitted,
                                   (state_struct, batch, rng))
             else:
-                t0 = time.perf_counter()
-                fn = jitted.lower(state_struct, batch, rng).compile()
-                telemetry.get().record_span("compile", t0,
-                                            time.perf_counter())
+                with telemetry.phase("compile", program="dp_train_step"):
+                    fn = jitted.lower(state_struct, batch, rng).compile()
             aot_exec["fn"] = fn
             aot_exec["resolved"] = True
             return True
@@ -1079,10 +1072,10 @@ def make_gspmd_train_step(model, tx, mesh: Mesh, config: TrainConfig,
                     jitted = _aot_acquire(aot, "gspmd_train_step", jitted,
                                           (state_struct, batch, rng))
                 else:
-                    t0 = time.perf_counter()
-                    jitted = jitted.lower(state_struct, batch, rng).compile()
-                    telemetry.get().record_span("compile", t0,
-                                                time.perf_counter())
+                    with telemetry.phase("compile",
+                                         program="gspmd_train_step"):
+                        jitted = jitted.lower(state_struct, batch,
+                                              rng).compile()
             jit_cache[key] = jitted
             return True
         except Exception:  # noqa: BLE001 - warm-up is optional

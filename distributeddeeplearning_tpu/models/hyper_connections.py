@@ -23,11 +23,15 @@ Layout. The streams travel as ONE (B, S, n*C) array, stream ``j`` the
 channels ``[j*C, (j+1)*C)``: ``vec(X)`` is then the array itself, the norm and
 the product with Phi run over its last axis, a stream is a slice of lanes at a
 multiple of 128, and nothing has an axis of 4 among its two minor ones, which
-the TPU's tiled layouts would pad to 8 or 16 rows. The coefficients are made
-with the TOKENS minor, (n^2 + 2n, B, S), for the same reason (a (.., 4, 4)
-array of float32 is padded to 8 x 128 a token, and 40 of them are kept for
-the Sinkhorn iterations' backward pass), and turned to (B, S, n^2 + 2n) once,
-for the mixes, where a token's coefficient is a scalar across the lanes.
+the TPU's tiled layouts would pad to 8 or 16 rows. The passes over the
+streams are Pallas kernels (ops/mhc.py): one read of the streams gives the
+norm, the product with Phi and the sub-layer's input ``H_pre X``
+(:func:`ops.mhc.mhc_in`), one more read writes ``X'`` (:func:`write`), and
+the backward rule reads them once a pass. The Sinkhorn iterations run on the
+coefficients with the TOKENS minor, (n^2 + n, B, S), between the two (a
+(.., 4, 4) array of float32 is padded to 8 x 128 a token, and 40 of them are
+kept for the iterations' backward pass); the kernels take them as a row a
+token.
 
 :class:`HyperConnection` makes the coefficients and the sub-layer's input;
 :func:`write` puts the sub-layer's result back. Both run under the scope
@@ -45,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributeddeeplearning_tpu.models.llama import Held
+from distributeddeeplearning_tpu.ops import mhc as kernels
 
 SCOPE = "mhc"
 # the collection a hyper-connection sows :func:`row_sum_gap` into; the step's
@@ -85,11 +90,6 @@ def row_sum_gap(h_res):
     return jnp.abs(h_res.sum(1) - 1.0).max()
 
 
-def _stream(x, j: int, n: int):
-    c = x.shape[-1] // n
-    return x[..., j * c:(j + 1) * c]
-
-
 def spread(x, n: int):
     """(B, S, C) -> (B, S, n*C): the embedding copied to the n streams."""
     with jax.named_scope(SCOPE):
@@ -99,30 +99,18 @@ def spread(x, n: int):
 def collect(x, n: int):
     """(B, S, n*C) -> (B, S, C): the streams summed, after the last layer."""
     with jax.named_scope(SCOPE):
-        total = sum(_stream(x, j, n).astype(jnp.float32) for j in range(n))
+        total = sum(kernels.stream(x, j, n).astype(jnp.float32)
+                    for j in range(n))
         return total.astype(x.dtype)
 
 
-def read(x, coef, n: int):
-    """The sub-layer's input ``H_pre X``: (B, S, C) in ``x``'s type, summed
-    in float32. ``coef``: (B, S, n^2 + 2n), H_pre first."""
-    h = sum(coef[..., j, None] * _stream(x, j, n).astype(jnp.float32)
-            for j in range(n))
-    return h.astype(x.dtype)
-
-
-def write(x, y, coef, n: int):
+def write(x, y, coef):
     """``X' = H_res X + H_post^T y``: (B, S, n*C) in ``x``'s type, each
-    stream summed in float32. ``coef`` as :class:`HyperConnection` returns
-    it: H_pre (n), H_post (n), H_res (n^2, rows first)."""
+    stream summed in float32. ``x``: the streams as :class:`HyperConnection`
+    hands them back; ``coef`` as it returns it: H_post (n), H_res (n^2, rows
+    first)."""
     with jax.named_scope(SCOPE):
-        yf = y.astype(jnp.float32)
-        xs = [_stream(x, j, n).astype(jnp.float32) for j in range(n)]
-        out = [coef[..., n + i, None] * yf
-               + sum(coef[..., 2 * n + i * n + j, None] * xs[j]
-                     for j in range(n))
-               for i in range(n)]
-        return jnp.concatenate(out, axis=-1).astype(x.dtype)
+        return kernels.mhc_out(x, y, coef)
 
 
 def static_bias_init(n: int):
@@ -140,10 +128,13 @@ def static_bias_init(n: int):
 
 
 class HyperConnection(nn.Module):
-    """One hyper-connection round a sub-layer: ``(h, coef) = hc(X)`` gives the
-    sub-layer's input and the coefficients :func:`write` puts its result back
-    with. Parameters: ``norm/scale`` (n*C), ``phi/kernel`` (n*C, n^2 + 2n),
-    ``bias`` (n^2 + 2n) and ``alpha`` (3: pre, post, res)."""
+    """One hyper-connection round a sub-layer: ``(h, coef, x) = hc(X)`` gives
+    the sub-layer's input, the coefficients :func:`write` puts its result
+    back with, and the streams for :func:`write` to read (``X`` itself: so
+    the write's share of the streams' cotangent reaches the input pass's
+    backward kernel, which adds it in the pass it makes anyway). Parameters:
+    ``norm/scale`` (n*C), ``phi/kernel`` (n*C, n^2 + 2n), ``bias`` (n^2 +
+    2n) and ``alpha`` (3: pre, post, res)."""
 
     streams: int
     sinkhorn_iters: int
@@ -164,20 +155,18 @@ class HyperConnection(nn.Module):
         alpha = self.param("alpha", nn.initializers.constant(ALPHA_INIT),
                            (3,), f32)
         with jax.named_scope(SCOPE):
-            xf = x.astype(f32)
-            xn = xf * jax.lax.rsqrt(
-                jnp.mean(xf * xf, -1, keepdims=True) + self.rms_eps) * scale
-            u = jnp.einsum("bsk,km->mbs", xn, phi,
-                           precision=jax.lax.Precision.HIGHEST)
-            by_group = alpha[np.repeat(np.arange(3), (n, n, n * n))]
-            h = by_group[:, None, None] * u + bias[:, None, None]
-            pre = jax.nn.sigmoid(h[:n])
-            post = 2.0 * jax.nn.sigmoid(h[n:2 * n])
-            res = sinkhorn(h[2 * n:].reshape((n, n) + h.shape[1:]),
+            h, u, x = kernels.mhc_in(x, scale, phi, alpha[0], bias[:n],
+                                     eps=self.rms_eps)
+            # H_post and H_res from their pre-activations, tokens minor
+            by_group = alpha[np.repeat(np.arange(1, 3), (n, n * n))]
+            logits = (by_group[:, None, None] * jnp.moveaxis(u[..., n:], -1, 0)
+                      + bias[n:, None, None])
+            post = 2.0 * jax.nn.sigmoid(logits[:n])
+            res = sinkhorn(logits[n:].reshape((n, n) + logits.shape[1:]),
                            iters=self.sinkhorn_iters, eps=self.eps,
                            clamp=self.clamp)
             self.sow(MHC_METRICS, "row_sum_gap",
                      jax.lax.stop_gradient(row_sum_gap(res)))
             coef = jnp.moveaxis(jnp.concatenate(
-                [pre, post, res.reshape((n * n,) + h.shape[1:])]), 0, -1)
-            return read(x, coef, n), coef
+                [post, res.reshape((n * n,) + logits.shape[1:])]), 0, -1)
+            return h, coef, x

@@ -181,12 +181,11 @@ class Xing4Block(nn.Module):
     @nn.compact
     def __call__(self, x, pad_mask, *, train: bool):
         cfg = self.cfg
-        n = cfg.hc_mult
-        h, coef = self._hyper_connection("attn_hc")(x)
+        h, coef, x = self._hyper_connection("attn_hc")(x)
         h = _rms_norm(cfg, self.dtype, "input_layernorm")(h)
         x = mhc.write(x, XingMlaAttention(cfg, self.dtype, name="attention")(
-            h, pad_mask), coef, n)
-        h, coef = self._hyper_connection("ffn_hc")(x)
+            h, pad_mask), coef)
+        h, coef, x = self._hyper_connection("ffn_hc")(x)
         h = _rms_norm(cfg, self.dtype, "post_attention_layernorm")(h)
         if self.index < cfg.num_dense_layers:
             with jax.named_scope("mlp"):
@@ -208,7 +207,7 @@ class Xing4Block(nn.Module):
                               * cfg.moe_intermediate_size),
                 bias_update_rate=cfg.load_balance_coeff, dtype=self.dtype,
                 name="moe")(h, train=train)
-        return mhc.write(x, h, coef, n)
+        return mhc.write(x, h, coef)
 
 
 # the streams as one array: batch, sequence, and the n streams' channels

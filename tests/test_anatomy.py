@@ -172,6 +172,22 @@ CASES = [
      "forward", "residual_mhc"),
     (STEP + "grads/jvp(Xing4LM)/layer3/ffn_hc/mhc/while/body/div", "forward",
      "residual_mhc"),
+    # the kernels of the passes over the streams (ops/mhc.py) sit inside the
+    # scope, forward, backward and where a recomputed block remakes them
+    (STEP + "grads/jvp(Xing4LM)/layer1.<lambda>/layer1/attn_hc/mhc/"
+     "mhc_in_fwd/cond/branch_0_fun/mhc_in_fwd/pallas_call", "forward",
+     "residual_mhc"),
+    (STEP + "grads/jvp(Xing4LM)/layer1.<lambda>/layer1/mhc/mhc_out_fwd/cond/"
+     "branch_0_fun/mhc_out_fwd/pallas_call", "forward", "residual_mhc"),
+    (STEP + "grads/transpose(jvp(Xing4LM))/jvp(Xing4LM)/checkpoint/"
+     "rematted_computation/layer1.<lambda>/layer1/ffn_hc/mhc/mhc_in_fwd/"
+     "cond/branch_0_fun/mhc_in_fwd/pallas_call", "backward", "residual_mhc"),
+    (STEP + "grads/transpose(jvp(Xing4LM))/jvp(Xing4LM)/checkpoint/"
+     "layer1.<lambda>/layer1/ffn_hc/mhc/mhc_in_bwd/cond/branch_0_fun/"
+     "mhc_in_bwd/pallas_call", "backward", "residual_mhc"),
+    (STEP + "grads/transpose(jvp(Xing4LM))/jvp(Xing4LM)/checkpoint/"
+     "layer1.<lambda>/layer1/mhc/mhc_out_bwd/cond/branch_0_fun/mhc_out_bwd/"
+     "pallas_call", "backward", "residual_mhc"),
     (STEP + "grads/transpose(jvp(Xing4LM))/grads/jvp(Xing4LM)/checkpoint/"
      "rematted_computation/layer3/mhc/add", "backward", "residual_mhc"),
     (STEP + "grads/jvp(Xing4LM)/mhc/concatenate", "forward", "residual_mhc"),

@@ -12,6 +12,7 @@ trainer's steps and counters."""
 import json
 import math
 import os
+import re
 import sys
 
 import flax
@@ -328,6 +329,48 @@ def test_each_flash_kernel_stands_once_a_layer(seeded, remat):
         jax.grad(lambda p: program_loss(model, p, router_state(extra),
                                         batch["input_ids"])[0]),
         unflatten(params)) == {"flash_fwd": n, "flash_dq": n, "flash_dkv": n}
+
+
+MHC_KERNELS = ("mhc_in_fwd", "mhc_in_bwd", "mhc_out_fwd", "mhc_out_bwd")
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "recomputed"])
+def test_each_mhc_kernel_stands_once_a_hyper_connection_and_pass(seeded,
+                                                                 remat):
+    """The counter that the hyper-connections' kernels (ops/mhc.py) engaged:
+    in the gradient's program, lowered for the TPU from the CPU, every
+    hyper-connection (two a layer) runs its input pass forward and backward
+    once, and its write-back backward once; a recomputed block runs both
+    input passes' forward again and the attention round's write-back (whose
+    result the feed-forward round reads), not the feed-forward round's. Each
+    call's name stack books it under the part ``residual_mhc`` by the rule
+    "a model's own scope wins" (analysis/anatomy.py), a backward kernel in
+    the backward phase."""
+    from distributeddeeplearning_tpu.analysis import anatomy
+
+    params, extra, batch = seeded
+    model = tiny_model(remat=remat)
+    hcs = 2 * model.cfg.num_layers
+    lowered = jax.jit(jax.grad(
+        lambda p: program_loss(model, p, router_state(extra),
+                               batch["input_ids"])[0])).trace(
+        unflatten(params)).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    again = hcs // 2 if remat else 0
+    assert {name: text.count(f'kernel_name = "{name}"')
+            for name in MHC_KERNELS} == {
+        "mhc_in_fwd": hcs + 2 * again, "mhc_in_bwd": hcs,
+        "mhc_out_fwd": hcs + again, "mhc_out_bwd": hcs}
+    stacks = re.findall(r'loc\("([^"]*/pallas_call)"',
+                        lowered.as_text(debug_info=True))
+    for name in MHC_KERNELS:
+        booked = {anatomy.part_of(stack) for stack in stacks
+                  if stack.endswith(f"/{name}/pallas_call")}
+        assert {part for _, part in booked} == {"residual_mhc"}, booked
+        if name.endswith("bwd"):
+            assert {phase for phase, _ in booked} == {"backward"}, booked
+        else:
+            assert ("forward", "residual_mhc") in booked
 
 
 @pytest.fixture(scope="module")

@@ -253,10 +253,10 @@ class KimiLinearLM(nn.Module):
                 # a block keeps what models/afmoe.py's keeps, the routed
                 # experts' result and the flash forward kernel's with its
                 # log-sum-exp, and the chunked recurrence's result with the
-                # state that enters each group of chunks (67 + 34 MB a KDA
-                # layer): its backward rule remakes each group from those
-                # (ops/kda.py), so the recomputed forward runs no loop over
-                # chunks at all, one forward in five (docs/kimi_linear.md)
+                # state that enters each chunk (67 + 268 MB a KDA layer):
+                # its backward kernel remakes each chunk from those
+                # (ops/kda.py), so the recomputed forward runs no forward
+                # kernel of the recurrence (docs/kimi_linear.md)
                 x = nn.remat(
                     lambda mdl, h, m: mdl(h, m, train=train),
                     policy=jax.checkpoint_policies.save_only_these_names(

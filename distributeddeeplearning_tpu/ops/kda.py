@@ -23,8 +23,8 @@ k_i)`` the row each token writes,
     S_C  = Diag(exp G_C) S_0 + (K * exp(G_C - G))^T U
 
 so with ``T = (I + Diag(beta) A)^-1 Diag(beta)``, ``U = T V - (T (K * exp
-G)) S_0``: ``T V`` and ``T (K * exp G)`` need no state and are made for a
-group of chunks at once, and the loop over chunks holds four products.
+G)) S_0``: ``T V`` and ``T (K * exp G)`` need no state, and what goes from
+chunk to chunk is four products with it.
 
 **Staying finite.** ``A`` and ``B`` hold ``exp(G_i - G_j)`` with ``j <= i``,
 never above 1, but as a product of two factors ``exp(G_i) exp(-G_j)`` the
@@ -45,51 +45,51 @@ L_d)(I + L_d^2)(I + L_d^4)...`` (``L_d^sub = 0``), and the rest by the same
 series in ``M = (I + L_d)^-1 L_off``, which is nilpotent in blocks
 (``M^(C/sub) = 0``); all in float32 at three bfloat16 passes a product.
 
-**What runs where.** Everything above that no state enters -- ``G``, ``A``,
-``B``, ``T``, ``T (K exp G)``, ``T V``, ``Q exp G``, ``K exp(G_C - G)`` and
-``exp G_C`` -- is one Pallas kernel forward and one backward
-(ops/kda_chunk.py, :func:`ops.kda_chunk.prepare`: a grid step is a chunk of
-the group and eight heads, and what lies between the operands and those
-results never leaves VMEM). Its precision: the gates summed in float32
-(exact products with a triangle of ones); the scores' and the inverse's
-float32 products at three bfloat16 passes (each operand a bfloat16 head and
-tail, ``hi hi + hi lo + lo hi``, written out since Mosaic lowers no
-``Precision.HIGH``: a CPU computes the same three); the sums over a pair's
-channels in float32; ``T`` rounded to q's type before it meets K and V, and
-those products added up in float32. What XLA still runs of the operator is
-the loop over a group's chunks (:func:`_group`: four products a chunk with
-the state in float32) and the scan over groups.
+**What runs where.** The whole chunked form is two Pallas kernels
+(ops/kda_chunk.py: ``kda_fwd`` and, as the ``jax.custom_vjp`` rule of
+:func:`_chunks`, ``kda_bwd``). A grid step is one chunk of eight heads, the
+chunks innermost and in order, and the state of those heads lives in VMEM
+from the first chunk to the last: a step makes what no state enters -- ``G``,
+``A``, ``B``, ``T``, ``T (K exp G)``, ``T V``, ``Q exp G``, ``K exp(G_C -
+G)`` and ``exp G_C`` -- and then the chunk's four products with the state,
+and only the chunk's result and the state that entered it go to HBM.
+Precision: the gates summed in float32 (exact products with a triangle of
+ones); the scores' and the inverse's float32 products at three bfloat16
+passes (each operand a bfloat16 head and tail, ``hi hi + hi lo + lo hi``,
+written out since Mosaic lowers no ``Precision.HIGH``: a CPU computes the
+same three); the sums over a pair's channels in float32; ``T`` rounded to
+q's type before it meets K and V; the state float32, rounded to q's type
+where it meets ``T (K exp G)`` and ``Q exp G``, ``U`` float32 and rounded
+where it meets ``B`` and ``K exp(G_C - G)``, every product summed in
+float32.
 
-**Memory and the backward pass.** Chunks are taken ``group`` at a time under
-a ``lax.scan``; the kernel makes a group's stateless operands at once (in
-HBM a group holds just those six results, (group, B*H, C, d) each) and a
-second scan hands the state through its chunks. The backward rule
-(:func:`_groups_bwd`) keeps the operands and the state that enters each
-group ((S / (C * group)) x B x H x d_k x d_v float32) and walks the groups
-last to first, remaking each from those and differentiating it there with
-``jax.vjp``: the loop over chunks by jax's own rule, the kernel by its
-``custom_vjp``, whose residuals are its operands and whose backward kernel
-remakes ``G``, ``A`` and ``T`` in VMEM and applies each piece's own rule
-(the inverse's ``dL = -X^T dX X^T``; the scores' gates' gradient ``x * dx - y
-* dy``, with no pass of its own). The result and the entering states are
-named (``KDA_OUT``, ``KDA_STATES``) for a recomputed block to keep. A last
-short chunk is padded with ``g = 0, beta = 0``, which leaves the state as it
-is.
+**Memory and the backward pass.** The forward kernel writes the state that
+enters each chunk ((S / C) x B x H x d_k x d_v float32, 268 MB a layer of
+the kimi cell); the backward kernel walks the chunks last to first with the
+state's gradient in VMEM, remakes a chunk's stateless values and its rows
+``U`` from its operands and its entering state, applies the four products'
+rules and hands their cotangents to the stateless work's own rules (the
+inverse's ``dL = -X^T dX X^T``; the scores' gates' gradient ``x * dx - y *
+dy``, with no pass of its own), all in VMEM: no forward kernel runs in the
+backward pass. The result and the entering states are named (``KDA_OUT``,
+``KDA_STATES``) for a recomputed block to keep. A last short chunk is padded
+with ``g = 0, beta = 0``, which leaves the state as it is.
 
 Layout: :func:`kda_recurrent` and :func:`kda_chunked` take the models' ``(B,
 S, H, D)``; ``g`` is ``(B, S, H, d_k)`` float32, ``beta`` ``(B, S, H)``.
 Products with q, k, v or the state take their operands in ``q``'s type and
 add up in float32; the state, the gates and the inverse are float32.
 
-**The layout the groups are scanned in, and who writes it.** ``_groups``
-scans operands ``(groups, group, B*H, C, d)``: group of chunks, chunk of the
-group, batch row and head merged (``b * H + h``), token of the chunk, channel
+**The layout the operator reads, and who writes it.** Operands are
+``(groups, group, B*H, C, d)``: group of chunks, chunk of the group, batch
+row and head merged (``b * H + h``), token of the chunk, channel
 (:func:`layout` has the counts, :func:`lay_out` the transpose; a sequence is
-padded to whole groups with ``g = 0, beta = 0`` and zeros elsewhere).
+padded to whole groups with ``g = 0, beta = 0`` and zeros elsewhere); the
+kernels take the first two axes as one, chunk by chunk.
 :func:`kda_groups` is the operator on operands that are already so, and gives
-its result so (a group's five operands go to ops/kda_chunk.py's kernels as
-they lie, blocks of (heads, C, d)); :func:`kda_chunked` lays out with XLA,
-calls it, and lays the result back. A model does neither relayout as a pass of its own: the
+its result so (a chunk's five operands go to the kernels as they lie, blocks
+of (heads, C, d)); :func:`kda_chunked` lays out with XLA, calls it, and lays
+the result back. A model does neither relayout as a pass of its own: the
 pointwise stages on either side of the operator (ops/kda_stages.py: the
 short convolutions with SiLU, the L2 norms and the gate before it, the gated
 RMSNorm after it) are fused kernels whose block index maps read ``(B, S,
@@ -114,7 +114,7 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 # tokens a chunk of the chunked form, and of the counter that bounds its
 # exponents (:func:`min_chunk_log_decay`)
 CHUNK = 64
-# chunks prepared at once, a group of the outer scan
+# chunks a group of the layout (the stages' kernels write it so)
 GROUP = 8
 # the collection a layer sows :func:`min_chunk_log_decay` into; the step's
 # metrics carry the smallest over the layers (train/steps.py)
@@ -157,74 +157,36 @@ def min_chunk_log_decay(g):
     return g.astype(jnp.float32).sum(-2).min()
 
 
-def _group(state, xs, sub: int):
-    """One group of chunks, operands (n, B*H, C, d): state (B*H, d_k, d_v)
-    in, (state out, the group's outputs (n, B*H, C, d_v))."""
-    f32 = jnp.float32
-    q, k, v, g, beta = xs
-    dtype = q.dtype
-
-    def chunk(state, x):
-        w, tv, bm, q_in, k_out, decay = x
-        sd = state.astype(dtype)
-        u = tv - jnp.matmul(w, sd, preferred_element_type=f32)
-        ud = u.astype(dtype)
-        o = (jnp.matmul(q_in, sd, preferred_element_type=f32)
-             + jnp.matmul(bm, ud, preferred_element_type=f32))
-        state = state * decay[..., None] + jnp.einsum(
-            "hck,hcv->hkv", k_out, ud, preferred_element_type=f32)
-        return state, o.astype(v.dtype)
-
-    return jax.lax.scan(chunk, state,
-                        kda_chunk.prepare(q, k, v, g, beta, sub))
-
-
 # ``checkpoint_name`` of what the chunked operator's forward rule hands its
 # backward rule beside its own operands, for a recomputed block to keep:
-# the result and the state that enters each group of chunks. With both kept
+# the result and the state that enters each chunk. With both kept
 # (``jax.checkpoint_policies.save_only_these_names(KDA_OUT, KDA_STATES)``)
-# the block's recomputed forward holds no loop over chunks at all: the
-# backward rule remakes each group from its operands and its entering state.
+# the block's recomputed forward runs no forward kernel: the backward
+# kernel remakes each chunk from its operands and its entering state.
 KDA_OUT = "kda_out"
-KDA_STATES = "kda_group_states"
+KDA_STATES = "kda_states"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _groups(state, xs, sub):
-    """All groups: (last state, outputs (groups, n, B*H, C, d_v))."""
-    return jax.lax.scan(lambda st, x: _group(st, x, sub), state, xs)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _chunks(q, k, v, g, beta, state, sub):
+    """Every chunk, operands (n, B*H, C, d) and the entering state (B*H,
+    d_v, d_k) transposed: (outputs (n, B*H, C, d_v), last state)."""
+    out, _, last = kda_chunk.forward(q, k, v, g, beta, state, sub)
+    return out, last
 
 
-def _groups_fwd(state, xs, sub):
-    def body(st, x):
-        new, out = _group(st, x, sub)
-        return new, (out, st)
-
-    last, (out, entering) = jax.lax.scan(body, state, xs)
+def _chunks_fwd(q, k, v, g, beta, state, sub):
+    out, entering, last = kda_chunk.forward(q, k, v, g, beta, state, sub)
     out = checkpoint_name(out, KDA_OUT)
     entering = checkpoint_name(entering, KDA_STATES)
-    return (last, out), (xs, entering)
+    return (out, last), (q, k, v, g, beta, entering)
 
 
-def _groups_bwd(sub, residuals, cotangents):
-    """Back through the groups, last first: each is remade from its operands
-    and the state that entered it, and differentiated by jax, so no more
-    than one group's intermediates are alive."""
-    xs, entering = residuals
-    d_last, d_out = cotangents
-
-    def body(d_state, x):
-        xs_g, st, d_out_g = x
-        _, vjp = jax.vjp(lambda a, b: _group(a, b, sub), st, xs_g)
-        d_state, d_xs = vjp((d_state, d_out_g))
-        return d_state, d_xs
-
-    d_state, d_xs = jax.lax.scan(body, d_last, (xs, entering, d_out),
-                                 reverse=True)
-    return d_state, d_xs
+def _chunks_bwd(sub, residuals, cotangents):
+    return kda_chunk.backward(*residuals, *cotangents, sub)
 
 
-_groups.defvjp(_groups_fwd, _groups_bwd)
+_chunks.defvjp(_chunks_fwd, _chunks_bwd)
 
 
 def layout(s: int, chunk: int = CHUNK, group: int = GROUP):
@@ -238,8 +200,8 @@ def layout(s: int, chunk: int = CHUNK, group: int = GROUP):
 
 
 def lay_out(x, chunk: int = CHUNK, group: int = GROUP):
-    """(B, S, H, ...) -> (groups, group, B*H, C, ...), the layout the groups
-    are scanned in: padded with zeros to whole groups, in one transpose."""
+    """(B, S, H, ...) -> (groups, group, B*H, C, ...), the layout the
+    operator reads: padded with zeros to whole groups, in one transpose."""
     b, s, h = x.shape[:3]
     group, groups, pad = layout(s, chunk, group)
     x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
@@ -266,14 +228,19 @@ def kda_groups(q, k, v, g, beta, initial_state=None, *, sub: int = 16,
     rows ``g = 0, beta = 0``; ``initial_state`` (B*H, d_k, d_v) or None for
     zeros. Returns o (groups, group, B*H, C, d_v) in ``v``'s type, and the
     last state (B*H, d_k, d_v) with ``return_state``."""
-    chunk = q.shape[3]
+    groups, group, bh, chunk, dk = q.shape
     if chunk % sub or (chunk // sub) & (chunk // sub - 1):
         raise ValueError(f"chunk {chunk} must be sub {sub} times a power of "
                          f"two")
-    state = (jnp.zeros((q.shape[2], q.shape[4], v.shape[4]), jnp.float32)
-             if initial_state is None else initial_state.astype(jnp.float32))
-    state, out = _groups(state, (q, k, v, g, beta), sub)
-    return (out, state) if return_state else out
+    # the kernels take the state transposed, (B*H, d_v, d_k), and the
+    # chunks in one row: the layout's first two axes merged
+    state = (jnp.zeros((bh, v.shape[4], dk), jnp.float32)
+             if initial_state is None
+             else jnp.swapaxes(initial_state.astype(jnp.float32), 1, 2))
+    out, state = _chunks(*(x.reshape((groups * group,) + x.shape[2:])
+                           for x in (q, k, v, g, beta)), state, sub)
+    out = out.reshape(q.shape[:2] + out.shape[1:])
+    return (out, jnp.swapaxes(state, 1, 2)) if return_state else out
 
 
 def kda_chunked(q, k, v, g, beta, initial_state=None, *, chunk: int = CHUNK,
@@ -281,8 +248,8 @@ def kda_chunked(q, k, v, g, beta, initial_state=None, *, chunk: int = CHUNK,
                 return_state: bool = False):
     """The chunked form (module text); same arguments and results as
     :func:`kda_recurrent`. ``chunk`` tokens a chunk (``sub`` times a power
-    of two), ``group`` chunks prepared at once. Lays the operands out with
-    XLA and runs :func:`kda_groups`."""
+    of two), ``group`` chunks a group of the layout. Lays the operands out
+    with XLA and runs :func:`kda_groups`."""
     b, s, h, dk = q.shape
     group = layout(s, chunk, group)[0]
     if initial_state is not None:

@@ -1,15 +1,27 @@
-"""What a chunk of the chunked delta rule needs that no state enters
-(ops/kda.py, module text: the cumulative gates ``G``, the in-chunk scores
-``A`` and ``B``, the inverse ``T`` and the decayed operands), as two Pallas
-kernels: :func:`prepare` runs ``kda_chunk_fwd`` and its ``jax.custom_vjp``
-rule ``kda_chunk_bwd``, whose residuals are the operands themselves. A grid
-step is one chunk of the group and eight heads (:func:`_heads_a_step`), taken
-together as arrays (heads, C, d): every line below is eight independent
-chains, which is what keeps the units busy (four heads a step take 1.17
-times as long a head, sixteen 0.98: PERF.md section 6, PR 35). Everything
-between the operands and the results lives in VMEM and is dropped there.
+"""The chunked delta rule (ops/kda.py, module text) as two Pallas kernels:
+:func:`forward` runs ``kda_fwd``, and :func:`backward`, its backward rule,
+``kda_bwd``. A grid step is one chunk of eight heads (:func:`_heads_a_step`),
+taken together as arrays (heads, C, d): every line below is eight
+independent chains, which is what keeps the units busy (on a v5e four heads
+a step take 1.17 times as long a head, sixteen 0.98: PERF.md section 6). The
+chunks are the grid's inner axis and run in order, first to last forward and
+last to first backward, and the eight heads' state (forward) or its gradient
+(backward) stays in a VMEM scratch from one chunk to the next. A step first
+makes what no state enters (:func:`stateless`), then the chunk's four
+products with the state: ``U = T V - (T (K exp G)) S``, ``O = (Q exp G) S +
+B U``, ``S' = Diag(exp G_C) S + (K exp(G_C - G))^T U``. The state is held
+transposed, (d_v, d_k), so that its decay by ``exp G_C`` is a product with a
+row. HBM sees the operands, the result, and the state that enters each chunk
+(written forward, read backward); everything else is made and dropped in
+VMEM.
 
-**Forward**, a chunk-head (C tokens, d channels), float32 throughout:
+**Backward**, a step: the chunk's stateless values and ``U`` remade from the
+operands and the entering state, the four products' rules (``dU = B^T dO +
+K_out dS'``, ``dS = Diag(exp G_C) dS' + Q_in^T dO - W^T dU`` and the
+operands' own), and those cotangents handed to :func:`stateless_bwd`.
+
+**What no state enters**, a chunk-head (C tokens, d channels), float32
+throughout:
 
 - ``G = tril(1) g``, exactly (:func:`_sums`).
 - The scores level by level (:func:`_levels`): a block of ``2 m`` tokens
@@ -29,8 +41,8 @@ between the operands and the results lives in VMEM and is dropped there.
   finite series, then ``T (K exp G)``, ``T V``, ``Q exp G``, ``K exp(G_C -
   G)`` and ``exp G_C``, rounded to the compute type where they leave.
 
-**Backward** remakes ``G``, the factors, the pair decays, ``A`` and ``T``
-once, then applies each piece's own rule: the inverse's ``dL = -X^T dX X^T``;
+Its **backward rule** reuses ``G``, the factors, the pair decays, ``A`` and
+``T`` as they were made, then applies each piece's own rule: the inverse's ``dL = -X^T dX X^T``;
 the scores' ``dx_i = sum_j dP_ij y_j E_ij``, ``dy_j = sum_i dP_ij x_i E_ij``
 from the same factors and decays, and since every term of a score holds
 ``exp(G_i - G_j)`` once, ``dG = x dx - y dy`` with no pass of its own
@@ -44,7 +56,9 @@ head and tail, ``hi hi + hi lo + lo hi``; Mosaic lowers no
 ``Precision.HIGH``, so the passes are written out, and a CPU computes the
 same three); products that meet q, k and v take their operands in the
 compute type and add up in float32; the pairs' sums over the channels are
-float32 sums.
+float32 sums. The state is float32 and meets a product in the compute type,
+as ``U`` does; the backward rule's cotangents of ``U`` and of the state are
+float32 and meet a product in the compute type.
 
 Block shapes come from the operands (chunks of a power of two of at least 8
 tokens); on a CPU the kernels run interpreted (ops/pallas.py).
@@ -300,56 +314,53 @@ def _scores(q, k, g, with_b: bool):
     return cum, kf, qf, a, b, factors, decays
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, bcol_ref, brow_ref,
-                w_ref, tv_ref, bm_ref, qin_ref, kout_ref, decay_ref, *,
-                sub: int):
-    c = q_ref.shape[1]
-    dtype = q_ref.dtype
-    cum, kf, qf, a, b, _, _ = _scores(q_ref[...], k_ref[...], g_ref[...],
-                                      True)
+def stateless(q, k, v, g, beta_col, beta_row, sub: int):
+    """What a step's chunk-heads need that no state enters, from their
+    operands (heads, C, d): q, k, v in the compute type, g float32, beta as
+    a column (heads, C, 1) and as a row (heads, 1, C). Returns the operands
+    of the state's four products, ``(T (K exp G), T V, B, Q exp G, K exp(G_C
+    - G), exp G_C)`` (``T V`` and the decay (heads, 1, d_k) float32, the
+    others rounded to q's type), and what :func:`stateless_bwd` reuses of
+    their making."""
+    c = q.shape[1]
+    dtype = q.dtype
+    cum, kf, qf, a, b, factors, decays = _scores(q, k, g, True)
     # a token with itself carries no gate
     eye = _iota((1, c, c), 1) == _iota((1, c, c), 2)
     bm = b + jnp.where(eye, jnp.sum(qf * kf, -1, keepdims=True), 0.0)
-    t = unit_lower_inverse(bcol_ref[...] * a, sub) * brow_ref[...]
-    td = t.astype(dtype)
-    decayed = jnp.exp(cum)
-    last = cum[:, c - 1:c]
-    w_ref[...] = _dot(td, (kf * decayed).astype(dtype)).astype(dtype)
-    tv_ref[...] = _dot(td, v_ref[...])
-    bm_ref[...] = bm.astype(dtype)
-    qin_ref[...] = (qf * decayed).astype(dtype)
-    kout_ref[...] = (kf * jnp.exp(last - cum)).astype(dtype)
-    decay = jnp.exp(last)
-    for h in range(decay.shape[0]):     # (a store of all heads' one row at
-        decay_ref[h] = decay[h]         # once is refused by the compiler)
-
-
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, bcol_ref, brow_ref,
-                dw_ref, dtv_ref, dbm_ref, dqin_ref, dkout_ref, ddecay_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbcol_ref, dbrow_ref, *,
-                sub: int):
-    heads, c, d = q_ref.shape
-    dtype = q_ref.dtype
-    cum, kf, qf, a, _, factors, decays = _scores(q_ref[...], k_ref[...],
-                                                 g_ref[...], False)
-    bcol, brow = bcol_ref[...], brow_ref[...]
-    x = unit_lower_inverse(bcol * a, sub)
-    td = (x * brow).astype(dtype)
+    x = unit_lower_inverse(beta_col * a, sub)
+    td = (x * beta_row).astype(dtype)
     decayed = jnp.exp(cum)
     last = cum[:, c - 1:c]
     to_end = jnp.exp(last - cum)
+    k_in = (kf * decayed).astype(dtype)
+    results = (_dot(td, k_in).astype(dtype), _dot(td, v), bm.astype(dtype),
+               (qf * decayed).astype(dtype), (kf * to_end).astype(dtype),
+               jnp.exp(last))
+    return results, (cum, kf, qf, a, factors, decays, x, td, k_in, decayed,
+                     to_end)
+
+
+def stateless_bwd(v, beta_col, beta_row, remade, cotangents, sub: int):
+    """:func:`stateless`' backward rule, from what it ``remade`` and the six
+    results' cotangents (in the results' types and shapes): the gradients
+    of q, k (q's type), v (v's type), g, and beta as a column and as a row
+    (float32). Each piece by its own rule (module text)."""
+    cum, kf, qf, a, factors, decays, x, td, k_in, decayed, to_end = remade
+    d_w, d_tv, d_bm, d_qin, d_kout, d_decay = cotangents
+    heads, c, d = kf.shape
+    dtype = td.dtype
     # T (K exp G) and T V
-    dw, dtv = dw_ref[...], dtv_ref[...].astype(dtype)
-    d_t = (_dot(dw, (kf * decayed).astype(dtype), "nt")
-           + _dot(dtv, v_ref[...], "nt"))
-    d_kin = _dot(td, dw, "tn")
-    dv_ref[...] = _dot(td, dtv, "tn").astype(dv_ref.dtype)
+    d_tv = d_tv.astype(dtype)
+    d_t = _dot(d_w, k_in, "nt") + _dot(d_tv, v, "nt")
+    d_kin = _dot(td, d_w, "tn")
+    dv = _dot(td, d_tv, "tn").astype(v.dtype)
     # T = X Diag(beta), X = (I + L)^-1, L = Diag(beta) A
-    dbrow_ref[...] = jnp.sum(d_t * x, 1, keepdims=True)
-    d_l = -_mm(_mm(x, d_t * brow, "tn"), x, "nt")
-    dbcol_ref[...] = jnp.sum(d_l * a, -1, keepdims=True)
-    d_a = bcol * d_l
-    d_b = dbm_ref[...].astype(_F32)
+    d_brow = jnp.sum(d_t * x, 1, keepdims=True)
+    d_l = -_mm(_mm(x, d_t * beta_row, "tn"), x, "nt")
+    d_bcol = jnp.sum(d_l * a, -1, keepdims=True)
+    d_a = beta_col * d_l
+    d_b = d_bm.astype(_F32)
     eye = _iota((1, c, c), 1) == _iota((1, c, c), 2)
     d_self = jnp.sum(jnp.where(eye, d_b, 0.0), -1, keepdims=True)
     # the scores, level by level: dx of the rows, dy of the columns
@@ -387,21 +398,89 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, bcol_ref, brow_ref,
                 0.0)
     d_xk, d_xq, d_y = _whole(d_xk4), _whole(d_xq4), _whole(d_y4)
     # the decayed operands, and every gate's term
-    d_qin = dqin_ref[...].astype(_F32)
-    d_kout = dkout_ref[...].astype(_F32)
-    dq_ref[...] = (d_xq + d_self * kf + d_qin * decayed).astype(dtype)
-    dk_ref[...] = (d_xk + d_y + d_self * qf + d_kin * decayed
-                   + d_kout * to_end).astype(dtype)
+    d_qin = d_qin.astype(_F32)
+    d_kout = d_kout.astype(_F32)
+    dq = (d_xq + d_self * kf + d_qin * decayed).astype(dtype)
+    dk = (d_xk + d_y + d_self * qf + d_kin * decayed
+          + d_kout * to_end).astype(dtype)
     # (the last row's exponent G_C - G_C is 0 whatever the gates are: its
     # two terms cancel, and are left out before they round)
+    last = cum[:, c - 1:c]
     last_row = _iota((1, c, 1), 1) == c - 1
     to_end_term = jnp.where(last_row, 0.0, d_kout * kf * to_end)
     d_last = (jnp.sum(to_end_term, 1, keepdims=True)
-              + ddecay_ref[...] * jnp.exp(last))
+              + d_decay * jnp.exp(last))
     d_cum = (kf * (d_xk - d_y) + qf * d_xq
              + (d_kin * kf + d_qin * qf) * decayed - to_end_term
              + jnp.where(last_row, d_last, 0.0))
-    dg_ref[...] = _sums(_triangle(heads, c, upper=True), d_cum)
+    dg = _sums(_triangle(heads, c, upper=True), d_cum)
+    return dq, dk, dv, dg, d_bcol, d_brow
+
+
+def _rows(state, w, tv):
+    """The state (heads, d_v, d_k) float32 in the compute type, and the
+    rows the chunk writes, ``U = T V - (T (K exp G)) S``, in it too."""
+    sd = state.astype(w.dtype)
+    return sd, (tv - _dot(w, sd, "nt")).astype(w.dtype)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, bcol_ref, brow_ref, first_ref,
+                o_ref, entering_ref, last_ref, state_ref, *, sub: int):
+    t = pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _():
+        state_ref[...] = first_ref[...]
+
+    (w, tv, bm, q_in, k_out, decay), _ = stateless(
+        q_ref[...], k_ref[...], v_ref[...], g_ref[...], bcol_ref[...],
+        brow_ref[...], sub)
+    state = state_ref[...]
+    entering_ref[...] = state
+    sd, ud = _rows(state, w, tv)
+    o_ref[...] = (_dot(q_in, sd, "nt") + _dot(bm, ud)).astype(o_ref.dtype)
+    state = state * decay + _dot(ud, k_out, "tn")
+    state_ref[...] = state
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _():
+        last_ref[...] = state
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, bcol_ref, brow_ref, entering_ref,
+                do_ref, dlast_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbcol_ref,
+                dbrow_ref, dfirst_ref, d_state_ref, *, sub: int):
+    t = pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _():
+        d_state_ref[...] = dlast_ref[...]
+
+    v, bcol, brow = v_ref[...], bcol_ref[...], brow_ref[...]
+    (w, tv, bm, q_in, k_out, decay), remade = stateless(
+        q_ref[...], k_ref[...], v, g_ref[...], bcol, brow, sub)
+    dtype = w.dtype
+    state = entering_ref[...]
+    sd, ud = _rows(state, w, tv)
+    do = do_ref[...].astype(dtype)
+    d_state = d_state_ref[...]
+    dsd = d_state.astype(dtype)
+    # O = (Q exp G) S + B U and S' = Diag(exp G_C) S + (K exp(G_C - G))^T U,
+    # back to U = T V - (T (K exp G)) S and to the state that entered
+    du = _dot(bm, do, "tn") + _dot(k_out, dsd, "nt")
+    dud = du.astype(dtype)
+    d_state_ref[...] = (d_state * decay + _dot(do, q_in, "tn")
+                        - _dot(dud, w, "tn"))
+    cotangents = (_dot(-dud, sd).astype(dtype), du,
+                  _dot(do, ud, "nt").astype(dtype), _dot(do, sd).astype(dtype),
+                  _dot(ud, dsd).astype(dtype),
+                  jnp.sum(state * d_state, 1, keepdims=True))
+    (dq_ref[...], dk_ref[...], dv_ref[...], dg_ref[...], dbcol_ref[...],
+     dbrow_ref[...]) = stateless_bwd(v, bcol, brow, remade, cotangents, sub)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _():
+        dfirst_ref[...] = d_state_ref[...]
 
 
 def _heads_a_step(bh: int) -> int:
@@ -409,78 +488,75 @@ def _heads_a_step(bh: int) -> int:
     return max(n for n in range(1, 9) if bh % n == 0)
 
 
-def _specs(q, v):
-    """The grid (chunk of the group, heads of a step), a step's block of an
-    (n, B*H, rows, width) array, and the blocks of the six operands both
-    kernels read: q, k, v, g, beta as a column and as a row."""
+def _specs(q, v, reverse: bool = False):
+    """The grid (heads of a step, chunk: the state's loop innermost, last to
+    first with ``reverse``), a step's block of an (n, B*H, rows, width)
+    array, its block of a (B*H, d_v, d_k) state, and the blocks of the six
+    operands both kernels read: q, k, v, g, beta as a column and as a
+    row."""
     n, bh, c, dk = q.shape
+    dv = v.shape[-1]
     step = _heads_a_step(bh)
 
     def block(*tail):
-        return pl.BlockSpec((None, step) + tail, lambda i, j: (i, j, 0, 0))
+        return pl.BlockSpec(
+            (None, step) + tail,
+            lambda j, t: (n - 1 - t if reverse else t, j, 0, 0))
 
-    return (n, bh // step), block, [
-        block(c, dk), block(c, dk), block(c, v.shape[-1]), block(c, dk),
+    state = pl.BlockSpec((step, dv, dk), lambda j, t: (j, 0, 0))
+    return (bh // step, n), block, state, [
+        block(c, dk), block(c, dk), block(c, dv), block(c, dk),
         block(c, 1), block(1, c)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def prepare(q, k, v, g, beta, sub: int):
-    """What a group of chunks needs that no state enters. Operands (n, B*H,
-    C, d) (beta without d; g and beta float32). Returns the operands of the
-    loop over chunks, chunk first: ``T (K exp G)`` and ``B`` in q's type,
-    ``T V`` float32, ``Q exp G`` and ``K exp(G_C - G)`` in q's type, and the
-    chunk's whole decay ``exp G_C`` (n, B*H, d) float32."""
+def forward(q, k, v, g, beta, state, sub: int):
+    """The chunked delta rule over every chunk (ops/kda.py, module text).
+    Operands (n, B*H, C, d), chunk first (beta without d; g and beta
+    float32); ``state`` (B*H, d_v, d_k) float32, the entering state
+    transposed. Returns o (n, B*H, C, d_v) in v's type, the state that
+    enters each chunk (n, B*H, d_v, d_k) and the last (B*H, d_v, d_k),
+    float32 and transposed."""
     n, bh, c, dk = q.shape
     if c % _TILE or c & (c - 1) or c % sub:
         raise ValueError(f"a chunk of {c} tokens must be a power of two, at "
                          f"least {_TILE}, and whole sub-chunks of {sub}")
     dv = v.shape[-1]
-    grid, block, operands = _specs(q, v)
-    laid = functools.partial(jax.ShapeDtypeStruct, dtype=q.dtype)
-    *results, decay = pallas_call(
-        functools.partial(_fwd_kernel, sub=sub), name="kda_chunk_fwd",
-        grid=grid, in_specs=operands,
-        out_specs=[block(c, dk), block(c, dv), block(c, c), block(c, dk),
-                   block(c, dk), block(1, dk)],
-        out_shape=[laid((n, bh, c, dk)),
-                   jax.ShapeDtypeStruct((n, bh, c, dv), _F32),
-                   laid((n, bh, c, c)), laid((n, bh, c, dk)),
-                   laid((n, bh, c, dk)),
-                   jax.ShapeDtypeStruct((n, bh, 1, dk), _F32)],
+    grid, block, whole, operands = _specs(q, v)
+    return pallas_call(
+        functools.partial(_fwd_kernel, sub=sub), name="kda_fwd",
+        grid=grid, in_specs=operands + [whole],
+        out_specs=[block(c, dv), block(dv, dk), whole],
+        out_shape=[jax.ShapeDtypeStruct((n, bh, c, dv), v.dtype),
+                   jax.ShapeDtypeStruct((n, bh, dv, dk), _F32),
+                   jax.ShapeDtypeStruct((bh, dv, dk), _F32)],
+        scratch_shapes=[pltpu.VMEM(whole.block_shape, _F32)],
         compiler_params=_PARAMS,
-    )(q, k, v, g, beta[..., None], beta[..., None, :])
-    return (*results, decay[:, :, 0])
+    )(q, k, v, g, beta[..., None], beta[..., None, :], state)
 
 
-def _prepare_fwd(q, k, v, g, beta, sub):
-    return prepare(q, k, v, g, beta, sub), (q, k, v, g, beta)
-
-
-def _prepare_bwd(sub, residuals, cotangents):
-    q, k, v, g, beta = residuals
-    d_w, d_tv, d_bm, d_qin, d_kout, d_decay = cotangents
+def backward(q, k, v, g, beta, entering, d_out, d_last, sub: int):
+    """:func:`forward`'s backward rule, from its operands, the states that
+    entered the chunks and the cotangents of o and of the last state: the
+    gradients of q, k, v, g, beta and the entering state."""
     n, bh, c, dk = q.shape
     dv = v.shape[-1]
-    grid, block, operands = _specs(q, v)
-    dq, dk_, dv_, dg, dbcol, dbrow = pallas_call(
-        functools.partial(_bwd_kernel, sub=sub), name="kda_chunk_bwd",
+    grid, block, whole, operands = _specs(q, v, reverse=True)
+    dq, dk_, dv_, dg, dbcol, dbrow, d_first = pallas_call(
+        functools.partial(_bwd_kernel, sub=sub), name="kda_bwd",
         grid=grid,
-        in_specs=operands + [block(c, dk), block(c, dv), block(c, c),
-                             block(c, dk), block(c, dk), block(1, dk)],
+        in_specs=operands + [block(dv, dk), block(c, dv), whole],
         out_specs=[block(c, dk), block(c, dk), block(c, dv), block(c, dk),
-                   block(c, 1), block(1, c)],
+                   block(c, 1), block(1, c), whole],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(g.shape, _F32),
                    jax.ShapeDtypeStruct((n, bh, c, 1), _F32),
-                   jax.ShapeDtypeStruct((n, bh, 1, c), _F32)],
+                   jax.ShapeDtypeStruct((n, bh, 1, c), _F32),
+                   jax.ShapeDtypeStruct((bh, dv, dk), _F32)],
+        scratch_shapes=[pltpu.VMEM(whole.block_shape, _F32)],
         compiler_params=_PARAMS,
-    )(q, k, v, g, beta[..., None], beta[..., None, :],
-      d_w, d_tv, d_bm, d_qin, d_kout, d_decay[:, :, None])
+    )(q, k, v, g, beta[..., None], beta[..., None, :], entering,
+      d_out, d_last.astype(_F32))
     return (dq, dk_, dv_, dg.astype(g.dtype),
-            (dbcol[..., 0] + dbrow[:, :, 0]).astype(beta.dtype))
-
-
-prepare.defvjp(_prepare_fwd, _prepare_bwd)
+            (dbcol[..., 0] + dbrow[:, :, 0]).astype(beta.dtype), d_first)

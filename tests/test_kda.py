@@ -12,27 +12,28 @@ import numpy as np
 import pytest
 
 from distributeddeeplearning_tpu.ops import kda, kda_chunk
+from tests.kda_refs import xla_groups
 
 B, H, DK, DV = 2, 3, 8, 12
 NAMES = ("q", "k", "v", "g", "beta")
 
 
-def operands(s, gate, beta, seed=1):
+def operands(s, gate, beta, seed=1, b=B, h=H):
     ks = jax.random.split(jax.random.key(seed), 6)
-    q = jax.random.normal(ks[0], (B, s, H, DK))
-    k = jax.random.normal(ks[1], (B, s, H, DK))
+    q = jax.random.normal(ks[0], (b, s, h, DK))
+    k = jax.random.normal(ks[1], (b, s, h, DK))
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * DK ** -0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (B, s, H, DV))
+    v = jax.random.normal(ks[2], (b, s, h, DV))
     if gate == "model":   # spread as the model's initial gates are
-        g = -jnp.exp(1.5 * jax.random.normal(ks[3], (B, s, H, DK)) - 1.0)
+        g = -jnp.exp(1.5 * jax.random.normal(ks[3], (b, s, h, DK)) - 1.0)
     else:
-        g = jnp.full((B, s, H, DK), float(gate), jnp.float32)
+        g = jnp.full((b, s, h, DK), float(gate), jnp.float32)
     if beta == "model":
-        b = jax.nn.sigmoid(jax.random.normal(ks[4], (B, s, H)))
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
     else:
-        b = jnp.full((B, s, H), float(beta), jnp.float32)
-    return q, k, v, g, b, jax.random.normal(ks[5], (B, s, H, DV))
+        beta = jnp.full((b, s, h), float(beta), jnp.float32)
+    return q, k, v, g, beta, jax.random.normal(ks[5], (b, s, h, DV))
 
 
 CASES = [(gate, beta, chunk, s)
@@ -152,6 +153,91 @@ def test_bfloat16_operands_keep_float32_state_and_gates():
     assert float(err) < 0.03 * float(jnp.abs(want.astype(jnp.float32)).max())
 
 
+# ---------------------------------------------------------------------------
+# The two kernels (ops/kda_chunk.py) against the recurrence and against the
+# loop over chunks as XLA ran it (tests/kda_refs.py::xla_groups): value, last
+# state and every gradient, the entering state's among them
+# ---------------------------------------------------------------------------
+
+# (B, H, S, chunk, group, entering state, last state handed on, operands'
+# type): 8 chunks in 4 groups; 100 tokens padded to 128; a state handed in;
+# one handed in and on, its gradient taken; B*H = 6, a grid step of six
+# heads; bfloat16 q, k, v, rounded where the loop rounds
+FUSED_CASES = {
+    "groups": (1, 8, 128, 16, 2, False, False, jnp.float32),
+    "padded-tail": (1, 8, 100, 32, 2, False, False, jnp.float32),
+    "initial-state": (1, 8, 64, 16, 4, True, False, jnp.float32),
+    "return-state": (1, 8, 96, 32, 4, True, True, jnp.float32),
+    "six-heads": (2, 3, 64, 16, 2, False, True, jnp.float32),
+    "bfloat16": (1, 8, 100, 32, 2, True, True, jnp.bfloat16),
+}
+
+
+def _laid_loop(q, k, v, g, beta, state, *, chunk, group):
+    """tests/kda_refs.py::xla_groups on the model's layout: (o, last)."""
+    b, s, h, _ = q.shape
+    laid = [kda.lay_out(x, chunk, group) for x in (q, k, v, g, beta)]
+    last, o = xla_groups(*laid, state.reshape((b * h,) + state.shape[2:]))
+    return kda.lay_back(o, b, s), last.reshape(state.shape)
+
+
+@pytest.mark.parametrize("case", FUSED_CASES)
+def test_the_kernels_hold_to_the_recurrence_and_the_xla_loop(case):
+    """float32: to 6e-5 of the largest entry in value and last state, 1.5e-4
+    in a gradient (test_values, test_gradients), against either yardstick.
+    bfloat16 operands: the same type out, value and last state within
+    2^-7 of the loop's and 0.03 of the recurrence's (a rounding or two to
+    bfloat16 of o and of the rows a chunk writes, and the recurrence rounds
+    nothing), the gradients within 2^-5 of the loop's (whose derivative
+    rounds the cotangents of the rows and of the state to bfloat16, where
+    the backward kernel keeps them float32) and 0.05 of the
+    recurrence's."""
+    b, h, s, chunk, group, entering, on, dtype = FUSED_CASES[case]
+    q, k, v, g, beta, w = operands(s, "model", "model", seed=11, b=b, h=h)
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    state = (0.5 * jax.random.normal(jax.random.key(12), (b, h, DK, DV))
+             if entering else jnp.zeros((b, h, DK, DV)))
+    w_last = jax.random.normal(jax.random.key(13), (b, h, DK, DV)) * on
+    forms = {
+        "kernels": functools.partial(kda.kda_chunked, chunk=chunk,
+                                     group=group, return_state=True),
+        "recurrence": functools.partial(kda.kda_recurrent,
+                                        return_state=True),
+        "loop": functools.partial(_laid_loop, chunk=chunk, group=group)}
+    leaves = (0, 1, 2, 3, 4, 5) if entering else (0, 1, 2, 3, 4)
+
+    def loss(fn):
+        def value(*a):
+            o, last = fn(*a)
+            return ((o.astype(jnp.float32) * w).sum() + (last * w_last).sum(),
+                    (o, last))
+        return jax.jit(jax.value_and_grad(value, argnums=leaves,
+                                          has_aux=True))
+
+    got = {}
+    with jax.default_matmul_precision("highest"):
+        for name, fn in forms.items():
+            (_, (out, last)), grads = loss(fn)(q, k, v, g, beta, state)
+            got[name] = [out, last, *grads]
+    kernels = got["kernels"]
+    assert kernels[0].dtype == dtype
+    assert [x.dtype for x in kernels[2:]][:5] == [dtype] * 3 + [
+        jnp.float32] * 2
+    wide = dtype == jnp.float32
+    for name, value_tol, grad_tol in (
+            ("recurrence", 6e-5 if wide else 0.03, 1.5e-4 if wide else 0.05),
+            ("loop", 6e-5 if wide else 2.0 ** -7,
+             1.5e-4 if wide else 2.0 ** -5)):
+        for i, (x, want) in enumerate(zip(kernels, got[name])):
+            x, want = (np.asarray(a, np.float64) for a in (x, want))
+            assert np.isfinite(x).all(), (name, i)
+            tol = value_tol if i < 2 else grad_tol
+            np.testing.assert_allclose(
+                x, want, rtol=0, atol=tol * np.abs(want).max() + 1e-30,
+                err_msg=f"{name}: {('o', 'last') + NAMES + ('state',)}"
+                        f"[{i}]")
+
+
 def test_the_chunk_must_be_whole_sub_chunks():
     *args, _ = operands(32, 0.0, 1.0)
     with pytest.raises(ValueError, match="power of two"):
@@ -173,23 +259,21 @@ def test_the_inverse_of_a_unit_lower_triangle():
 
 # ---------------------------------------------------------------------------
 # What a recomputed block keeps: the forward rule names the result and the
-# states that enter the groups of chunks (KDA_OUT, KDA_STATES); a policy that
-# lists both takes the loops over chunks out of the block's recomputed
-# forward, as FLASH_OUT / FLASH_LSE take the forward kernel out.
+# states that enter the chunks (KDA_OUT, KDA_STATES); a policy that lists
+# both takes the forward kernel out of the block's recomputed forward, as
+# FLASH_OUT / FLASH_LSE take the flash forward kernel out.
 # ---------------------------------------------------------------------------
 
 _names = jax.checkpoint_policies.save_only_these_names
-# loops in the gradient's compiled program at 4 groups of 8 chunks: forward
-# (groups, chunks), backward (groups, a group's chunks remade, and back
-# through them): 5; a recomputed forward adds its 2. And the kernels of a
-# group's stateless work (ops/kda_chunk.py), whose grid is a loop each where
-# they are interpreted: forward, remade, backward, and the recomputed
-# forward's
+# loops of XLA's in the gradient's compiled program: none, the chunks are
+# walked inside the kernels. And the kernels (ops/kda_chunk.py), whose grid
+# is a loop each where they are interpreted: forward and backward, and the
+# recomputed forward's where the block keeps no states
 LOOPS_CASES = [
-    pytest.param("kept", 5, 3, id="not-recomputed"),
-    pytest.param(None, 7, 4, id="recomputed-no-policy"),
-    pytest.param(_names(kda.KDA_OUT), 7, 4, id="result-without-states"),
-    pytest.param(_names(kda.KDA_OUT, kda.KDA_STATES), 5, 3,
+    pytest.param("kept", 0, 2, id="not-recomputed"),
+    pytest.param(None, 0, 3, id="recomputed-no-policy"),
+    pytest.param(_names(kda.KDA_OUT), 0, 3, id="result-without-states"),
+    pytest.param(_names(kda.KDA_OUT, kda.KDA_STATES), 0, 2,
                  id="result-and-states"),
 ]
 

@@ -251,20 +251,21 @@ def recomputed(seeded):
 @pytest.mark.parametrize("leaf", LEAVES)
 def test_a_recomputed_blocks_gradient_is_the_kept_ones(both, recomputed,
                                                        leaf):
-    """To rounding: the chunked scan's recomputed groups are compiled apart
-    from the first pass, unlike a kernel's bits. The operator's cotangent
-    for the gates comes out of the two passes 4e-7 to 5e-7 of its largest
-    entry apart, and the leaves it alone reaches are sums of it over every
-    token with both signs: they read 1.3e-6 to 3.6e-6 of their largest entry
-    over seeds 0 to 5 with the stages as array lines and 2.2e-6 to 3.8e-6
-    as kernels (the same cotangent's gap, summed in another order), every
-    other leaf under 1.3e-6 either way (PERF.md, PR 32). Since PR 35 the
-    operator's float32 products are three bfloat16 passes on a CPU too (its
-    kernels write them out, ops/kda_chunk.py), and a last bit's difference
-    in a cotangent that enters them comes out as a pass's rounding: over
-    seeds 0 to 5 the gates' leaves read 4.5e-6 to 2.8e-5 and every other
-    leaf 3.9e-6 to 6.1e-6 (PR 34's tree: the readings above, under the 5e-6
-    and 2e-6 these pins were)."""
+    """To rounding. The block keeps the operator's result and entering
+    states, so its recomputed forward runs no forward kernel of the
+    recurrence, and the backward kernel gives the same bits for the same
+    inputs; but the two blocks hand it inputs a last bit apart (XLA computes
+    the recomputed projections and the output stage's cotangent in programs
+    of their own: the last KDA layer's q, k, g up to 2.4e-7 apart, every
+    layer's incoming cotangent up to 9.3e-7, seed 3), and the operator's
+    float32 products are three bfloat16 passes on a CPU too
+    (ops/kda_chunk.py writes them out), where such a difference comes out
+    as a pass's rounding. The leaves that the gates' cotangent alone reaches
+    are sums of it over every token with both signs: over seeds 0 to 5 they
+    read 8.3e-6 to 2.2e-5 of their largest entry (4.5e-6 to 2.8e-5 when XLA
+    ran the loop over chunks) and every other leaf 4.0e-6 to 6.7e-6 (3.9e-6
+    to 6.1e-6); when a CPU ran those products whole they read under the
+    5e-6 and 2e-6 these pins were."""
     want = both["grads"][leaf]
     limit = 6e-5 if leaf.endswith(GATE_LEAVES) else 1.5e-5
     np.testing.assert_allclose(

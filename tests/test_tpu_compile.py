@@ -243,30 +243,34 @@ def test_kda_stages_compile_for_v5e(compile_for, stage, grad, kernel):
         2 if grad else 1)
 
 
-@pytest.mark.parametrize("grad,kernel", [(False, "kda_chunk_fwd"),
-                                         (True, "kda_chunk_bwd")])
+@pytest.mark.parametrize("grad,kernel", [(False, "kda_fwd"),
+                                         (True, "kda_bwd")])
 def test_kda_chunk_compiles_for_v5e(compile_for, grad, kernel):
-    """A chunk's stateless work (ops/kda_chunk.py) at the kimi cell's block
-    shapes: a group of 8 chunks of 64 tokens, 32 heads of 128 channels,
-    sub-chunks of 16, bf16 q, k and v; a grid step is four heads of one
-    chunk."""
-    from distributeddeeplearning_tpu.ops import kda_chunk
+    """The chunked delta rule's two kernels (ops/kda_chunk.py) at the kimi
+    cell's shapes: 16 groups of 8 chunks of 64 tokens, 32 heads of 128
+    channels, sub-chunks of 16, bf16 q, k and v, with a state handed in and
+    out; a grid step is eight heads of one chunk, the chunks innermost. The
+    forward alone is one kernel; the gradient is the forward and the
+    backward, and no loop of XLA's."""
+    from distributeddeeplearning_tpu.ops import kda
 
-    laid = (8, 32, 64, 128)
+    laid = (16, 8, 32, 64, 128)
 
-    def fn(q, k, v, g, beta):
-        return kda_chunk.prepare(q, k, v, g, beta, 16)
+    def fn(q, k, v, g, beta, state):
+        return kda.kda_groups(q, k, v, g, beta, state, return_state=True)
 
     if grad:
         value = fn
         fn = jax.grad(lambda *a: sum(
             (o.astype(F32) ** 2).sum() for o in value(*a)),
-            argnums=tuple(range(5)))
+            argnums=tuple(range(6)))
     text = compile_for(fn, *([(laid, BF16)] * 3
-                             + [(laid, F32), (laid[:3], F32)]))
+                             + [(laid, F32), (laid[:4], F32),
+                                ((32, 128, 128), F32)]))
     assert f"%{kernel}" in text
     assert text.count('custom_call_target="tpu_custom_call"') == (
         2 if grad else 1)
+    assert not re.search(r" while\(", text)
 
 
 @pytest.mark.parametrize("stage,grad,kernel", [
@@ -377,17 +381,17 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
     `train/loop.build` builds it (`make_gspmd_train_step` on a mesh of the
     described chip), lowered with shapes and compiled. What the chip's
     compiler says of it: it fits (7.23 GB of state: float32 masters and
-    Adam's two moments of 602M parameters; 4.82 GB of temporaries, the
-    float32 gradients among them: 4.83 before a chunk's stateless work was
-    two kernels, whose (8, 32, 4, 16, 16, 128) pair intermediates were never
-    the peak; 5.40 before the pointwise stages round the delta rule were
-    kernels), the latent layer's three flash kernels are there at 192 / 128,
-    and the chunked delta rule's loops are `while`s: five a KDA layer
-    (forward over groups, with `kda_chunk_fwd` in its body, and over a
-    group's chunks; backward over groups, with `kda_chunk_fwd` remaking the
-    group and `kda_chunk_bwd` in its body, a group's chunks remade, and back
-    through them) and none for a recomputed forward, whose result and
-    entering states the block keeps."""
+    Adam's two moments of 602M parameters; 4.81 GB of temporaries, the
+    float32 gradients and the state that enters each chunk of each KDA
+    layer, kept across remat, among them: 4.82 when a group's entering
+    state was kept and XLA looped over the chunks, 4.83 before a chunk's
+    stateless work was two kernels, whose (8, 32, 4, 16, 16, 128) pair
+    intermediates were never the peak; 5.40 before the pointwise stages
+    round the delta rule were kernels), the latent layer's three flash
+    kernels are there at 192 / 128, and the chunked delta rule is two
+    kernels a KDA layer and no `while`: its forward kernel once (not again
+    for a recomputed forward, whose result and entering states the block
+    keeps) and its backward kernel once."""
     compiled, parameters = _compiled_share_step(
         one_chip, "kimi_linear_ep32", seq=8192, vocab=20480)
     assert parameters == 602_449_792
@@ -397,27 +401,25 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
           len(re.findall(r" while\(", compiled.as_text())))
     assert memory.argument_size_in_bytes == pytest.approx(
         12 * parameters, rel=0.001)
-    assert memory.temp_size_in_bytes < 1.1 * 4.82e9
+    assert memory.temp_size_in_bytes < 1.1 * 4.81e9
     text = compiled.as_text()
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert len(re.findall(rf"%{name}\S* = ", text)) == 1, name
     assert re.search(r"%flash_fwd\S* = \(bf16\[32,8192,128\]", text)
-    assert len(re.findall(r" while\(", text)) == 4 * 5
+    assert not re.findall(r" while\(", text)
     # the pointwise stages round the operator (ops/kda_stages.py): a KDA
     # layer's input stage forward, again under the block's remat, and
     # backward; its output stage likewise (the recomputed forward feeds the
     # output projection's weight gradient); each under the model's scope,
     # so the trace books it as `attention_kda`
-    # and what a chunk needs that no state enters (ops/kda_chunk.py): the
-    # forward kernel once in the body of the forward loop over groups and
-    # once where the backward loop remakes a group, the backward kernel once
-    # in that loop's body
+    # and the chunked delta rule (ops/kda_chunk.py): the forward kernel
+    # once, the backward kernel once
     calls = {name: re.findall(rf"%{name}\S* = .*", text)
              for name in ("kda_in_fwd", "kda_in_bwd", "kda_out_fwd",
-                          "kda_out_bwd", "kda_chunk_fwd", "kda_chunk_bwd")}
+                          "kda_out_bwd", "kda_fwd", "kda_bwd")}
     assert {k: len(v) for k, v in calls.items()} == {
         "kda_in_fwd": 8, "kda_in_bwd": 4, "kda_out_fwd": 8, "kda_out_bwd": 4,
-        "kda_chunk_fwd": 8, "kda_chunk_bwd": 4}
+        "kda_fwd": 4, "kda_bwd": 4}
     assert all("attn_kda" in line for lines in calls.values()
                for line in lines)
     # so no float32 array of a group's pair intermediates is left under the

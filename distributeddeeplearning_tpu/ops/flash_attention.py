@@ -19,9 +19,13 @@ on (3 of 4 tiles visited there, 10 of 16 at S = 2048, 136 of 256 at S =
 8192); at S <= 512 one tile holds the triangle and is faster than three
 smaller ones (PERF.md, PR 26).
 The backward pass recomputes block scores from the saved logsumexp (the
-flash recurrence) in two kernels: dq (accumulated over a Q tile's K tiles)
-and dk/dv (accumulated over a K tile's Q tiles, on transposed (BK, BQ)
-scores so that nothing has to be transposed); the revisited output blocks
+flash recurrence) in one kernel, ``flash_dkv``: dk/dv accumulate over a K
+tile's Q tiles, on transposed (BK, BQ) scores so that nothing has to be
+transposed, and dq from the same ds into a float32 scratch that holds a
+whole Q head, so a tile's scores, probabilities, masks and dP are made
+once. Where that scratch passes a budget (long sequences,
+:func:`fused_bwd_fits`) a second kernel, ``flash_dq``, accumulates dq over
+a Q tile's K tiles instead, and remakes them. The revisited output blocks
 stay resident in VMEM across the accumulation.
 
 Kernels run compiled on TPU devices and in Pallas interpret mode elsewhere
@@ -194,7 +198,7 @@ def tile_plan(s: int, causal: bool, block_q: Optional[int] = None,
 
 
 def _schedule(s: int, plan: TilePlan, causal: bool, k_major: bool = False,
-              groups: int = 1):
+              groups: int = 1, head_major: bool = False):
     """The two scalar-prefetch tables of a kernel: grid step t works on tile
     (qi[t], kj[t]). Q-major for forward and dQ (a Q tile's K tiles follow
     each other, so its accumulators stay in scratch); K-major for dK/dV.
@@ -203,28 +207,39 @@ def _schedule(s: int, plan: TilePlan, causal: bool, k_major: bool = False,
     heads): a K tile's run holds its Q tiles once for each of the ``groups``
     Q heads that read it, head after head, and the first table's entry is
     ``g * (s // bq) + qi``, which the index maps and the kernel split
-    again."""
+    again. ``head_major`` (the fused backward): the Q heads of a group
+    follow each other, each with all of its K tiles' runs, so that one Q
+    head's dQ is complete before the next one's begins; with one head a row
+    it is the K-major order."""
     qi, kj = _tiles(s, plan.bq, plan.bk, causal, plan.window)
     if groups > 1:
         head = np.repeat(np.arange(groups, dtype=np.int32), qi.size)
         qi = np.tile(qi, groups) + head * (s // plan.bq)
         kj = np.tile(kj, groups)
-    if k_major:
+    if head_major:
+        order = np.lexsort((qi, kj, qi // (s // plan.bq)))
+        qi, kj = qi[order], kj[order]
+    elif k_major:
         order = np.lexsort((qi, kj))  # K tile, then (head, then) Q tile
         qi, kj = qi[order], kj[order]
     return jnp.asarray(qi), jnp.asarray(kj)
 
 
-def _run_edges(row_ref, t):
+def _run_edges(row_ref, t, per: int = 1):
     """(first, last): does grid step ``t`` open / close its run of equal
     entries of ``row_ref``, the table of the tile index that stays put while
     the kernel accumulates (qi for forward and dQ, kj for dK/dV)? Read from
-    the table itself, so the kernels hold no second copy of the schedule."""
+    the table itself, so the kernels hold no second copy of the schedule.
+    ``per`` > 1: runs of equal ``entry // per`` (the fused backward's Q head,
+    from its table of ``g * nq + qi``)."""
     n = pl.num_programs(1)
-    cur = row_ref[t]
-    first = jnp.logical_or(t == 0, row_ref[jnp.maximum(t - 1, 0)] != cur)
-    last = jnp.logical_or(t == n - 1,
-                          row_ref[jnp.minimum(t + 1, n - 1)] != cur)
+
+    def at(u):
+        return row_ref[u] // per if per > 1 else row_ref[u]
+
+    cur = at(t)
+    first = jnp.logical_or(t == 0, at(jnp.maximum(t - 1, 0)) != cur)
+    last = jnp.logical_or(t == n - 1, at(jnp.minimum(t + 1, n - 1)) != cur)
     return first, last
 
 
@@ -532,9 +547,17 @@ def _dq_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
 
 
 def _dkv_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
-                do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale: float, causal: bool, dropout_rate: float,
-                window: Optional[int] = None, groups: int = 1, nq: int = 0):
+                do_ref, lse_ref, delta_ref, *refs, scale: float,
+                causal: bool, dropout_rate: float,
+                window: Optional[int] = None, groups: int = 1, nq: int = 0,
+                with_dq: bool = False):
+    """dK and dV, accumulated along a K tile's run; ``with_dq`` (the fused
+    backward, :func:`_bwd_fused`): dQ too, from the same ``ds``, into a
+    float32 scratch that holds the whole current Q head."""
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, kt_scr, dq_scr = refs
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = refs
     pid0, t = pl.program_id(0), pl.program_id(1)
     i, j = qi_ref[t], kj_ref[t]          # K-major: j stays, i accumulates
     if groups > 1:
@@ -544,8 +567,17 @@ def _dkv_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
         pid0, i = pid0 * groups + i // nq, i % nq
     first, last = _run_edges(kj_ref, t)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
+    # Fused under grouped heads the schedule is head-major (_schedule): a K
+    # tile comes back once a Q head, so dK and dV are held for the whole row.
+    whole_row = with_dq and groups > 1
+    if whole_row:
+        n = pl.num_programs(1)
+        kv_first, kv_last = t == 0, t == n - 1
+        rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+    else:
+        kv_first, kv_last, rows = first, last, slice(None)
 
-    @pl.when(first)
+    @pl.when(kv_first)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -575,7 +607,7 @@ def _dkv_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
         p_drop = jnp.where(keep, p * inv_keep, 0.0)
     else:
         keep, p_drop = None, p
-    dv_scr[:] += jax.lax.dot_general(
+    dv_scr[rows] += jax.lax.dot_general(
         p_drop.astype(do.dtype), do, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(
@@ -584,14 +616,64 @@ def _dkv_kernel(qi_ref, kj_ref, seed_ref, q_ref, k_ref, v_ref, mask_ref,
     if keep is not None:
         dp = jnp.where(keep, dp * inv_keep, 0.0)
     ds = (p * (dp - delta_ref[0]) * scale).astype(q.dtype)
-    dk_scr[:] += jax.lax.dot_general(
+    dk_scr[rows] += jax.lax.dot_general(
         ds, q, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-    @pl.when(last)
+    @pl.when(kv_last)
     def _():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    if not with_dq:
+        return
+    # dQ as its transpose, (D, BQ) a Q tile: K's tile is transposed once a
+    # run, and dQᵀ += Kᵀ·ds is then a plain product on the (BK, BQ) ds that
+    # is already here. A Q tile's terms come in ascending K tile, as in
+    # _dq_kernel.
+    q_first, q_last = _run_edges(qi_ref, t, per=nq)
+
+    @pl.when(first)
+    def _():
+        kt_scr[:] = k.T
+
+    @pl.when(q_first)
+    def _():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    dq_scr[i] += jax.lax.dot_general(
+        kt_scr[:], ds, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(q_last)
+    def _():
+        for c in range(dq_scr.shape[0]):
+            dq_ref[0, c * bq:(c + 1) * bq, :] = dq_scr[c].T.astype(
+                dq_ref.dtype)
+
+
+# The fused backward holds dQ of a whole Q head in float32 VMEM, and under
+# grouped K/V heads dK and dV of a whole K/V head beside it. Up to this many
+# bytes of such accumulators it runs; past them (long sequences) the two
+# kernels do, whose scratch is a tile's. 16 MiB holds every shape the models
+# train at: trinity_mini's 8192 tokens on 32/4 heads of 128 take 12.
+_FUSED_BWD_BYTES = 16 * 2 ** 20
+_FUSED_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=64 * 2 ** 20)
+
+
+def fused_bwd_bytes(s: int, d: int, dv: int, groups: int) -> int:
+    """float32 accumulators the fused backward holds for a whole grid row at
+    sequence length ``s`` (padded), keys ``d`` and values ``dv`` wide,
+    ``groups`` Q heads a K/V head: dQ of one Q head, and under grouped heads
+    dK and dV of the K/V head."""
+    return 4 * s * (d + (d + dv if groups > 1 else 0))
+
+
+def fused_bwd_fits(s: int, d: int, dv: int, groups: int) -> bool:
+    """Does the backward pass run as one kernel at these shapes?"""
+    return fused_bwd_bytes(s, d, dv, groups) <= _FUSED_BWD_BYTES
 
 
 def _bwd(scale, plan, causal, dropout_rate, residuals, g):
@@ -604,6 +686,10 @@ def _bwd(scale, plan, causal, dropout_rate, residuals, g):
     bq, bk = plan.bq, plan.bk
     groups = bh // k.shape[0]
     width_v = v.shape[2]
+    operands = (seed, q, k, v, mask3, g, lse3, delta3)
+    if fused_bwd_fits(s, d, width_v, groups):
+        dq, dk, dv = _bwd_fused(scale, plan, causal, dropout_rate, operands)
+        return dq, dk, dv, None, None
 
     def specs(**kv_rows):
         """(the seven operands' specs, the Q, K and V tiles' own)"""
@@ -613,7 +699,6 @@ def _bwd(scale, plan, causal, dropout_rate, residuals, g):
                 q_tile, k_tile, v_tile)
 
     in_specs, q_tile, k_tile, v_tile = specs()
-    operands = (seed, q, k, v, mask3, g, lse3, delta3)
 
     tables = _schedule(s, plan, causal)
     dq = pallas_call(
@@ -647,6 +732,49 @@ def _bwd(scale, plan, causal, dropout_rate, residuals, g):
         compiler_params=_PARAMS,
     )(*tables, *operands)
     return dq, dk, dv, None, None
+
+
+def _bwd_fused(scale, plan, causal, dropout_rate, operands):
+    """dQ, dK and dV in one K-major kernel (``flash_dkv``): the scores,
+    probabilities, masks and dP of a tile are made once. The grid rows are
+    the K/V heads; a row walks its Q heads one after the other (head-major,
+    :func:`_schedule`), and a Q head's dQ stays in VMEM until its last tile.
+    dK and dV stay a K tile's under equal heads, and the row's under
+    grouped ones (a K tile comes back once a Q head)."""
+    _, q, k, v = operands[:4]
+    bh, s, d = q.shape
+    bkv, width_v = k.shape[0], v.shape[2]
+    groups, nq = bh // bkv, s // plan.bq
+    bq, bk = plan.bq, plan.bk
+    q_tile, k_tile, vec_q, vec_k, o_tile, v_tile = _tile_specs(
+        bq, bk, d, groups, dv=width_v, kv_rows=True, nq=nq)
+    dq_row = pl.BlockSpec(
+        (1, s, d), lambda b, t, qi, kj, _: (b * groups + qi[t] // nq, 0, 0))
+    if groups > 1:
+        dk_out, dv_out = (pl.BlockSpec((1, s, w), lambda b, t, qi, kj, _:
+                                       (b, 0, 0)) for w in (d, width_v))
+        kv_rows = s
+    else:
+        dk_out, dv_out, kv_rows = k_tile, v_tile, bk
+    tables = _schedule(s, plan, causal, groups=groups, head_major=True)
+    return pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, causal=causal,
+                          dropout_rate=dropout_rate, groups=groups, nq=nq,
+                          with_dq=True, **_window_kw(plan)),
+        name="flash_dkv",
+        grid_spec=_grid_spec(
+            bkv, tables,
+            [q_tile, k_tile, v_tile, vec_k, o_tile, vec_q, vec_q],
+            [dq_row, dk_out, dv_out],
+            [pltpu.VMEM((kv_rows, d), jnp.float32),
+             pltpu.VMEM((kv_rows, width_v), jnp.float32),
+             pltpu.VMEM((d, bk), k.dtype),
+             pltpu.VMEM((nq, d, bq), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        compiler_params=_FUSED_PARAMS,
+    )(*tables, *operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 import functools
+import importlib
 
 from distributeddeeplearning_tpu.ops import flash_attention
 from distributeddeeplearning_tpu.ops.flash_attention import (FLASH_LSE,
@@ -412,7 +413,10 @@ def test_tile_plan_visited_counts_of_the_models_shapes(s, window, visited):
     tiles; S = 8192 visits 16 * 17 / 2 = 136 of 256; under a window of 2048
     a row of Q tiles meets its own tile and the four before it (the fourth
     holds the pairs 1537 .. 2047 apart), so rows 0-3 visit 1 + 2 + 3 + 4 and
-    the other twelve 5 each: 70. A call without a window plans as PR 26's."""
+    the other twelve 5 each: 70. A call without a window plans as PR 26's.
+    The fused backward's table is head-major: a grid row's Q heads one
+    after the other, each with its tiles K-major, so a Q head's dQ is whole
+    before the next begins; with one head a row it is the dK/dV order."""
     from distributeddeeplearning_tpu.ops.flash_attention import (
         _schedule, tile_plan)
 
@@ -429,6 +433,17 @@ def test_tile_plan_visited_counts_of_the_models_shapes(s, window, visited):
         assert (np.diff(kj) >= 0).all()       # a K tile's run is one run
         heads = qi // (s // plan.bq)
         assert sorted(set(heads.tolist())) == list(range(groups))
+        hq, hk = (np.asarray(t) for t in
+                  _schedule(s, plan, True, groups=groups, head_major=True))
+        head = hq // (s // plan.bq)
+        assert (np.diff(head) >= 0).all()     # a Q head's tiles are one run
+        pairs = list(zip(head.tolist(), hk.tolist(), hq.tolist()))
+        assert pairs == sorted(pairs)         # and K-major within it
+        assert sorted(zip(qi.tolist(), kj.tolist())) == sorted(
+            zip(hq.tolist(), hk.tolist()))    # the same tiles
+        if groups == 1:
+            np.testing.assert_array_equal(hq, qi)
+            np.testing.assert_array_equal(hk, kj)
 
 
 @pytest.mark.parametrize("s,bq,bk,window", [
@@ -500,7 +515,7 @@ def test_a_recomputed_block_runs_the_forward_kernel_as_its_policy_says(
     whatever was kept."""
     grad = jax.grad(_block_loss(policy), argnums=(0, 1))
     assert flash_kernel_calls(grad, *block_inputs) == {
-        "flash_fwd": forwards, "flash_dq": 1, "flash_dkv": 1}
+        "flash_fwd": forwards, "flash_dq": 0, "flash_dkv": 1}
     for got, want in zip(grad(*block_inputs), kept_block_grads):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -523,7 +538,7 @@ def test_gpt_blocks_recomputed_without_a_policy_run_the_forward_twice(
     calls = flash_kernel_calls(
         jax.grad(lambda p: (model.apply({"params": p}, ids,
                                         train=False) ** 2).mean()), params)
-    assert calls == {"flash_fwd": forwards * layers, "flash_dq": layers,
+    assert calls == {"flash_fwd": forwards * layers, "flash_dq": 0,
                      "flash_dkv": layers}
 
 
@@ -608,7 +623,7 @@ def test_the_kept_result_keeps_its_meaning_at_unequal_widths(
     want = jax.grad(_unequal_block_loss("kept"), argnums=(0, 1, 2))(x, w, wv)
     grad = jax.grad(_unequal_block_loss(policy), argnums=(0, 1, 2))
     assert flash_kernel_calls(grad, x, w, wv) == {
-        "flash_fwd": forwards, "flash_dq": 1, "flash_dkv": 1}
+        "flash_fwd": forwards, "flash_dq": 0, "flash_dkv": 1}
     for got, kept in zip(grad(x, w, wv), want):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(kept))
 
@@ -632,9 +647,10 @@ def _kernel_types(fn, *args):
 
 def test_equal_widths_trace_to_the_kernels_they_traced_to():
     """The benchmark's two older cells call with one width; what each kernel
-    reads and writes there is what it read and wrote before values could be
-    of another width (the shapes PERF_LEDGER.jsonl names the kernels by),
-    and at 192 / 128 only the V side, the result and dV change."""
+    reads there is what it read before values could be of another width
+    (the shapes PERF_LEDGER.jsonl names the kernels by), and at 192 / 128
+    only the V side, the result and dV change. The backward pass is one
+    kernel, ``flash_dkv``, which writes dQ beside dK and dV."""
     def grads(d, dv, hkv):
         shapes = [(1, 1024, 4, d), (1, 1024, hkv, d), (1, 1024, hkv, dv)]
         args = [jnp.zeros(s, jnp.bfloat16) for s in shapes]
@@ -649,9 +665,129 @@ def test_equal_widths_trace_to_the_kernels_they_traced_to():
         mask, vec = "4x1x1024xi32", "4x1x1024xf32"
         backward = [q, k, v, mask, o, vec, vec]
         return {"flash_fwd": ([q, k, v, mask], [o, vec]),
-                "flash_dq": (backward, [q]),
-                "flash_dkv": (backward, [k, v])}
+                "flash_dkv": (backward, [q, k, v])}
 
     assert grads(64, 64, 4) == expect(64, 64, 4)       # gpt2's heads
     assert grads(128, 128, 1) == expect(128, 128, 1)   # trinity's, grouped
     assert grads(192, 128, 4) == expect(192, 128, 4)   # latent attention's
+
+
+# ---------------------------------------------------------------------------
+# One backward kernel: flash_dkv carries dQ where its accumulators fit the
+# budget (fused_bwd_fits), from the ds it makes for dK; past the budget the
+# two kernels run. Both paths against each other and the dense reference.
+# ---------------------------------------------------------------------------
+
+FLASH_MODULE = importlib.import_module(
+    "distributeddeeplearning_tpu.ops.flash_attention")
+
+
+def _dense_keep_reference(q, k, v, mask, causal, window, keep, rate):
+    """Dense softmax attention in float32: Q head h on K/V head h // (H //
+    Hkv), the key-padding ``mask``, causal and cut to ``query - key <
+    window``, and dropout by ``keep`` (B, H, S, S) after the softmax."""
+    s, h = q.shape[1], q.shape[2]
+    k, v = (jnp.repeat(x, h // k.shape[2], axis=2) for x in (k, v))
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    valid = (j <= i) if causal else jnp.ones((s, s), bool)
+    if window is not None:
+        valid = valid & (i - j < window)
+    valid = valid[None, None] & mask[:, None, None, :]
+    p = jax.nn.softmax(jnp.where(valid, sc, -1e30), axis=-1)
+    if keep is not None:
+        p = jnp.where(keep, p / (1.0 - rate), 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+FUSED_CASES = [
+    # s, h, hkv, d, dv, causal, window, valid keys a row, dropout, block;
+    # S = 200 is padded to 256 inside, and S = 64 is one K tile, which a
+    # grid row's Q heads then share
+    pytest.param(200, 4, 2, 24, 16, True, None, (200, 150), 0.0, 64,
+                 id="causal-masked-grouped-values-of-another-width-padded"),
+    pytest.param(128, 2, 2, 16, 16, False, None, (128, 70), 0.2, 32,
+                 id="full-masked-dropout"),
+    pytest.param(64, 4, 1, 16, 16, True, 20, None, 0.2, 64,
+                 id="grouped-window-dropout-one-k-tile"),
+]
+
+
+@pytest.mark.parametrize("s,h,hkv,d,dv,causal,window,lens,rate,block",
+                         FUSED_CASES)
+def test_fused_backward_matches_two_kernels_and_dense(
+        monkeypatch, s, h, hkv, d, dv, causal, window, lens, rate, block):
+    """dQ, dK and dV of the fused kernel against the two kernels' (dQ sums
+    the same terms in the same order) and against the dense reference,
+    whose dropout is ops/hash_dropout.py::dense_keep_mask: the mask the
+    fused kernel regenerates is that one. The two paths differentiate one
+    forward call, which is not jitted: each call of its vjp traces the
+    backward rule anew, and so takes the path the budget gives then."""
+    from distributeddeeplearning_tpu.ops.hash_dropout import dense_keep_mask
+
+    q, k, v, w = _unequal_qkv(s, h, hkv, d, dv)
+    mask = _ragged_mask(2, s, lens or (s, s))
+    seed = jnp.int32(1234)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask, causal=causal, window=window,
+                               block_q=block, block_k=block,
+                               dropout_rate=rate,
+                               dropout_seed=seed if rate else None)
+
+    def dense(q, k, v):
+        keep = dense_keep_mask(seed, 2, h, s, s, rate) if rate else None
+        return _dense_keep_reference(q, k, v, mask, causal, window, keep,
+                                     rate)
+
+    assert FLASH_MODULE.fused_bwd_fits(FLASH_MODULE._padded_len(s), d, dv,
+                                       h // hkv)
+    out, flash_vjp = jax.vjp(flash, q, k, v)
+    want_out, dense_vjp = jax.vjp(jax.jit(dense), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                               rtol=2e-5, atol=2e-5)
+    fused, want = flash_vjp(w), dense_vjp(w)
+    dq_kernel, traced = FLASH_MODULE._dq_kernel, []
+
+    def counted(*args, **kw):
+        traced.append(1)
+        return dq_kernel(*args, **kw)
+
+    with monkeypatch.context() as m:
+        m.setattr(FLASH_MODULE, "_FUSED_BWD_BYTES", -1)
+        m.setattr(FLASH_MODULE, "_dq_kernel", counted)
+        two = flash_vjp(w)
+    assert traced  # the second pass did run the two kernels
+    for name, a, t, r in zip(("dq", "dk", "dv"), fused, two, want):
+        assert a.shape == r.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(t), rtol=1e-5,
+                                   atol=1e-5, err_msg=f"{name}: two kernels")
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-4,
+                                   atol=1e-4, err_msg=f"{name}: dense")
+
+
+def test_the_budget_decides_the_backward_kernels_by_the_shapes():
+    """Every shape the cells train at fits the fused kernel's budget (12
+    MiB at trinity_mini's 8192 tokens on 32/4 heads of 128, the largest);
+    long sequences do not, and their gradient's program holds the two
+    kernels. The traced program follows the shapes alone."""
+    fits, size = FLASH_MODULE.fused_bwd_fits, FLASH_MODULE.fused_bwd_bytes
+    assert size(8192, 128, 128, 8) == 12 * 2 ** 20
+    assert size(8192, 192, 128, 1) == 6 * 2 ** 20
+    for shape in ((1024, 64, 64, 1), (512, 64, 64, 1), (8192, 128, 128, 8),
+                  (8192, 192, 128, 1), (4096, 192, 128, 1)):
+        assert fits(*shape), shape
+    for shape in ((65536, 128, 128, 1), (16384, 128, 128, 8),
+                  (131072, 64, 64, 1)):
+        assert not fits(*shape), shape
+
+    def calls(s, hkv):
+        shapes = [(1, s, 8, 128), (1, s, hkv, 128), (1, s, hkv, 128)]
+        args = [jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in shapes]
+        return flash_kernel_calls(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)), *args)
+
+    assert calls(8192, 1) == {"flash_fwd": 1, "flash_dq": 0, "flash_dkv": 1}
+    assert calls(16384, 1) == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+    assert calls(16384, 8) == {"flash_fwd": 1, "flash_dq": 0, "flash_dkv": 1}

@@ -233,8 +233,8 @@ def test_each_flash_kernel_stands_once_a_latent_layer(seeded, remat):
     assert flash_kernel_calls(
         jax.grad(lambda p: program_loss(model, p, router_state(extra),
                                         batch["input_ids"])[0]),
-        unflatten(params)) == {"flash_fwd": latent, "flash_dq": latent,
-                               "flash_dkv": latent}
+        unflatten(params)) == {"flash_fwd": latent, "flash_dq": 0,
+                               "flash_dkv": latent}  # one backward kernel
 
 
 @pytest.fixture(scope="module")

@@ -103,12 +103,13 @@ def test_flash_attention_compiles_for_v5e(compile_for, shape, causal, rate,
         fn = jax.grad(fn, argnums=(0, 1, 2))
     text = compile_for(fn, (shape, BF16), (shape, BF16), (shape, BF16),
                        (shape[:2], I32))
-    # forward alone is one kernel; backward adds dq and dk/dv
+    # forward alone is one kernel; backward adds one, the fused dK/dV/dQ
+    # (every shape here fits its budget)
     assert text.count('custom_call_target="tpu_custom_call"') == (
-        3 if grad else 1)
-    for name in (("flash_fwd", "flash_dq", "flash_dkv") if grad
-                 else ("flash_fwd",)):
+        2 if grad else 1)
+    for name in (("flash_fwd", "flash_dkv") if grad else ("flash_fwd",)):
         assert f"%{name}" in text
+    assert "%flash_dq" not in text
 
 
 # Trinity-Mini's attention at the benchmark cell's size: 32 Q heads of 128 on
@@ -130,6 +131,40 @@ def test_flash_attention_grouped_window_compiles_for_v5e(compile_for, window,
     text = compile_for(jax.grad(loss, argnums=(0, 1, 2)),
                        ((1, 8192, 32, 128), BF16), ((1, 8192, 4, 128), BF16),
                        ((1, 8192, 4, 128), BF16))
+    # one backward kernel: dK and dV of a K/V head and dQ of a Q head fit
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("flash_fwd", "flash_dkv"):
+        assert f"%{name}" in text
+    assert "%flash_dq" not in text
+
+
+# The two backward kernels, which run where the fused kernel's accumulators
+# pass its budget (long sequences), compiled at the shapes above with the
+# budget set to nothing: gpt2's heads with dropout, trinity's grouped heads
+# under the window, and latent attention's 192 / 128.
+@pytest.mark.parametrize("q_shape,kv_heads,v_dim,window,rate", [
+    pytest.param((16, 1024, 12, 64), 12, 64, None, 0.1, id="gpt2-dropout"),
+    pytest.param((1, 8192, 32, 128), 4, 128, 2048, 0.0, id="grouped-window"),
+    pytest.param((1, 8192, 32, 192), 32, 128, None, 0.0, id="latent")])
+def test_flash_backward_two_kernels_compile_for_v5e(
+        compile_for, monkeypatch, q_shape, kv_heads, v_dim, window, rate):
+    import importlib
+
+    from distributeddeeplearning_tpu.ops import flash_attention
+    module = importlib.import_module(
+        "distributeddeeplearning_tpu.ops.flash_attention")
+    monkeypatch.setattr(module, "_FUSED_BWD_BYTES", -1)
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, window=window, dropout_rate=rate,
+            dropout_seed=jnp.int32(7) if rate else None)
+        return out.astype(F32).sum()
+
+    b, s, _, d = q_shape
+    text = compile_for(jax.grad(loss, argnums=(0, 1, 2)), (q_shape, BF16),
+                       ((b, s, kv_heads, d), BF16),
+                       ((b, s, kv_heads, v_dim), BF16))
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert f"%{name}" in text
@@ -381,14 +416,17 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
     `train/loop.build` builds it (`make_gspmd_train_step` on a mesh of the
     described chip), lowered with shapes and compiled. What the chip's
     compiler says of it: it fits (7.23 GB of state: float32 masters and
-    Adam's two moments of 602M parameters; 4.81 GB of temporaries, the
+    Adam's two moments of 602M parameters; 4.96 GB of temporaries, the
     float32 gradients and the state that enters each chunk of each KDA
-    layer, kept across remat, among them: 4.82 when a group's entering
+    layer, kept across remat, among them: 4.81 before the latent layer's
+    backward was one flash kernel, whose dQ, dK and dV are live at once,
+    where dQ could go before dK and dV came; 4.82 when a group's entering
     state was kept and XLA looped over the chunks, 4.83 before a chunk's
     stateless work was two kernels, whose (8, 32, 4, 16, 16, 128) pair
     intermediates were never the peak; 5.40 before the pointwise stages
-    round the delta rule were kernels), the latent layer's three flash
-    kernels are there at 192 / 128, and the chunked delta rule is two
+    round the delta rule were kernels), the latent layer's two flash
+    kernels (the forward and the one backward kernel, which carries dQ) are
+    there at 192 / 128, and the chunked delta rule is two
     kernels a KDA layer and no `while`: its forward kernel once (not again
     for a recomputed forward, whose result and entering states the block
     keeps) and its backward kernel once."""
@@ -403,8 +441,9 @@ def test_kimi_linear_ep32_step_compiles_for_v5e(one_chip):
         12 * parameters, rel=0.001)
     assert memory.temp_size_in_bytes < 1.1 * 4.81e9
     text = compiled.as_text()
-    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert len(re.findall(rf"%{name}\S* = ", text)) == 1, name
+    # one backward kernel (the fused dK/dV/dQ), so no flash_dq
+    for name, n in (("flash_fwd", 1), ("flash_dq", 0), ("flash_dkv", 1)):
+        assert len(re.findall(rf"%{name}\S* = ", text)) == n, name
     assert re.search(r"%flash_fwd\S* = \(bf16\[32,8192,128\]", text)
     assert not re.findall(r" while\(", text)
     # the pointwise stages round the operator (ops/kda_stages.py): a KDA
@@ -446,8 +485,9 @@ def test_xing4_ep8_step_compiles_for_v5e(one_chip):
     of it: it fits (9.11 GB of state: float32 masters and Adam's two moments
     of 759M parameters; 4.35 GB of temporaries, the float32 gradients and
     the four residual streams' kept block inputs among them), every layer's
-    three flash kernels are there once, at queries of 192 and values of 128
-    (a recomputed block keeps the forward kernel's result), and the Sinkhorn
+    two flash kernels (the forward and the one backward kernel, which
+    carries dQ) are there once, at queries of 192 and values of 128 (a
+    recomputed block keeps the forward kernel's result), and the Sinkhorn
     iterations are `while`s under the hyper-connections' scope: one a
     hyper-connection forward, one recomputed, one backward. The passes over
     the streams are the four kernels of ops/mhc.py under the same scope: the
@@ -469,8 +509,9 @@ def test_xing4_ep8_step_compiles_for_v5e(one_chip):
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 0.9 * 16 * 1024 ** 3)
     text = compiled.as_text()
-    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert len(re.findall(rf"%{name}\S* = ", text)) == 5, name
+    # one backward kernel (the fused dK/dV/dQ), so no flash_dq
+    for name, n in (("flash_fwd", 5), ("flash_dq", 0), ("flash_dkv", 5)):
+        assert len(re.findall(rf"%{name}\S* = ", text)) == n, name
     assert re.search(r"%flash_fwd\S* = \(bf16\[32,4096,128\]", text)
     loops = re.findall(r" while\(.*", text)
     assert len(loops) == 10 * 3

@@ -328,7 +328,8 @@ def test_each_flash_kernel_stands_once_a_layer(seeded, remat):
     assert flash_kernel_calls(
         jax.grad(lambda p: program_loss(model, p, router_state(extra),
                                         batch["input_ids"])[0]),
-        unflatten(params)) == {"flash_fwd": n, "flash_dq": n, "flash_dkv": n}
+        unflatten(params)) == {"flash_fwd": n, "flash_dq": 0,
+                               "flash_dkv": n}  # one backward kernel
 
 
 MHC_KERNELS = ("mhc_in_fwd", "mhc_in_bwd", "mhc_out_fwd", "mhc_out_bwd")

@@ -11,14 +11,21 @@ time flash against dense, and read each kernel's device time from a trace.
 With no ``--shape`` it does all of that at the shapes the models run
 (``MODEL_SHAPES``): the gpt2 cell's (16,1024,12,64, causal) and BERT's
 (8,512,12,64, not causal), both with a key-padding mask and dropout 0.1 in
-the kernel times, and latent attention's in the kimi_linear cell
-(1,8192,32,192/128, causal: values 128 wide) beside the same call at 128/128.
-A heads field ``32/4`` is 32 Q heads on 4 K/V heads, and ``--window`` cuts
-the causal triangle to a band: ``--shape 1,8192,32/4,128 --causal --window
-2048`` is a sliding layer of the trinity_mini cell, and without ``--window``
-its full layer. The dense reference goes a head at a time, so S = 8192 fits;
-where the (B,H,S,S) dropout mask of the reference would not, the dropout
-comparison is left out and the kernels are timed without dropout.
+the kernel times, latent attention's in the kimi_linear and xing4 cells
+(1,8192,32,192/128 and 1,4096,32,192/128, causal: values 128 wide), and the
+trinity_mini cell's sliding and full layers (1,8192,32/4,128, causal, with
+and without a window of 2048). A heads field ``32/4`` is 32 Q heads on 4 K/V
+heads, and ``--window`` cuts the causal triangle to a band.
+
+The backward pass is one kernel (``flash_dkv`` carries dQ) where its
+accumulators fit ``ops/flash_attention.py::fused_bwd_fits``, and two
+(``flash_dq`` beside it) past that. Both are run at every shape: the
+gradients of the one are held to the other's (``rel_err_two_kernels``), and
+each path's kernels are timed (``"path"`` of a ``kernel_times`` line).
+
+The dense reference goes a head at a time, so S = 8192 fits; where the
+(B,H,S,S) dropout mask of the reference would not, the dropout comparison
+is left out and the kernels are timed without dropout.
 ``--tiles`` times further tile sizes (block_q x block_k overrides) beside
 the derived ones, which is how a default in ``ops/flash_attention.py`` is
 chosen.
@@ -31,8 +38,10 @@ from __future__ import annotations
 
 import argparse
 import collections
+import contextlib
 import functools
 import glob
+import importlib
 import json
 import os
 import re
@@ -48,6 +57,23 @@ import jax.numpy as jnp
 import numpy as np
 
 RATE, SEED = 0.1, 20260731
+FLASH = "distributeddeeplearning_tpu.ops.flash_attention"
+PATHS = ("fused", "two_kernels")
+
+
+@contextlib.contextmanager
+def backward_path(path: str):
+    """Trace the flash backward as ``path`` whatever the shapes: the module's
+    budget for the fused kernel is set to nothing for ``two_kernels``, and
+    left as it is for ``fused`` (every shape of ``MODEL_SHAPES`` fits it)."""
+    module = importlib.import_module(FLASH)
+    kept = module._FUSED_BWD_BYTES
+    if path == "two_kernels":
+        module._FUSED_BWD_BYTES = -1
+    try:
+        yield
+    finally:
+        module._FUSED_BWD_BYTES = kept
 
 
 def dense_ref(q, k, v, mask, *, causal=False, keep=None, window=None):
@@ -98,10 +124,26 @@ def _inputs(shape, kv_heads=None, v_dim=None):
     return q, k, v, mask
 
 
+def _rel_err(a, r) -> float:
+    """max |a - r| over max(max |r|, 1), in float32."""
+    a, r = a.astype(jnp.float32), r.astype(jnp.float32)
+    return float(jnp.abs(a - r).max() / jnp.maximum(jnp.abs(r).max(), 1.0))
+
+
+def _fits(q, k, v) -> bool:
+    """Does the fused backward run at these (B,S,H,D) inputs' shapes?"""
+    module = importlib.import_module(FLASH)
+    s = module._padded_len(q.shape[1])
+    return module.fused_bwd_fits(s, q.shape[3], v.shape[3],
+                                 q.shape[2] // k.shape[2])
+
+
 def check_correctness(shape=(8, 512, 12, 64), causal=False, window=None,
                       kv_heads=None, v_dim=None) -> bool:
     """Compiled flash vs the dense reference at ``shape`` (B,S,H,D), bf16:
-    forward and gradients, without and with dropout. One JSON line each."""
+    forward and gradients, without and with dropout, and the gradients of
+    the fused backward against those of the two kernels. One JSON line
+    each."""
     from distributeddeeplearning_tpu.ops.flash_attention import (
         flash_attention)
     from distributeddeeplearning_tpu.ops.hash_dropout import dense_keep_mask
@@ -141,20 +183,25 @@ def check_correctness(shape=(8, 512, 12, 64), causal=False, window=None,
                                         RATE).mean())()), 4)
         print(json.dumps(rec), flush=True)
 
-        gf = jax.jit(jax.grad(functools.partial(loss, flash),
-                              argnums=(0, 1, 2)))(q, k, v)
+        grads = {}
+        for path in PATHS:
+            with backward_path(path):
+                grads[path] = jax.jit(jax.grad(functools.partial(
+                    loss, flash), argnums=(0, 1, 2)))(q, k, v)
         gr = jax.jit(jax.grad(functools.partial(loss, ref),
                               argnums=(0, 1, 2)))(q, k, v)
-        errs, ok_bwd = {}, True
-        for name, a, r in zip(("dq", "dk", "dv"), gf, gr):
-            a, r = a.astype(jnp.float32), r.astype(jnp.float32)
-            errs[name] = float(jnp.abs(a - r).max()
-                               / jnp.maximum(jnp.abs(r).max(), 1.0))
-            ok_bwd &= errs[name] < 3e-2
+        errs, errs_two, ok_bwd = {}, {}, True
+        for name, a, t, r in zip(("dq", "dk", "dv"), grads["fused"],
+                                 grads["two_kernels"], gr):
+            errs[name] = _rel_err(a, r)
+            errs_two[name] = _rel_err(a, t)
+            ok_bwd &= errs[name] < 3e-2 and errs_two[name] < 1e-2
         print(json.dumps({"check": f"flash_{label}backward",
                           "shape": list(shape), "kv_heads": k.shape[2],
                           "v_dim": v.shape[3], "causal": causal,
-                          "window": window, "rel_err": errs,
+                          "window": window, "fused": _fits(q, k, v),
+                          "rel_err": errs,
+                          "rel_err_two_kernels": errs_two,
                           "ok": ok_bwd}), flush=True)
         ok &= ok_fwd and ok_bwd
     return ok
@@ -206,12 +253,14 @@ KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
 def kernel_times(shape, causal, block_q=None, block_k=None, iters=10,
-                 window=None, kv_heads=None, v_dim=None) -> None:
-    """The plan at ``shape`` and the device time of each of the three
-    kernels, from a profiler trace of ``iters`` forward+backward calls with
-    the key-padding mask and dropout in the kernels: ms a call, and us a
-    visited tile. The kernels are found by the names they were given
-    (ops/flash_attention.py), as the benchmark's ``device_ms.flash_*`` do."""
+                 window=None, kv_heads=None, v_dim=None,
+                 path="fused") -> None:
+    """The plan at ``shape`` and the device time of each of the kernels of
+    the backward ``path`` (``PATHS``), from a profiler trace of ``iters``
+    forward+backward calls with the key-padding mask and dropout in the
+    kernels: ms a call, and us a visited tile. The kernels are found by the
+    names they were given (ops/flash_attention.py), as the benchmark's
+    ``device_ms.flash_*`` do."""
     from distributeddeeplearning_tpu.ops.flash_attention import (
         flash_attention, tile_plan)
 
@@ -224,15 +273,16 @@ def kernel_times(shape, causal, block_q=None, block_k=None, iters=10,
             block_k=block_k, dropout_rate=rate,
             dropout_seed=jnp.int32(SEED) if rate else None,
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
-    jax.block_until_ready(step(q, k, v))  # compile + warm
+    with backward_path(path):
+        jax.block_until_ready(step(q, k, v))  # compile + warm
     with tempfile.TemporaryDirectory() as log_dir:
         with jax.profiler.trace(log_dir):
             for _ in range(iters):
                 out = step(q, k, v)
             jax.block_until_ready(out)
-        path = sorted(glob.glob(os.path.join(
+        xplane = sorted(glob.glob(os.path.join(
             log_dir, "**", "*.xplane.pb"), recursive=True))[-1]
-        data = jax.profiler.ProfileData.from_file(path)
+        data = jax.profiler.ProfileData.from_file(xplane)
     ns = collections.Counter()
     for plane in data.planes:
         if not re.match(r"^/device:TPU:\d+$", plane.name):
@@ -246,9 +296,9 @@ def kernel_times(shape, causal, block_q=None, block_k=None, iters=10,
     plan = tile_plan(s, causal, block_q, block_k, window=window)
     ms = {name: ns[name] / iters / 1e6 for name in KERNELS}
     print(json.dumps({
-        "check": "kernel_times", "shape": list(shape),
+        "check": "kernel_times", "path": path, "shape": list(shape),
         "kv_heads": k.shape[2], "v_dim": v.shape[3], "causal": causal,
-        "dropout": rate,
+        "window": window, "dropout": rate,
         "plan": plan._asdict(),
         "visited_share": round(plan.visited / plan.total, 4),
         "ms_a_call": {n: round(t, 4) for n, t in ms.items()},
@@ -259,16 +309,19 @@ def kernel_times(shape, causal, block_q=None, block_k=None, iters=10,
     }), flush=True)
 
 
-# What the models run: the benchmark cell's attention (gpt2_small, 16 x
-# 1024, causal), BERT-base's (8 x 512, key-padding mask only), and latent
-# attention's in the kimi_linear cell (32 heads, queries and keys 192 wide,
-# values 128, one causal sequence of 8192) with the same call at one width
-# of 128 beside it, so that the kernels' times at the new widths stand
-# beside those at the old. (shape, causal, values' width if another)
-MODEL_SHAPES = (((16, 1024, 12, 64), True, None),
-                ((8, 512, 12, 64), False, None),
-                ((1, 8192, 32, 128), True, None),
-                ((1, 8192, 32, 192), True, 128))
+# What the models run: the gpt2 cell's attention (16 x 1024, causal), BERT-
+# base's (8 x 512, key-padding mask only), latent attention's in the
+# kimi_linear and xing4 cells (32 heads, queries and keys 192 wide, values
+# 128, one causal sequence of 8192 and of 4096), and the trinity_mini cell's
+# sliding and full layers (32 Q heads on 4 K/V heads of 128, one causal
+# sequence of 8192, a window of 2048 and none).
+# (shape, causal, values' width if another, K/V heads if fewer, window)
+MODEL_SHAPES = (((16, 1024, 12, 64), True, None, None, None),
+                ((8, 512, 12, 64), False, None, None, None),
+                ((1, 8192, 32, 192), True, 128, None, None),
+                ((1, 4096, 32, 192), True, 128, None, None),
+                ((1, 8192, 32, 128), True, None, 4, 2048),
+                ((1, 8192, 32, 128), True, None, 4, None))
 
 
 def main(argv=None) -> int:
@@ -289,26 +342,28 @@ def main(argv=None) -> int:
     if platform != "tpu":
         print(json.dumps({"error": f"need TPU, got {platform}"}))
         return 1
-    kv_heads = None
     if args.shape is not None:
         b, s, heads, d = args.shape.split(",")
         heads, _, kv = heads.partition("/")
         d, _, dv = d.partition("/")
-        kv_heads = int(kv) if kv else None
-        shape = (int(b), int(s), int(heads), int(d))
-    cases = (MODEL_SHAPES if args.shape is None
-             else ((shape, args.causal, int(dv) if dv else None),))
+        cases = (((int(b), int(s), int(heads), int(d)), args.causal,
+                  int(dv) if dv else None, int(kv) if kv else None,
+                  args.window),)
+    else:
+        cases = MODEL_SHAPES
     tiles = [(None, None)] + [tuple(int(x) for x in t.split("x"))
                               for t in args.tiles.split(",") if t]
     ok = True
-    for shape, causal, v_dim in cases:
-        kw = dict(window=args.window, kv_heads=kv_heads, v_dim=v_dim)
+    for shape, causal, v_dim, kv_heads, window in cases:
+        kw = dict(window=window, kv_heads=kv_heads, v_dim=v_dim)
         ok &= check_correctness(shape, causal, **kw)
         if not args.skip_timing:
             if _dropout_fits(shape):  # its dense side makes (B,H,S,S) too
                 time_kernels(shape, causal, **kw)
             for block_q, block_k in tiles:
-                kernel_times(shape, causal, block_q, block_k, **kw)
+                for path in PATHS:
+                    kernel_times(shape, causal, block_q, block_k, path=path,
+                                 **kw)
     return 0 if ok else 1
 
 

@@ -8,7 +8,7 @@ the flax module path, every ``jax.named_scope`` (train/steps.py's
 ``STEP_SCOPES`` and ``LOSS_SCOPE``, models/gpt.py's ``embed`` / ``mlp`` /
 ``head``, models/afmoe.py's ``attn_window`` / ``attn_full``,
 models/kimi_linear.py's ``attn_kda`` / ``attn_mla``,
-models/hyper_connections.py's ``mhc``, models/moe.py's
+models/nemotron_h.py's ``mamba``, models/hyper_connections.py's ``mhc``, models/moe.py's
 ``moe_router`` / ``moe_dispatch`` / ``moe_experts`` / ``moe_combine``) and
 every Pallas kernel's ``name`` (ops/pallas.py). This module reads those names
 back:
@@ -32,7 +32,8 @@ PHASES = ("forward", "backward", "update")
 # no rule placed (instructions the compiler made without metadata, scopes the
 # rule does not know — a CNN's convolutions, today).
 PARTS = ("flash_fwd", "flash_dq", "flash_dkv", "attention_window",
-         "attention_full", "attention_kda", "attention_mla", "residual_mhc",
+         "attention_full", "attention_kda", "attention_mla", "mixer_mamba",
+         "residual_mhc",
          "moe_routing", "moe_experts", "attention_other",
          "mlp", "layernorm", "embed", "head", "loss", "remat", "loss_scale",
          "optimizer", "ema_guard", "grad_reduce", "unattributed")
@@ -42,15 +43,17 @@ _KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 # differ in attention kind scopes the kernel calls by kind, and the three
 # kernels then book under the kind (so do a delta-rule layer's convolutions,
 # gates, chunked scan and gated norm, which have no kernel of their own
-# name); one with routed experts has the router,
-# the sort, the gathers and the weighted sum as ``moe_routing`` and the
-# grouped products as ``moe_experts``; one whose residual is several streams
-# has everything its hyper-connections add (the norm over the streams, the
-# product that makes the coefficients, Sinkhorn's iterations and the three
-# mixes) as ``residual_mhc``.
+# name); a state-space mixer has its convolution, step sizes, chunked
+# recurrence and gated norm as ``mixer_mamba``; one with routed experts has
+# the router, the sort, the gathers and the weighted sum as ``moe_routing``
+# and the grouped products as ``moe_experts``; one whose residual is several
+# streams has everything its hyper-connections add (the norm over the
+# streams, the product that makes the coefficients, Sinkhorn's iterations
+# and the three mixes) as ``residual_mhc``.
 _MODEL_SCOPES = {"attn_window": "attention_window",
                  "attn_full": "attention_full",
                  "attn_kda": "attention_kda", "attn_mla": "attention_mla",
+                 "mamba": "mixer_mamba",
                  "mhc": "residual_mhc",
                  "moe_router": "moe_routing", "moe_dispatch": "moe_routing",
                  "moe_combine": "moe_routing", "moe_experts": "moe_experts"}

@@ -30,7 +30,8 @@ class ModelSpec:
 def _registry() -> dict[str, ModelSpec]:
     from distributeddeeplearning_tpu.models import (afmoe, bert, densenet,
                                                     gpt, kimi_linear, llama,
-                                                    resnet, vit, xing4)
+                                                    nemotron_h, resnet, vit,
+                                                    xing4)
 
     def img(build, name, params):
         return ModelSpec(name=name, build=build, input_kind="image",
@@ -119,6 +120,21 @@ def _registry() -> dict[str, ModelSpec]:
             param_count=759_489_550, objective="causal"),
         "xing4_tiny": ModelSpec(
             name="xing4_tiny", build=xing4.xing4_tiny, input_kind="tokens",
+            param_count=0, objective="causal"),
+        # Nemotron 3 Nano 30B-A3B as published, for shape tests, and one
+        # chip's share of it when 16 chips share each layer
+        # (models/nemotron_h.py; the benchmark's nemotron3_nano cell).
+        "nemotron3_nano_30b": ModelSpec(
+            name="nemotron3_nano_30b", build=nemotron_h.nemotron3_nano_30b,
+            input_kind="tokens", param_count=31_577_937_344,
+            objective="causal"),
+        "nemotron3_nano_ep16": ModelSpec(
+            name="nemotron3_nano_ep16",
+            build=nemotron_h.nemotron3_nano_ep16, input_kind="tokens",
+            param_count=666_962_944, objective="causal"),
+        "nemotron3_nano_tiny": ModelSpec(
+            name="nemotron3_nano_tiny",
+            build=nemotron_h.nemotron3_nano_tiny, input_kind="tokens",
             param_count=0, objective="causal"),
         # Nano drafters for speculative decoding (serve/engine.py): a
         # shrunk config of the same family — cheap to step, same

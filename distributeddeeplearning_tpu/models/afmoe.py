@@ -247,3 +247,11 @@ def tiny_afmoe(vocab_size: int = 512, dtype: Dtype = jnp.float32,
            "layer_types": (SLIDING, SLIDING, FULL), "sliding_window": 16,
            "num_experts": 8, "experts_held": (2, 4), "experts_per_token": 2,
            **overrides}), dtype=dtype)
+
+
+# What a recomputed block keeps, for every decoder built of these parts: the
+# routed experts' result and the flash forward kernel's with its log-sum-exp
+# (AfmoeLM's policy, whose comment says why; AfmoeLM spells the names out at
+# its call, whose place in this file the trinity step's kernels record as
+# their source location).
+KEPT_BY_A_BLOCK = (ROUTED_OUT, FLASH_OUT, FLASH_LSE)

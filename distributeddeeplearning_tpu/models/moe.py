@@ -252,11 +252,13 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 EXPECTED_ROWS_FACTOR = 2
 
 
-def _dispatch_to_combine(rows, u, gates, w_gate, w_up, w_down,
+def _dispatch_to_combine(rows, activation, u, gates, weights,
                          order, pos, here, group_sizes):
     """(T, H) float32: the routed experts' part of the layer's result, from
     the gather into a buffer of ``rows`` rows to the weighted sum back in
-    token order. ``order`` lists the (token, slot) assignments sorted by
+    token order. ``weights`` are the stacked kernels of the experts'
+    products: (gate, up, down) for ``activation`` "swiglu", (up, down) for
+    "relu2". ``order`` lists the (token, slot) assignments sorted by
     expert, those that land here first, ``pos`` (T, k) is its inverse,
     ``here`` (T, k) says which land here, ``group_sizes`` how many on each
     held expert. Every assignment that lands here has a row when
@@ -281,14 +283,19 @@ def _dispatch_to_combine(rows, u, gates, w_gate, w_up, w_down,
             y = jax.lax.ragged_dot(jnp.where(live, x, 0), w, group_sizes)
             return jnp.where(live, y, 0)
 
-        hidden = nn.silu(product(xs, w_gate)) * product(xs, w_up)
+        if activation == "swiglu":
+            w_gate, w_up, w_down = weights
+            hidden = nn.silu(product(xs, w_gate)) * product(xs, w_up)
+        else:
+            w_up, w_down = weights
+            hidden = jnp.square(nn.relu(product(xs, w_up)))
         ys = product(hidden, w_down)
 
     with jax.named_scope("moe_combine"):
         return _combine(ys, gates, token_of, pos, row_gate)
 
 
-def _sized_to_what_lands(rows: int, worst: int):
+def _sized_to_what_lands(rows: int, worst: int, activation: str):
     """:func:`_dispatch_to_combine` as ``f(differentiable, integer)``
     arguments, over a buffer of ``rows`` rows where what landed fits it and
     of ``worst`` rows, which nothing can overflow, where it does not: one
@@ -308,7 +315,8 @@ def _sized_to_what_lands(rows: int, worst: int):
     then run once forward, once recomputed and once backward, as a single
     body's do."""
     def body(size):
-        return lambda args, ints: _dispatch_to_combine(size, *args, *ints)
+        return lambda args, ints: _dispatch_to_combine(size, activation,
+                                                       *args, *ints)
 
     if rows >= worst:
         return body(worst)
@@ -355,8 +363,11 @@ class _Kernel(nn.Module):
 
 
 class RoutedExperts(nn.Module):
-    """A token's ``experts_per_token`` of ``num_experts`` SwiGLU experts,
-    no token dropped, computed for the experts this chip holds.
+    """A token's ``experts_per_token`` of ``num_experts`` experts, no token
+    dropped, computed for the experts this chip holds. An expert is SwiGLU,
+    ``down(silu(gate x) * up x)`` (``activation`` "swiglu"), or a squared
+    ReLU, ``down(relu(up x)^2)`` ("relu2": two products, no ``gate``
+    kernel), and so is the shared expert.
 
     The router scores every token over all ``num_experts`` in float32 and
     picks as the whole model does; the layer then computes the part of the
@@ -387,8 +398,9 @@ class RoutedExperts(nn.Module):
     grouped-matmul kernel that visits only the tiles that hold rows); rows
     past the last group are nobody's and are kept at zero. The gated
     results return to token order by a gather and a weighted sum over a
-    token's slots. A shared expert, ``shared_width`` > 0, is a dense SwiGLU
-    every token passes through, held whole by every chip.
+    token's slots. A shared expert, ``shared_width`` > 0, is a dense expert
+    of the same activation that every token passes through, held whole by
+    every chip.
 
     The selection bias (collection ``ROUTER_STATE``) is state without a
     gradient: when ``train`` and the collection is mutable it moves by
@@ -405,6 +417,7 @@ class RoutedExperts(nn.Module):
     route_scale: float = 1.0
     shared_width: int = 0
     bias_update_rate: float = 0.0
+    activation: str = "swiglu"
     dtype: Dtype = jnp.bfloat16
 
     @nn.compact
@@ -417,6 +430,9 @@ class RoutedExperts(nn.Module):
                              f"of {e} experts")
         if self.score_func not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown score_func {self.score_func!r}")
+        if self.activation not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown activation {self.activation!r}")
+        gated = self.activation == "swiglu"
         u = x.reshape(t, h)
 
         with jax.named_scope("moe_router"):
@@ -452,15 +468,16 @@ class RoutedExperts(nn.Module):
                 return _Kernel((held,) + shape, ("experts",) + axes,
                                name=name)().astype(self.dtype)
 
-            w_gate = kernel("experts_gate", (h, self.expert_width),
-                            ("embed", "mlp"))
-            w_up = kernel("experts_up", (h, self.expert_width),
-                          ("embed", "mlp"))
-            w_down = kernel("experts_down", (self.expert_width, h),
-                            ("mlp", "embed"))
+            weights = tuple(
+                kernel("experts_" + name, shape, axes)
+                for name, shape, axes in (
+                    ("gate", (h, self.expert_width), ("embed", "mlp")),
+                    ("up", (h, self.expert_width), ("embed", "mlp")),
+                    ("down", (self.expert_width, h), ("mlp", "embed")))
+                if gated or name != "gate")
 
-        out = _sized_to_what_lands(rows, worst)(
-            (u.astype(self.dtype), gates, w_gate, w_up, w_down),
+        out = _sized_to_what_lands(rows, worst, self.activation)(
+            (u.astype(self.dtype), gates, weights),
             (order, pos, here, group_sizes))
 
         if self.shared_width:
@@ -473,11 +490,15 @@ class RoutedExperts(nn.Module):
                             nn.initializers.normal(0.02), axes), name=name)
 
                 xd = u.astype(self.dtype)
-                shared = dense(h, ("mlp", "embed"), "shared_down")(
-                    nn.silu(dense(self.shared_width, ("embed", "mlp"),
-                                  "shared_gate")(xd))
-                    * dense(self.shared_width, ("embed", "mlp"),
-                            "shared_up")(xd))
+                up = dense(self.shared_width, ("embed", "mlp"),
+                           "shared_up")
+                if gated:
+                    hidden = nn.silu(dense(self.shared_width,
+                                           ("embed", "mlp"),
+                                           "shared_gate")(xd)) * up(xd)
+                else:
+                    hidden = jnp.square(nn.relu(up(xd)))
+                shared = dense(h, ("mlp", "embed"), "shared_down")(hidden)
                 out = out + shared.astype(jnp.float32)
 
         self.sow(MOE_METRICS, "tokens_here", landed.astype(jnp.float32))

@@ -40,7 +40,7 @@ from distributeddeeplearning_tpu.analysis import anatomy
 from distributeddeeplearning_tpu.config import TrainConfig, resolve_precision
 from distributeddeeplearning_tpu.models import moe
 from distributeddeeplearning_tpu.models.hyper_connections import MHC_METRICS
-from distributeddeeplearning_tpu.ops import kda
+from distributeddeeplearning_tpu.ops import kda, ssd
 from distributeddeeplearning_tpu.parallel import collectives
 from distributeddeeplearning_tpu.parallel import sharding as shardlib
 from distributeddeeplearning_tpu.parallel import zero
@@ -279,13 +279,13 @@ def _moe_metrics(sown) -> dict:
 
 
 # What layers sow for the step's metrics beside the experts' counters: the
-# collection, the metric's name, and how the layers' values become one.
-# `kda_min_chunk_log_decay` (ops/kda.py): the most negative cumulative gate
-# inside a chunk, over the delta-rule layers. `mhc_max_row_sum_gap`
-# (models/hyper_connections.py): the largest |row sum - 1| of any residual
-# mixing matrix after its Sinkhorn iterations, over tokens and
-# hyper-connections.
+# collection, the metric's name, and how the layers' values become one: the
+# least cumulative gate inside a chunk over the delta-rule layers (ops/kda.py)
+# and the least summed decay of a chunk over the state-space mixers
+# (ops/ssd.py); the largest |row sum - 1| of any residual mixing matrix after
+# its Sinkhorn iterations, over tokens and hyper-connections (mhc).
 _SOWN = ((kda.KDA_METRICS, "kda_min_chunk_log_decay", jnp.min),
+         (ssd.SSD_METRICS, "ssd_min_chunk_log_decay", jnp.min),
          (MHC_METRICS, "mhc_max_row_sum_gap", jnp.max))
 
 

@@ -224,6 +224,21 @@ CASES = [
      "unattributed"),
     (STEP + "grads/transpose(jvp(ResNet))/conv_init/conv_general_dilated",
      "backward", "unattributed"),
+    # a state-space mixer's convolution, step sizes, chunked recurrence and
+    # gated norm book under its scope, forward, recomputed and backward; its
+    # two projections and the block's norm stay outside
+    (STEP + "grads/jvp(NemotronHLM)/layer0/mixer/mamba/add", "forward",
+     "mixer_mamba"),
+    (STEP + "grads/jvp(NemotronHLM)/layer2.<lambda>/layer2/mixer/mamba/"
+     "bcgjls,bcsgjp->bclgjp/dot_general", "forward", "mixer_mamba"),
+    (STEP + "grads/transpose(jvp(NemotronHLM))/layer2/mixer/mamba/"
+     "rematted_computation/exp", "backward", "mixer_mamba"),
+    (STEP + "grads/jvp(NemotronHLM)/layer0/mixer/in_proj/dot_general",
+     "forward", "attention_other"),
+    (STEP + "grads/transpose(jvp(NemotronHLM))/layer0/mixer/out_proj/"
+     "dot_general", "backward", "attention_other"),
+    (STEP + "grads/jvp(NemotronHLM)/layer0/input_layernorm/rsqrt",
+     "forward", "layernorm"),
 ]
 
 

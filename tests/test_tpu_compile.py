@@ -524,3 +524,31 @@ def test_xing4_ep8_step_compiles_for_v5e(one_chip):
         "mhc_out_bwd": 10}
     assert all("/mhc/" in line for lines in calls.values()
                for line in lines)
+
+
+@pytest.mark.slow
+def test_nemotron3_nano_ep16_step_compiles_for_v5e(one_chip):
+    """The benchmark's nemotron3_nano cell: the whole mixed-precision AdamW
+    step of `nemotron3_nano_ep16` at one sequence of 8192 tokens. What the
+    chip's compiler says of it: it fits (8.0 GB of state: float32 masters
+    and Adam's two moments of 667M parameters, with the temporaries below
+    nine tenths of the chip), the attention layer's two flash kernels (the
+    forward and the one backward kernel, which carries dQ) are there once at
+    32 query heads of 128, and the chunked recurrence is XLA's products with
+    no loop. Marked slow: a whole step's compile, as kimi's and xing4's
+    above, is a minute or two of a tier-1 run."""
+    compiled, parameters = _compiled_share_step(
+        one_chip, "nemotron3_nano_ep16", seq=8192, vocab=16384)
+    assert parameters == 666_962_944
+    memory = compiled.memory_analysis()
+    print("state:", memory.argument_size_in_bytes, "temporaries:",
+          memory.temp_size_in_bytes)
+    assert memory.argument_size_in_bytes == pytest.approx(
+        12 * parameters, rel=0.001)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 0.9 * 16 * 1024 ** 3)
+    text = compiled.as_text()
+    for name, n in (("flash_fwd", 1), ("flash_dq", 0), ("flash_dkv", 1)):
+        assert len(re.findall(rf"%{name}\S* = ", text)) == n, name
+    assert re.search(r"%flash_fwd\S* = \(bf16\[32,8192,128\]", text)
+    assert not re.findall(r" while\(", text)
